@@ -178,23 +178,8 @@ def _check_grad(fn, case: OpTestCase, tensor_idx) -> None:
     # without x64 enabled jnp.asarray silently downcasts the f64 inputs
     # and the check produces spurious results.  Enable x64 locally so
     # validate_case is correct even outside the test suite's conftest.
-    # `jax.enable_x64` (the context manager re-exported at top level) was
-    # removed from recent jax; its home is jax.experimental, with a plain
-    # config flip as the last-resort fallback.
-    try:
-        from jax.experimental import enable_x64
-    except ImportError:
-        enable_x64 = None
-    if enable_x64 is not None:
-        with enable_x64():
-            _check_grad_x64(fn, case, tensor_idx)
-        return
-    prev = jax.config.jax_enable_x64
-    jax.config.update("jax_enable_x64", True)
-    try:
+    with jax.enable_x64(True):
         _check_grad_x64(fn, case, tensor_idx)
-    finally:
-        jax.config.update("jax_enable_x64", prev)
 
 
 def _check_grad_x64(fn, case: OpTestCase, tensor_idx) -> None:

@@ -26,6 +26,6 @@ from deeplearning4j_tpu.compile.fingerprint import (  # noqa: F401
     model_fingerprint, transform_fingerprint)
 from deeplearning4j_tpu.compile.persistent import (  # noqa: F401
     PersistentExecutableCache, as_cache, default_cache, default_cache_dir,
-    enable_jax_compilation_cache, set_default_cache)
+    place_compilation_cache, set_default_cache)
 from deeplearning4j_tpu.compile.step_cache import (  # noqa: F401
     AotStepFunction, step_function)

@@ -10,11 +10,12 @@ skip the multi-second compile stall entirely.
 
 Entry format (one file per executable, `<sha256-key>.jexe`):
 
-    DL4JXC1\n                       magic + format version
+    DL4JXC2\n                       magic + format version
     {json header}\n                 crc32 of payload, byte count, the full
                                     key parts (env fingerprint included)
     <pickle payload>                (serialized bytes, in_tree, out_tree)
                                     from jax.experimental.serialize_executable
+                                    + the ids of the devices it runs on
 
 Writes are atomic in the style of `parallel/checkpoint.py`: tmp file +
 `os.replace`, so a torn write never commits; loads verify the crc32 and
@@ -24,10 +25,10 @@ invalidation is structural: the jax/jaxlib version, backend platform,
 device population and mesh topology are hashed *into the key*, so a stale
 executable is unreachable rather than detected late.
 
-When a backend cannot serialize executables (`serialize` raises), the
-cache degrades to the process-wide JAX compilation cache directory
-(`jax_compilation_cache_dir` under `<dir>/xla-fallback`) — cold starts
-then still skip XLA's optimization passes even though tracing re-runs.
+When a backend cannot serialize executables (`serialize` raises), nothing
+is stored here; the process-wide JAX compilation cache
+(`place_compilation_cache`) still spares the next process XLA's
+optimization passes, though tracing re-runs.
 """
 from __future__ import annotations
 
@@ -43,7 +44,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 from deeplearning4j_tpu.compile.fingerprint import (canonical_json, digest,
                                                     environment_fingerprint)
 
-MAGIC = b"DL4JXC1\n"
+MAGIC = b"DL4JXC2\n"
 ENTRY_SUFFIX = ".jexe"
 
 _ENV_DIR_VAR = "DL4J_TPU_EXEC_CACHE"
@@ -75,12 +76,10 @@ class PersistentExecutableCache:
     """
 
     def __init__(self, directory: str,
-                 env: Optional[Dict[str, Any]] = None,
-                 fallback_compilation_cache: bool = True):
+                 env: Optional[Dict[str, Any]] = None):
         self.directory = os.path.expanduser(directory)
         os.makedirs(self.directory, exist_ok=True)
         self._env = env
-        self._fallback = fallback_compilation_cache
         self._serialize_ok: Optional[bool] = None   # None = not yet probed
         self._lock = threading.Lock()
         from deeplearning4j_tpu.monitor.instrument import aot_instruments
@@ -140,9 +139,15 @@ class PersistentExecutableCache:
                     header.get("parts") != _summarize(keyed):
                 raise ValueError("header key/parts mismatch — entry does "
                                  "not belong to this request")
-            serialized, in_tree, out_tree = pickle.loads(payload)
+            serialized, in_tree, out_tree, device_ids = pickle.loads(payload)
+            import jax
             from jax.experimental import serialize_executable as se
-            fn = se.deserialize_and_load(serialized, in_tree, out_tree)
+            # jax 0.9.0 loads onto EVERY device of the backend unless told
+            # which ones the executable was compiled for
+            by_id = {d.id: d for d in jax.devices()}
+            fn = se.deserialize_and_load(
+                serialized, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in device_ids])
         except Exception as e:
             self._record("errors")
             self._record("disk_misses")
@@ -160,25 +165,25 @@ class PersistentExecutableCache:
     # ---- store ----
     def store(self, parts: Dict[str, Any], compiled) -> bool:
         """Serialize `compiled` and commit it atomically under the key for
-        `parts`.  Returns False (and enables the XLA compilation-cache
-        fallback tier once) when the backend cannot serialize."""
+        `parts`.  Returns False when the backend cannot serialize."""
         if self._serialize_ok is False:
             return False
         t0 = time.perf_counter()
         try:
             from jax.experimental import serialize_executable as se
             serialized, in_tree, out_tree = se.serialize(compiled)
-            payload = pickle.dumps((serialized, in_tree, out_tree),
-                                   protocol=pickle.HIGHEST_PROTOCOL)
+            device_ids = [
+                d.id for d in compiled.runtime_executable().local_devices()]
+            payload = pickle.dumps(
+                (serialized, in_tree, out_tree, device_ids),
+                protocol=pickle.HIGHEST_PROTOCOL)
         except Exception as e:
-            # backend can't serialize executables: degrade to the
-            # process-wide XLA compilation cache (tier 2)
+            # backend can't serialize executables: recorded once, then
+            # every later store() is a no-op
             self._serialize_ok = False
             self._record("errors")
             self._instr.errors.inc()
             self._instr.note_error("serialize", e)
-            if self._fallback:
-                self.enable_fallback_tier()
             return False
         self._serialize_ok = True
         keyed = self._key_parts(parts)
@@ -232,15 +237,6 @@ class PersistentExecutableCache:
         self.store(parts, compiled)
         return compiled, "compiled"
 
-    # ---- tier 2: process-wide XLA compilation cache ----
-    def enable_fallback_tier(self) -> None:
-        """Point jax's own persistent compilation cache at a sibling
-        directory, once per process.  Executable *deserialization* beats
-        it (no tracing at all), but on backends without serialization this
-        still skips the XLA optimization passes across processes."""
-        enable_jax_compilation_cache(
-            os.path.join(self.directory, "xla-fallback"))
-
     # ---- maintenance ----
     def entries(self) -> Dict[str, Dict[str, Any]]:
         """key -> header for every committed entry (debug/tooling)."""
@@ -275,29 +271,22 @@ class PersistentExecutableCache:
             self.stats[stat] += n
 
 
-_jax_cc_enabled: Optional[str] = None
-
-
-def enable_jax_compilation_cache(directory: str) -> None:
-    """Enable jax's persistent compilation cache at `directory` (idempotent;
-    first directory wins for the process — jax's cache dir is global)."""
-    global _jax_cc_enabled
-    if _jax_cc_enabled is not None:
-        return
+def place_compilation_cache() -> str:
+    """Decide where jax's persistent compilation cache lives; entry points
+    call this once, before the first compile.  With
+    `$JAX_COMPILATION_CACHE_DIR` set jax already uses that directory and
+    nothing is set here.  Otherwise the cache goes to one fixed directory
+    inside the checkout (git-ignored): a directory named after a pid, a
+    time or `mkdtemp` is never found again by the next process.  Returns
+    the directory in effect."""
+    d = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if d:
+        return d
     import jax
-    directory = os.path.expanduser(directory)
-    os.makedirs(directory, exist_ok=True)
-    try:
-        jax.config.update("jax_compilation_cache_dir", directory)
-        # cache even sub-second compiles: the point is cross-process reuse
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        try:
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        except Exception:       # pragma: no cover - knob name drift
-            pass
-        _jax_cc_enabled = directory
-    except Exception:           # pragma: no cover - very old jax
-        _jax_cc_enabled = ""
+    d = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_compilation_cache")
+    jax.config.update("jax_compilation_cache_dir", d)
+    return d
 
 
 # ---------------------------------------------------------------------------

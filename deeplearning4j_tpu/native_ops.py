@@ -1,9 +1,10 @@
 """ctypes bindings for the C++ native runtime (native/*.cpp).
 
-Loads `native/libdl4jtpu_native.so`, building it with `make` on first use
-if the toolchain is present; every entry point has a numpy fallback so the
-framework works without the native library (the reference's nd4j-native
-fallback discipline, minus the hard JNI dependency).
+Loads `native/libdl4jtpu_native.so`, building it from `native/*.cpp` with
+`make` on first use (the binary is never committed); every entry point has
+a numpy fallback so the framework works without the native library (the
+reference's nd4j-native fallback discipline, minus the hard JNI
+dependency).  A failed build or load is reported once, as a warning.
 
 Public surface:
 - ThresholdCodec: compressed-gradient encode/decode with residual carry
@@ -16,6 +17,7 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
+import warnings
 from typing import Optional, Tuple
 
 import numpy as np
@@ -37,14 +39,20 @@ def _load() -> Optional[ctypes.CDLL]:
             os.path.join(_NATIVE_DIR, "Makefile")):
         try:
             subprocess.run(["make", "-C", _NATIVE_DIR], check=True,
-                           capture_output=True, timeout=120)
-        except Exception:
+                           capture_output=True, text=True, timeout=120)
+        except (OSError, subprocess.SubprocessError) as e:
+            warnings.warn(
+                f"native runtime not built (`make -C {_NATIVE_DIR}`: {e}"
+                f"{(getattr(e, 'stderr', None) or '')[-500:]}); using the "
+                "numpy fallbacks", RuntimeWarning)
             return None
     if not os.path.exists(_LIB_PATH):
         return None
     try:
         lib = ctypes.CDLL(_LIB_PATH)
-    except OSError:
+    except OSError as e:
+        warnings.warn(f"native runtime not loadable ({e}); using the numpy "
+                      "fallbacks", RuntimeWarning)
         return None
     lib.threshold_encode.restype = ctypes.c_int64
     lib.threshold_encode.argtypes = [

@@ -1192,11 +1192,11 @@ class ComputationGraph:
         return self
 
     # ---- persistence ----
-    def save(self, path: str, save_updater: bool = True):
+    def save(self, path, save_updater: bool = True):
         from deeplearning4j_tpu.utils.serialization import write_model
         write_model(self, path, save_updater=save_updater)
 
     @staticmethod
-    def load(path: str, load_updater: bool = True) -> "ComputationGraph":
+    def load(path, load_updater: bool = True) -> "ComputationGraph":
         from deeplearning4j_tpu.utils.serialization import read_model
         return read_model(path, load_updater=load_updater)

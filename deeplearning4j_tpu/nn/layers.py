@@ -77,23 +77,20 @@ class DenseLayer(Layer):
         act = self.activation if self.activation is not None else "identity"
         if not isinstance(act, str):
             return None
-        try:
-            from deeplearning4j_tpu.ops import pallas as tier
-            b = params.get("b") if self.has_bias else None
-            if tier.dispatch.resolve("fused_dense", x, params["W"], bias=b,
-                                     activation=act) != "pallas":
-                return None
-            rows = 1
-            for d in x.shape[:-1]:
-                rows *= int(d)
-            sc = tier.shape_class(m=rows, k=int(x.shape[-1]),
-                                  n=int(params["W"].shape[-1]))
-            return tier.matmul.fused_dense(
-                x, params["W"], bias=b, activation=act,
-                tile=tier.dispatch.get_tile("fused_dense", sc),
-                interpret=tier.dispatch.interpret_mode())
-        except Exception:
+        from deeplearning4j_tpu.ops import pallas as tier
+        b = params.get("b") if self.has_bias else None
+        if tier.dispatch.resolve("fused_dense", x, params["W"], bias=b,
+                                 activation=act) != "pallas":
             return None
+        rows = 1
+        for d in x.shape[:-1]:
+            rows *= int(d)
+        sc = tier.shape_class(m=rows, k=int(x.shape[-1]),
+                              n=int(params["W"].shape[-1]))
+        return tier.matmul.fused_dense(
+            x, params["W"], bias=b, activation=act,
+            tile=tier.dispatch.get_tile("fused_dense", sc),
+            interpret=tier.dispatch.interpret_mode())
 
     def _is_recurrent_input(self, x):
         # [batch, time, features] passes through time-distributed.

@@ -4,7 +4,8 @@ VERDICT r3 #3: ResNet-50's conv backward is 45% of step time at ~40% MXU
 (bench_artifacts/PERF_ANALYSIS.md); the prescribed experiment is a Pallas
 wgrad (or dgrad) kernel for the 3x3 stride-1 SAME shapes, A/B'd against
 XLA's lowering ON CHIP — a measured win adopts it, a measured loss gets a
-committed negative-result table (tunnel_playbook.py stage 6).
+committed negative-result table (measured on v5e, 2026-07-31: wgrad
+0.93-1.20x and dgrad 0.95-1.24x in isolation; PERF.md).
 
 Formulation: for a 3x3 stride-1 SAME conv,
 
@@ -179,10 +180,11 @@ def conv3x3_dgrad_xla(dy, w):
 # ---------------------------------------------------------------------------
 # Measured-dispatch adoption hook (the flash/fused-LN pattern): a
 # custom_vjp 3x3-s1-SAME conv whose BACKWARD routes to the Pallas
-# wgrad/dgrad kernels when the corresponding flag is on.  Default off —
-# `tunnel_playbook.py` stage 8 A/Bs the full train step with the flags
-# enabled and a measured win flips them (one line, or the
-# DL4J_TPU_CONV_BWD_PALLAS env var).
+# wgrad/dgrad kernels when the corresponding flag is on.  Default off:
+# measured on v5e, 2026-07-31, the full ResNet-50 step went from 35.6 ms
+# (XLA) to 45.4 (wgrad), 44.3 (dgrad) and 47.3 ms (both) — the custom_vjp
+# boundary breaks XLA's conv+BN+relu fusion.  The
+# DL4J_TPU_CONV_BWD_PALLAS env var turns the flags on.
 # ---------------------------------------------------------------------------
 
 import os as _os

@@ -3,11 +3,11 @@
 Every kernel in ``ops/pallas`` ships two implementations: a Pallas kernel
 parameterized by a :class:`~deeplearning4j_tpu.ops.pallas.tiles.TileConfig`
 and a pure-jnp reference that is the definition of correctness.  Call sites
-ask this module which implementation to run; the answer depends on three
-things:
+ask this module which implementation to run; the answer depends on what
+it can observe — the platform, the operands' dtypes and shapes — through:
 
-* availability — ``jax.experimental.pallas`` importable at all (a missing
-  import degrades the whole tier to reference-only instead of raising),
+* the kernel's registration — a kernel registered with ``pallas_fn=None``
+  is reference-only, whatever the mode,
 * the dispatch mode — ``auto`` (Pallas on TPU/GPU when the kernel's
   support *and* profitability predicates pass, reference everywhere else),
   ``pallas`` (force Pallas wherever the hard support predicate allows;
@@ -15,6 +15,9 @@ things:
   suite pins ``pallas == reference``), or ``reference`` (force the jnp
   lowering),
 * the kernel's own predicates, registered alongside its implementations.
+
+Once the answer is ``pallas`` the call site runs the kernel and a failure
+in it propagates: nothing here or at the call sites catches it.
 
 The mode comes from ``DL4J_TPU_KERNEL_TIER`` or :func:`set_dispatch_mode`.
 The module also owns the in-process tile table (installed by the autotuner
@@ -37,24 +40,17 @@ from deeplearning4j_tpu.ops.pallas.tiles import DEFAULT_TILES, TileConfig
 _MODES = ("auto", "pallas", "reference")
 
 _lock = threading.Lock()
-_mode: str = os.environ.get("DL4J_TPU_KERNEL_TIER", "auto")
-if _mode not in _MODES:  # bad env value: fail safe, not loud
-    _mode = "auto"
-
-_pallas_ok: Optional[bool] = None
 
 
-def pallas_available() -> bool:
-    """True when ``jax.experimental.pallas`` imports cleanly (memoized)."""
-    global _pallas_ok
-    if _pallas_ok is None:
-        try:
-            from jax.experimental import pallas  # noqa: F401
+def _env_mode() -> str:
+    mode = os.environ.get("DL4J_TPU_KERNEL_TIER", "auto")
+    if mode not in _MODES:
+        raise ValueError(
+            f"DL4J_TPU_KERNEL_TIER={mode!r}; want one of {_MODES}")
+    return mode
 
-            _pallas_ok = True
-        except Exception:
-            _pallas_ok = False
-    return _pallas_ok
+
+_mode: str = _env_mode()
 
 
 def on_accelerator() -> bool:
@@ -119,7 +115,7 @@ def resolve(name: str, *args: Any, **kwargs: Any) -> str:
     """Pick ``"pallas"`` or ``"reference"`` for one call and record it."""
     spec = _registry.get(name)
     impl = "reference"
-    if spec is not None and spec.pallas_fn is not None and pallas_available():
+    if spec is not None and spec.pallas_fn is not None:
         mode = _mode
         if mode != "reference":
             ok = spec.supports is None or bool(spec.supports(*args, **kwargs))
@@ -196,9 +192,7 @@ def reset() -> None:
     """Test hook: restore env-derived mode and drop installed tiles."""
     global _mode, _kv_dtype
     with _lock:
-        _mode = os.environ.get("DL4J_TPU_KERNEL_TIER", "auto")
-        if _mode not in _MODES:
-            _mode = "auto"
+        _mode = _env_mode()
         _tiles.clear()
         _kv_dtype = "f32"
 
@@ -207,13 +201,12 @@ def kernel_tier_fingerprint() -> Dict[str, Any]:
     """Stable description of the tier config, folded into AOT cache keys.
 
     Distinguishes reference programs from Pallas-default programs from
-    autotuned-tile programs: any change in mode, availability, any
-    installed tile, or the decode KV-cache dtype changes the fingerprint
+    autotuned-tile programs: any change in mode, any installed tile, or
+    the decode KV-cache dtype changes the fingerprint
     (an f32-KV and an int8-KV decode program never share an AOT entry).
     """
     return {
         "mode": _mode,
-        "pallas": pallas_available(),
         "tiles": {k: cfg.to_json() for k, cfg in sorted(_tiles.items())},
         "kv_dtype": _kv_dtype,
     }

@@ -27,19 +27,16 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from deeplearning4j_tpu.ops.pallas.tiles import DEFAULT_TILES, TileConfig
 from deeplearning4j_tpu.ops.quant_kernels import dequant_epilogue
 
-try:  # degrade to reference-only dispatch when pallas is unavailable
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover - exercised via dispatch tests
-    pl = None
-    pltpu = None
 
 #: Epilogue activations.  Both the kernel epilogue and the reference call
-#: these same functions, so conformance is a pure tiling question.
+#: these same functions, so conformance is a pure tiling question — except
+#: "gelu", for which the kernel calls `_gelu_kernel` below.
 EPILOGUE_ACTIVATIONS: Dict[str, Any] = {
     "identity": lambda y: y,
     "linear": lambda y: y,
@@ -49,6 +46,23 @@ EPILOGUE_ACTIVATIONS: Dict[str, Any] = {
     # exact erf form, matching ops.activations.gelu
     "gelu": lambda y: jax.nn.gelu(y, approximate=False),
 }
+
+
+def _gelu_kernel(y):
+    """Exact-form gelu for the kernel epilogue.  Mosaic (jax 0.9.0) lowers
+    neither `erf` nor `erfc` ("NotImplementedError: Unimplemented primitive
+    in Pallas TPU lowering for KernelType.TC: erfc"), so erf is evaluated
+    by Abramowitz & Stegun 7.1.26 (|error| <= 1.5e-7, f32 round-off) from
+    ops Mosaic does lower; the reference keeps `jax.nn.gelu`."""
+    z = jnp.abs(y) * 0.7071067811865476
+    t = 1.0 / (1.0 + 0.3275911 * z)
+    poly = t * (0.254829592 + t * (-0.284496736 + t * (
+        1.421413741 + t * (-1.453152027 + t * 1.061405429))))
+    erf_abs = 1.0 - poly * jnp.exp(-z * z)
+    return 0.5 * y * (1.0 + jnp.where(y < 0.0, -erf_abs, erf_abs))
+
+
+_KERNEL_ACTIVATIONS = {**EPILOGUE_ACTIVATIONS, "gelu": _gelu_kernel}
 
 _FLOAT_DTYPES = (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16))
 
@@ -107,7 +121,7 @@ def _matmul_kernel(x_ref, w_ref, *rest, nk, acc_dtype, compute_dtype,
             if has_bias:
                 y = y + bias_ref[...].astype(jnp.float32)
         if activation is not None:
-            y = EPILOGUE_ACTIVATIONS[activation](y)
+            y = _KERNEL_ACTIVATIONS[activation](y)
         out_ref[...] = y.astype(out_ref.dtype)
 
 
@@ -155,6 +169,9 @@ def _tiled_matmul(x2, w, *, scale=None, bias=None, activation=None,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.dtype(out_dtype)),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
+        # the accumulator is carried across K only
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*inputs)
     if (Mp, Np) != (M, N):

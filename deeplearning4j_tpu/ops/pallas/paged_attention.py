@@ -13,10 +13,11 @@ layout, shipped under the PR-13 two-implementation contract:
   tables followed by masked softmax.  It IS the spec; the conformance
   suite pins the Pallas kernel against it on CPU.
 - :func:`paged_attention` — a Pallas kernel whose grid walks
-  ``(batch, head, page)`` with the block tables and sequence lengths in
-  scalar-prefetch memory, so each grid step DMAs exactly one page
-  (``pl.BlockSpec`` index maps read the block table to find it) and
-  folds it into a running online softmax held in VMEM scratch.  No
+  ``(batch, page)`` with the block tables and sequence lengths in
+  scalar-prefetch memory, so each grid step DMAs exactly one page, all
+  heads of it (``pl.BlockSpec`` index maps read the block table to find
+  it), and folds it head by head into a running online softmax held in
+  VMEM scratch.  No
   per-sequence padding to a max length ever materializes.
 
 Int8 KV pages ride through the PR-10 quantization seam: pages may be
@@ -50,15 +51,11 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from deeplearning4j_tpu.ops.pallas.tiles import DEFAULT_TILES, TileConfig
 
-try:  # degrade to reference-only dispatch when pallas is unavailable
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover - exercised via dispatch tests
-    pl = None
-    pltpu = None
 
 #: Matches ops.attention_kernels.NEG_INF — masked logits, not -jnp.inf,
 #: so fully-masked tails stay NaN-free.
@@ -122,9 +119,9 @@ def _paged_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref, *rest,
         ks_ref = vs_ref = None
         out_ref, acc_ref, m_ref, l_ref = rest
     b = pl.program_id(0)
-    p = pl.program_id(2)
-    n_pages = pl.num_programs(2)
-    page = k_ref.shape[1]
+    p = pl.program_id(1)
+    n_pages = pl.num_programs(1)
+    page, H = k_ref.shape[1], k_ref.shape[2]
 
     @pl.when(p == 0)
     def _init():
@@ -137,29 +134,31 @@ def _paged_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref, *rest,
 
     @pl.when(start < seq_len)
     def _accumulate():
-        qv = q_ref[0].astype(jnp.float32)                  # (1, D)
-        kb = k_ref[0, :, 0, :]                             # (page, D)
-        vb = v_ref[0, :, 0, :]
-        kb = dequant_rows(kb, ks_ref[0, :, 0] if quantized else None)
-        vb = dequant_rows(vb, vs_ref[0, :, 0] if quantized else None)
-        s = jnp.dot(qv, kb.T,
-                    preferred_element_type=jnp.float32) * sm_scale
         idx = jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
-        s = jnp.where(start + idx < seq_len, s, NEG_INF)   # (1, page)
-        m_prev = m_ref[0, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s))
-        corr = jnp.exp(m_prev - m_new)
-        w = jnp.exp(s - m_new)                             # (1, page)
-        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-            w, vb, preferred_element_type=jnp.float32)
-        l_ref[0, 0] = l_ref[0, 0] * corr + jnp.sum(w)
-        m_ref[0, 0] = m_new
+        live = start + idx < seq_len                       # (1, page)
+        for h in range(H):             # static: one page serves every head
+            qv = q_ref[0, h:h + 1, :].astype(jnp.float32)  # (1, D)
+            kb = dequant_rows(k_ref[0, :, h, :],           # (page, D)
+                              ks_ref[0, :, h] if quantized else None)
+            vb = dequant_rows(v_ref[0, :, h, :],
+                              vs_ref[0, :, h] if quantized else None)
+            s = jnp.dot(qv, kb.T,
+                        preferred_element_type=jnp.float32) * sm_scale
+            s = jnp.where(live, s, NEG_INF)                # (1, page)
+            m_prev = m_ref[h:h + 1, :]                     # (1, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)
+            w = jnp.exp(s - m_new)                         # (1, page)
+            acc_ref[h:h + 1, :] = acc_ref[h:h + 1, :] * corr + jnp.dot(
+                w, vb, preferred_element_type=jnp.float32)
+            l_ref[h:h + 1, :] = (l_ref[h:h + 1, :] * corr
+                                 + jnp.sum(w, axis=-1, keepdims=True))
+            m_ref[h:h + 1, :] = m_new
 
     @pl.when(p == n_pages - 1)
     def _finalize():
-        norm = jnp.maximum(l_ref[0, 0], 1e-37)             # seq_len >= 1
-        out_ref[...] = (acc_ref[...] / norm).reshape(
-            out_ref.shape).astype(out_ref.dtype)
+        norm = jnp.maximum(l_ref[...], 1e-37)              # seq_len >= 1
+        out_ref[0] = (acc_ref[...] / norm).astype(out_ref.dtype)
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
@@ -177,33 +176,33 @@ def paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
     block_tables = block_tables.astype(jnp.int32)
     seq_lens = seq_lens.astype(jnp.int32)
 
-    def page_map(b, h, p, bt, sl):
-        return (bt[b, p], 0, h, 0)
+    # Blocks span all H heads: Mosaic wants the last two block dims equal
+    # to the array's (or multiples of the 8x128 tile), and H is second-minor
+    # in q [B, H, D], the pages [P, page, H, D] and the scales [P, page, H].
+    def page_map(b, p, bt, sl):
+        return (bt[b, p], 0, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, D), lambda b, h, p, bt, sl: (b, h, 0)),
-        pl.BlockSpec((1, page, 1, D), page_map),
-        pl.BlockSpec((1, page, 1, D), page_map),
+        pl.BlockSpec((1, H, D), lambda b, p, bt, sl: (b, 0, 0)),
+        pl.BlockSpec((1, page, H, D), page_map),
+        pl.BlockSpec((1, page, H, D), page_map),
     ]
     args = [q, k_pages, v_pages]
     if quantized:
         in_specs += [
-            pl.BlockSpec((1, page, 1), lambda b, h, p, bt, sl:
-                         (bt[b, p], 0, h)),
-            pl.BlockSpec((1, page, 1), lambda b, h, p, bt, sl:
-                         (bt[b, p], 0, h)),
+            pl.BlockSpec((1, page, H), lambda b, p, bt, sl: (bt[b, p], 0, 0)),
+            pl.BlockSpec((1, page, H), lambda b, p, bt, sl: (bt[b, p], 0, 0)),
         ]
         args += [k_scales, v_scales]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, H, max_pages),
+        grid=(B, max_pages),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, D), lambda b, h, p, bt, sl:
-                               (b, h, 0)),
+        out_specs=pl.BlockSpec((1, H, D), lambda b, p, bt, sl: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((1, D), jnp.float32),   # online-softmax accumulator
-            pltpu.VMEM((1, 1), jnp.float32),   # running max
-            pltpu.VMEM((1, 1), jnp.float32),   # running normalizer
+            pltpu.VMEM((H, D), jnp.float32),   # online-softmax accumulator
+            pltpu.VMEM((H, 1), jnp.float32),   # running max
+            pltpu.VMEM((H, 1), jnp.float32),   # running normalizer
         ],
     )
     kernel = functools.partial(_paged_kernel, sm_scale=sm,

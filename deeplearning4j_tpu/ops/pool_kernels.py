@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-# measured adoption only (tunnel_playbook stage 11); the env override
+# measured adoption only (never timed on the chip); the env override
 # mirrors CONV_BWD_PALLAS's discipline in conv_kernels.py
 import os as _os
 

@@ -155,15 +155,10 @@ def dequant_epilogue(y, scale, bias=None, out_dtype=None):
 
 
 def _tier_resolve(kernel, *args, **kwargs):
-    """Ask the fused-kernel tier which implementation this call gets.
-
-    Returns ("reference", None) when the tier is unavailable so the pure
-    jnp path below never depends on `ops.pallas` importing."""
-    try:
-        from deeplearning4j_tpu.ops import pallas as tier
-        return tier.dispatch.resolve(kernel, *args, **kwargs), tier
-    except Exception:
-        return "reference", None
+    """Ask the fused-kernel tier which implementation this call gets
+    (imported here: `ops.pallas.matmul` imports this module)."""
+    from deeplearning4j_tpu.ops import pallas as tier
+    return tier.dispatch.resolve(kernel, *args, **kwargs), tier
 
 
 def _matmul_shape_class(x, n_out: int):

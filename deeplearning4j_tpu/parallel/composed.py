@@ -32,9 +32,8 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from deeplearning4j_tpu.parallel.mesh import shard_map
 
 from deeplearning4j_tpu.parallel.ring_attention import ring_attention
 

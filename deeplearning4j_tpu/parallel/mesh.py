@@ -16,21 +16,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:                                     # jax >= 0.6: top-level export
-    from jax import shard_map as _jax_shard_map
-    _SHARD_MAP_LEGACY = False
-except ImportError:                      # older jax: experimental module,
-    from jax.experimental.shard_map import (  # check_rep instead of
-        shard_map as _jax_shard_map)          # check_vma
-    _SHARD_MAP_LEGACY = True
-
-
-def shard_map(f, *args, **kwargs):
-    """`jax.shard_map` across jax versions (maps check_vma -> check_rep)."""
-    if _SHARD_MAP_LEGACY and "check_vma" in kwargs:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    return _jax_shard_map(f, *args, **kwargs)
-
 
 @dataclasses.dataclass(frozen=True)
 class MeshSpec:

@@ -27,7 +27,7 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from deeplearning4j_tpu.parallel.mesh import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

@@ -69,8 +69,10 @@ def _flat_to_tree(template: Any, buf: bytes, manifest):
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
-def write_model(net, path: str, save_updater: bool = True,
+def write_model(net, path, save_updater: bool = True,
                 normalizer=None) -> None:
+    """`path` is a file name or a seekable binary file object (the
+    reference's `writeModel` likewise takes a File or an OutputStream)."""
     params_buf, params_manifest = _tree_to_flat(net.params_)
     state_buf, state_manifest = _tree_to_flat(net.state_)
     manifest = {
@@ -95,10 +97,11 @@ def write_model(net, path: str, save_updater: bool = True,
             z.writestr(NORMALIZER_BIN, normalizer.to_bytes())
 
 
-def read_model(path: str, load_updater: bool = True):
+def read_model(path, load_updater: bool = True):
     """Restore either model class; dispatch on the config `format` tag (the
     reference's ModelSerializer likewise restores MultiLayerNetwork or
-    ComputationGraph from one zip format)."""
+    ComputationGraph from one zip format).  `path` is a file name or a
+    seekable binary file object."""
     from deeplearning4j_tpu.nn.multilayer import (
         MultiLayerConfiguration, MultiLayerNetwork)
     from deeplearning4j_tpu.nn.graph import (

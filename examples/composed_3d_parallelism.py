@@ -29,9 +29,10 @@ if os.environ.get("JAX_PLATFORMS") == "cpu":
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
             + " --xla_force_host_platform_device_count=8").strip()
-if os.environ.get("JAX_PLATFORMS"):
-    import jax                                             # noqa: E402
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
+from deeplearning4j_tpu.compile import (                 # noqa: E402
+    place_compilation_cache)
+
+place_compilation_cache()
 
 import jax                                                 # noqa: E402
 import jax.numpy as jnp                                    # noqa: E402

@@ -23,6 +23,11 @@ import tempfile
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
 
+from deeplearning4j_tpu.compile import (                 # noqa: E402
+    place_compilation_cache)
+
+place_compilation_cache()
+
 import numpy as np                                         # noqa: E402
 
 STEPS, N_IN, N_OUT, GLOBAL_BATCH = 20, 16, 3, 12

@@ -28,6 +28,11 @@ import time
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
 
+from deeplearning4j_tpu.compile import (                 # noqa: E402
+    place_compilation_cache)
+
+place_compilation_cache()
+
 import numpy as np                                         # noqa: E402
 
 N_IN, N_OUT, HOSTS = 8, 3, ("h1", "h2", "h3")

@@ -21,6 +21,11 @@ import tempfile
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
 
+from deeplearning4j_tpu.compile import (                 # noqa: E402
+    place_compilation_cache)
+
+place_compilation_cache()
+
 import numpy as np                                         # noqa: E402
 
 STEPS, BATCH, N_IN = 80, 32, 16

@@ -474,6 +474,43 @@ def test_wrapper_apply_schedule_toggles_zero1():
 
 
 # ---------------------------------------------------------------------------
+# jax's own compilation cache: placed once, from outside or in the checkout
+# ---------------------------------------------------------------------------
+
+def test_place_compilation_cache_leaves_env_choice_alone(monkeypatch,
+                                                         tmp_path):
+    from deeplearning4j_tpu.compile import place_compilation_cache
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
+    before = jax.config.jax_compilation_cache_dir
+    assert place_compilation_cache() == str(tmp_path / "cc")
+    assert jax.config.jax_compilation_cache_dir == before   # nothing set
+    assert not (tmp_path / "cc").exists()                   # nor created
+
+
+def test_place_compilation_cache_fixed_in_checkout_path():
+    """Unset, two fresh processes agree on one directory inside the
+    checkout — never a temporary name, pid or timestamp."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=repo)
+    code = ("import jax\n"
+            "from deeplearning4j_tpu.compile import place_compilation_cache\n"
+            "d = place_compilation_cache()\n"
+            "assert jax.config.jax_compilation_cache_dir == d\n"
+            "print(d)\n")
+
+    def run():
+        p = subprocess.run([sys.executable, "-c", code], env=env, cwd="/",
+                           capture_output=True, text=True, timeout=300)
+        assert p.returncode == 0, p.stderr[-2000:]
+        return p.stdout.strip().splitlines()[-1]
+
+    first, second = run(), run()
+    assert first == second == os.path.join(repo, ".jax_compilation_cache")
+
+
+# ---------------------------------------------------------------------------
 # slow lane: true cross-process warm restart
 # ---------------------------------------------------------------------------
 

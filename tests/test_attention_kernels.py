@@ -6,7 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from deeplearning4j_tpu.parallel.mesh import shard_map
+from jax import shard_map
 
 from deeplearning4j_tpu.ops.attention_kernels import (
     blockwise_attention, flash_attention_tpu, fused_attention, mha_reference)

@@ -2,8 +2,9 @@
 CI; VERDICT r3 #7 — `keras_import_and_serving.py` exercises the longest
 dependency chain in the repo and must not rot silently).
 
-Each example self-bootstraps onto CPU and is documented to finish in
-under a minute; a nonzero exit fails with the script's tail."""
+Each example runs where JAX_PLATFORMS puts it (cpu here) and is
+documented to finish in under a minute; a nonzero exit fails with the
+script's tail."""
 import os
 import subprocess
 import sys

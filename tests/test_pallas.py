@@ -12,9 +12,9 @@ correctness — behind one dispatch layer.  These tests pin:
   variants allow 1-ulp-scale drift because XLA may contract the
   `y*scale + b` epilogue into an FMA inside the kernel.
 - dispatch: CPU always gets the reference in auto mode; forced `pallas`
-  mode runs interpret-mode kernels on CPU; a missing
-  `jax.experimental.pallas` degrades to reference-only instead of
-  breaking; decisions are counted in `ops_kernel_dispatch_total`.
+  mode runs interpret-mode kernels on CPU; a kernel that raises
+  propagates to the caller; a bad `DL4J_TPU_KERNEL_TIER` raises;
+  decisions are counted in `ops_kernel_dispatch_total`.
 - tiles: TileAutotuner grid+greedy search, memoization, persistence via
   the per-device tile table, zero re-search on replay (cache-hit metric),
   and `kernel_tier_fingerprint` splitting AOT keys on mode/tile changes.
@@ -304,16 +304,37 @@ def test_dispatch_forced_pallas_respects_hard_supports():
                             mask=bad_mask) == "reference"
 
 
-def test_dispatch_missing_pallas_degrades_to_reference(monkeypatch):
-    """CI-hygiene satellite: without jax.experimental.pallas the tier must
-    answer `reference` everywhere — even forced — not raise."""
+def test_kernel_failure_propagates_in_forced_mode(monkeypatch):
+    """Once dispatch says `pallas`, a kernel that raises is not replaced by
+    the reference: the error reaches the caller of `DenseLayer.apply` and
+    `fused_attention`."""
+    from deeplearning4j_tpu.nn.core import InputType
+    from deeplearning4j_tpu.nn.layers import DenseLayer
+    from deeplearning4j_tpu.ops.attention_kernels import fused_attention
+
+    def boom(*a, **kw):
+        raise RuntimeError("mosaic refused")
+
     rng = _rng(17)
-    xq, wq, ws = _int8_case(rng)
-    monkeypatch.setattr(dispatch, "_pallas_ok", False)
     dispatch.set_dispatch_mode("pallas")
-    assert not dispatch.pallas_available()
-    assert dispatch.resolve("int8_matmul", xq, wq, ws) == "reference"
-    assert kernel_tier_fingerprint()["pallas"] is False
+    monkeypatch.setattr(pm, "fused_dense", boom)
+    layer = DenseLayer(n_out=128, activation="relu")
+    params, state, _ = layer.initialize(jax.random.PRNGKey(0),
+                                        InputType.feed_forward(128))
+    x = jnp.asarray(rng.randn(8, 128).astype(np.float32))
+    with pytest.raises(RuntimeError, match="mosaic refused"):
+        layer.apply(params, state, x)
+    monkeypatch.setattr(pa, "flash_attention", boom)
+    q, k, v = _qkv(rng, 1, 1, 64, 64, 64)
+    with pytest.raises(RuntimeError, match="mosaic refused"):
+        fused_attention(q, k, v)
+
+
+def test_bad_kernel_tier_env_raises(monkeypatch):
+    monkeypatch.setenv("DL4J_TPU_KERNEL_TIER", "fastest")
+    with pytest.raises(ValueError, match="DL4J_TPU_KERNEL_TIER"):
+        dispatch.reset()
+    monkeypatch.delenv("DL4J_TPU_KERNEL_TIER")
 
 
 def test_dispatch_decisions_counted():

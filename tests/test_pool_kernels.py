@@ -2,7 +2,7 @@
 
 Reference role: cuDNN PoolingBackward in CudnnSubsamplingHelper; here the
 taps VJP is the TPU-shaped alternative, adopted only on measurement
-(tunnel_playbook stage 11)."""
+(it has never been timed on the chip)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
