@@ -196,6 +196,13 @@ class DevicePrefetchIterator(DataSetIterator):
     underlying iterator is reset and replayed past the batches already
     delivered, so the consumer sees an uninterrupted batch sequence.
     Retries assume a deterministic, restartable underlying iterator.
+
+    Each batch leaves two intervals in the monitor's ring
+    (`monitor.recorded`), both on the consumer's thread and both with
+    ``n`` = the batch's ordinal since this ``__iter__`` began:
+    ``input_wait`` (blocked on the producer thread's queue) and
+    ``input_stage`` (``stage()``: the host-to-device puts, or the sharded
+    placement under ``ParallelWrapper.fit_prefetched``).
     """
 
     def __init__(self, underlying: DataSetIterator, depth: int = 2,
@@ -251,6 +258,7 @@ class DevicePrefetchIterator(DataSetIterator):
 
     def __iter__(self):
         from deeplearning4j_tpu.monitor.instrument import pipeline_instruments
+        from deeplearning4j_tpu.monitor.spans import note
         ins = pipeline_instruments()
         buf: collections.deque = collections.deque()
         state = {"it": iter(self._async), "delivered": 0, "attempts": 0}
@@ -280,9 +288,12 @@ class DevicePrefetchIterator(DataSetIterator):
                 except StopIteration:
                     break
                 state["delivered"] += 1
-                wait = time.perf_counter() - t0
+                t1 = time.perf_counter()
                 buf.append(stage(ds, counting_put))
-                ins.record_stage(wait, len(buf))
+                n = state["delivered"] - 1
+                note("input_wait", t0, t1, n)
+                note("input_stage", t1, time.perf_counter(), n)
+                ins.record_stage(t1 - t0, len(buf))
                 if len(buf) >= self.depth:
                     yield buf.popleft()
                     ins.prefetch_depth.set(len(buf))

@@ -9,8 +9,13 @@ and PerformanceTracker (SURVEY.md §5.1), collapsed into:
                   p50/p95/p99, labeled series, thread-safe, near-zero
                   cost when idle (`set_enabled(False)` kill-switch)
     spans       — `span("fit_epoch")` host wall-time regions, nested,
-                  forwarded into `jax.profiler.TraceAnnotation` so host
-                  spans line up with the XLA device trace
+                  into the `span_ms` histogram; `note(name, t0, t1, n)`
+                  for sites that hold both clock reads; both land in a
+                  ring of the last 8,192 intervals on `time.perf_counter`
+                  (`recorded`, `clear_recorded`), which the benchmark lays
+                  over the device trace's idle gaps.  `span` also feeds
+                  `jax.profiler.TraceAnnotation`, visible only in a
+                  capture with the profiler's host tracer on
     instrument  — cached hot-path handle bundles (training / pipeline /
                   parallel) and the metric-name contract
 
@@ -24,4 +29,4 @@ from deeplearning4j_tpu.monitor.registry import (  # noqa: F401
     Counter, Gauge, Histogram, MetricsRegistry, enabled, registry,
     set_enabled)
 from deeplearning4j_tpu.monitor.spans import (  # noqa: F401
-    current_span, span, span_stack)
+    clear_recorded, current_span, note, recorded, span, span_stack)

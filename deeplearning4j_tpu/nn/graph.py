@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deeplearning4j_tpu.monitor.instrument import TrainingInstruments
-from deeplearning4j_tpu.monitor.spans import span
+from deeplearning4j_tpu.monitor.spans import note, span
 from deeplearning4j_tpu.nn.core import InputType, Layer, PyTree
 from deeplearning4j_tpu.nn.multilayer import _add_scaled_where, _masked_leaves
 from deeplearning4j_tpu.train.updaters import (
@@ -866,8 +866,10 @@ class ComputationGraph:
         it_dev, ep_dev = device_counters(self)
         self.params_, self.opt_state_, new_it = astep(
             self.params_, self.opt_state_, combined, it_dev, ep_dev)
+        t1 = time.perf_counter()
+        note("step_dispatch", t0, t1, self.iteration)
         ins = self._instruments()
-        ins.record_dispatch(time.perf_counter() - t0)
+        ins.record_dispatch(t1 - t0)
         ins.check_compile(gstep, self)
         ins.check_compile(astep, self)
         self._score = loss
@@ -932,8 +934,10 @@ class ComputationGraph:
          losses, last_loss) = step((self.params_, self.state_,
                                     self.opt_state_, self._rng, it_dev),
                                    ep_dev, (inputs, labels, lmasks))
+        t1 = time.perf_counter()
+        note("step_dispatch", t0, t1, self.iteration)
         ins = self._instruments()
-        ins.record_dispatch(time.perf_counter() - t0, steps=int(k))
+        ins.record_dispatch(t1 - t0, steps=int(k))
         ins.check_compile(step, self)
         self._score = last_loss
         self._last_batch_size = int(next(iter(inputs.values())).shape[1])
@@ -1046,8 +1050,10 @@ class ComputationGraph:
          new_it) = step(
             self.params_, self.state_, self.opt_state_, inputs, labels,
             lmasks, self._rng, it_dev, ep_dev)
+        t1 = time.perf_counter()
+        note("step_dispatch", t0, t1, self.iteration)
         ins = self._instruments()
-        ins.record_dispatch(time.perf_counter() - t0)
+        ins.record_dispatch(t1 - t0)
         ins.check_compile(step, self)
         self._score = loss
         self._last_batch_size = int(next(iter(inputs.values())).shape[0])

@@ -16,12 +16,14 @@ TPU-native design choices:
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deeplearning4j_tpu.monitor.spans import note, span
 from deeplearning4j_tpu.ops.attention_kernels import fused_attention
 from deeplearning4j_tpu.train.updaters import Adam, IUpdater
 
@@ -225,11 +227,12 @@ class BertModel:
         for _ in range(epochs):
             if hasattr(iterator, "reset"):
                 iterator.reset()
-            if fused_steps > 1:
-                self._fit_epoch_fused(iterator, fused_steps)
-            else:
-                for mds in iterator:
-                    self.fit_batch(mds)
+            with span("fit_epoch", model=type(self).__name__):
+                if fused_steps > 1:
+                    self._fit_epoch_fused(iterator, fused_steps)
+                else:
+                    for mds in iterator:
+                        self.fit_batch(mds)
             self.epoch += 1
         return self
 
@@ -259,6 +262,7 @@ class BertModel:
         ids, input_mask = [jnp.asarray(f) for f in mds.features]
         (labels,) = [jnp.asarray(l) for l in mds.labels]
         it, ep = device_counters(self)
+        t0 = time.perf_counter()
         if mds.labels_masks is not None:                 # masked LM
             lmask = jnp.asarray(mds.labels_masks[0])
             step = self._step("mlm")
@@ -270,6 +274,7 @@ class BertModel:
             self.params_, self.opt_state_, loss, new_it = step(
                 self.params_, self.opt_state_, it, ep,
                 ids.astype(jnp.int32), input_mask, labels)
+        note("step_dispatch", t0, time.perf_counter(), self.iteration)
         self._score = loss
         advance(self, new_it)
         # return the device-side loss WITHOUT forcing a D2H sync: a per-step
@@ -290,6 +295,7 @@ class BertModel:
         k = check_steps_axes([("ids", ids), ("input_mask", input_mask),
                               ("labels", labels), ("labels_mask", lm0)])
         it, ep = device_counters(self)
+        t0 = time.perf_counter()
         if mds.labels_masks is not None:                 # masked LM
             lmask = lm0
             step = self._scan_step("mlm")
@@ -301,6 +307,7 @@ class BertModel:
             (self.params_, self.opt_state_, new_it), losses, last_loss = step(
                 (self.params_, self.opt_state_, it), ep,
                 (ids.astype(jnp.int32), input_mask, labels))
+        note("step_dispatch", t0, time.perf_counter(), self.iteration)
         self._score = last_loss
         advance(self, new_it, steps=int(k))
         return losses

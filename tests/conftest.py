@@ -22,3 +22,15 @@ import jax  # noqa: E402
 # in which case the env var alone is too late — set the config directly.
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_enable_x64", True)
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture
+def ring():
+    """The monitor's ring of recorded host intervals, empty before the test
+    and after it (`monitor/spans.py`; the ring is process-wide)."""
+    from deeplearning4j_tpu.monitor import clear_recorded
+    clear_recorded()
+    yield
+    clear_recorded()

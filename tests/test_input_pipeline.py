@@ -375,3 +375,102 @@ def test_parallel_wrapper_fit_prefetched():
     pw.fit_prefetched(ListDataSetIterator(list(batches)), epochs=1,
                       fused_steps=2)
     assert np.isfinite(float(net.score()))
+
+
+# ---------------------------------------------------------------------------
+# Host spans in the monitor's ring (monitor/spans.py)
+# ---------------------------------------------------------------------------
+
+def _named(records, name):
+    return [r for r in records if r.name == name]
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_prefetch_pass_records_wait_and_stage_per_batch(ring, depth):
+    from deeplearning4j_tpu.monitor import recorded
+    k = 5
+    pf = DevicePrefetchIterator(ListDataSetIterator(_batches(k)), depth=depth)
+    t_before = time.perf_counter()
+    assert len(list(pf)) == k
+    t_after = time.perf_counter()
+    pf.close()
+    got = recorded()
+    waits, stages = _named(got, "input_wait"), _named(got, "input_stage")
+    assert [r.n for r in waits] == [r.n for r in stages] == list(range(k))
+    assert {r.name for r in got} == {"input_wait", "input_stage"}
+    me = threading.get_ident()         # both on the consumer's thread
+    assert {r.thread_ident for r in got} == {me}
+    for w, s in zip(waits, stages):
+        # the stage of a batch starts on the read that ends its wait
+        assert t_before <= w.t0 <= w.t1 == s.t0 <= s.t1 <= t_after
+        assert w.parent is None and s.parent is None
+    for a, b in zip(stages, waits[1:]):
+        assert a.t1 <= b.t0            # batches follow one another
+
+
+def _mln_case():
+    return _mlp(), [DataSet(b.features, b.labels) for b in _batches(4)]
+
+
+def _graph_case():
+    from deeplearning4j_tpu.nn import ComputationGraph, GraphBuilder
+    conf = (GraphBuilder().seed(3).add_inputs("in")
+            .set_input_types(InputType.feed_forward(6))
+            .add_layer("d", DenseLayer(n_out=8, activation="relu"), "in")
+            .add_layer("out", OutputLayer(n_out=3, loss="mcxent",
+                                          activation="softmax"), "d")
+            .set_outputs("out").build())
+    return ComputationGraph(conf).init(), _batches(4)
+
+
+def _bert_case():
+    from deeplearning4j_tpu.data.dataset import MultiDataSet
+    from deeplearning4j_tpu.train.updaters import Adam
+    from deeplearning4j_tpu.zoo import BertConfig, BertModel
+    rng = np.random.RandomState(0)
+    batches = [MultiDataSet(
+        features=[rng.randint(0, 100, (4, 16)).astype(np.int32),
+                  np.ones((4, 16), np.float32)],
+        labels=[rng.randint(0, 100, (4, 16)).astype(np.int32)],
+        labels_masks=[(rng.rand(4, 16) < 0.2).astype(np.float32)])
+        for _ in range(4)]
+    return BertModel(BertConfig.tiny(), seed=0, updater=Adam(1e-3)), batches
+
+
+@pytest.mark.parametrize("case", [_mln_case, _graph_case, _bert_case],
+                         ids=["MultiLayerNetwork", "ComputationGraph",
+                              "BertModel"])
+def test_fit_records_one_step_dispatch_per_batch_under_fit_epoch(ring, case):
+    from deeplearning4j_tpu.monitor import recorded
+    model, batches = case()
+    k = len(batches)
+    start = model.iteration
+    model.fit(DevicePrefetchIterator(ListDataSetIterator(batches)))
+    got = recorded()
+    (epoch,) = _named(got, "fit_epoch")
+    steps = _named(got, "step_dispatch")
+    assert len(steps) == k
+    # n is the model's iteration count before the step ...
+    assert [r.n for r in steps] == list(range(start, start + k))
+    for r in steps:
+        assert r.parent == "fit_epoch"
+        assert r.thread_ident == epoch.thread_ident
+        assert epoch.t0 <= r.t0 <= r.t1 <= epoch.t1
+    # ... and the k-th dispatch of the epoch consumes the batch whose input
+    # spans carry n = k: staged before it is dispatched, on the fit thread,
+    # inside the same fit_epoch
+    stages = _named(got, "input_stage")
+    assert [r.n for r in stages] == list(range(k))
+    for s, d in zip(stages, steps):
+        assert s.t1 <= d.t0
+        assert s.parent == "fit_epoch" and s.thread_ident == d.thread_ident
+    assert len(_named(got, "input_wait")) == k
+
+
+def test_fused_dispatch_is_one_step_dispatch_span(ring):
+    from deeplearning4j_tpu.monitor import recorded
+    net, batches = _mln_case()
+    net.fit(ListDataSetIterator(batches), fused_steps=2)
+    steps = _named(recorded(), "step_dispatch")
+    assert [r.n for r in steps] == [0, 2]      # two dispatches of two steps
+    assert net.iteration == 4

@@ -2,15 +2,18 @@
 exposition, the UIServer `/metrics` endpoint, and the end-to-end acceptance
 path (fit + prefetch + serving all visible in one scrape)."""
 import threading
+import time
 import urllib.request
 
 import numpy as np
 import pytest
 
 from deeplearning4j_tpu.monitor import (Counter, Gauge, Histogram,
-                                        MetricsRegistry, current_span,
-                                        enabled, registry, set_enabled,
-                                        span, span_stack)
+                                        MetricsRegistry, clear_recorded,
+                                        current_span, enabled, note, recorded,
+                                        registry, set_enabled, span,
+                                        span_stack)
+from deeplearning4j_tpu.monitor import spans as spans_mod
 from deeplearning4j_tpu.monitor.instrument import TrainingInstruments
 
 
@@ -172,6 +175,138 @@ def test_span_disabled_is_a_noop():
     finally:
         set_enabled(True)
     assert reg.get("span_ms", {"span": "quiet"}) is None
+
+
+# ---------------------------------------------------------------------------
+# The ring of recorded intervals
+# ---------------------------------------------------------------------------
+
+def test_note_and_span_land_in_the_ring_with_all_six_fields(ring):
+    reg = MetricsRegistry()
+    before = time.perf_counter()
+    with span("outer", registry_=reg):
+        note("leaf", 1.0, 2.5, n=7)
+        with span("inner", registry_=reg):
+            pass
+    after = time.perf_counter()
+    note("bare", 3.0, 4.0)
+    leaf, inner, outer, bare = recorded()
+    me = threading.get_ident()
+    assert leaf == ("leaf", 1.0, 2.5, me, 7, "outer")
+    assert (leaf.name, leaf.t0, leaf.t1, leaf.thread_ident, leaf.n,
+            leaf.parent) == tuple(leaf)
+    assert (inner.name, inner.n, inner.parent) == ("outer/inner", None,
+                                                   "outer")
+    assert (outer.name, outer.n, outer.parent) == ("outer", None, None)
+    # a span's interval is read from perf_counter and holds its child's
+    assert before <= outer.t0 <= inner.t0 <= inner.t1 <= outer.t1 <= after
+    assert outer.thread_ident == inner.thread_ident == me
+    assert bare == ("bare", 3.0, 4.0, me, None, None)
+    # note() writes no histogram: the ring is its only trace
+    assert reg.get("span_ms", {"span": "leaf"}) is None
+
+
+def test_ring_is_bounded(ring):
+    for i in range(spans_mod.RING_SIZE + 10):
+        note("x", float(i), float(i) + 0.5, n=i)
+    got = recorded()
+    assert len(got) == spans_mod.RING_SIZE == 8192
+    assert got[0].n == 10 and got[-1].n == spans_mod.RING_SIZE + 9
+    clear_recorded()
+    assert recorded() == []
+
+
+def test_nothing_is_recorded_when_disabled(ring):
+    reg = MetricsRegistry()
+    set_enabled(False)
+    try:
+        note("quiet", 0.0, 1.0, n=1)
+        with span("quiet_span", registry_=reg):
+            note("inside", 0.0, 1.0)
+    finally:
+        set_enabled(True)
+    assert recorded() == []
+    note("loud", 0.0, 1.0)
+    assert [r.name for r in recorded()] == ["loud"]
+
+
+@pytest.mark.parametrize("since,until,want", [
+    (None, None, ["a", "b", "c"]),
+    (2.5, None, ["b", "c"]),          # b straddles `since`
+    (None, 2.5, ["a", "b"]),          # b straddles `until`
+    (1.5, 1.8, ["a"]),                # window inside an interval
+    (4.5, 4.8, []),                   # between b and c: overlaps neither
+    (0.0, 0.5, []),
+    (7.0, None, []),
+])
+def test_recorded_filters_by_overlap(ring, since, until, want):
+    note("a", 1.0, 2.0)
+    note("b", 2.2, 4.0)
+    note("c", 5.0, 6.0)
+    got = recorded(since, until)
+    assert [r.name for r in got] == want
+    got.clear()                       # a copy: the ring is untouched
+    assert len(recorded()) == 3
+
+
+def test_spans_of_two_threads_carry_two_thread_identifiers(ring):
+    reg = MetricsRegistry()
+
+    def work():
+        with span("worker", registry_=reg):
+            note("w_leaf", 0.0, 1.0)
+
+    with span("main", registry_=reg):
+        t = threading.Thread(target=work)
+        t.start()
+        t.join()
+        note("m_leaf", 0.0, 1.0)
+    by_name = {r.name: r for r in recorded()}
+    assert by_name["w_leaf"].thread_ident == by_name["worker"].thread_ident \
+        == t.ident
+    assert by_name["m_leaf"].thread_ident == by_name["main"].thread_ident \
+        == threading.get_ident() != t.ident
+    # nesting is per thread: the worker's spans do not hang under `main`
+    assert by_name["w_leaf"].parent == "worker"
+    assert by_name["worker"].parent is None
+    assert by_name["m_leaf"].parent == "main"
+
+
+def test_ring_takes_appends_from_many_threads_while_it_is_read(ring):
+    """More writers than cores, a short switch interval, a reader copying
+    all the while: no append is lost or torn, each thread's stay in order."""
+    import sys
+    writers, each = 16, 400            # 6,400 < RING_SIZE: nothing drops
+    start = threading.Event()
+
+    def work(k):
+        start.wait(5.0)
+        for i in range(each):
+            note(f"w{k}", float(i), float(i) + 1.0, n=i)
+
+    threads = [threading.Thread(target=work, args=(k,))
+               for k in range(writers)]
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        start.set()
+        deadline = time.time() + 30.0
+        while any(t.is_alive() for t in threads) and time.time() < deadline:
+            for r in recorded():       # a copy taken while others append
+                assert len(r) == 6 and r.t1 == r.t0 + 1.0
+        for t in threads:
+            t.join(30.0)
+            assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(old)
+    got = recorded()
+    assert len(got) == writers * each
+    for k in range(writers):
+        mine = [r for r in got if r.name == f"w{k}"]
+        assert [r.n for r in mine] == list(range(each))
+        assert len({r.thread_ident for r in mine}) == 1
 
 
 # ---------------------------------------------------------------------------
