@@ -191,6 +191,13 @@ def phase_bert(config=None, batch=64, n_fit=4, k=2):
     block = model.fit_steps(stacked)
     jax.block_until_ready(model.params_)
     _check_falling("bert", [*losses, *np.asarray(block)])
+    head = model.mlm_head_stats()
+    print(f"[smoke] bert MLM head: {head['gathered_steps']} of "
+          f"{head['steps']} steps in one pass of {head['capacity']} rows, "
+          f"most labelled positions {head['max_labelled']} of {batch * t}",
+          flush=True)
+    if head["steps"] != n_fit + k or head["fallback_steps"]:
+        raise AssertionError(f"bert: MLM head counters {head}")
 
     logits = _finite("bert output_mlm", model.output_mlm(ids[:4], mask[:4]))
     if logits.shape != (4, t, cfg.vocab_size):

@@ -12,6 +12,13 @@ TPU-native design choices:
   (ops/attention_kernels.py); `compute_dtype="bfloat16"` keeps master
   params f32 and casts activations/matmuls to bf16 for the MXU.
 - Post-LN residual wiring (original BERT), GELU FFN.
+- The masked-LM train step runs its head (transform, GELU, LayerNorm, tied
+  product, `log_softmax`) on the labelled positions only: they are compacted
+  inside the compiled step to a capacity taken from the batch shape, so no
+  `[B*T, vocab]` array is written.  A batch with more labelled positions
+  than the capacity takes further passes of the same size — exact for every
+  mask, no recompile, no host read (`_head_capacity`, `_mlm_head_loss`).
+  `output_mlm` (inference) computes logits at every position.
 """
 from __future__ import annotations
 
@@ -54,6 +61,29 @@ class BertConfig:
         return BertConfig(**d)
 
 
+_HEAD_KEYS = ("mlm_W", "mlm_b", "mlm_ln_g", "mlm_ln_b", "tok_emb", "mlm_bias")
+
+
+def _head_capacity(positions: int) -> int:
+    """Rows one pass of the masked-LM train head runs on: a quarter of the
+    batch's positions (BERT labels 15%), rounded up to the fused-LayerNorm
+    kernel's 256-row block; all of them where that is no fewer."""
+    return min(positions, -(-positions // (4 * 256)) * 256)
+
+
+def _fold_head(acc, report):
+    """`acc` = int32 [steps, steps that took more than one head pass, most
+    labelled positions in a step, rows of a pass at the newest step];
+    `report` = `[n, passes, capacity]` of one step or `[k, 3]` of k steps."""
+    r = report.reshape(-1, 3)
+    return jnp.stack([acc[0] + r.shape[0], acc[1] + jnp.sum(r[:, 1] > 1),
+                      jnp.maximum(acc[2], jnp.max(r[:, 0])),
+                      r[-1, 2]]).astype(jnp.int32)
+
+
+_fold_head_jit = jax.jit(_fold_head)
+
+
 def _ln(x, g, b, eps):
     # measured dispatch: Pallas fused LayerNorm on TPU for tiling shapes
     from deeplearning4j_tpu.ops.norm_kernels import fused_layer_norm
@@ -76,6 +106,7 @@ class BertModel:
         self.params_ = self._init(jax.random.PRNGKey(seed))
         self.opt_state_ = self.updater.init_state(self.params_)
         self._steps: Dict[str, Any] = {}
+        self._mlm_head = jnp.zeros((4,), jnp.int32)    # see `_fold_head`
 
     # ---- init ----
     def _init(self, key) -> Dict[str, Any]:
@@ -160,39 +191,116 @@ class BertModel:
         return pooled @ params["cls_W"] + params["cls_b"]
 
     # ---- losses ----
-    def _mlm_loss(self, params, ids, input_mask, labels, label_mask):
-        """labels: sparse [B, T] int token ids (preferred — a one-hot
-        [B, T, V] labels array is 250MB/step of H2D at BERT-base scale) or
-        dense one-hot [B, T, V]."""
-        h = self._encode(params, ids, input_mask)
-        logits = self._mlm_logits(params, h)
-        lp = jax.nn.log_softmax(logits, -1)
-        if labels.ndim == 2:
-            per_tok = -jnp.take_along_axis(
-                lp, labels[..., None].astype(jnp.int32), axis=-1)[..., 0]
+    def _head_nll(self, head, rows, labels, weights):
+        """Weighted sum of the masked-LM negative log-likelihood over
+        `rows` [R, H].  labels: sparse [R] int token ids (preferred — a
+        one-hot [B, T, V] labels array is 250MB/step of H2D at BERT-base
+        scale) or dense one-hot [R, V]."""
+        lp = jax.nn.log_softmax(self._mlm_logits(head, rows), -1)
+        if labels.ndim == 1:
+            per_row = -jnp.take_along_axis(
+                lp, labels[:, None].astype(jnp.int32), axis=-1)[:, 0]
         else:
-            per_tok = -jnp.sum(labels * lp, -1)            # [B, T]
-        denom = jnp.maximum(jnp.sum(label_mask), 1.0)
-        return jnp.sum(per_tok * label_mask) / denom
+            per_row = -jnp.sum(labels * lp, -1)
+        return jnp.sum(per_row * weights)
+
+    def _head_passes(self, head, rows, labels, weights):
+        """Masked-LM loss `sum(nll * weights) / max(sum(weights), 1)` of the
+        head on `rows` [R, H], and its gradients, computed over the rows
+        whose weight is not zero, `_head_capacity(R)` of them a pass:
+        (loss, d head, d rows, [number of such rows, passes, rows a pass]).
+        One pass at BERT's 15%; a fuller mask takes more passes of the same
+        program, and an empty one none."""
+        positions = rows.shape[0]
+        cap = _head_capacity(positions)
+        grad = jax.value_and_grad(self._head_nll, argnums=(0, 1))
+        labelled = weights != 0
+        n = jnp.sum(labelled, dtype=jnp.int32)
+        denom = jnp.maximum(jnp.sum(weights), 1.0)
+        if cap == positions:
+            nll, (d_head, d_rows) = grad(head, rows, labels, weights / denom)
+            report = jnp.stack([n, 1, cap]).astype(jnp.int32)
+            return nll, d_head, d_rows, report
+        passes = (n + (cap - 1)) // cap
+        # labelled positions first, in order; the tail repeats position 0
+        # and is given weight 0 below
+        order = jnp.nonzero(labelled, size=-(-positions // cap) * cap,
+                            fill_value=0)[0].astype(jnp.int32)
+        slots = jnp.arange(cap, dtype=jnp.int32)
+
+        def one_pass(i, acc):
+            nll, d_head, d_rows = acc
+            at = jax.lax.dynamic_slice(order, (i * cap,), (cap,))
+            w = jnp.where(i * cap + slots < n, weights[at], 0) / denom
+            nll_i, (g_head, g_rows) = grad(head, rows[at], labels[at], w)
+            return (nll + nll_i,
+                    jax.tree_util.tree_map(jnp.add, d_head, g_head),
+                    d_rows.at[at].add(g_rows))
+
+        zero = (jnp.zeros((), jnp.result_type(
+                    rows, weights, *jax.tree_util.tree_leaves(head))),
+                jax.tree_util.tree_map(jnp.zeros_like, head),
+                jnp.zeros_like(rows))
+        nll, d_head, d_rows = jax.lax.fori_loop(0, passes, one_pass, zero)
+        return nll, d_head, d_rows, jnp.stack([n, passes, cap])
+
+    def _mlm_head_loss(self, head, rows, labels, weights):
+        """(loss, report) of `_head_passes`, differentiable in `head`
+        and `rows`.  Loss and gradients are taken together inside the pass
+        loop and the gradients kept for the backward pass, so differentiating
+        the step stores `d head` and `d rows` and nothing of width `vocab`;
+        `labels` and `weights` get no gradient."""
+        @jax.custom_vjp
+        def f(head, rows, labels, weights):
+            loss, _, _, report = self._head_passes(head, rows, labels, weights)
+            return loss, report
+
+        def fwd(head, rows, labels, weights):
+            loss, d_head, d_rows, report = self._head_passes(
+                head, rows, labels, weights)
+            return (loss, report), (d_head, d_rows)
+
+        def bwd(res, ct):
+            g = ct[0]
+            d_head, d_rows = jax.tree_util.tree_map(
+                lambda a: (g * a).astype(a.dtype), res)
+            return d_head, d_rows, None, None
+
+        f.defvjp(fwd, bwd)
+        return f(head, rows, labels, jax.lax.stop_gradient(weights))
+
+    def _mlm_loss(self, params, ids, input_mask, labels, label_mask):
+        """Masked-LM loss `sum(nll * label_mask) / max(sum(label_mask), 1)`
+        and the head's `[n, passes, capacity]` report.  The head runs only
+        where `label_mask` is not zero (`_head_passes`); the value and the
+        parameters' gradients are those of the head run at every position.
+        labels: [B, T] int token ids or one-hot [B, T, V]."""
+        h = self._encode(params, ids, input_mask)
+        labels, label_mask = jnp.asarray(labels), jnp.asarray(label_mask)
+        positions = h.shape[0] * h.shape[1]
+        return self._mlm_head_loss(
+            {k: params[k] for k in _HEAD_KEYS}, h.reshape(positions, -1),
+            labels.reshape(positions, *labels.shape[2:]),
+            label_mask.reshape(positions))
 
     def _cls_loss(self, params, ids, input_mask, labels):
         h = self._encode(params, ids, input_mask)
         logits = self._cls_logits(params, h)
         return -jnp.mean(jnp.sum(labels * jax.nn.log_softmax(logits, -1),
-                                 -1))
+                                 -1)), None
 
     # ---- compiled steps ----
     def _step_body(self, kind: str):
         loss_fn = self._mlm_loss if kind == "mlm" else self._cls_loss
 
         def step(params, opt_state, iteration, epoch, *batch):
-            loss, grads = jax.value_and_grad(
-                lambda p: loss_fn(p, *batch))(params)
+            (loss, report), grads = jax.value_and_grad(
+                lambda p: loss_fn(p, *batch), has_aux=True)(params)
             upd, new_opt = self.updater.apply(opt_state, grads, iteration,
                                               epoch, params=params)
             new_params = jax.tree_util.tree_map(lambda p, u: p - u,
                                                 params, upd)
-            return new_params, new_opt, loss, iteration + 1
+            return new_params, new_opt, loss, iteration + 1, report
 
         return step
 
@@ -204,16 +312,19 @@ class BertModel:
 
     def _scan_step(self, kind: str):
         """k steps per dispatch (see utils/scan_fit.py for the rationale);
-        BERT's step carry is (params, opt, iteration) — no state/rng."""
+        BERT's step carry is (params, opt, iteration) — no state/rng — and,
+        for the masked LM, the head's counters (`mlm_head_stats`)."""
         key = "scan_" + kind
         if key not in self._steps:
             from deeplearning4j_tpu.utils.scan_fit import make_scan_step
             body = self._step_body(kind)
 
             def tick(carry, epoch, batch):
-                p, o, it = carry
-                p, o, loss, it = body(p, o, it, epoch, *batch)
-                return (p, o, it), loss
+                p, o, it, *head = carry
+                p, o, loss, it, report = body(p, o, it, epoch, *batch)
+                if head:                                 # masked LM
+                    head = [_fold_head(head[0], report)]
+                return (p, o, it, *head), loss
 
             self._steps[key] = make_scan_step(tick)
         return self._steps[key]
@@ -266,15 +377,17 @@ class BertModel:
         if mds.labels_masks is not None:                 # masked LM
             lmask = jnp.asarray(mds.labels_masks[0])
             step = self._step("mlm")
-            self.params_, self.opt_state_, loss, new_it = step(
+            self.params_, self.opt_state_, loss, new_it, report = step(
                 self.params_, self.opt_state_, it, ep,
                 ids.astype(jnp.int32), input_mask, labels, lmask)
         else:                                            # classification
             step = self._step("cls")
-            self.params_, self.opt_state_, loss, new_it = step(
+            self.params_, self.opt_state_, loss, new_it, report = step(
                 self.params_, self.opt_state_, it, ep,
                 ids.astype(jnp.int32), input_mask, labels)
         note("step_dispatch", t0, time.perf_counter(), self.iteration)
+        if report is not None:                           # masked LM
+            self._mlm_head = _fold_head_jit(self._mlm_head, report)
         self._score = loss
         advance(self, new_it)
         # return the device-side loss WITHOUT forcing a D2H sync: a per-step
@@ -299,8 +412,9 @@ class BertModel:
         if mds.labels_masks is not None:                 # masked LM
             lmask = lm0
             step = self._scan_step("mlm")
-            (self.params_, self.opt_state_, new_it), losses, last_loss = step(
-                (self.params_, self.opt_state_, it), ep,
+            ((self.params_, self.opt_state_, new_it, self._mlm_head),
+             losses, last_loss) = step(
+                (self.params_, self.opt_state_, it, self._mlm_head), ep,
                 (ids.astype(jnp.int32), input_mask, labels, lmask))
         else:                                            # classification
             step = self._scan_step("cls")
@@ -315,6 +429,18 @@ class BertModel:
     def score(self) -> float:
         s = getattr(self, "_score", None)
         return float(s) if s is not None else float("nan")
+
+    def mlm_head_stats(self) -> Dict[str, Optional[int]]:
+        """What the masked-LM train steps' head did so far (one device
+        read): `steps`; `fallback_steps`, those with more labelled positions
+        than `capacity` (the head then takes further passes); `max_labelled`,
+        the most labelled positions a step saw; `capacity`, the rows of one
+        head pass at the newest batch shape (0 before the first step)."""
+        steps, fallback, most, cap = (int(v) for v in
+                                      np.asarray(self._mlm_head))
+        return {"steps": steps, "gathered_steps": steps - fallback,
+                "fallback_steps": fallback, "max_labelled": most,
+                "capacity": cap}
 
     def output_hidden(self, ids, input_mask):
         return self._encode(self.params_, jnp.asarray(ids, jnp.int32),
