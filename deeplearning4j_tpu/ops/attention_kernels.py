@@ -19,7 +19,10 @@ cuDNN fused attention plays for the reference's platform helpers:
   tiling shapes, blockwise scan for the rest; differentiable everywhere.
 
 Layouts: [B, H, T, D] (heads separated — the TPU-native layout; the nn/
-attention layers reshape from [B, T, F]).
+attention layers reshape from [B, T, F]).  `q` and `k` share the key width
+D; `v` (and the output) may be narrower or wider, [B, H, S, Dv] — latent
+attention's expanded form has keys of 192 and values of 128.  The default
+scale is over the key width.
 """
 from __future__ import annotations
 
@@ -54,12 +57,12 @@ def mha_reference(q, k, v, mask=None, causal=False, scale=None):
 def _blockwise_fwd(q, k, v, mask, causal, scale, block_k):
     """Online-softmax scan over KV blocks; returns (out, (m, l))."""
     B, H, T, D = q.shape
-    S = k.shape[2]
+    S, Dv = k.shape[2], v.shape[3]
     nblocks = S // block_k
     qs = q * scale
 
     kb = k.reshape(B, H, nblocks, block_k, D).transpose(2, 0, 1, 3, 4)
-    vb = v.reshape(B, H, nblocks, block_k, D).transpose(2, 0, 1, 3, 4)
+    vb = v.reshape(B, H, nblocks, block_k, Dv).transpose(2, 0, 1, 3, 4)
     if mask is not None:
         mb = mask.reshape(B, nblocks, block_k).transpose(1, 0, 2)
     else:
@@ -87,7 +90,7 @@ def _blockwise_fwd(q, k, v, mask, causal, scale, block_k):
             preferred_element_type=jnp.float32)
         return (acc_new, m_new, l_new, j + 1), None
 
-    acc0 = jnp.zeros(q.shape, jnp.float32)
+    acc0 = jnp.zeros((B, H, T, Dv), jnp.float32)
     m0 = jnp.full((B, H, T), NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, H, T), jnp.float32)
     (acc, m, l, _), _ = jax.lax.scan(step, (acc0, m0, l0, 0), (kb, vb, mb))
@@ -207,13 +210,14 @@ def _mask_bias3(mask, B, S):
 def flash_attention_tpu(q, k, v, causal=False, scale=None,
                         block_q=256, block_k=256, interpret=False,
                         return_lse=False, mask=None):
-    """Pallas flash-attention forward.  [B, H, T, D]; T divisible by the
-    block sizes (dispatcher checks).  With ``return_lse`` also returns the
+    """Pallas flash-attention forward.  q [B, H, T, D], k [B, H, S, D],
+    v [B, H, S, Dv] -> [B, H, T, Dv]; T and S divisible by the block sizes
+    (dispatcher checks).  With ``return_lse`` also returns the
     row logsumexp [B*H, T] (f32) for the backward kernels.  ``mask``:
     optional [B, S] 1/0 keep-mask over KV positions (padding/segment
     mask), shared across heads."""
     B, H, T, D = q.shape
-    S = k.shape[2]
+    S, Dv = k.shape[2], v.shape[3]
     if scale is None:
         scale = D ** -0.5
     bq = min(block_q, T)
@@ -221,7 +225,7 @@ def flash_attention_tpu(q, k, v, causal=False, scale=None,
     nkv = S // bk
     qf = q.reshape(B * H, T, D)
     kf = k.reshape(B * H, S, D)
-    vf = v.reshape(B * H, S, D)
+    vf = v.reshape(B * H, S, Dv)
     has_mask = mask is not None
     kernel = functools.partial(_flash_kernel, block_q=bq, block_k=bk,
                                nkv=nkv, causal=causal, scale=scale,
@@ -229,7 +233,7 @@ def flash_attention_tpu(q, k, v, causal=False, scale=None,
     in_specs = [
         pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
-        pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
+        pl.BlockSpec((1, bk, Dv), lambda b, i, j: (b, j, 0)),
     ]
     inputs = [qf, kf, vf]
     if has_mask:
@@ -243,23 +247,23 @@ def flash_attention_tpu(q, k, v, causal=False, scale=None,
         grid=(B * H, T // bq, nkv),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0)),
             # lse rides a trailing singleton lane dim — (1, bq, 1) blocks
             # satisfy the TPU (8, 128)-or-full tiling rule
             pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
+            jax.ShapeDtypeStruct((B * H, T, Dv), q.dtype),
             jax.ShapeDtypeStruct((B * H, T, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, D), jnp.float32),
+            pltpu.VMEM((bq, Dv), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
     )(*inputs)
-    out = out.reshape(B, H, T, D)
+    out = out.reshape(B, H, T, Dv)
     return (out, lse.reshape(B * H, T)) if return_lse else out
 
 
@@ -376,18 +380,18 @@ def flash_attention_bwd_tpu(q, k, v, out, lse, g, causal=False, scale=None,
     precomputed on-device, then separate dQ and dK/dV kernels so both
     matmul passes stay on the MXU without [T,T] materialization."""
     B, H, T, D = q.shape
-    S = k.shape[2]
+    S, Dv = k.shape[2], v.shape[3]
     if scale is None:
         scale = D ** -0.5
     bq = min(block_q, T)
     bk = min(block_k, S)
     qf = q.reshape(B * H, T, D)
     kf = k.reshape(B * H, S, D)
-    vf = v.reshape(B * H, S, D)
-    gf = g.reshape(B * H, T, D)
+    vf = v.reshape(B * H, S, Dv)
+    gf = g.reshape(B * H, T, Dv)
     # delta_i = rowsum(dO_i * O_i) — cheap elementwise reduce, XLA-fused
     delta = jnp.sum(gf.astype(jnp.float32)
-                    * out.reshape(B * H, T, D).astype(jnp.float32), axis=-1)
+                    * out.reshape(B * H, T, Dv).astype(jnp.float32), axis=-1)
     lse3 = lse.reshape(B * H, T, 1)
     delta3 = delta.reshape(B * H, T, 1)
     nkv = S // bk
@@ -410,8 +414,8 @@ def flash_attention_bwd_tpu(q, k, v, out, lse, g, causal=False, scale=None,
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bk, Dv), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
         ] + extra_specs_ij,
@@ -430,27 +434,27 @@ def flash_attention_bwd_tpu(q, k, v, out, lse, g, causal=False, scale=None,
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, j, i: (b, i, 0)),
             pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bq, D), lambda b, j, i: (b, i, 0)),
+            pl.BlockSpec((1, bk, Dv), lambda b, j, i: (b, j, 0)),
+            pl.BlockSpec((1, bq, Dv), lambda b, j, i: (b, i, 0)),
             pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0)),
             pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0)),
         ] + extra_specs_ji,
         out_specs=[
             pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
+            pl.BlockSpec((1, bk, Dv), lambda b, j, i: (b, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, S, D), k.dtype),
-            jax.ShapeDtypeStruct((B * H, S, D), v.dtype),
+            jax.ShapeDtypeStruct((B * H, S, Dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, D), jnp.float32),
-            pltpu.VMEM((bk, D), jnp.float32),
+            pltpu.VMEM((bk, Dv), jnp.float32),
         ],
         interpret=interpret,
     )(qf, kf, vf, gf, lse3, delta3, *extra_in)
     return (dq.reshape(B, H, T, D), dk.reshape(B, H, S, D),
-            dv.reshape(B, H, S, D))
+            dv.reshape(B, H, S, Dv))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))

@@ -31,6 +31,16 @@ def layer_norm_reference(x, gain, bias=None, eps: float = 1e-5):
     return y if bias is None else y + bias
 
 
+def rms_norm(x, gain, eps: float = 1e-6):
+    """RMSNorm over the last axis (Zhang & Sennrich 2019): `x / sqrt(mean(x^2)
+    + eps) * gain`, no mean removed and no bias.  Computed in float32 whatever
+    the input's dtype, returned in it.  Plain jnp: XLA fuses it into its
+    neighbours, and no cell has shown a kernel to be worth having."""
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (y * gain.astype(jnp.float32)).astype(x.dtype)
+
+
 # -- forward kernel ---------------------------------------------------------
 
 def _ln_fwd_kernel(x_ref, g_ref, b_ref, y_ref, mean_ref, rstd_ref, *, eps):

@@ -12,7 +12,8 @@ shape class, and folded into AOT cache keys via
 Importing this package registers the kernel set; call sites go through
 ``dispatch.resolve`` and never import kernel modules directly.
 """
-from deeplearning4j_tpu.ops.pallas import (attention, dispatch, matmul,
+from deeplearning4j_tpu.ops.pallas import (attention, dispatch,
+                                           grouped_matmul, matmul,
                                            paged_attention, tiles)
 from deeplearning4j_tpu.ops.pallas.tiles import (  # noqa: F401
     DEFAULT_TILES,
@@ -58,10 +59,18 @@ dispatch.register(
     supports=matmul.dense_supports,
     profitable=matmul.dense_profitable,
 )
+dispatch.register(
+    "grouped_matmul",
+    pallas_fn=grouped_matmul.grouped_matmul,
+    reference_fn=grouped_matmul.grouped_matmul_reference,
+    supports=grouped_matmul.grouped_supports,
+    profitable=grouped_matmul.grouped_profitable,
+)
 
 __all__ = [
     "attention",
     "dispatch",
+    "grouped_matmul",
     "matmul",
     "paged_attention",
     "tiles",

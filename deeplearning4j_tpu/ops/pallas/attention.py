@@ -35,8 +35,10 @@ def _q_sublane(dtype) -> int:
 def flash_attention(q, k, v, mask=None, causal: bool = False, scale=None,
                     tile: Optional[TileConfig] = None,
                     interpret: bool = False):
-    """[B, H, T, D] flash attention with TileConfig-driven blocks and
-    masked-tail padding for ragged T/S.  Differentiable."""
+    """Flash attention of q [B, H, T, D], k [B, H, S, D], v [B, H, S, Dv]
+    -> [B, H, T, Dv] with TileConfig-driven blocks and masked-tail padding
+    for ragged T/S (the padding is along T and S only, so it holds for any
+    value width).  Differentiable."""
     import deeplearning4j_tpu.ops.attention_kernels as ak
 
     tile = tile or DEFAULT_TILES["attention"]
@@ -81,6 +83,11 @@ def attention_supports(q, k, v, mask=None, causal: bool = False,
         return False
     if k.dtype != q.dtype or v.dtype != q.dtype:
         return False
+    # q and k share the key width; v may have a width of its own but lies
+    # over the same batch, heads and positions as k
+    if getattr(k, "ndim", 0) != 4 or getattr(v, "ndim", 0) != 4 \
+            or k.shape[3] != q.shape[3] or v.shape[:3] != k.shape[:3]:
+        return False
     if mask is not None:
         B, _, _, _ = q.shape
         S = k.shape[2]
@@ -92,9 +99,9 @@ def attention_supports(q, k, v, mask=None, causal: bool = False,
 def attention_profitable(q, k, v, mask=None, causal: bool = False,
                          **kw) -> bool:
     """Auto-mode perf heuristics: mirror the measured v5e policy the old
-    dispatcher encoded (flash wins from ~2k sequence, D a lane multiple)."""
+    dispatcher encoded (flash wins from ~2k sequence, the key width — the
+    scores' contraction — a multiple of 64; the value width is free)."""
     import deeplearning4j_tpu.ops.attention_kernels as ak
 
-    T, D = q.shape[2], q.shape[3]
-    S = k.shape[2]
-    return D % 64 == 0 and max(T, S) >= ak._FLASH_MIN_SEQ
+    T, S, key_width = q.shape[2], k.shape[2], k.shape[3]
+    return key_width % 64 == 0 and max(T, S) >= ak._FLASH_MIN_SEQ

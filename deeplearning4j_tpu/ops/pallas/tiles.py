@@ -64,6 +64,10 @@ DEFAULT_TILES: Dict[str, TileConfig] = {
     # decode attention: block_kv IS the KV page size the serving layer
     # allocates (one page per grid step), block_q is the single decode row
     "paged_attention": TileConfig(block_q=1, block_kv=16),
+    # grouped (per-expert) products: whole expert widths fit one tile, so a
+    # grid step is a few microseconds of MXU work; 256 rows because an
+    # expert sees a few hundred rows a step
+    "grouped_matmul": TileConfig(block_m=256, block_n=1024, block_k=1024),
 }
 
 #: Candidate values per tile dimension, per kernel.  Kept deliberately
@@ -92,6 +96,11 @@ TILE_SPACES: Dict[str, Dict[str, List[int]]] = {
     "paged_attention": {
         "block_kv": [8, 16, 32, 64, 128],
     },
+    "grouped_matmul": {
+        "block_m": [128, 256, 512],
+        "block_n": [256, 512, 1024],
+        "block_k": [256, 512, 1024],
+    },
 }
 
 #: Dimensions swept by the coarse grid stage (the rest are greedy-refined).
@@ -101,6 +110,7 @@ TILE_GRID_DIMS: Dict[str, Tuple[str, ...]] = {
     "q_matmul": ("block_m", "block_n"),
     "fused_dense": ("block_m", "block_n"),
     "paged_attention": ("block_kv",),
+    "grouped_matmul": ("block_m", "block_n"),
 }
 
 

@@ -11,6 +11,8 @@ from deeplearning4j_tpu.zoo.models import (  # noqa: F401
 from deeplearning4j_tpu.zoo.graphs import (  # noqa: F401
     ResNet50, SqueezeNet, UNet)
 from deeplearning4j_tpu.zoo.bert import BertConfig, BertModel  # noqa: F401
+from deeplearning4j_tpu.zoo.decoder import (  # noqa: F401
+    DecoderConfig, DecoderModel)
 from deeplearning4j_tpu.zoo.vision import (  # noqa: F401
     InceptionResNetV1, TinyYOLO, Xception, YOLO2)
 from deeplearning4j_tpu.zoo.nasnet import NASNet  # noqa: F401

@@ -1,0 +1,443 @@
+"""The decoder's parts against hand-written cases: RMSNorm, rotary, the
+router, the expert layer's shares and its dropless dispatch, the grouped
+product, attention with values narrower than keys, the routing counter, and
+`zoo.DecoderModel`'s surface."""
+import io
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+
+from deeplearning4j_tpu.data.dataset import MultiDataSet
+from deeplearning4j_tpu.ops import attention_kernels as ak
+from deeplearning4j_tpu.ops import moe
+from deeplearning4j_tpu.ops import pallas as tier
+from deeplearning4j_tpu.ops.norm_kernels import rms_norm
+from deeplearning4j_tpu.ops.rotary import rotary_interleaved
+from deeplearning4j_tpu.zoo import DecoderConfig, DecoderModel
+
+
+@pytest.fixture(autouse=True)
+def _reset_tier():
+    yield
+    tier.dispatch.reset()
+
+
+# ---------------------------------------------------------------------------
+# RMSNorm, rotary
+# ---------------------------------------------------------------------------
+
+def test_rms_norm_hand_case():
+    x = jnp.array([[3.0, 4.0], [0.0, 0.0]])
+    got = rms_norm(x, jnp.array([2.0, 0.5]), eps=0.0 + 1e-12)
+    rms = np.sqrt((9 + 16) / 2)
+    np.testing.assert_allclose(got[0], [2 * 3 / rms, 0.5 * 4 / rms], rtol=1e-6)
+    np.testing.assert_allclose(got[1], [0.0, 0.0])      # eps keeps it finite
+
+
+def test_rms_norm_computes_in_float32_and_returns_the_input_dtype():
+    x = (jnp.arange(8, dtype=jnp.float32).reshape(2, 4) + 300).astype(
+        jnp.bfloat16)
+    got = rms_norm(x, jnp.ones((4,), jnp.bfloat16), 1e-6)
+    assert got.dtype == jnp.bfloat16
+    xf = np.asarray(x, np.float32)
+    want = xf / np.sqrt((xf ** 2).mean(-1, keepdims=True) + 1e-6)
+    np.testing.assert_allclose(np.asarray(got, np.float32), want, rtol=1e-2)
+
+
+def test_rotary_interleaved_hand_case():
+    """d = 4, base 100: pair 0 turns by pos, pair 1 by pos / 10; position 0
+    is left alone; pairs are (x0, x1) and (x2, x3), not (x0, x2)."""
+    x = jnp.array([[[1.0, 0.0, 0.0, 2.0]], [[1.0, 0.0, 0.0, 2.0]]])  # [T,1,4]
+    got = np.asarray(rotary_interleaved(x, jnp.arange(2), base=100.0))
+    np.testing.assert_allclose(got[0, 0], [1, 0, 0, 2], atol=1e-7)
+    np.testing.assert_allclose(
+        got[1, 0], [np.cos(1.0), np.sin(1.0),
+                    -2 * np.sin(0.1), 2 * np.cos(0.1)], rtol=1e-6)
+
+
+def test_rotary_scores_depend_on_the_distance_only():
+    k = jax.random.split(jax.random.PRNGKey(0), 2)
+    q = jax.random.normal(k[0], (1, 3, 8))
+    kk = jax.random.normal(k[1], (1, 3, 8))
+
+    def score(pq, pk):
+        return jnp.sum(rotary_interleaved(q, jnp.array([pq]), 1e4)
+                       * rotary_interleaved(kk, jnp.array([pk]), 1e4))
+
+    np.testing.assert_allclose(score(7, 3), score(104, 100), rtol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# the router
+# ---------------------------------------------------------------------------
+
+def test_router_bias_picks_but_does_not_weigh():
+    """One token, four experts, top-2.  Scores sigmoid([2, 1, 0, -1]); a
+    bias of +1 on expert 3 lifts it over expert 1 for the CHOICE, but its
+    weight is its own score: normalised over the chosen, then scaled."""
+    x = jnp.array([[1.0, 0.0]])
+    w = jnp.array([[2.0, 1.0, 0.0, -1.0], [0.0, 0.0, 0.0, 0.0]])
+    s = 1 / (1 + np.exp(-np.array([2.0, 1.0, 0.0, -1.0])))
+    chosen, weights = moe.router(x, w, jnp.zeros(4), top_k=2, scale=2.5)
+    assert sorted(np.asarray(chosen[0])) == [0, 1]
+    chosen, weights = moe.router(x, w, jnp.array([0.0, 0.0, 0.0, 1.0]),
+                                 top_k=2, scale=2.5)
+    order = np.argsort(np.asarray(chosen[0]))
+    assert list(np.asarray(chosen[0])[order]) == [0, 3]
+    np.testing.assert_allclose(
+        np.asarray(weights[0])[order],
+        2.5 * np.array([s[0], s[3]]) / (s[0] + s[3]), rtol=1e-6)
+    np.testing.assert_allclose(np.sum(weights), 2.5, rtol=1e-6)
+
+
+def test_router_bias_gets_no_gradient():
+    x = jax.random.normal(jax.random.PRNGKey(0), (5, 6))
+    w = jax.random.normal(jax.random.PRNGKey(1), (6, 8))
+    g = jax.grad(lambda b: jnp.sum(moe.router(x, w, b, 2, 1.0)[1] ** 2))(
+        jnp.zeros(8))
+    assert not np.any(np.asarray(g))
+
+
+def test_bias_update_sign():
+    """An expert under the mean load is made likelier, one over it less
+    likely, one at the mean is left; by `speed` whatever the distance."""
+    bias = jnp.array([[0.5, 0.5, 0.5, 0.5]])
+    counts = jnp.array([[10, 2, 6, 6]])              # mean 6
+    got = moe.update_router_bias(bias, counts, speed=0.01)
+    np.testing.assert_allclose(got, [[0.49, 0.51, 0.5, 0.5]], rtol=1e-6)
+
+
+def test_expert_counts_against_a_host_count():
+    rng = np.random.default_rng(0)
+    chosen = rng.integers(0, 16, (50, 3)).astype(np.int32)
+    got = np.asarray(moe.expert_counts(jnp.asarray(chosen), 16))
+    np.testing.assert_array_equal(got, np.bincount(chosen.ravel(),
+                                                   minlength=16))
+
+
+# ---------------------------------------------------------------------------
+# the expert layer: shares, dropless dispatch
+# ---------------------------------------------------------------------------
+
+T, H, I, E, K = 24, 16, 8, 8, 2
+
+
+def _layer_params(seed=0, experts=E):
+    k = jax.random.split(jax.random.PRNGKey(seed), 8)
+
+    def n(i, *shape):
+        return jax.random.normal(k[i], shape) * 0.3
+
+    return {"router": n(0, H, experts), "w_gate": n(1, experts, H, I),
+            "w_up": n(2, experts, H, I), "w_down": n(3, experts, I, H),
+            "shared_gate": n(4, H, 2 * I), "shared_up": n(5, H, 2 * I),
+            "shared_down": n(6, 2 * I, H)}
+
+
+def _choose(x, p, bias, top_k):
+    """[T, k] experts with the largest score + bias, on the host."""
+    s = 1 / (1 + np.exp(-(np.asarray(x) @ np.asarray(p["router"]))))
+    return np.argsort(-(s + np.asarray(bias)), axis=-1, kind="stable")[:, :top_k]
+
+
+def _uncut_layer(x, p, bias, top_k, scale, held=None, chosen=None):
+    """The layer as the equations say, token by token and expert by expert;
+    `held` (first, count) leaves the other experts' terms out.  `chosen`:
+    the choice made beforehand (it has no gradient), for use under
+    `jax.grad`."""
+    silu = lambda v: v / (1 + jnp.exp(-v))
+    ffn = lambda v, g, u, d: (silu(v @ g) * (v @ u)) @ d
+    s = 1 / (1 + jnp.exp(-(x @ p["router"])))
+    all_chosen = _choose(x, p, bias, top_k) if chosen is None else chosen
+    out = []
+    for t in range(x.shape[0]):
+        chosen = all_chosen[t]
+        w = s[t, chosen]
+        w = w / (jnp.sum(w) + 1e-20) * scale
+        y = ffn(x[t], p["shared_gate"], p["shared_up"], p["shared_down"])
+        for j, e in enumerate(chosen):
+            e = int(e)
+            if held is None or held[0] <= e < held[0] + held[1]:
+                i = e - (held[0] if held else 0)
+                y = y + w[j] * ffn(x[t], p["w_gate"][i], p["w_up"][i],
+                                   p["w_down"][i])
+        out.append(y)
+    return jnp.stack(out)
+
+
+def _share(p, first, count):
+    return {**p, **{n: p[n][first:first + count]
+                    for n in ("w_gate", "w_up", "w_down")}}
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """8 experts cut into 4 shares of 2: the routed parts of all shares plus
+    the shared experts counted once are the uncut reference layer."""
+    p = _layer_params()
+    x = jax.random.normal(jax.random.PRNGKey(9), (T, H))
+    bias = jax.random.normal(jax.random.PRNGKey(8), (E,)) * 0.1
+    want = _uncut_layer(x, p, bias, K, 2.448)
+    shared = moe.swiglu(x, p["shared_gate"], p["shared_up"], p["shared_down"])
+    total, counts = shared, None
+    for first in range(0, E, 2):
+        y, counts = moe.expert_layer(x, _share(p, first, 2), bias, top_k=K,
+                                     scale=2.448, first_held=first)
+        total = total + (y - shared)              # this share's routed part
+        # one share against the reference given the same share
+        np.testing.assert_allclose(
+            y, _uncut_layer(x, _share(p, first, 2), bias, K, 2.448,
+                            held=(first, 2)), atol=1e-5)
+    np.testing.assert_allclose(total, want, atol=1e-5)
+    assert int(jnp.sum(counts)) == T * K          # every share counts all E
+
+
+@pytest.mark.parametrize("case", ["all_choose_one_held", "none_held"])
+def test_dropless_under_imbalance(case):
+    """A batch whose tokens all choose one held expert (its group holds
+    every token, the other held expert none), and one in which no token
+    chooses a held expert: the reference's result and gradients."""
+    p = _share(_layer_params(3), 2, 2)            # experts 2, 3 held
+    x = jax.random.normal(jax.random.PRNGKey(4), (T, H))
+    bias = jnp.zeros(E)
+    if case == "all_choose_one_held":
+        bias = bias.at[3].set(10.0).at[2].set(-10.0)
+    else:
+        bias = bias.at[2].set(-10.0).at[3].set(-10.0)
+
+    def system(x, p):
+        return moe.expert_layer(x, p, bias, top_k=K, scale=2.448,
+                                first_held=2)
+
+    y, counts = system(x, p)
+    held_counts = np.asarray(counts)[2:4]
+    assert list(held_counts) == ([0, T] if case == "all_choose_one_held"
+                                 else [0, 0])
+    np.testing.assert_allclose(
+        y, _uncut_layer(x, p, bias, K, 2.448, held=(2, 2)), atol=1e-5)
+    g = jax.random.normal(jax.random.PRNGKey(5), (T, H))
+    got = jax.grad(lambda x, p: jnp.sum(system(x, p)[0] * g), (0, 1))(x, p)
+    chosen = _choose(x, p, bias, K)
+    want = jax.grad(lambda x, p: jnp.sum(_uncut_layer(
+        x, p, bias, K, 2.448, held=(2, 2), chosen=chosen) * g), (0, 1))(x, p)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(a, b, atol=2e-5)
+
+
+@pytest.mark.parametrize("sizes", [[100, 0, 60], [0, 0, 0], [256, 0, 0]])
+def test_grouped_matmul_kernel_against_the_reference(sizes):
+    """The Pallas lowering (interpret mode) against `ragged_dot`: values,
+    both gradients, and zeros in the rows of no group.  (Without x64, as on
+    the chip: the library kernel's index arithmetic is int32.)"""
+    with jax.enable_x64(False):
+        _check_grouped_matmul(sizes)
+
+
+def _check_grouped_matmul(sizes):
+    gm = tier.grouped_matmul
+    k = jax.random.split(jax.random.PRNGKey(0), 3)
+    lhs = jax.random.normal(k[0], (256, 128), jnp.float32)
+    rhs = jax.random.normal(k[1], (3, 128, 256), jnp.float32)
+    g = jax.random.normal(k[2], (256, 256), jnp.float32)
+    gs = jnp.array(sizes, jnp.int32)
+    assert gm.grouped_supports(lhs, rhs, gs)
+    tile = tier.TileConfig(block_m=64, block_n=128, block_k=128)
+
+    def kernel(l, r):
+        return gm.grouped_matmul(l, r, gs, tile=tile, interpret=True)
+
+    def reference(l, r):
+        return gm.grouped_matmul_reference(l, r, gs)
+
+    out = kernel(lhs, rhs)
+    np.testing.assert_allclose(out, reference(lhs, rhs), atol=1e-3)
+    assert not np.any(np.asarray(out[sum(sizes):]))
+    got = jax.grad(lambda l, r: jnp.sum(kernel(l, r) * g), (0, 1))(lhs, rhs)
+    want = jax.grad(lambda l, r: jnp.sum(reference(l, r) * g), (0, 1))(
+        lhs, rhs)
+    for a, b in zip(got, want):
+        assert np.all(np.isfinite(np.asarray(a)))
+        np.testing.assert_allclose(a, b, atol=2e-3)
+
+
+def test_expert_layer_takes_the_kernel_when_the_tier_is_forced():
+    """Widths of 128 so the grouped kernel's tiles divide them: the forced
+    tier (interpret mode) gives what the reference lowering gives."""
+    with jax.enable_x64(False):
+        _check_forced_tier()
+
+
+def _check_forced_tier():
+    k = jax.random.split(jax.random.PRNGKey(1), 8)
+    n = lambda i, *s: jax.random.normal(k[i], s) * 0.05
+    p = {"router": n(0, 128, 4), "w_gate": n(1, 2, 128, 128),
+         "w_up": n(2, 2, 128, 128), "w_down": n(3, 2, 128, 128),
+         "shared_gate": n(4, 128, 128), "shared_up": n(5, 128, 128),
+         "shared_down": n(6, 128, 128)}
+    x = n(7, 64, 128)
+    run = lambda: moe.expert_layer(x, p, jnp.zeros(4), top_k=2, scale=1.0,
+                                   first_held=1)[0]
+    want = run()
+    tier.dispatch.set_dispatch_mode("pallas")
+    np.testing.assert_allclose(run(), want, atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# attention with values narrower than keys
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("branch", ["xla", "blockwise", "flash"])
+def test_fused_attention_values_narrower_than_keys(branch, monkeypatch):
+    """Keys of 24, values of 16, causal, forward and backward, through each
+    of `fused_attention`'s branches against the plain reference."""
+    k = jax.random.split(jax.random.PRNGKey(0), 4)
+    q = jax.random.normal(k[0], (1, 2, 64, 24), jnp.float32)
+    kk = jax.random.normal(k[1], (1, 2, 64, 24), jnp.float32)
+    v = jax.random.normal(k[2], (1, 2, 64, 16), jnp.float32)
+    g = jax.random.normal(k[3], (1, 2, 64, 16), jnp.float32)
+    taken = []
+    if branch == "blockwise":
+        monkeypatch.setattr(ak, "_XLA_SCORE_BYTES_MAX", 0)
+        real = ak.blockwise_attention
+        monkeypatch.setattr(ak, "blockwise_attention", lambda *a: (
+            taken.append(branch), real(*a[:6], 16))[1])
+    elif branch == "flash":
+        tier.dispatch.set_dispatch_mode("pallas")
+        tier.dispatch.set_tile("attention", tier.TileConfig(block_q=16,
+                                                            block_kv=32))
+        real = tier.attention.flash_attention
+        monkeypatch.setattr(tier.attention, "flash_attention", lambda *a, **kw: (
+            taken.append(branch), real(*a, **kw))[1])
+    else:
+        real = ak.mha_reference
+        monkeypatch.setattr(ak, "mha_reference", lambda *a: (
+            taken.append(branch), real(*a))[1])
+
+    def loss(f):
+        return lambda q, k, v: jnp.sum(f(q, k, v) * g)
+
+    out = ak.fused_attention(q, kk, v, causal=True)
+    assert taken == [branch] and out.shape == (1, 2, 64, 16)
+    got = jax.grad(loss(lambda q, k, v: ak.fused_attention(
+        q, k, v, causal=True)), (0, 1, 2))(q, kk, v)
+    monkeypatch.undo()
+    tier.dispatch.reset()
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, kk) / np.sqrt(24)
+    s = jnp.where(jnp.tril(jnp.ones((64, 64), bool)), s, -jnp.inf)
+    np.testing.assert_allclose(
+        out, jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v),
+        atol=1e-5)
+    want = jax.grad(loss(lambda q, k, v: ak.mha_reference(
+        q, k, v, None, True)), (0, 1, 2))(q, kk, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=1e-5)
+
+
+def test_attention_predicates_speak_of_the_value_width():
+    q = jnp.zeros((1, 2, 4096, 192), jnp.bfloat16)
+    v = jnp.zeros((1, 2, 4096, 128), jnp.bfloat16)
+    assert tier.attention.attention_supports(q, q, v)
+    assert tier.attention.attention_profitable(q, q, v)
+    # keys of another width than the queries, or values over other
+    # positions than the keys, are no attention
+    assert not tier.attention.attention_supports(q, v, v)
+    assert not tier.attention.attention_supports(q, q, v[:, :, :128])
+    # the rule is on the key width: 96 is no multiple of 64, whatever v is
+    q96 = jnp.zeros((1, 2, 4096, 96), jnp.bfloat16)
+    assert not tier.attention.attention_profitable(q96, q96, v)
+
+
+# ---------------------------------------------------------------------------
+# the model
+# ---------------------------------------------------------------------------
+
+def _batch(seed=0, rows=2, t=16, vocab=96):
+    ids = np.random.default_rng(seed).integers(0, vocab, (rows, t)).astype(
+        np.int32)
+    labels = np.concatenate([ids[:, 1:], np.zeros((rows, 1), np.int32)], 1)
+    return MultiDataSet(features=[ids], labels=[labels])
+
+
+def test_model_trains_through_fit_and_counts_its_routing():
+    m = DecoderModel(DecoderConfig.tiny(first_expert=2, n_experts_held=4),
+                     seed=1)
+    assert m.params_["moe"]["w_gate"].shape == (2, 4, 32, 16)
+    assert m.params_["moe"]["router"].shape == (2, 32, 8)
+    first = float(m.fit_batch(_batch()))
+    m.fit([_batch()] * 7)
+    assert m.iteration == 8 and m.epoch == 1
+    assert m.score() < first
+    # every token chose top_k of the router's 8 in both expert layers, in
+    # every step: the device counter against the host's arithmetic
+    load = m.expert_load()
+    assert load.shape == (2, 8) and load.dtype == np.int32
+    np.testing.assert_array_equal(load.sum(1), [8 * 2 * 16 * 2] * 2)
+    # the bias moved against the load, by the speed a step
+    bias = np.asarray(m.state_["router_bias"])
+    assert np.all(np.abs(bias) <= 8 * 1e-3 + 1e-9) and np.any(bias != 0)
+    assert m.output(_batch().features[0]).shape == (2, 16, 96)
+
+
+def test_routing_counter_against_a_count_made_on_the_host():
+    """One step in float32: the counter the step kept on the device is the
+    host's bincount of the experts the router chose in each expert layer."""
+    m = DecoderModel(DecoderConfig.tiny(), seed=2)
+    batch = _batch(3)
+    ids = jnp.asarray(batch.features[0])
+    chosen = []
+    real = moe.router
+
+    def spy(*a, **kw):
+        c, w = real(*a, **kw)
+        # the router's own choices, sent to the host from inside the scan
+        jax.debug.callback(lambda c: chosen.append(np.asarray(c)), c)
+        return c, w
+
+    moe.router, keep = spy, moe.router
+    try:
+        jax.block_until_ready(
+            m._trunk(m.params_, m.state_["router_bias"], ids))
+        jax.effects_barrier()
+    finally:
+        moe.router = keep
+    assert len(chosen) == 2
+    want = np.stack([np.bincount(np.asarray(c).ravel(), minlength=8)
+                     for c in chosen])
+    m.fit_batch(batch)
+    np.testing.assert_array_equal(m.expert_load(), want)
+
+
+def test_fit_steps_is_k_fit_batches():
+    a = DecoderModel(DecoderConfig.tiny(), seed=4)
+    b = DecoderModel(DecoderConfig.tiny(), seed=4)
+    b1, b2 = _batch(1), _batch(2)
+    la = [float(a.fit_batch(b1)), float(a.fit_batch(b2))]
+    lb = b.fit_steps(MultiDataSet(
+        features=[np.stack([b1.features[0], b2.features[0]])],
+        labels=[np.stack([b1.labels[0], b2.labels[0]])]))
+    np.testing.assert_allclose(np.asarray(lb), la, rtol=1e-5)
+    assert b.iteration == 2
+    np.testing.assert_array_equal(a.expert_load(), b.expert_load())
+
+
+def test_save_load_round_trip():
+    m = DecoderModel(DecoderConfig.tiny(), seed=5)
+    m.fit_batch(_batch())
+    f = io.BytesIO()
+    m.save(f)
+    f.seek(0)
+    m2 = DecoderModel.load(f)
+    assert m2.iteration == 1 and m2.num_params() == m.num_params()
+    ids = _batch().features[0]
+    np.testing.assert_array_equal(np.asarray(m.output(ids)),
+                                  np.asarray(m2.output(ids)))
+    np.testing.assert_array_equal(m.expert_load(), m2.expert_load())
+    assert float(m.fit_batch(_batch(1))) == float(m2.fit_batch(_batch(1)))
+
+
+def test_a_share_outside_the_routers_width_is_refused():
+    with pytest.raises(ValueError, match="not among"):
+        DecoderModel(DecoderConfig.tiny(first_expert=6, n_experts_held=4))
