@@ -31,6 +31,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -464,11 +465,32 @@ def _flash_attention_diff(q, k, v, mask, causal, scale, block_q=256,
                                mask=mask, interpret=interpret)
 
 
+# What `_fa_fwd` names for a caller's `jax.checkpoint` policy.
+FLASH_OUT = "flash_attention_out"
+FLASH_LSE = "flash_attention_lse"
+
+
 def _fa_fwd(q, k, v, mask, causal, scale, block_q, block_k,
             interpret=False):
+    """The forward kernel, once; residuals for `_fa_bwd`.
+
+    `out` [B, H, T, Dv] and the row logsumexp [B*H, T] (float32) are the two
+    residuals the backward kernels need that a caller cannot rebuild without
+    running this kernel again, so they carry the names `FLASH_OUT` and
+    `FLASH_LSE`: a block under `jax.checkpoint(policy=
+    save_only_these_names(FLASH_OUT, FLASH_LSE))` keeps them and its
+    backward pass holds no second forward kernel (`zoo/decoder.py`).  These
+    two and not q/k/v: at kanana's shapes they are 68 MB a layer against
+    268 MB, and q/k/v come back from the projections at the MXU's rate
+    (saving them too did not fit 16 GB: PERF.md, PR 27 and PR 28).  The
+    logsumexp is named in its [B*H, T] form; the kernel's [B*H, T, 1] pads
+    to 128 lanes in HBM.  The named `out` is both the primal result and the
+    residual.  Without such a policy a name is an identity."""
     out, lse = flash_attention_tpu(q, k, v, causal, scale, block_q, block_k,
                                    return_lse=True, mask=mask,
                                    interpret=interpret)
+    out = checkpoint_name(out, FLASH_OUT)
+    lse = checkpoint_name(lse, FLASH_LSE)
     return out, (q, k, v, mask, out, lse)
 
 

@@ -26,9 +26,15 @@ TPU-native choices, as `zoo/bert.py`: one jitted, donated train step;
 float32 master parameters cast to `compute_dtype` a layer at a time; the
 identical expert layers STACKED `[L, ...]` under one `lax.scan`, so compile
 time is flat in depth.  What is saved for the backward pass is fixed here,
-by measurement (PERF.md, PR 27): each block's input, and the block is
-computed again in the backward pass.  At 8,192 tokens a step beside 9.2 GB
-of training state nothing less fits a 16 GB chip.
+by measurement (PERF.md, PR 27 and PR 28): each block's input and the
+attention kernel's output and logsumexp.  The rest of the block is computed
+again in the backward pass, the flash forward kernel is not: its two
+results are what its backward kernels need and 68 MB a layer at 2 x 4,096
+tokens, where q, k and v are 268 MB and come back from the projections.  At
+8,192 tokens a step beside 9.2 GB of training state saving those too does
+not fit a 16 GB chip.  Where `fused_attention` takes no Pallas kernel (the
+CPU, short sequences) there is nothing of that name and the block is
+recomputed whole.
 
 Not here yet: prefill/decode through a cache, absorbed latent attention,
 the experts' exchange over several chips.
@@ -45,7 +51,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from deeplearning4j_tpu.monitor.spans import note, span
-from deeplearning4j_tpu.ops.attention_kernels import fused_attention
+from deeplearning4j_tpu.ops.attention_kernels import (FLASH_LSE, FLASH_OUT,
+                                                      fused_attention)
 from deeplearning4j_tpu.ops.moe import (expert_layer, swiglu,
                                         update_router_bias)
 from deeplearning4j_tpu.ops.norm_kernels import rms_norm
@@ -229,13 +236,18 @@ class DecoderModel:
                 first_held=c.first_expert)
             return x + y.reshape(B, T, H).astype(x.dtype), counts
 
-        # each block keeps its input alone for the backward pass and is
-        # computed again there (module docstring)
-        @jax.checkpoint
+        # each block keeps its input and the flash kernel's two results for
+        # the backward pass; the rest is computed again there (module
+        # docstring)
+        keep = jax.checkpoint_policies.save_only_these_names(FLASH_OUT,
+                                                             FLASH_LSE)
+
+        @functools.partial(jax.checkpoint, policy=keep)
         def dense_block(x, lp):
             return dense_ffn(self._attention(x, lp), lp)
 
-        @functools.partial(jax.checkpoint, prevent_cse=False)  # under scan
+        @functools.partial(jax.checkpoint, policy=keep,
+                           prevent_cse=False)                  # under scan
         def moe_block(x, layer):
             lp, bias = layer
             lp = {**cast(lp), "router": lp["router"]}   # it stays float32

@@ -5,6 +5,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from jax.sharding import PartitionSpec as P
 from jax import shard_map
 
@@ -237,6 +238,112 @@ def test_flash_lse_matches_reference():
     ref_lse = jax.scipy.special.logsumexp(scores, axis=-1).reshape(1, 256)
     np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
                                rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# The names on the forward kernel's two results (`FLASH_OUT`, `FLASH_LSE`)
+# ---------------------------------------------------------------------------
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr, those of its inner jaxprs (scan bodies,
+    checkpoints, custom rules) included; a kernel's own body is not entered."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub)
+
+
+def _primitives(jaxpr):
+    return [eqn.primitive.name for eqn in _equations(jaxpr)]
+
+
+def _forced_flash(q, k, v):
+    from deeplearning4j_tpu.ops import pallas as tier
+    prev = tier.dispatch.set_dispatch_mode("pallas")
+    tier.dispatch.set_tile("attention", tier.TileConfig(block_q=64,
+                                                        block_kv=128))
+    try:
+        return fused_attention(q, k, v, causal=True)
+    finally:
+        tier.dispatch.set_dispatch_mode(prev)
+        tier.dispatch.clear_tiles()
+
+
+def test_names_are_inert_without_a_policy():
+    """Un-checkpointed `jax.grad(fused_attention)` through the kernels: three
+    `pallas_call`s, and the gradients are to the last bit what the forward
+    and backward kernels give when called by hand, with no name between
+    them."""
+    from deeplearning4j_tpu.ops.attention_kernels import \
+        flash_attention_bwd_tpu
+    q, k, v = _qkv(B=1, H=2, T=256, D=32)
+    g = _qkv(B=1, H=2, T=256, D=32, seed=1)[0]
+    loss = lambda q, k, v: jnp.sum(_forced_flash(q, k, v) * g)
+    prims = _primitives(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, k, v).jaxpr)
+    assert prims.count("pallas_call") == 3
+    assert prims.count("name") == 2          # there, and an identity
+    got = jax.grad(loss, (0, 1, 2))(q, k, v)
+    out, lse = flash_attention_tpu(q, k, v, True, None, 64, 128,
+                                   interpret=True, return_lse=True)
+    want = flash_attention_bwd_tpu(q, k, v, out, lse, g, True, None, 64, 128,
+                                   interpret=True)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_array_equal(np.asarray(_forced_flash(q, k, v)),
+                                  np.asarray(out))
+
+
+@pytest.mark.parametrize("branch", ["xla", "blockwise"])
+def test_branches_without_the_kernels_hold_no_name(branch, monkeypatch):
+    """The XLA and the blockwise branch (every CPU run; short sequences on
+    the chip) carry no named value, so a caller's policy saves nothing of
+    theirs; their gradients are the reference's."""
+    import deeplearning4j_tpu.ops.attention_kernels as ak
+    if branch == "blockwise":
+        monkeypatch.setattr(ak, "_XLA_SCORE_BYTES_MAX", 0)
+    q, k, v = _qkv(B=1, H=2, T=256, D=32)
+    loss = lambda f: lambda q, k, v: jnp.sum(f(q, k, v) ** 2)
+    fused = loss(lambda q, k, v: ak.fused_attention(q, k, v, causal=True))
+    keep = jax.checkpoint(fused, policy=jax.checkpoint_policies
+                          .save_only_these_names(ak.FLASH_OUT, ak.FLASH_LSE))
+    prims = _primitives(jax.make_jaxpr(jax.grad(keep, (0, 1, 2)))(q, k, v).jaxpr)
+    assert "name" not in prims and "pallas_call" not in prims
+    want = jax.grad(loss(lambda q, k, v: mha_reference(q, k, v, None, True)),
+                    (0, 1, 2))(q, k, v)
+    for f in (fused, keep):
+        for a, b in zip(jax.grad(f, (0, 1, 2))(q, k, v), want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-4, atol=2e-5)
+
+
+def test_a_policy_keeps_the_two_named_results_and_no_second_forward(capsys):
+    """Under `save_only_these_names(FLASH_OUT, FLASH_LSE)` the backward pass
+    is handed q, k, v (arguments), `out` [B, H, T, Dv] and the logsumexp as
+    [B*H, T] — never the kernel's lane-padded [B*H, T, 1] — and runs the
+    two backward kernels alone; a bare checkpoint runs the forward again."""
+    import deeplearning4j_tpu.ops.attention_kernels as ak
+    q, k, _ = _qkv(B=1, H=2, T=256, D=32)
+    v = _qkv(B=1, H=2, T=256, D=16, seed=2)[2]
+    f = lambda q, k, v: jnp.sum(_forced_flash(q, k, v) ** 2)
+    keep = jax.checkpoint(f, policy=jax.checkpoint_policies
+                          .save_only_these_names(ak.FLASH_OUT, ak.FLASH_LSE))
+    count = lambda f: _primitives(jax.make_jaxpr(jax.grad(f, (0, 1, 2)))(
+        q, k, v).jaxpr).count("pallas_call")
+    assert (count(keep), count(jax.checkpoint(f)), count(f)) == (3, 4, 3)
+    capsys.readouterr()
+    jax.ad_checkpoint.print_saved_residuals(keep, q, k, v)
+    lines = capsys.readouterr().out.strip().splitlines()
+    saved = [l for l in lines if " from the argument " not in l]
+    assert [l.split()[0] for l in saved] == ["f32[1,2,256,16]", "f32[2,256]"], lines
+    assert f"named '{ak.FLASH_LSE}'" in saved[1], lines
+    for a, b in zip(jax.grad(keep, (0, 1, 2))(q, k, v),
+                    jax.grad(f, (0, 1, 2))(q, k, v)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 # ---------------------------------------------------------------------------

@@ -17,6 +17,7 @@ from deeplearning4j_tpu.ops import pallas as tier
 from deeplearning4j_tpu.ops.norm_kernels import rms_norm
 from deeplearning4j_tpu.ops.rotary import rotary_interleaved
 from deeplearning4j_tpu.zoo import DecoderConfig, DecoderModel
+from tests.test_attention_kernels import _equations
 
 
 @pytest.fixture(autouse=True)
@@ -441,3 +442,140 @@ def test_save_load_round_trip():
 def test_a_share_outside_the_routers_width_is_refused():
     with pytest.raises(ValueError, match="not among"):
         DecoderModel(DecoderConfig.tiny(first_expert=6, n_experts_held=4))
+
+
+# ---------------------------------------------------------------------------
+# what the blocks save for the backward pass
+# ---------------------------------------------------------------------------
+
+T_FLASH = 64          # a sequence the forced tier's tiles (16 x 32) divide
+
+
+def _flash_model(seed=6):
+    """`DecoderConfig.tiny` with the attention kernels forced (interpret
+    mode on the CPU), as the chip runs them from 2k tokens on."""
+    tier.dispatch.set_dispatch_mode("pallas")
+    tier.dispatch.set_tile("attention", tier.TileConfig(block_q=16,
+                                                        block_kv=32))
+    return DecoderModel(DecoderConfig.tiny(), seed=seed)
+
+
+def _patch_recomputation(mp, scheme):
+    """`policy`: the decoder as it is.  `bare`: each block under a bare
+    `jax.checkpoint` (what it was before PR 28).  `none`: no checkpoint."""
+    if scheme == "bare":
+        mp.setattr(jax.checkpoint_policies, "save_only_these_names",
+                   lambda *names: None)
+    elif scheme == "none":
+        mp.setattr(jax, "checkpoint", lambda f, **kw: f)
+    else:
+        assert scheme == "policy"
+
+
+def _blocks(model, scheme="policy"):
+    """The dense block and the expert block as `_trunk` wraps them, each with
+    arguments to call it on."""
+    made = []
+    with pytest.MonkeyPatch.context() as mp:
+        _patch_recomputation(mp, scheme)
+        wrap = jax.checkpoint
+        mp.setattr(jax, "checkpoint",
+                   lambda f, **kw: made.append(wrap(f, **kw)) or made[-1])
+        jax.eval_shape(model._trunk, model.params_,
+                       model.state_["router_bias"],
+                       jnp.zeros((2, T_FLASH), jnp.int32))
+    dense, moe_block = made
+    c = model.config
+    x = jax.random.normal(jax.random.PRNGKey(9), (2, T_FLASH, c.hidden),
+                          jnp.float32)
+    lp = jax.tree_util.tree_map(lambda a: a[0], model.params_["dense"])
+    layers = (model.params_["moe"], model.state_["router_bias"])
+    return {"dense": (dense, (x, lp)),
+            "scanned_experts": (
+                lambda x, layers: jax.lax.scan(moe_block, x, layers)[0],
+                (x, layers)),
+            "expert": (lambda x, layer: moe_block(x, layer)[0],
+                       (x, jax.tree_util.tree_map(lambda a: a[0], layers)))}
+
+
+def _kernels(jaxpr):
+    """The kernel functions of a jaxpr's `pallas_call`s, by name."""
+    return [eqn.params["jaxpr"].debug_info.func_name
+            for eqn in _equations(jaxpr)
+            if eqn.primitive.name == "pallas_call"]
+
+
+@pytest.mark.parametrize("block", ["dense", "scanned_experts"])
+@pytest.mark.parametrize("scheme,kernels", [("policy", 3), ("bare", 4)])
+def test_a_blocks_gradient_runs_the_flash_forward_once(block, scheme,
+                                                       kernels):
+    """Forward kernel, dQ kernel, dK/dV kernel: 3 a block (a scan's body
+    counts once).  Under a bare checkpoint the backward pass holds the
+    forward kernel a second time."""
+    f, args = _blocks(_flash_model(), scheme)[block]
+    jaxpr = jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(f(*a))))(*args)
+    names = _kernels(jaxpr.jaxpr)
+    assert sorted(names) == sorted(
+        ["_flash_kernel"] * (kernels - 2)
+        + ["_flash_bwd_dq_kernel", "_flash_bwd_dkv_kernel"]), names
+
+
+@pytest.mark.parametrize("block", ["dense", "expert", "scanned_experts"])
+def test_a_block_saves_its_input_and_the_kernels_two_results(block, capsys):
+    """Beside the block's arguments (and rotary's two-element constants)
+    the backward pass is handed the kernel's output [B, H, T, Dv] and the
+    logsumexp as [B*H, T] — not the kernel's own [B*H, T, 1], which pads to
+    128 lanes on the chip — and nothing else."""
+    m = _flash_model()
+    f, args = _blocks(m)[block]
+    capsys.readouterr()
+    jax.ad_checkpoint.print_saved_residuals(f, *args)
+    lines = capsys.readouterr().out.strip().splitlines()
+    saved = [l for l in lines
+             if " from the argument " not in l and " from a constant" not in l]
+    c = m.config
+    out = f"f32[2,{c.n_heads},{T_FLASH},{c.v_head_dim}]"
+    lse = f"f32[{2 * c.n_heads},{T_FLASH}]"
+    if block == "scanned_experts":      # stacked by the scan, with its input
+        L = c.n_layers - c.n_dense_layers
+        want = [f"f32[{L},{s[4:]}" for s in
+                (out, lse, f"f32[2,{T_FLASH},{c.hidden}]")]
+        assert sorted(l.split()[0] for l in saved) == sorted(want), lines
+    else:
+        assert [l.split()[0] for l in saved] == [out, lse], lines
+        assert f"named '{ak.FLASH_LSE}'" in saved[1], lines
+        assert any(" from the argument x" in l for l in lines), lines
+
+
+def test_fit_batch_is_bit_equal_whatever_the_blocks_save():
+    """One train step with the kernels: the parameters after it are the
+    same to the last bit whether a block keeps the kernel's two results or
+    runs the kernel again, and within float32 round-off of a step that
+    recomputes nothing."""
+    after = {}
+    for scheme in ("policy", "bare", "none"):
+        m = _flash_model(seed=7)
+        with pytest.MonkeyPatch.context() as mp:
+            _patch_recomputation(mp, scheme)
+            loss = float(m.fit_batch(_batch(5, t=T_FLASH)))
+        assert np.isfinite(loss)
+        after[scheme] = (loss, jax.tree_util.tree_leaves(m.params_))
+    assert after["policy"][0] == after["bare"][0]
+    for a, b, c in zip(*(after[s][1] for s in ("policy", "bare", "none"))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c), atol=1e-6)
+
+
+def test_without_the_kernels_a_block_is_recomputed_whole(capsys):
+    """Where `fused_attention` takes the XLA branch (every CPU run, short
+    sequences) nothing carries the names: the block saves its arguments
+    alone, as under a bare checkpoint."""
+    m = DecoderModel(DecoderConfig.tiny(), seed=6)
+    f, args = _blocks(m)["dense"]
+    assert _kernels(jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(f(*a))))(*args).jaxpr) == []
+    capsys.readouterr()
+    jax.ad_checkpoint.print_saved_residuals(f, *args)
+    lines = capsys.readouterr().out.strip().splitlines()
+    assert lines and all(" from the argument " in l
+                         or " from a constant" in l for l in lines), lines
