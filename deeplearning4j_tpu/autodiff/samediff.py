@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deeplearning4j_tpu.autodiff.ops import OP_TABLE
+from deeplearning4j_tpu.nn.trainer import CompiledStepOwner
 from deeplearning4j_tpu.ops.initializers import init_weights
 from deeplearning4j_tpu.train.updaters import Adam, IUpdater
 
@@ -468,10 +469,16 @@ def _eval_control_flow(node: "Node", args: List[Any]) -> Any:
 RNG_FEED = "__dropout_rng__"
 
 
-class SameDiff:
-    """The graph container (reference `SameDiff.create()`)."""
+class SameDiff(CompiledStepOwner):
+    """The graph container (reference `SameDiff.create()`).  The executable
+    cache, the schedule and the disk key of its compiled train step are
+    `CompiledStepOwner`'s (nn/trainer.py); the step builder is its own."""
+
+    _AOT_PREFIX = "samediff"
+    _DONATED = (0, 1)         # variables, opt_state
 
     def __init__(self):
+        super().__init__()
         self._nodes: Dict[str, Node] = {}
         self.variables_: Dict[str, jnp.ndarray] = {}   # trainable values
         self._constants: Dict[str, jnp.ndarray] = {}
@@ -483,9 +490,6 @@ class SameDiff:
         self.epoch = 0
         self._train_step = None
         self._scan_step = None
-        self._step_transform = None   # ZeRO-1 weight update (parallel/zero)
-        self._exec_cache_override = None  # compile.PersistentExecutableCache
-        self._schedule = None             # compile.Schedule (autotuner)
         self._output_fns: Dict[Tuple[str, ...], Callable] = {}
         self._key = jax.random.PRNGKey(0)
         self.math = SDMath(self)
@@ -520,9 +524,12 @@ class SameDiff:
         self._invalidate()
         return SDVariable(self, node.name)
 
-    def _invalidate(self):
+    def _invalidate_steps(self) -> None:
         self._train_step = None
         self._scan_step = None
+
+    def _invalidate(self):
+        self._invalidate_steps()
         self._output_fns = {}
 
     # ---- declaration API ----
@@ -820,48 +827,6 @@ class SameDiff:
 
         return step
 
-    def _exec_cache(self):
-        """The persistent executable cache in play: the per-graph override
-        (`set_executable_cache`), else the process default — None keeps
-        the plain jax.jit path."""
-        if self._exec_cache_override is not None:
-            return self._exec_cache_override
-        from deeplearning4j_tpu.compile import default_cache
-        return default_cache()
-
-    def set_executable_cache(self, cache) -> "SameDiff":
-        """Route this graph's train-step compilation through a
-        `compile.PersistentExecutableCache` (or a directory path); None
-        reverts to the process default.  Triggers a step rebuild."""
-        if isinstance(cache, str):
-            from deeplearning4j_tpu.compile import PersistentExecutableCache
-            cache = PersistentExecutableCache(cache)
-        self._exec_cache_override = cache
-        self._train_step = None
-        self._scan_step = None
-        return self
-
-    def apply_schedule(self, schedule) -> "SameDiff":
-        """Install an autotuned `compile.Schedule` (iterator `fit()`
-        defaults `fused_steps` from it; the step builder honors
-        `schedule.donation`).  Triggers a step rebuild."""
-        self._schedule = schedule
-        self._train_step = None
-        self._scan_step = None
-        return self
-
-    def _donate_argnums(self) -> tuple:
-        if self._schedule is not None and not self._schedule.donation:
-            return ()
-        return (0, 1)
-
-    def _aot_key_parts(self) -> dict:
-        from deeplearning4j_tpu.compile import (model_fingerprint,
-                                                transform_fingerprint)
-        return {"kind": "samediff_train_step",
-                "model": model_fingerprint(self),
-                "transform": transform_fingerprint(self._step_transform)}
-
     def _build_train_step(self):
         from deeplearning4j_tpu.compile import step_function
         return step_function(self._build_step_body(),
@@ -883,8 +848,7 @@ class SameDiff:
 
         return make_scan_step(
             tick,
-            key_base=lambda: dict(self._aot_key_parts(),
-                                  kind="samediff_scan_step"),
+            key_base=lambda: self._aot_key_parts("scan_step"),
             cache=self._exec_cache(),
             donate=(self._schedule is None or self._schedule.donation))
 

@@ -17,19 +17,16 @@ from __future__ import annotations
 
 import dataclasses
 import json
-import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deeplearning4j_tpu.monitor.instrument import TrainingInstruments
-from deeplearning4j_tpu.monitor.spans import note, span
 from deeplearning4j_tpu.nn.core import InputType, Layer, PyTree
-from deeplearning4j_tpu.nn.multilayer import _add_scaled_where, _masked_leaves
-from deeplearning4j_tpu.train.updaters import (
-    IUpdater, Sgd, apply_gradient_normalization)
+from deeplearning4j_tpu.nn.multilayer import _masked_leaves
+from deeplearning4j_tpu.nn.trainer import LayerwiseTrainer
+from deeplearning4j_tpu.train.updaters import IUpdater, Sgd
 
 Params = Dict[str, PyTree]
 
@@ -447,12 +444,20 @@ class GraphBuilder:
 # Network
 # ---------------------------------------------------------------------------
 
-class ComputationGraph:
+class ComputationGraph(LayerwiseTrainer):
     """DAG network (reference `ComputationGraph`).  API parity:
     `init`, `fit(MultiDataSet | (features, labels))`, `output(*features)`,
-    `score`, `evaluate`, `gradient_for`, `save`/`load`."""
+    `score`, `evaluate`, `gradient_for`, `save`/`load`.  The compiled train
+    step, its cache and its dispatch are `LayerwiseTrainer`'s
+    (nn/trainer.py)."""
+
+    _AOT_PREFIX = "cg"
+    _BATCH_ARITY = 3          # inputs, labels, lmasks
+    # a vertex with no parameters passes through the update loop untouched
+    _SKIP_EMPTY_ENTRIES = True
 
     def __init__(self, conf: ComputationGraphConfiguration):
+        super().__init__()
         self.conf = conf
         self.params_: Optional[Params] = None
         self.state_: Optional[Params] = None
@@ -462,24 +467,9 @@ class ComputationGraph:
         self.listeners: List[Any] = []
         self._rng = jax.random.PRNGKey(conf.seed)
         self._topo = conf.topological_order()
-        self._train_step = None
-        self._scan_step = None
-        self._grad_step = None    # hierarchical-sharing split: grad half
-        self._apply_step = None   # hierarchical-sharing split: apply half
-        self._grad_sharing = None  # parallel.hierarchical.HierarchicalAllReduce
         self._output_fn = None
-        self._step_transform = None   # ZeRO-1 weight update (parallel/zero)
         self._vertex_types: Dict[str, InputType] = {}
         self._device_norm: Dict[str, Any] = {}  # input name -> DeviceNormalizer
-        self._instr: Optional[TrainingInstruments] = None
-        self._exec_cache_override = None  # compile.PersistentExecutableCache
-        self._schedule = None             # compile.Schedule (autotuner)
-
-    def _instruments(self) -> TrainingInstruments:
-        """Lazy telemetry handles shared via the monitor registry."""
-        if self._instr is None:
-            self._instr = TrainingInstruments(type(self).__name__)
-        return self._instr
 
     def _layer_of(self, name: str) -> Optional[Layer]:
         v = self.conf.vertices[name]
@@ -601,350 +591,37 @@ class ComputationGraph:
                     penalty = penalty + 0.5 * l2 * jnp.sum(w * w)
         return penalty
 
-    # ---- compiled step ----
-    def _build_step_body(self):
-        conf = self.conf
-        zt = self._step_transform   # ZeRO-1 sharded weight update, or None
+    # ---- what the trainer asks of the model (nn/trainer.py) ----
+    def _update_entries(self):
+        return [(name, self._layer_of(name), self._updater_for(name))
+                for name in self._topo]
 
-        def step(params, state, opt_state, inputs, labels, lmasks, rng,
-                 iteration, epoch):
-            # split inside the compiled step (see MultiLayerNetwork._fit_batch:
-            # device-resident rng/iteration carries, no per-step H2D)
-            inputs = self._apply_device_norm(inputs)
-            rng, srng = jax.random.split(rng)
-            master = params
-            if zt is not None:
-                # all-gather sharded master params once per step; the DAG
-                # forward/backward run on the gathered (or TP) layout
-                params = zt.gather_all(params)
+    def _batch_loss(self, params, state, batch, rng):
+        inputs, labels, lmasks = batch
+        return self._loss(params, state, inputs, labels, rng, lmasks)
 
-            def loss_fn(p):
-                return self._loss(p, state, inputs, labels, srng, lmasks)
+    def _normalize_batch(self, batch):
+        inputs, labels, lmasks = batch
+        return self._apply_device_norm(inputs), labels, lmasks
 
-            (loss, new_state), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params)
-
-            new_params, new_opt = {}, {}
-            for name in self._topo:
-                layer = self._layer_of(name)
-                if not params[name]:
-                    new_params[name], new_opt[name] = master[name], opt_state[name]
-                    continue
-                if layer is not None and layer.frozen:
-                    new_params[name], new_opt[name] = master[name], opt_state[name]
-                    continue
-                g = grads[name]
-                gn = (layer.gradient_normalization if layer is not None and
-                      layer.gradient_normalization is not None
-                      else conf.gradient_normalization)
-                if gn:
-                    thr = (layer.gradient_normalization_threshold
-                           if layer is not None and
-                           layer.gradient_normalization is not None
-                           else conf.gradient_normalization_threshold)
-                    g = apply_gradient_normalization(g, gn, thr)
-                if zt is None:
-                    p_upd = params[name]
-                else:
-                    # reduce-scatter grads; updater touches only this
-                    # device's shard of params/moments
-                    g = zt.scatter(name, g)
-                    p_upd = zt.update_view(name, master[name])
-                upd_cfg = self._updater_for(name)
-                upd, new_o = upd_cfg.apply(
-                    opt_state[name], g, iteration, epoch, params=p_upd)
-                wd = (layer.weight_decay if layer is not None and
-                      layer.weight_decay is not None else conf.weight_decay)
-                if wd and layer is not None:
-                    lr = upd_cfg.lr_at(iteration, epoch)
-                    upd = _add_scaled_where(
-                        upd, p_upd,
-                        layer.regularizable_mask(p_upd), lr * wd)
-                new_p = jax.tree_util.tree_map(
-                    lambda p_, u_: p_ - u_, p_upd, upd)
-                if zt is not None:
-                    new_p = zt.restore(name, new_p)
-                    new_o = zt.constrain_opt(name, new_o)
-                new_params[name], new_opt[name] = new_p, new_o
-            return new_params, new_state, new_opt, loss, rng, iteration + 1
-
-        return step
-
-    def _exec_cache(self):
-        """The persistent executable cache in play: the per-model override
-        (`set_executable_cache`), else the process default — None keeps
-        the plain jax.jit path."""
-        if self._exec_cache_override is not None:
-            return self._exec_cache_override
-        from deeplearning4j_tpu.compile import default_cache
-        return default_cache()
-
-    def set_executable_cache(self, cache) -> "ComputationGraph":
-        """Route this graph's train-step compilation through a
-        `compile.PersistentExecutableCache` (or a directory path); None
-        reverts to the process default.  Triggers a step rebuild."""
-        if isinstance(cache, str):
-            from deeplearning4j_tpu.compile import PersistentExecutableCache
-            cache = PersistentExecutableCache(cache)
-        self._exec_cache_override = cache
-        self._train_step = None
-        self._scan_step = None
-        self._grad_step = None
-        self._apply_step = None
-        return self
-
-    def apply_schedule(self, schedule) -> "ComputationGraph":
-        """Install an autotuned `compile.Schedule` (iterator `fit()`
-        defaults `fused_steps` from it; step builders honor
-        `schedule.donation`).  Triggers a step rebuild."""
-        self._schedule = schedule
-        self._train_step = None
-        self._scan_step = None
-        self._grad_step = None
-        self._apply_step = None
-        return self
-
-    def _donate_argnums(self) -> tuple:
-        if self._schedule is not None and not self._schedule.donation:
-            return ()
-        return (0, 1, 2)
-
-    def _aot_key_parts(self) -> dict:
-        from deeplearning4j_tpu.compile import (model_fingerprint,
-                                                transform_fingerprint)
-        return {"kind": "cg_train_step",
-                "model": model_fingerprint(self),
-                "transform": transform_fingerprint(self._step_transform)}
-
-    def _get_train_step(self):
-        if self._train_step is None:
-            from deeplearning4j_tpu.compile import step_function
-            self._train_step = step_function(
-                self._build_step_body(),
-                donate_argnums=self._donate_argnums(),
-                key_base=self._aot_key_parts,
-                cache=self._exec_cache(),
-                dynamic_argnums=(3, 4, 5))
-        return self._train_step
-
-    # ---- hierarchical gradient sharing (parallel.hierarchical) ----
-    def set_gradient_sharing(self, sharing) -> "ComputationGraph":
-        """Enable/disable hierarchical compressed cross-host gradient
-        sharing (see MultiLayerNetwork.set_gradient_sharing — identical
-        semantics over the DAG step)."""
-        from deeplearning4j_tpu.parallel.hierarchical import (
-            HierarchicalAllReduce, HierarchicalGradientSharing)
-        if sharing is None:
-            if self._grad_sharing is not None:
-                self._grad_sharing.close()
-            self._grad_sharing = None
-        elif isinstance(sharing, HierarchicalGradientSharing):
-            self._grad_sharing = HierarchicalAllReduce(sharing)
-        elif isinstance(sharing, HierarchicalAllReduce):
-            self._grad_sharing = sharing
-        else:
-            raise TypeError(
-                "set_gradient_sharing expects HierarchicalGradientSharing, "
-                f"HierarchicalAllReduce or None, got {type(sharing).__name__}")
-        self._grad_step = None
-        self._apply_step = None
-        return self
-
-    @property
-    def gradient_sharing(self):
-        """The installed `HierarchicalAllReduce`, or None."""
-        return self._grad_sharing
-
-    def _build_grad_body(self):
-        """Grad half of the split step (params NOT donated — the apply
-        half consumes them next)."""
-        zt = self._step_transform
-
-        def grad_step(params, state, inputs, labels, lmasks, rng):
-            inputs = self._apply_device_norm(inputs)
-            rng, srng = jax.random.split(rng)
-            fwd_params = params if zt is None else zt.gather_all(params)
-
-            def loss_fn(p):
-                return self._loss(p, state, inputs, labels, srng, lmasks)
-
-            (loss, new_state), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(fwd_params)
-            if zt is not None:
-                # ship the reduce-scattered (padded) shard, not the
-                # gathered tree; empty param subtrees scatter to empty
-                grads = {name: zt.scatter(name, grads[name])
-                         for name in self._topo}
-            return grads, new_state, loss, rng
-
-        return grad_step
-
-    def _build_apply_body(self):
-        """Apply half: updater loop on the DCN-combined gradient
-        (normalization runs here, on the combined gradient)."""
-        conf = self.conf
-        zt = self._step_transform
-
-        def apply_step(params, opt_state, grads, iteration, epoch):
-            new_params, new_opt = {}, {}
-            for name in self._topo:
-                layer = self._layer_of(name)
-                if not params[name]:
-                    new_params[name] = params[name]
-                    new_opt[name] = opt_state[name]
-                    continue
-                if layer is not None and layer.frozen:
-                    new_params[name] = params[name]
-                    new_opt[name] = opt_state[name]
-                    continue
-                g = grads[name]
-                if zt is not None:
-                    g = zt.constrain_update(name, g)
-                gn = (layer.gradient_normalization if layer is not None and
-                      layer.gradient_normalization is not None
-                      else conf.gradient_normalization)
-                if gn:
-                    thr = (layer.gradient_normalization_threshold
-                           if layer is not None and
-                           layer.gradient_normalization is not None
-                           else conf.gradient_normalization_threshold)
-                    g = apply_gradient_normalization(g, gn, thr)
-                p_upd = (params[name] if zt is None
-                         else zt.update_view(name, params[name]))
-                upd_cfg = self._updater_for(name)
-                upd, new_o = upd_cfg.apply(
-                    opt_state[name], g, iteration, epoch, params=p_upd)
-                wd = (layer.weight_decay if layer is not None and
-                      layer.weight_decay is not None else conf.weight_decay)
-                if wd and layer is not None:
-                    lr = upd_cfg.lr_at(iteration, epoch)
-                    upd = _add_scaled_where(
-                        upd, p_upd,
-                        layer.regularizable_mask(p_upd), lr * wd)
-                new_p = jax.tree_util.tree_map(
-                    lambda p_, u_: p_ - u_, p_upd, upd)
-                if zt is not None:
-                    new_p = zt.restore(name, new_p)
-                    new_o = zt.constrain_opt(name, new_o)
-                new_params[name], new_opt[name] = new_p, new_o
-            return new_params, new_opt, iteration + 1
-
-        return apply_step
-
-    def _get_grad_step(self):
-        if self._grad_step is None:
-            from deeplearning4j_tpu.compile import step_function
-            self._grad_step = step_function(
-                self._build_grad_body(),
-                donate_argnums=(1,),
-                key_base=lambda: dict(
-                    self._aot_key_parts(), kind="cg_grad_step"),
-                cache=self._exec_cache(),
-                dynamic_argnums=(2, 3, 4))
-        return self._grad_step
-
-    def _get_apply_step(self):
-        if self._apply_step is None:
-            from deeplearning4j_tpu.compile import step_function
-            self._apply_step = step_function(
-                self._build_apply_body(),
-                donate_argnums=(0, 1),
-                key_base=lambda: dict(
-                    self._aot_key_parts(), kind="cg_apply_step"),
-                cache=self._exec_cache(),
-                dynamic_argnums=())
-        return self._apply_step
-
-    def _fit_batch_shared(self, inputs, labels, lmasks=None):
-        from deeplearning4j_tpu.utils.counters import advance, device_counters
-        t0 = time.perf_counter()
-        gstep = self._get_grad_step()
-        grads, self.state_, loss, self._rng = gstep(
-            self.params_, self.state_, inputs, labels, lmasks, self._rng)
-        combined = self._grad_sharing.exchange(grads)
-        astep = self._get_apply_step()
-        it_dev, ep_dev = device_counters(self)
-        self.params_, self.opt_state_, new_it = astep(
-            self.params_, self.opt_state_, combined, it_dev, ep_dev)
-        t1 = time.perf_counter()
-        note("step_dispatch", t0, t1, self.iteration)
-        ins = self._instruments()
-        ins.record_dispatch(t1 - t0)
-        ins.check_compile(gstep, self)
-        ins.check_compile(astep, self)
-        self._score = loss
-        self._last_batch_size = int(next(iter(inputs.values())).shape[0])
-        advance(self, new_it)
-        for lst in self.listeners:
-            lst.iteration_done(self, self.iteration, self.epoch)
-
-    def _get_scan_step(self):
-        if self._scan_step is None:
-            from deeplearning4j_tpu.utils.scan_fit import make_scan_step
-            body = self._build_step_body()
-
-            def tick(carry, epoch, batch):
-                p, s, o, r, it = carry
-                ins, ys, lm = batch
-                p, s, o, loss, r, it = body(p, s, o, ins, ys, lm,
-                                            r, it, epoch)
-                return (p, s, o, r, it), loss
-
-            self._scan_step = make_scan_step(
-                tick,
-                key_base=lambda: dict(self._aot_key_parts(),
-                                      kind="cg_scan_step"),
-                cache=self._exec_cache(),
-                donate=(self._schedule is None or self._schedule.donation))
-        return self._scan_step
+    @staticmethod
+    def _batch_rows(batch, axis: int) -> int:
+        return int(next(iter(batch[0].values())).shape[axis])
 
     def fit_steps(self, features, labels, labels_masks=None):
         """Run k training steps in one device dispatch; every array in
         `features`/`labels`/`labels_masks` carries a leading `[k, batch]`
         steps axis.  Same math as k sequential `fit` calls (scan carries
         params/updater/rng/iteration); listeners fire once per block."""
-        from deeplearning4j_tpu.utils.counters import advance, device_counters
+        from deeplearning4j_tpu.utils.scan_fit import check_steps_axes
         inputs = self._as_input_dict(features)
         labels = self._as_list(labels)
-        if labels_masks is not None and not isinstance(labels_masks,
-                                                       (list, tuple)):
-            labels_masks = [labels_masks]
-        lmasks = (None if labels_masks is None
-                  else [jnp.asarray(m) for m in labels_masks])
-        from deeplearning4j_tpu.utils.scan_fit import check_steps_axes
+        lmasks = self._as_list(labels_masks)
         k = check_steps_axes(
             [(f"input '{n}'", a) for n, a in inputs.items()]
             + [(f"label {i}", l) for i, l in enumerate(labels)]
             + [(f"labels_mask {i}", m) for i, m in enumerate(lmasks or [])])
-        if self._grad_sharing is not None:
-            # host exchange can't run mid-scan: per-step two-phase loop
-            # (same math; see MultiLayerNetwork.fit_steps)
-            losses = []
-            for i in range(int(k)):
-                self._fit_batch_shared(
-                    {n: a[i] for n, a in inputs.items()},
-                    [l[i] for l in labels],
-                    None if lmasks is None else [m[i] for m in lmasks])
-                losses.append(self._score)
-            return jnp.stack(losses)
-        step = self._get_scan_step()
-        it_dev, ep_dev = device_counters(self)
-        t0 = time.perf_counter()
-        ((self.params_, self.state_, self.opt_state_, self._rng, new_it),
-         losses, last_loss) = step((self.params_, self.state_,
-                                    self.opt_state_, self._rng, it_dev),
-                                   ep_dev, (inputs, labels, lmasks))
-        t1 = time.perf_counter()
-        note("step_dispatch", t0, t1, self.iteration)
-        ins = self._instruments()
-        ins.record_dispatch(t1 - t0, steps=int(k))
-        ins.check_compile(step, self)
-        self._score = last_loss
-        self._last_batch_size = int(next(iter(inputs.values())).shape[1])
-        advance(self, new_it, steps=int(k))
-        for lst in self.listeners:
-            lst.iteration_done(self, self.iteration, self.epoch)
-        return losses
+        return self._fit_block((inputs, labels, lmasks), int(k))
 
     # ---- public API ----
     def _as_input_dict(self, features) -> Dict[str, jnp.ndarray]:
@@ -972,40 +649,19 @@ class ComputationGraph:
         into one compiled dispatch (`fit_steps`); tails and shape changes
         fall back to per-step dispatch (identical math either way).  Unset,
         it defaults to the installed schedule's (`apply_schedule`), else 1."""
-        if labels is not None:
-            if fused_steps not in (None, 1):
-                raise ValueError(
-                    "fused_steps applies to the iterator form only; for a "
-                    "pre-stacked [k, batch, ...] block call fit_steps")
-            self._fit_batch(self._as_input_dict(data), self._as_list(labels))
-            return self
-        if fused_steps is None:
-            fused_steps = (self._schedule.fused_steps
-                           if self._schedule is not None else 1)
-        for _ in range(epochs):
-            if hasattr(data, "reset"):
-                data.reset()
-            with span("fit_epoch", model=type(self).__name__):
-                if fused_steps > 1:
-                    self._fit_epoch_fused(data, fused_steps)
-                else:
-                    for ds in data:
-                        self._fit_dataset(ds)
-            self.epoch += 1
-            self._instruments().record_epoch()
-            for lst in self.listeners:
-                if hasattr(lst, "on_epoch_end"):
-                    lst.on_epoch_end(self)
+        if labels is None:
+            return self._fit_epochs(data, epochs, fused_steps)
+        if fused_steps not in (None, 1):
+            raise ValueError(
+                "fused_steps applies to the iterator form only; for a "
+                "pre-stacked [k, batch, ...] block call fit_steps")
+        self._fit_batch(self._as_input_dict(data), self._as_list(labels))
         return self
 
     def _fit_dataset(self, ds):
-        lmasks = getattr(ds, "labels_mask", None)
-        if lmasks is not None and not isinstance(lmasks, (list, tuple)):
-            lmasks = [lmasks]
         self._fit_batch(self._as_input_dict(ds.features),
                         self._as_list(ds.labels),
-                        None if lmasks is None else
-                        [jnp.asarray(m) for m in lmasks])
+                        self._as_list(getattr(ds, "labels_mask", None)))
 
     def _fit_epoch_fused(self, iterator, k: int):
         # blocks stack ON DEVICE (jnp.stack over staged per-batch arrays):
@@ -1019,12 +675,8 @@ class ComputationGraph:
                 continue
             feats = [self._as_input_dict(ds.features) for ds in block]
             labs = [self._as_list(ds.labels) for ds in block]
-            lms = []
-            for ds in block:
-                lm = getattr(ds, "labels_mask", None)
-                if lm is not None and not isinstance(lm, (list, tuple)):
-                    lm = [lm]
-                lms.append(lm)
+            lms = [self._as_list(getattr(ds, "labels_mask", None))
+                   for ds in block]
             if any(m is None for m in lms) and not all(m is None for m in lms):
                 for ds in block:            # mixed-mask block: not fusable
                     self._fit_dataset(ds)
@@ -1037,40 +689,6 @@ class ComputationGraph:
                            [_stack_staged([m[i] for m in lms])
                             for i in range(len(lms[0]))])
             self.fit_steps(stacked_feats, stacked_labs, stacked_lms)
-
-    def _fit_batch(self, inputs: Dict[str, jnp.ndarray],
-                   labels: List[jnp.ndarray], lmasks=None):
-        from deeplearning4j_tpu.utils.counters import advance, device_counters
-        if self._grad_sharing is not None:
-            return self._fit_batch_shared(inputs, labels, lmasks)
-        step = self._get_train_step()
-        it_dev, ep_dev = device_counters(self)
-        t0 = time.perf_counter()
-        (self.params_, self.state_, self.opt_state_, loss, self._rng,
-         new_it) = step(
-            self.params_, self.state_, self.opt_state_, inputs, labels,
-            lmasks, self._rng, it_dev, ep_dev)
-        t1 = time.perf_counter()
-        note("step_dispatch", t0, t1, self.iteration)
-        ins = self._instruments()
-        ins.record_dispatch(t1 - t0)
-        ins.check_compile(step, self)
-        self._score = loss
-        self._last_batch_size = int(next(iter(inputs.values())).shape[0])
-        advance(self, new_it)
-        for lst in self.listeners:
-            lst.iteration_done(self, self.iteration, self.epoch)
-
-    def score(self) -> float:
-        """Blocking read of the most recent minibatch loss; steady-state
-        loops should prefer `score_array()` (no host sync)."""
-        s = getattr(self, "_score", None)
-        return float(s) if s is not None else float("nan")
-
-    def score_array(self):
-        """Most recent minibatch loss as a (possibly in-flight) device
-        array, or None before the first step.  Never forces a host sync."""
-        return getattr(self, "_score", None)
 
     def _apply_device_norm(self, inputs: Dict[str, Any]) -> Dict[str, Any]:
         if not self._device_norm:
@@ -1097,10 +715,7 @@ class ComputationGraph:
                 raise ValueError(f"unknown network inputs: {sorted(unknown)}")
             self._device_norm = {n: DeviceNormalizer.from_host(nz)
                                  for n, nz in normalizers.items()}
-        self._train_step = None
-        self._scan_step = None
-        self._grad_step = None
-        self._apply_step = None
+        self._invalidate_steps()
         self._output_fn = None
         return self
 

@@ -18,18 +18,15 @@ from __future__ import annotations
 
 import dataclasses
 import json
-import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deeplearning4j_tpu.monitor.instrument import TrainingInstruments
-from deeplearning4j_tpu.monitor.spans import note, span
 from deeplearning4j_tpu.nn.core import InputType, Layer, PyTree
-from deeplearning4j_tpu.train.updaters import (
-    IUpdater, Sgd, apply_gradient_normalization)
+from deeplearning4j_tpu.nn.trainer import LayerwiseTrainer
+from deeplearning4j_tpu.train.updaters import IUpdater, Sgd
 
 Params = Dict[str, PyTree]
 
@@ -42,16 +39,6 @@ def _masked_leaves(params, mask):
             yield from _masked_leaves(params[k], m)
     elif mask:
         yield from jax.tree_util.tree_leaves(params)
-
-
-def _add_scaled_where(upd, params, mask, scale):
-    """upd += scale * params wherever mask is True (decoupled weight decay)."""
-    if isinstance(mask, dict):
-        return {k: _add_scaled_where(upd[k], params[k], mask[k], scale)
-                for k in upd}
-    if mask:
-        return jax.tree_util.tree_map(lambda u, p: u + scale * p, upd, params)
-    return upd
 
 
 # ---------------------------------------------------------------------------
@@ -220,16 +207,22 @@ class NeuralNetConfiguration:
 # Network
 # ---------------------------------------------------------------------------
 
-class MultiLayerNetwork:
+class MultiLayerNetwork(LayerwiseTrainer):
     """Sequential network (reference `MultiLayerNetwork`).
 
     Public surface parity: `init`, `fit(x, y | iterator)`, `output`,
     `feed_forward`, `score`, `evaluate`, `params`/`set_params` (flat-buffer
     view semantics at the API/checkpoint boundary only), `gradient_for`
-    (gradient-check hook), `save`/`load` via utils.serialization.
+    (gradient-check hook), `save`/`load` via utils.serialization.  The
+    compiled train step, its cache and its dispatch are `LayerwiseTrainer`'s
+    (nn/trainer.py).
     """
 
+    _AOT_PREFIX = "mln"
+    _BATCH_ARITY = 4          # x, y, fmask, lmask
+
     def __init__(self, conf: MultiLayerConfiguration):
+        super().__init__()
         self.conf = conf
         self.params_: Optional[Params] = None
         self.state_: Optional[Params] = None      # BN running stats etc.
@@ -238,25 +231,9 @@ class MultiLayerNetwork:
         self.epoch = 0
         self.listeners: List[Any] = []
         self._rng = jax.random.PRNGKey(conf.seed)
-        self._train_step = None
-        self._scan_step = None
-        self._grad_step = None    # hierarchical-sharing split: grad half
-        self._apply_step = None   # hierarchical-sharing split: apply half
-        self._grad_sharing = None  # parallel.hierarchical.HierarchicalAllReduce
         self._output_fn = None
-        self._step_transform = None   # ZeRO-1 weight update (parallel/zero)
         self._layer_types: List[InputType] = []
         self._device_norm = None   # on-device normalizer prologue (pipeline)
-        self._instr: Optional[TrainingInstruments] = None
-        self._exec_cache_override = None  # compile.PersistentExecutableCache
-        self._schedule = None             # compile.Schedule (autotuner)
-
-    def _instruments(self) -> TrainingInstruments:
-        """Lazy telemetry handles (monitor registry series labeled by
-        model kind) — created on first dispatch, shared series thereafter."""
-        if self._instr is None:
-            self._instr = TrainingInstruments(type(self).__name__)
-        return self._instr
 
     # ---- init ----
     def init(self) -> "MultiLayerNetwork":
@@ -384,335 +361,25 @@ class MultiLayerNetwork:
                     penalty = penalty + 0.5 * l2 * jnp.sum(w * w)
         return penalty
 
-    # ---- compiled step ----
-    def _exec_cache(self):
-        """The persistent executable cache in play: the per-model override
-        (`set_executable_cache`), else the process default — None keeps
-        the plain jax.jit path."""
-        if self._exec_cache_override is not None:
-            return self._exec_cache_override
-        from deeplearning4j_tpu.compile import default_cache
-        return default_cache()
+    # ---- what the trainer asks of the model (nn/trainer.py) ----
+    def _update_entries(self):
+        return [(self.conf.layer_name(i), layer, self._updater_for(i))
+                for i, layer in enumerate(self.conf.layers)]
 
-    def set_executable_cache(self, cache) -> "MultiLayerNetwork":
-        """Route this model's train-step compilation through a
-        `compile.PersistentExecutableCache` (or a directory path), so a
-        restarted process deserializes the step instead of recompiling it.
-        None reverts to the process default ($DL4J_TPU_EXEC_CACHE /
-        `compile.set_default_cache`).  Triggers a step rebuild."""
-        if isinstance(cache, str):
-            from deeplearning4j_tpu.compile import PersistentExecutableCache
-            cache = PersistentExecutableCache(cache)
-        self._exec_cache_override = cache
-        self._train_step = None
-        self._scan_step = None
-        self._grad_step = None
-        self._apply_step = None
-        return self
+    def _batch_loss(self, params, state, batch, rng):
+        x, y, fmask, lmask = batch
+        return self._loss(params, state, x, y, rng, fmask, lmask)
 
-    def apply_schedule(self, schedule) -> "MultiLayerNetwork":
-        """Install an autotuned `compile.Schedule`: the iterator form of
-        `fit()` defaults its `fused_steps` to the schedule's and the step
-        builders honor `schedule.donation`.  (`zero1` is a wrapper-level
-        knob — `parallel.ParallelWrapper.apply_schedule` handles it and
-        delegates the rest here.)  Triggers a step rebuild."""
-        self._schedule = schedule
-        self._train_step = None
-        self._scan_step = None
-        self._grad_step = None
-        self._apply_step = None
-        return self
+    def _normalize_batch(self, batch):
+        if self._device_norm is None:
+            return batch
+        x, y, fmask, lmask = batch
+        return (self._device_norm.apply_features(x),
+                self._device_norm.apply_labels(y), fmask, lmask)
 
-    def _donate_argnums(self) -> tuple:
-        if self._schedule is not None and not self._schedule.donation:
-            return ()
-        return (0, 1, 2)
-
-    def _aot_key_parts(self) -> dict:
-        """Disk-key parts for the persistent tier: model architecture (not
-        weights — restarts and same-arch rolls share the executable) plus
-        the step-shaping config the body closes over."""
-        from deeplearning4j_tpu.compile import (model_fingerprint,
-                                                transform_fingerprint)
-        return {"kind": "mln_train_step",
-                "model": model_fingerprint(self),
-                "transform": transform_fingerprint(self._step_transform)}
-
-    def _build_train_step(self):
-        from deeplearning4j_tpu.compile import step_function
-        return step_function(self._build_step_body(),
-                             donate_argnums=self._donate_argnums(),
-                             key_base=self._aot_key_parts,
-                             cache=self._exec_cache(),
-                             dynamic_argnums=(3, 4, 5, 6))
-
-    def _build_step_body(self):
-        conf = self.conf
-        zt = self._step_transform   # ZeRO-1 sharded weight update, or None
-
-        def step(params, state, opt_state, x, y, fmask, lmask, rng,
-                 iteration, epoch):
-            # split inside the compiled step: keeps the per-step host work at
-            # zero device round-trips (the carry key + iteration counter live
-            # on device and flow step→step without fresh H2D transfers)
-            if self._device_norm is not None:
-                # on-device normalizer prologue: stats are executable
-                # constants, the apply fuses into the forward — raw batches
-                # stream to device with zero host ETL (data.pipeline)
-                x = self._device_norm.apply_features(x)
-                y = self._device_norm.apply_labels(y)
-            rng, srng = jax.random.split(rng)
-            master = params
-            if zt is not None:
-                # all-gather the data-axis-sharded master params once;
-                # forward/backward run on the gathered (or TP) layout
-                params = zt.gather_all(params)
-
-            def loss_fn(p):
-                loss, new_state = self._loss(p, state, x, y, srng, fmask,
-                                             lmask)
-                return loss, new_state
-
-            (loss, new_state), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
-
-            new_params = {}
-            new_opt = {}
-            for i, layer in enumerate(conf.layers):
-                name = conf.layer_name(i)
-                if layer.frozen:
-                    # FrozenLayer semantics (reference `nn/layers/FrozenLayer`):
-                    # no update applied, updater state untouched.
-                    new_params[name] = master[name]
-                    new_opt[name] = opt_state[name]
-                    continue
-                g = grads[name]
-                gn = (layer.gradient_normalization
-                      if layer.gradient_normalization is not None
-                      else conf.gradient_normalization)
-                if gn:
-                    thr = (layer.gradient_normalization_threshold
-                           if layer.gradient_normalization is not None
-                           else conf.gradient_normalization_threshold)
-                    g = apply_gradient_normalization(g, gn, thr)
-                if zt is None:
-                    p_upd = params[name]
-                else:
-                    # reduce-scatter the (already normalized) grads and run
-                    # the updater on this device's shard of params/moments
-                    g = zt.scatter(name, g)
-                    p_upd = zt.update_view(name, master[name])
-                upd_cfg = self._updater_for(i)
-                upd, new_o = upd_cfg.apply(opt_state[name], g,
-                                           iteration, epoch,
-                                           params=p_upd)
-                # decoupled weight decay (reference WeightDecay regularization,
-                # applyLR=true): update += lr * coeff * w for regularizable params
-                wd = (layer.weight_decay if layer.weight_decay is not None
-                      else conf.weight_decay)
-                if wd:
-                    lr = upd_cfg.lr_at(iteration, epoch)
-                    upd = _add_scaled_where(
-                        upd, p_upd,
-                        layer.regularizable_mask(p_upd), lr * wd)
-                new_p = jax.tree_util.tree_map(
-                    lambda p_, u_: p_ - u_, p_upd, upd)
-                if zt is not None:
-                    new_p = zt.restore(name, new_p)
-                    new_o = zt.constrain_opt(name, new_o)
-                new_params[name] = new_p
-                new_opt[name] = new_o
-            return new_params, new_state, new_opt, loss, rng, iteration + 1
-
-        return step
-
-    def _get_train_step(self):
-        if self._train_step is None:
-            self._train_step = self._build_train_step()
-        return self._train_step
-
-    # ---- hierarchical gradient sharing (parallel.hierarchical) ----
-    def set_gradient_sharing(self, sharing) -> "MultiLayerNetwork":
-        """Enable/disable hierarchical compressed cross-host gradient
-        sharing.  Accepts a `HierarchicalGradientSharing` config (the
-        runtime is built here), a prebuilt `HierarchicalAllReduce`, or
-        None to clear.  Active sharing splits the compiled step in two —
-        a grad half (forward/backward + ICI reduce, emits the local
-        gradient tree) and an apply half (updater loop on the DCN-combined
-        gradient) — with the host-side compressed exchange between them."""
-        from deeplearning4j_tpu.parallel.hierarchical import (
-            HierarchicalAllReduce, HierarchicalGradientSharing)
-        if sharing is None:
-            if self._grad_sharing is not None:
-                self._grad_sharing.close()
-            self._grad_sharing = None
-        elif isinstance(sharing, HierarchicalGradientSharing):
-            self._grad_sharing = HierarchicalAllReduce(sharing)
-        elif isinstance(sharing, HierarchicalAllReduce):
-            self._grad_sharing = sharing
-        else:
-            raise TypeError(
-                "set_gradient_sharing expects HierarchicalGradientSharing, "
-                f"HierarchicalAllReduce or None, got {type(sharing).__name__}")
-        self._grad_step = None
-        self._apply_step = None
-        return self
-
-    @property
-    def gradient_sharing(self):
-        """The installed `HierarchicalAllReduce`, or None."""
-        return self._grad_sharing
-
-    def _build_grad_body(self):
-        """Grad half of the split step: forward/backward on the local
-        mesh (ICI all-reduce via SPMD, reduce-scatter under ZeRO-1), NO
-        update.  Params are NOT donated — the apply half needs them."""
-        conf = self.conf
-        zt = self._step_transform
-
-        def grad_step(params, state, x, y, fmask, lmask, rng):
-            if self._device_norm is not None:
-                x = self._device_norm.apply_features(x)
-                y = self._device_norm.apply_labels(y)
-            rng, srng = jax.random.split(rng)
-            fwd_params = params if zt is None else zt.gather_all(params)
-
-            def loss_fn(p):
-                loss, new_state = self._loss(p, state, x, y, srng, fmask,
-                                             lmask)
-                return loss, new_state
-
-            (loss, new_state), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(fwd_params)
-            if zt is not None:
-                # ship the reduce-scattered (padded, update-layout) shard —
-                # compress the shard, not the gathered tree (ISSUE: ZeRO-1
-                # composition); the apply half re-pins the wire grads with
-                # constrain_update instead of re-padding
-                grads = {conf.layer_name(i): zt.scatter(conf.layer_name(i),
-                                                        grads[conf.layer_name(i)])
-                         for i in range(len(conf.layers))}
-            return grads, new_state, loss, rng
-
-        return grad_step
-
-    def _build_apply_body(self):
-        """Apply half: updater loop on the DCN-combined gradient.
-        Gradient normalization runs HERE, on the cross-host-combined
-        gradient — the same quantity the single-mesh step normalizes
-        (zero pads under ZeRO-1 don't perturb L2 norms)."""
-        conf = self.conf
-        zt = self._step_transform
-
-        def apply_step(params, opt_state, grads, iteration, epoch):
-            new_params = {}
-            new_opt = {}
-            for i, layer in enumerate(conf.layers):
-                name = conf.layer_name(i)
-                if layer.frozen:
-                    new_params[name] = params[name]
-                    new_opt[name] = opt_state[name]
-                    continue
-                g = grads[name]
-                if zt is not None:
-                    g = zt.constrain_update(name, g)
-                gn = (layer.gradient_normalization
-                      if layer.gradient_normalization is not None
-                      else conf.gradient_normalization)
-                if gn:
-                    thr = (layer.gradient_normalization_threshold
-                           if layer.gradient_normalization is not None
-                           else conf.gradient_normalization_threshold)
-                    g = apply_gradient_normalization(g, gn, thr)
-                p_upd = (params[name] if zt is None
-                         else zt.update_view(name, params[name]))
-                upd_cfg = self._updater_for(i)
-                upd, new_o = upd_cfg.apply(opt_state[name], g,
-                                           iteration, epoch,
-                                           params=p_upd)
-                wd = (layer.weight_decay if layer.weight_decay is not None
-                      else conf.weight_decay)
-                if wd:
-                    lr = upd_cfg.lr_at(iteration, epoch)
-                    upd = _add_scaled_where(
-                        upd, p_upd,
-                        layer.regularizable_mask(p_upd), lr * wd)
-                new_p = jax.tree_util.tree_map(
-                    lambda p_, u_: p_ - u_, p_upd, upd)
-                if zt is not None:
-                    new_p = zt.restore(name, new_p)
-                    new_o = zt.constrain_opt(name, new_o)
-                new_params[name] = new_p
-                new_opt[name] = new_o
-            return new_params, new_opt, iteration + 1
-
-        return apply_step
-
-    def _get_grad_step(self):
-        if self._grad_step is None:
-            from deeplearning4j_tpu.compile import step_function
-            self._grad_step = step_function(
-                self._build_grad_body(),
-                donate_argnums=(1,),        # state only: params feed the
-                key_base=lambda: dict(      # apply half next
-                    self._aot_key_parts(), kind="mln_grad_step"),
-                cache=self._exec_cache(),
-                dynamic_argnums=(2, 3, 4, 5))
-        return self._grad_step
-
-    def _get_apply_step(self):
-        if self._apply_step is None:
-            from deeplearning4j_tpu.compile import step_function
-            self._apply_step = step_function(
-                self._build_apply_body(),
-                donate_argnums=(0, 1),
-                key_base=lambda: dict(
-                    self._aot_key_parts(), kind="mln_apply_step"),
-                cache=self._exec_cache(),
-                dynamic_argnums=())
-        return self._apply_step
-
-    def _fit_batch_shared(self, x, y, fmask=None, lmask=None):
-        """One training step through the hierarchical path: compiled grad
-        half → host-side DCN exchange → compiled apply half."""
-        from deeplearning4j_tpu.utils.counters import advance, device_counters
-        t0 = time.perf_counter()
-        gstep = self._get_grad_step()
-        grads, self.state_, loss, self._rng = gstep(
-            self.params_, self.state_, x, y, fmask, lmask, self._rng)
-        combined = self._grad_sharing.exchange(grads)
-        astep = self._get_apply_step()
-        it_dev, ep_dev = device_counters(self)
-        self.params_, self.opt_state_, new_it = astep(
-            self.params_, self.opt_state_, combined, it_dev, ep_dev)
-        t1 = time.perf_counter()
-        note("step_dispatch", t0, t1, self.iteration)
-        ins = self._instruments()
-        ins.record_dispatch(t1 - t0)
-        ins.check_compile(gstep, self)
-        ins.check_compile(astep, self)
-        self._score = loss
-        self._last_batch_size = int(x.shape[0])
-        advance(self, new_it)
-        for lst in self.listeners:
-            lst.iteration_done(self, self.iteration, self.epoch)
-
-    def _get_scan_step(self):
-        if self._scan_step is None:
-            from deeplearning4j_tpu.utils.scan_fit import make_scan_step
-            body = self._build_step_body()
-
-            def tick(carry, epoch, batch):
-                p, s, o, r, it = carry
-                p, s, o, loss, r, it = body(p, s, o, *batch, r, it, epoch)
-                return (p, s, o, r, it), loss
-
-            self._scan_step = make_scan_step(
-                tick,
-                key_base=lambda: dict(self._aot_key_parts(),
-                                      kind="mln_scan_step"),
-                cache=self._exec_cache(),
-                donate=(self._schedule is None or self._schedule.donation))
-        return self._scan_step
+    @staticmethod
+    def _batch_rows(batch, axis: int) -> int:
+        return int(batch[0].shape[axis])
 
     def fit_steps(self, xs, ys, features_masks=None, labels_masks=None):
         """Run `k` training steps in one device dispatch.
@@ -726,16 +393,9 @@ class MultiLayerNetwork:
         semantics) but compiled as a single `lax.scan`, eliminating
         per-step host→device dispatch latency.  Listeners fire once per
         block with the final loss; per-step losses are returned as a
-        length-k array."""
-        from deeplearning4j_tpu.utils.counters import advance, device_counters
+        length-k array.  (With gradient sharing installed the block runs as
+        k two-phase steps: `LayerwiseTrainer._fit_block`.)"""
         from deeplearning4j_tpu.utils.scan_fit import check_steps_axes
-        if self._grad_sharing is not None:
-            # a host-side exchange cannot run mid-lax.scan: degrade to a
-            # per-step two-phase loop — exact same math, the fused-dispatch
-            # latency win is traded for the DCN bytes win (documented in
-            # docs/performance.md §6)
-            return self._fit_steps_shared(xs, ys, features_masks,
-                                          labels_masks)
         if isinstance(xs, (list, tuple)):
             k = len(xs)
             if not (isinstance(ys, (list, tuple)) and len(ys) == k):
@@ -749,36 +409,14 @@ class MultiLayerNetwork:
                  None if fms[i] is None else jnp.asarray(fms[i]),
                  None if lms[i] is None else jnp.asarray(lms[i]))
                 for i in range(k))
-            batch_n = int(batches[0][0].shape[0])
-        else:
-            xs = jnp.asarray(xs)
-            ys = jnp.asarray(ys)
-            fm = None if features_masks is None else \
-                jnp.asarray(features_masks)
-            lm = None if labels_masks is None else jnp.asarray(labels_masks)
-            check_steps_axes([("xs", xs), ("ys", ys), ("features_masks", fm),
+            return self._fit_block(batches, k, listed=True)
+        xs = jnp.asarray(xs)
+        ys = jnp.asarray(ys)
+        fm = None if features_masks is None else jnp.asarray(features_masks)
+        lm = None if labels_masks is None else jnp.asarray(labels_masks)
+        k = check_steps_axes([("xs", xs), ("ys", ys), ("features_masks", fm),
                               ("labels_masks", lm)])
-            batches = (xs, ys, fm, lm)
-            k = int(xs.shape[0])
-            batch_n = int(xs.shape[1])
-        step = self._get_scan_step()
-        it_dev, ep_dev = device_counters(self)
-        t0 = time.perf_counter()
-        ((self.params_, self.state_, self.opt_state_, self._rng, new_it),
-         losses, last_loss) = step((self.params_, self.state_,
-                                    self.opt_state_, self._rng, it_dev),
-                                   ep_dev, batches)
-        t1 = time.perf_counter()
-        note("step_dispatch", t0, t1, self.iteration)
-        ins = self._instruments()
-        ins.record_dispatch(t1 - t0, steps=k)
-        ins.check_compile(step, self)
-        self._score = last_loss
-        self._last_batch_size = batch_n
-        advance(self, new_it, steps=k)
-        for lst in self.listeners:
-            lst.iteration_done(self, self.iteration, self.epoch)
-        return losses
+        return self._fit_block((xs, ys, fm, lm), int(k))
 
     # ---- public API ----
     def fit(self, data, labels=None, *, epochs: int = 1, features_mask=None,
@@ -793,31 +431,14 @@ class MultiLayerNetwork:
         results are identical to `fused_steps=1` up to listener cadence.
         Unset, it defaults to the installed schedule's (`apply_schedule`),
         else 1."""
-        if labels is not None:
-            if fused_steps not in (None, 1):
-                raise ValueError(
-                    "fused_steps applies to the iterator form only; for a "
-                    "pre-stacked [k, batch, ...] block call fit_steps(xs, ys)")
-            self._fit_batch(jnp.asarray(data), jnp.asarray(labels),
-                            features_mask, labels_mask)
-            return self
-        if fused_steps is None:
-            fused_steps = (self._schedule.fused_steps
-                           if self._schedule is not None else 1)
-        for _ in range(epochs):
-            if hasattr(data, "reset"):
-                data.reset()
-            with span("fit_epoch", model=type(self).__name__):
-                if fused_steps > 1:
-                    self._fit_epoch_fused(data, fused_steps)
-                else:
-                    for ds in data:
-                        self._fit_dataset(ds)
-            self.epoch += 1
-            self._instruments().record_epoch()
-            for lst in self.listeners:
-                if hasattr(lst, "on_epoch_end"):
-                    lst.on_epoch_end(self)
+        if labels is None:
+            return self._fit_epochs(data, epochs, fused_steps)
+        if fused_steps not in (None, 1):
+            raise ValueError(
+                "fused_steps applies to the iterator form only; for a "
+                "pre-stacked [k, batch, ...] block call fit_steps(xs, ys)")
+        self._fit_batch(jnp.asarray(data), jnp.asarray(labels),
+                        features_mask, labels_mask)
         return self
 
     def _fit_dataset(self, ds):
@@ -841,70 +462,6 @@ class MultiLayerNetwork:
             else:
                 self.fit_steps(*payload)
 
-    def _fit_steps_shared(self, xs, ys, features_masks=None,
-                          labels_masks=None):
-        """Per-step loop replacement for `fit_steps` when hierarchical
-        sharing is active (host exchange can't run inside a scan)."""
-        if isinstance(xs, (list, tuple)):
-            k = len(xs)
-            fms = features_masks if features_masks is not None else [None] * k
-            lms = labels_masks if labels_masks is not None else [None] * k
-            steps = [(jnp.asarray(xs[i]), jnp.asarray(ys[i]),
-                      None if fms[i] is None else jnp.asarray(fms[i]),
-                      None if lms[i] is None else jnp.asarray(lms[i]))
-                     for i in range(k)]
-        else:
-            xs, ys = jnp.asarray(xs), jnp.asarray(ys)
-            k = int(xs.shape[0])
-            steps = [(xs[i], ys[i],
-                      None if features_masks is None
-                      else jnp.asarray(features_masks)[i],
-                      None if labels_masks is None
-                      else jnp.asarray(labels_masks)[i])
-                     for i in range(k)]
-        losses = []
-        for x, y, fm, lm in steps:
-            self._fit_batch_shared(x, y, fm, lm)
-            losses.append(self._score)
-        return jnp.stack(losses)
-
-    def _fit_batch(self, x, y, fmask=None, lmask=None):
-        from deeplearning4j_tpu.utils.counters import advance, device_counters
-        if self._grad_sharing is not None:
-            return self._fit_batch_shared(x, y, fmask, lmask)
-        step = self._get_train_step()
-        it_dev, ep_dev = device_counters(self)
-        t0 = time.perf_counter()
-        (self.params_, self.state_, self.opt_state_, loss, self._rng,
-         new_it) = step(
-            self.params_, self.state_, self.opt_state_, x, y, fmask, lmask,
-            self._rng, it_dev, ep_dev)
-        t1 = time.perf_counter()
-        note("step_dispatch", t0, t1, self.iteration)
-        ins = self._instruments()
-        ins.record_dispatch(t1 - t0)
-        ins.check_compile(step, self)
-        self._score = loss
-        self._last_batch_size = int(x.shape[0])
-        advance(self, new_it)
-        for lst in self.listeners:
-            lst.iteration_done(self, self.iteration, self.epoch)
-
-    def score(self) -> float:
-        """Loss of the most recent minibatch (reference `score()`).  This
-        is the BLOCKING read: coercing to float waits for the step to
-        complete.  Steady-state loops should prefer `score_array()`."""
-        s = getattr(self, "_score", None)
-        return float(s) if s is not None else float("nan")
-
-    def score_array(self):
-        """Loss of the most recent minibatch as a device array (or None
-        before the first step).  Never syncs: the array may still be in
-        flight — the async-dispatch window stays open until the caller
-        coerces it (float/np.asarray), so listeners can record scores
-        without stalling the step pipeline."""
-        return getattr(self, "_score", None)
-
     def set_normalizer(self, normalizer) -> "MultiLayerNetwork":
         """Fold a fitted normalizer (NormalizerStandardize / MinMaxScaler /
         ImagePreProcessingScaler, or a DeviceNormalizer) into the compiled
@@ -914,10 +471,7 @@ class MultiLayerNetwork:
         from deeplearning4j_tpu.data.pipeline import DeviceNormalizer
         self._device_norm = (None if normalizer is None
                              else DeviceNormalizer.from_host(normalizer))
-        self._train_step = None
-        self._scan_step = None
-        self._grad_step = None
-        self._apply_step = None
+        self._invalidate_steps()
         self._output_fn = None
         return self
 

@@ -182,16 +182,6 @@ class Zero1Transform:
             shape_of=lambda pl: pl.padded_shape)
 
 
-def _invalidate_steps(model) -> None:
-    model._train_step = None
-    model._scan_step = None
-    # hierarchical-sharing split steps (only MLN/CG grow these attrs)
-    if hasattr(model, "_grad_step"):
-        model._grad_step = None
-    if hasattr(model, "_apply_step"):
-        model._apply_step = None
-
-
 def _params_attr(model) -> str:
     return "variables_" if hasattr(model, "variables_") else "params_"
 
@@ -255,7 +245,7 @@ def enable_zero1(model, mesh: Mesh, axis: str = "data",
         model.state_ = jax.device_put(model.state_,
                                       NamedSharding(mesh, P()))
     model._step_transform = zt
-    _invalidate_steps(model)
+    model._invalidate_steps()
     return zt
 
 
@@ -280,7 +270,7 @@ def disable_zero1(model) -> None:
             unpad, model.opt_state_, zt.plans, lambda s: s,
             shape_of=lambda pl: pl.padded_shape)
     model._step_transform = None
-    _invalidate_steps(model)
+    model._invalidate_steps()
 
 
 def reshard_zero1(model, new_mesh: Mesh, axis: str = "data",

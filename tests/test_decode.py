@@ -9,6 +9,7 @@ int8 page parity), the `paged_attention` kernel pair (Pallas-in-interpret
 admit/retire, exhaustion sheds), the `ContinuousBatcher.cancel` slot
 release, and `ModelFleet.deploy_decode`/`generate` failover
 (restart-and-count, heal via the controller)."""
+import threading
 import time
 from concurrent.futures import Future
 
@@ -328,11 +329,39 @@ class TestContinuousDecode:
 
     def test_cancel_waiting_and_active(self):
         eng = _engine(max_decode_batch=1)
+        # The worker steps under the engine's lock and takes it again at
+        # once, so on a busy host a thread that wants to cancel can be kept
+        # out until a 40-token run is over.  The test must not depend on
+        # being faster than the engine: it holds the worker at a gate
+        # BETWEEN two steps, once the long runner has 3 tokens, where the
+        # worker (as in its idle wait) waits on the condition and gives the
+        # lock up.  Retiring the runner, or `resume`, lets it go on.
+        resume = threading.Event()
+        admit = eng._admit_locked
+
+        def held(seq):
+            return len(seq.generated) >= 3
+
+        def admit_then_hold():
+            admit()
+            eng._cond.wait_for(
+                lambda: resume.is_set() or not any(map(held, eng._active)),
+                timeout=30)
+
+        eng._admit_locked = admit_then_hold
         try:
             eng.warmup()
             # long runner occupies the single slot
             long = eng.submit(np.arange(1, 4), max_new_tokens=40)
             waiting = eng.submit(np.arange(1, 4), max_new_tokens=40)
+            deadline = time.monotonic() + 30
+            while time.monotonic() < deadline:
+                with eng._cond:
+                    if any(s.future is long and held(s)
+                           for s in eng._active):
+                        break
+                time.sleep(0.005)
+            assert not long.done() and eng.cache.blocks_in_use > 0
             assert eng.cancel(waiting) is True
             assert waiting.cancelled()
             assert eng.cancel(long) is True         # mid-flight retire
@@ -342,6 +371,7 @@ class TestContinuousDecode:
             assert eng.cache.blocks_in_use == 0     # pages back NOW
             assert eng.cancel(Future()) is False    # unknown future
         finally:
+            resume.set()
             eng.shutdown(drain=False)
 
     def test_exhaustion_sheds_not_crashes(self):

@@ -615,8 +615,13 @@ def test_fleet_instruments_record_admissions(tmp_path):
         fleet.deploy("a", _net(seed=1, hidden=8))
         fleet.deploy("b", _net(seed=2, hidden=12))
         fleet.deploy("c", _net(seed=3, hidden=20))
+        # The default budget is 4x the SLO's p99 target, from submit: a
+        # cold admission (placement + compile) under six test workers on a
+        # shared CPU can outlast it before the first dispatch.  This test
+        # counts admissions, not latency, so the budget is one that cannot
+        # run out.
         for name in ("a", "b", "c", "a"):            # c evicts a; a re-admits
-            fleet.output(name, _x())
+            fleet.output(name, _x(), deadline_ms=600_000.0)
         cold = reg.get("fleet_admissions_total", {"warm": "false"})
         warm = reg.get("fleet_admissions_total", {"warm": "true"})
         assert cold.value == 3 and warm.value == 1
