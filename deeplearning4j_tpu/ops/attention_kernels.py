@@ -11,9 +11,11 @@ cuDNN fused attention plays for the reference's platform helpers:
   building block ring attention reuses across chips.
 - `flash_attention_tpu` + `flash_attention_bwd_tpu`: Pallas TPU kernels,
   3D grid (batch*heads, Q blocks, KV blocks) with online-softmax state in
-  VMEM scratch; the forward saves per-row logsumexp and the backward is a
-  true FlashAttention-2-style pair of kernels (dQ, then dK/dV) recomputing
-  P from the logsumexp — no [T,T] materialization in either direction.
+  VMEM scratch; the forward saves per-row logsumexp and the backward is
+  ONE kernel over (batch*heads, KV blocks, Q blocks) that recomputes P from
+  the logsumexp once a tile and takes dV, dK (tile scratch) and dQ
+  (resident in VMEM across the KV blocks) from the same pass — no [T,T]
+  materialization in either direction, no partial dQ in HBM.
 - `fused_attention`: measured dispatcher — XLA-fused naive path for short
   sequences (fastest on v5e below ~2k), Pallas kernels for long unmasked
   tiling shapes, blockwise scan for the rest; differentiable everywhere.
@@ -150,7 +152,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest,
     """3D grid (batch*head, q-block, kv-block): Pallas pipelines the KV
     block fetches (double-buffered HBM→VMEM) while online-softmax state
     lives in VMEM scratch across the kv dimension.  Emits per-row
-    logsumexp for the backward kernels.  With ``has_mask`` an additive
+    logsumexp for the backward kernel.  With ``has_mask`` an additive
     f32 bias block [1, 1, bk] (0 keep / NEG_INF drop over KV positions)
     precedes the outputs."""
     if has_mask:
@@ -214,7 +216,7 @@ def flash_attention_tpu(q, k, v, causal=False, scale=None,
     """Pallas flash-attention forward.  q [B, H, T, D], k [B, H, S, D],
     v [B, H, S, Dv] -> [B, H, T, Dv]; T and S divisible by the block sizes
     (dispatcher checks).  With ``return_lse`` also returns the
-    row logsumexp [B*H, T] (f32) for the backward kernels.  ``mask``:
+    row logsumexp [B*H, T] (f32) for the backward kernel.  ``mask``:
     optional [B, S] 1/0 keep-mask over KV positions (padding/segment
     mask), shared across heads."""
     B, H, T, D = q.shape
@@ -268,124 +270,136 @@ def flash_attention_tpu(q, k, v, causal=False, scale=None,
     return (out, lse.reshape(B * H, T)) if return_lse else out
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         *rest, block_q: int, block_k: int,
-                         nkv: int, causal: bool, scale: float,
-                         has_mask: bool):
-    """dQ over grid (batch*head, q-block, kv-block): recompute P from the
-    saved logsumexp (no [T,T] materialization), accumulate dS·K in
-    scratch."""
+# VMEM of the one backward kernel.  `_BWD_VMEM_LIMIT` is what the call states
+# to Mosaic (`vmem_limit_bytes`; a v5e has 128 MiB): at kanana's 4096 x 192
+# a tile of 512 x 1024 takes all of the 16 MiB default and 1024 x 1024 takes
+# 20.  `_BWD_DQ_VMEM` of it is the room of the resident dQ: the f32
+# accumulator [span, D] and the double-buffered output block [span, D] (6
+# MiB at 4096 x 192 in bfloat16); the tile's temporaries, operands and dK/dV
+# share the rest.
+_BWD_VMEM_LIMIT = 48 << 20
+_BWD_DQ_VMEM = 16 << 20
+
+_NT = (((1,), (1,)), ((), ()))                 # a [m, c], b [n, c] -> [m, n]
+
+
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
+                      block_q: int, block_k: int, nq: int, nkv: int,
+                      q_offset: int, causal: bool, scale: float,
+                      has_mask: bool):
+    """dQ, dK and dV over grid (batch*head, kv-block, q-block), the q blocks
+    innermost.  A live tile recomputes P from the saved logsumexp and
+    computes dP and dS once; from them dV += P^T dO and dK += dS^T Q into
+    the kv block's scratch and dQ += dS K into the rows of a [span, D]
+    scratch that stays in VMEM across the kv axis: five products, no [T, T]
+    array and no partial dQ in HBM.  The tile is held keys-by-queries
+    ([bk, bq]), so that dV and dK are plain products, dQ's is the one
+    transposed operand and the row statistics lie along lanes ([1, bq]).
+    ``q_offset`` is the first query's position (a span of a longer
+    sequence)."""
     if has_mask:
-        bias_ref, dq_ref, dq_sc = rest
+        bias_ref, dq_ref, dk_ref, dv_ref, dq_sc, dk_sc, dv_sc = rest
     else:
-        dq_ref, dq_sc = rest
+        dq_ref, dk_ref, dv_ref, dq_sc, dk_sc, dv_sc = rest
         bias_ref = None
-    qi = pl.program_id(1)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
+    i = pl.program_id(2)
+    rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+
+    @pl.when(i == 0)
+    def _init_dkv():
+        dk_sc[...] = jnp.zeros_like(dk_sc)
+        dv_sc[...] = jnp.zeros_like(dv_sc)
 
     @pl.when(j == 0)
-    def _init():
-        dq_sc[...] = jnp.zeros_like(dq_sc)
+    def _init_dq():
+        dq_sc[rows, :] = jnp.zeros((block_q, dq_sc.shape[1]), jnp.float32)
 
-    live = (j * block_k <= qi * block_q + block_q - 1) if causal else True
+    # causal: q blocks strictly above the kv block's diagonal see nothing
+    live = (q_offset + i * block_q + block_q - 1 >= j * block_k) \
+        if causal else True
 
     @pl.when(live)
     def _step():
         q = q_ref[0]                                       # [bq, D]
-        do = do_ref[0]
-        lse = lse_ref[0]                                   # [bq, 1]
-        delta = delta_ref[0]
+        do = do_ref[0]                                     # [bq, Dv]
         kj = k_ref[0]                                      # [bk, D]
-        vj = v_ref[0]
-        s = jnp.dot(q, kj.T, preferred_element_type=jnp.float32) * scale
+        vj = v_ref[0]                                      # [bk, Dv]
+        s = jax.lax.dot_general(
+            kj, q, _NT, preferred_element_type=jnp.float32) * scale
         if has_mask:
-            s = s + bias_ref[0]
+            s = s + bias_ref[0]                            # [bk, 1] → cols
         if causal:
-            rows = (qi * block_q
+            keys = (j * block_k
                     + jax.lax.broadcasted_iota(jnp.int32,
-                                               (block_q, block_k), 0))
-            cols = (j * block_k
-                    + jax.lax.broadcasted_iota(jnp.int32,
-                                               (block_q, block_k), 1))
-            s = jnp.where(rows >= cols, s, NEG_INF)
-        p = jnp.exp(s - lse)                               # [bq, bk] f32
-        dp = jnp.dot(do, vj.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        dq_sc[...] += jnp.dot(ds.astype(kj.dtype), kj,
+                                               (block_k, block_q), 0))
+            queries = (q_offset + i * block_q
+                       + jax.lax.broadcasted_iota(jnp.int32,
+                                                  (block_k, block_q), 1))
+            s = jnp.where(queries >= keys, s, NEG_INF)
+        p = jnp.exp(s - lse_ref[0, 0])                     # [bk, bq] f32
+        dv_sc[...] += jnp.dot(p.astype(do.dtype), do,
                               preferred_element_type=jnp.float32)
-
-    @pl.when(j == nkv - 1)
-    def _finalize():
-        dq_ref[0] = (dq_sc[...] * scale).astype(dq_ref.dtype)
-
-
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          *rest, block_q: int,
-                          block_k: int, nq: int, causal: bool, scale: float,
-                          has_mask: bool):
-    """dK/dV over grid (batch*head, kv-block, q-block): recompute P,
-    accumulate P^T·dO and dS^T·Q in scratch."""
-    if has_mask:
-        bias_ref, dk_ref, dv_ref, dk_sc, dv_sc = rest
-    else:
-        dk_ref, dv_ref, dk_sc, dv_sc = rest
-        bias_ref = None
-    ji = pl.program_id(1)
-    i = pl.program_id(2)
-
-    @pl.when(i == 0)
-    def _init():
-        dk_sc[...] = jnp.zeros_like(dk_sc)
-        dv_sc[...] = jnp.zeros_like(dv_sc)
-
-    # causal: q blocks strictly above the kv block's diagonal see nothing
-    live = (i * block_q + block_q - 1 >= ji * block_k) if causal else True
-
-    @pl.when(live)
-    def _step():
-        kj = k_ref[0]                                      # [bk, D]
-        vj = v_ref[0]
-        qi = q_ref[0]                                      # [bq, D]
-        doi = do_ref[0]
-        lse_i = lse_ref[0]                                 # [bq, 1]
-        delta_i = delta_ref[0]
-        s = jnp.dot(qi, kj.T, preferred_element_type=jnp.float32) * scale
-        if has_mask:
-            s = s + bias_ref[0]
-        if causal:
-            rows = (i * block_q
-                    + jax.lax.broadcasted_iota(jnp.int32,
-                                               (block_q, block_k), 0))
-            cols = (ji * block_k
-                    + jax.lax.broadcasted_iota(jnp.int32,
-                                               (block_q, block_k), 1))
-            s = jnp.where(rows >= cols, s, NEG_INF)
-        p = jnp.exp(s - lse_i)                             # [bq, bk]
-        dv_sc[...] += jnp.dot(p.T.astype(doi.dtype), doi,
-                              preferred_element_type=jnp.float32)
-        dp = jnp.dot(doi, vj.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_i)
-        dk_sc[...] += jnp.dot(ds.T.astype(qi.dtype), qi,
-                              preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(
+            vj, do, _NT, preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta_ref[0, 0])).astype(q.dtype)
+        dk_sc[...] += jnp.dot(ds, q, preferred_element_type=jnp.float32)
+        dq_sc[rows, :] += jnp.dot(ds.T, kj,
+                                  preferred_element_type=jnp.float32)
 
     @pl.when(i == nq - 1)
-    def _finalize():
+    def _write_dkv():
         dk_ref[0] = (dk_sc[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
+
+    @pl.when(j == nkv - 1)
+    def _write_dq():
+        dq_ref[0, rows, :] = (dq_sc[rows, :] * scale).astype(dq_ref.dtype)
+
+
+def _bwd_plan(T, S, D, Dv, itemsize, block_q, block_k):
+    """(bq, bk, span) of the backward kernel, from the shapes alone: the
+    asked blocks, the larger halved until the tile's four f32 [bk, bq]
+    temporaries, the double-buffered operands and dK/dV's accumulators and
+    output blocks fit `_BWD_VMEM_LIMIT` less `_BWD_DQ_VMEM`; and the most
+    query rows (whole blocks) whose resident dQ fits `_BWD_DQ_VMEM`."""
+    bq, bk = min(block_q, T), min(block_k, S)
+
+    def tile_bytes(bq, bk):
+        return (16 * bq * bk + 2 * itemsize * (bq + bk) * (D + Dv)
+                + (4 + 2 * itemsize) * bk * (D + Dv))
+
+    while tile_bytes(bq, bk) > _BWD_VMEM_LIMIT - _BWD_DQ_VMEM:
+        if bk >= bq and bk % 256 == 0:
+            bk //= 2
+        elif bq % 256 == 0:
+            bq //= 2
+        else:
+            break
+    rows = _BWD_DQ_VMEM // (D * (4 + 2 * itemsize))
+    return bq, bk, min(T, max(bq, rows // bq * bq))
 
 
 def flash_attention_bwd_tpu(q, k, v, out, lse, g, causal=False, scale=None,
                             block_q=256, block_k=256, interpret=False,
                             mask=None):
-    """Pallas flash-attention backward (FlashAttention-2 style): delta
-    precomputed on-device, then separate dQ and dK/dV kernels so both
-    matmul passes stay on the MXU without [T,T] materialization."""
+    """Pallas flash-attention backward: delta precomputed on-device, then
+    ONE kernel (`_flash_bwd_kernel`) that computes the scores, P, dP and dS
+    of a tile once and takes dQ, dK and dV from them — no [T, T] array, no
+    second pass over the scores.  ``block_q``/``block_k`` are the forward's
+    blocks; `_bwd_plan` keeps them where they fit the VMEM the call states
+    (`_BWD_VMEM_LIMIT`, 48 MiB) and halves them where they do not.  dQ of a
+    whole (batch, head) stays in VMEM while its kv blocks pass, within a
+    budget of 16 MiB (`_BWD_DQ_VMEM`: 10,922 rows at keys of 192 in
+    bfloat16); a longer sequence is cut into spans of queries that fit, one
+    call each over all the keys, and dK/dV are summed over the spans in
+    float32."""
     B, H, T, D = q.shape
     S, Dv = k.shape[2], v.shape[3]
     if scale is None:
         scale = D ** -0.5
-    bq = min(block_q, T)
-    bk = min(block_k, S)
+    bq, bk, span = _bwd_plan(T, S, D, Dv, q.dtype.itemsize, block_q, block_k)
+    nkv = S // bk
     qf = q.reshape(B * H, T, D)
     kf = k.reshape(B * H, S, D)
     vf = v.reshape(B * H, S, Dv)
@@ -393,67 +407,78 @@ def flash_attention_bwd_tpu(q, k, v, out, lse, g, causal=False, scale=None,
     # delta_i = rowsum(dO_i * O_i) — cheap elementwise reduce, XLA-fused
     delta = jnp.sum(gf.astype(jnp.float32)
                     * out.reshape(B * H, T, Dv).astype(jnp.float32), axis=-1)
-    lse3 = lse.reshape(B * H, T, 1)
-    delta3 = delta.reshape(B * H, T, 1)
-    nkv = S // bk
-    nq = T // bq
+    lse = lse.reshape(B * H, T)
     has_mask = mask is not None
-    extra_in, extra_specs_ij, extra_specs_ji = [], [], []
+    extra_in, extra_specs = [], []
     if has_mask:
-        extra_in = [_mask_bias3(mask, B, S)]
-        extra_specs_ij = [pl.BlockSpec((1, 1, bk),
-                                       lambda b, i, j, H=H: (b // H, 0, j))]
-        extra_specs_ji = [pl.BlockSpec((1, 1, bk),
-                                       lambda b, j, i, H=H: (b // H, 0, j))]
+        # bias [B, S, 1] along the tile's rows: per-batch, shared across the
+        # H heads folded into grid dim 0 — the index map divides the head out
+        extra_in = [_mask_bias3(mask, B, S).reshape(B, S, 1)]
+        extra_specs = [pl.BlockSpec((1, bk, 1),
+                                    lambda b, j, i, H=H: (b // H, j, 0))]
 
-    dq_kernel = functools.partial(_flash_bwd_dq_kernel, block_q=bq,
-                                  block_k=bk, nkv=nkv, causal=causal,
-                                  scale=scale, has_mask=has_mask)
-    dq = pl.pallas_call(
-        dq_kernel,
-        grid=(B * H, nq, nkv),
-        in_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, Dv), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
-        ] + extra_specs_ij,
-        out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        interpret=interpret,
-    )(qf, kf, vf, gf, lse3, delta3, *extra_in)
+    def one_span(t0, t1, part_dtype=None):
+        """The kernel over queries [t0, t1) and all the keys; dK and dV in
+        ``part_dtype`` where they are one span's part of a sum."""
+        n = t1 - t0
+        nq = n // bq
 
-    dkv_kernel = functools.partial(_flash_bwd_dkv_kernel, block_q=bq,
-                                   block_k=bk, nq=nq, causal=causal,
-                                   scale=scale, has_mask=has_mask)
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
-        grid=(B * H, nkv, nq),
-        in_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bk, Dv), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bq, Dv), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0)),
-        ] + extra_specs_ji,
-        out_specs=[
-            pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bk, Dv), lambda b, j, i: (b, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * H, S, D), k.dtype),
-            jax.ShapeDtypeStruct((B * H, S, Dv), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, D), jnp.float32),
-            pltpu.VMEM((bk, Dv), jnp.float32),
-        ],
-        interpret=interpret,
-    )(qf, kf, vf, gf, lse3, delta3, *extra_in)
+        def qi(j, i):
+            # a dead causal tile names the kv block's first live q block,
+            # which the next step needs anyway: nothing is fetched for it
+            if not causal:
+                return i
+            first = jnp.maximum(j * bk - t0, 0) // bq
+            return jnp.maximum(i, jnp.minimum(first, nq - 1))
+
+        def stat(a):                    # [B*H, n] -> [1, bq] blocks
+            return a[:, t0:t1].reshape(B * H, nq, 1, bq)
+
+        kernel = functools.partial(
+            _flash_bwd_kernel, block_q=bq, block_k=bk, nq=nq, nkv=nkv,
+            q_offset=t0, causal=causal, scale=scale, has_mask=has_mask)
+        stat_spec = pl.BlockSpec((1, 1, 1, bq),
+                                 lambda b, j, i: (b, qi(j, i), 0, 0))
+        return pl.pallas_call(
+            kernel,
+            grid=(B * H, nkv, nq),
+            in_specs=[
+                pl.BlockSpec((1, bq, D), lambda b, j, i: (b, qi(j, i), 0)),
+                pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
+                pl.BlockSpec((1, bk, Dv), lambda b, j, i: (b, j, 0)),
+                pl.BlockSpec((1, bq, Dv), lambda b, j, i: (b, qi(j, i), 0)),
+                stat_spec, stat_spec,
+            ] + extra_specs,
+            out_specs=[
+                # dQ: one block a (batch, head), written back when it ends
+                pl.BlockSpec((1, n, D), lambda b, j, i: (b, 0, 0)),
+                pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
+                pl.BlockSpec((1, bk, Dv), lambda b, j, i: (b, j, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((B * H, n, D), q.dtype),
+                jax.ShapeDtypeStruct((B * H, S, D), part_dtype or k.dtype),
+                jax.ShapeDtypeStruct((B * H, S, Dv), part_dtype or v.dtype),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((n, D), jnp.float32),
+                pltpu.VMEM((bk, D), jnp.float32),
+                pltpu.VMEM((bk, Dv), jnp.float32),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=_BWD_VMEM_LIMIT),
+            interpret=interpret,
+        )(qf[:, t0:t1], kf, vf, gf[:, t0:t1], stat(lse), stat(delta),
+          *extra_in)
+
+    if span >= T:
+        dq, dk, dv = one_span(0, T)
+    else:
+        parts = [one_span(t0, min(t0 + span, T), jnp.float32)
+                 for t0 in range(0, T, span)]
+        dq = jnp.concatenate([part[0] for part in parts], axis=1)
+        dk = sum(part[1] for part in parts).astype(k.dtype)
+        dv = sum(part[2] for part in parts).astype(v.dtype)
     return (dq.reshape(B, H, T, D), dk.reshape(B, H, S, D),
             dv.reshape(B, H, S, Dv))
 
@@ -475,7 +500,7 @@ def _fa_fwd(q, k, v, mask, causal, scale, block_q, block_k,
     """The forward kernel, once; residuals for `_fa_bwd`.
 
     `out` [B, H, T, Dv] and the row logsumexp [B*H, T] (float32) are the two
-    residuals the backward kernels need that a caller cannot rebuild without
+    residuals the backward kernel needs that a caller cannot rebuild without
     running this kernel again, so they carry the names `FLASH_OUT` and
     `FLASH_LSE`: a block under `jax.checkpoint(policy=
     save_only_these_names(FLASH_OUT, FLASH_LSE))` keeps them and its
@@ -525,7 +550,7 @@ def fused_attention(q, k, v, mask=None, causal=False, scale=None):
     """Dispatcher (the platform-helper pattern — cuDNN-attention role):
 
     - kernel tier (`ops/pallas/dispatch`): Pallas flash kernels (fwd +
-      true FlashAttention-2-style bwd, O(T) memory) with TileConfig-driven
+      one-kernel recomputing bwd, O(T) memory) with TileConfig-driven
       blocks and masked-tail padding for ragged shapes, on TPU/GPU when
       the measured heuristics say flash wins (long seq, lane-multiple D),
       or whenever the tier is forced to `pallas`.

@@ -1,12 +1,15 @@
 """Flash attention entry point for the fused-kernel tier.
 
 Thin, tile-aware wrapper over the blockwise online-softmax kernels in
-``ops/attention_kernels.py`` (forward + FlashAttention-2-style backward
-via ``_flash_attention_diff``).  What the tier adds on top:
+``ops/attention_kernels.py`` (forward + the one backward kernel that takes
+dQ, dK and dV from a single pass over the recomputed scores, via
+``_flash_attention_diff``).  What the tier adds on top:
 
 - tiling comes from a :class:`TileConfig` (``block_q``/``block_kv``)
   instead of the fixed ``_pick_block`` ladder, so the autotuner's
-  persisted winners take effect here;
+  persisted winners take effect here (the backward starts from the same
+  blocks and halves them only where its VMEM budget asks:
+  ``attention_kernels._bwd_plan``);
 - ragged / non-multiple-of-tile shapes are handled by zero-padding T and
   S up to block multiples with the padded KV positions knocked out via
   the additive [B, S] mask (a masked tail), then slicing the padded query

@@ -126,10 +126,12 @@ def ring_attention_flash(q, k, v, axis_name: str, causal: bool = False,
 
     Differentiable via custom_vjp: the backward delegates to the einsum
     ring's autodiff (mathematically the same function, so the gradients
-    are exact); a fused flash-bwd ring is a future multi-chip-measured
-    step.  Single-chip A/B is vacuous (axis size 1 = plain flash), so
-    adoption into dispatch waits for multi-chip hardware; correctness is
-    CPU-tested via interpret mode.
+    are exact); a ring over the one-kernel flash backward
+    (``flash_attention_bwd_tpu``, which already yields dQ, dK and dV of a
+    chunk pair in one pass) is a future multi-chip-measured step.
+    Single-chip A/B is vacuous (axis size 1 = plain flash), so adoption
+    into dispatch waits for multi-chip hardware; correctness is CPU-tested
+    via interpret mode.
 
     ``block_q``/``block_k`` default to the kernel tier's installed
     attention :class:`TileConfig` (autotuned winners apply here too),

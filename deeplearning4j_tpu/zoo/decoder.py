@@ -29,7 +29,7 @@ time is flat in depth.  What is saved for the backward pass is fixed here,
 by measurement (PERF.md, PR 27 and PR 28): each block's input and the
 attention kernel's output and logsumexp.  The rest of the block is computed
 again in the backward pass, the flash forward kernel is not: its two
-results are what its backward kernels need and 68 MB a layer at 2 x 4,096
+results are what its backward kernel needs and 68 MB a layer at 2 x 4,096
 tokens, where q, k and v are 268 MB and come back from the projections.  At
 8,192 tokens a step beside 9.2 GB of training state saving those too does
 not fit a 16 GB chip.  Where `fused_attention` takes no Pallas kernel (the

@@ -147,7 +147,7 @@ def test_ring_attention_differentiable():
 
 
 def test_flash_bwd_kernel_interpret_matches_reference():
-    """Pallas backward kernels (dq/dkv) vs jax.grad of the naive reference."""
+    """Pallas backward kernel vs jax.grad of the naive reference."""
     from deeplearning4j_tpu.ops.attention_kernels import flash_attention_bwd_tpu
     for causal in (False, True):
         q, k, v = _qkv(B=1, H=2, T=256, D=64)
@@ -275,8 +275,8 @@ def _forced_flash(q, k, v):
 
 
 def test_names_are_inert_without_a_policy():
-    """Un-checkpointed `jax.grad(fused_attention)` through the kernels: three
-    `pallas_call`s, and the gradients are to the last bit what the forward
+    """Un-checkpointed `jax.grad(fused_attention)` through the kernels: two
+    `pallas_call`s (forward, backward), and the gradients are to the last bit what the forward
     and backward kernels give when called by hand, with no name between
     them."""
     from deeplearning4j_tpu.ops.attention_kernels import \
@@ -285,7 +285,7 @@ def test_names_are_inert_without_a_policy():
     g = _qkv(B=1, H=2, T=256, D=32, seed=1)[0]
     loss = lambda q, k, v: jnp.sum(_forced_flash(q, k, v) * g)
     prims = _primitives(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, k, v).jaxpr)
-    assert prims.count("pallas_call") == 3
+    assert prims.count("pallas_call") == 2
     assert prims.count("name") == 2          # there, and an identity
     got = jax.grad(loss, (0, 1, 2))(q, k, v)
     out, lse = flash_attention_tpu(q, k, v, True, None, 64, 128,
@@ -325,7 +325,7 @@ def test_a_policy_keeps_the_two_named_results_and_no_second_forward(capsys):
     """Under `save_only_these_names(FLASH_OUT, FLASH_LSE)` the backward pass
     is handed q, k, v (arguments), `out` [B, H, T, Dv] and the logsumexp as
     [B*H, T] — never the kernel's lane-padded [B*H, T, 1] — and runs the
-    two backward kernels alone; a bare checkpoint runs the forward again."""
+    backward kernel alone; a bare checkpoint runs the forward again."""
     import deeplearning4j_tpu.ops.attention_kernels as ak
     q, k, _ = _qkv(B=1, H=2, T=256, D=32)
     v = _qkv(B=1, H=2, T=256, D=16, seed=2)[2]
@@ -334,7 +334,7 @@ def test_a_policy_keeps_the_two_named_results_and_no_second_forward(capsys):
                           .save_only_these_names(ak.FLASH_OUT, ak.FLASH_LSE))
     count = lambda f: _primitives(jax.make_jaxpr(jax.grad(f, (0, 1, 2)))(
         q, k, v).jaxpr).count("pallas_call")
-    assert (count(keep), count(jax.checkpoint(f)), count(f)) == (3, 4, 3)
+    assert (count(keep), count(jax.checkpoint(f)), count(f)) == (2, 3, 2)
     capsys.readouterr()
     jax.ad_checkpoint.print_saved_residuals(keep, q, k, v)
     lines = capsys.readouterr().out.strip().splitlines()
@@ -344,6 +344,119 @@ def test_a_policy_keeps_the_two_named_results_and_no_second_forward(capsys):
     for a, b in zip(jax.grad(keep, (0, 1, 2))(q, k, v),
                     jax.grad(f, (0, 1, 2))(q, k, v)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# ---------------------------------------------------------------------------
+# The one backward kernel (`_flash_bwd_kernel`): dQ, dK, dV from one pass
+# ---------------------------------------------------------------------------
+
+def _bwd_case(T, S, D, Dv, masked, seed=11):
+    """q, k, v, the cotangent and a key mask that cuts inside a kv block."""
+    rng = np.random.RandomState(seed)
+    q, k, v, g = (jnp.asarray(rng.randn(1, 2, n, w).astype(np.float32) * 0.3)
+                  for n, w in ((T, D), (S, D), (S, Dv), (T, Dv)))
+    mask = None
+    if masked:
+        keep = np.ones((1, S), np.float32)
+        keep[0, S - 70:] = 0.0
+        mask = jnp.asarray(keep)
+    return q, k, v, g, mask
+
+
+def _reference_grads(q, k, v, g, mask, causal):
+    return jax.grad(lambda q, k, v: jnp.sum(
+        mha_reference(q, k, v, mask=mask, causal=causal) * g),
+        (0, 1, 2))(q, k, v)
+
+
+def _kernel_grads(q, k, v, g, mask, causal, bq, bk):
+    from deeplearning4j_tpu.ops.attention_kernels import \
+        flash_attention_bwd_tpu
+    out, lse = flash_attention_tpu(q, k, v, causal=causal, block_q=bq,
+                                   block_k=bk, interpret=True,
+                                   return_lse=True, mask=mask)
+    return flash_attention_bwd_tpu(q, k, v, out, lse, g, causal=causal,
+                                   block_q=bq, block_k=bk, interpret=True,
+                                   mask=mask)
+
+
+@pytest.mark.parametrize("blocks", [(128, 128), (64, 128), (128, 64)],
+                         ids=lambda b: f"{b[0]}x{b[1]}")
+@pytest.mark.parametrize("widths", [(64, 64), (192, 128)],
+                         ids=lambda w: f"k{w[0]}v{w[1]}")
+@pytest.mark.parametrize("masked", [False, True], ids=["nomask", "keymask"])
+@pytest.mark.parametrize("shape", [("causal", 256, 256), ("full", 256, 256),
+                                   ("full", 128, 384)],
+                         ids=["causal", "full", "full_T128_S384"])
+def test_one_backward_kernel_matches_reference(shape, masked, widths, blocks):
+    """dQ, dK, dV of the one kernel against `jax.grad` of `mha_reference`:
+    causal and not, with and without a key mask, keys as wide as the values
+    and wider (latent attention's 192 / 128), the diagonal crossing a tile
+    along the queries and along the keys, and T != S."""
+    (kind, T, S), (D, Dv), (bq, bk) = shape, widths, blocks
+    q, k, v, g, mask = _bwd_case(T, S, D, Dv, masked)
+    causal = kind == "causal"
+    got = _kernel_grads(q, k, v, g, mask, causal, bq, bk)
+    want = _reference_grads(q, k, v, g, mask, causal)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["nomask", "keymask"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_backward_cuts_the_queries_into_spans_that_fit(causal, masked,
+                                                       monkeypatch):
+    """A resident dQ larger than its budget: the queries go in spans (here
+    64 rows of 256: four calls), dQ is each span's own and to the last bit
+    the whole sequence's, dK/dV are the spans' sums."""
+    import deeplearning4j_tpu.ops.attention_kernels as ak
+    q, k, v, g, mask = _bwd_case(256, 256, 64, 64, masked)
+    whole = _kernel_grads(q, k, v, g, mask, causal, 64, 128)
+    monkeypatch.setattr(ak, "_BWD_DQ_VMEM", 64 * 64 * 12)
+    assert ak._bwd_plan(256, 256, 64, 64, 4, 64, 128) == (64, 128, 64)
+    spans = _kernel_grads(q, k, v, g, mask, causal, 64, 128)
+    calls = _primitives(jax.make_jaxpr(
+        lambda *a: _kernel_grads(*a, mask, causal, 64, 128))(q, k, v, g).jaxpr)
+    assert calls.count("pallas_call") == 1 + 4
+    np.testing.assert_array_equal(np.asarray(spans[0]), np.asarray(whole[0]))
+    for a, b in zip(spans, _reference_grads(q, k, v, g, mask, causal)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("args,plan", [
+    # kanana's cell: the forward's tile as it is, the whole sequence resident
+    ((4096, 4096, 192, 128, 2, 512, 1024), (512, 1024, 4096)),
+    # a tile whose temporaries do not fit: the larger side halved
+    ((8192, 8192, 128, 128, 2, 1024, 2048), (1024, 1024, 8192)),
+    # 32k tokens at keys of 192: three spans of whole blocks
+    ((32768, 32768, 192, 128, 2, 512, 1024), (512, 1024, 10752)),
+    # a short ragged sequence: one block, one span
+    ((48, 128, 64, 64, 4, 48, 128), (48, 128, 48)),
+], ids=["kanana", "halved", "spans", "short"])
+def test_backward_plan_from_shapes(args, plan):
+    from deeplearning4j_tpu.ops.attention_kernels import _bwd_plan
+    assert _bwd_plan(*args) == plan
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["nomask", "keymask"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_gradient_through_the_padded_ragged_tail(causal, masked):
+    """`jax.grad` through `_flash_attention_diff` as the tier calls it on a
+    ragged shape (T = S = 200 under 64 x 128 blocks: padded to 256 with the
+    tail's keys masked out): the reference's gradients on the real rows."""
+    from deeplearning4j_tpu.ops import pallas as tier
+    q, k, v, g, mask = _bwd_case(200, 200, 192, 128, masked)
+    tile = tier.TileConfig(block_q=64, block_kv=128)
+    got = jax.grad(lambda q, k, v: jnp.sum(tier.attention.flash_attention(
+        q, k, v, mask=mask, causal=causal, tile=tile, interpret=True) * g),
+        (0, 1, 2))(q, k, v)
+    for a, b in zip(got, _reference_grads(q, k, v, g, mask, causal)):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-5)
 
 
 # ---------------------------------------------------------------------------

@@ -1,0 +1,101 @@
+"""The attention kernels of the train path, compiled by Mosaic for a
+DESCRIBED TPU v5e (no chip attached, nothing runs): what interpret mode
+cannot see — a block the tiling rule refuses, more VMEM than the call
+states, an index map Mosaic cannot lower.  A compile that passes is not a
+chip run and gives no time.
+
+The topology is described inside a fixture and only in this file: the
+process that describes it loads libtpu and keeps it (see the
+`on-chip-measurement` guide, section 2)."""
+import jax
+import jax.numpy as jnp
+import pytest
+
+from deeplearning4j_tpu.ops import attention_kernels as ak
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    with pytest.MonkeyPatch.context() as env:
+        for name, value in (("TPU_LOG_DIR", "disabled"),
+                            ("TPU_ACCELERATOR_TYPE", "v5litepod-1"),
+                            ("TPU_WORKER_HOSTNAMES", "localhost"),
+                            ("TPU_SKIP_MDS_QUERY", "1")):
+            if name not in os.environ:
+                env.setenv(name, value)
+        try:
+            topo = topologies.get_topology_desc(
+                platform="tpu", topology_name="v5e:1x1",
+                chips_per_host_bounds=(1, 1, 1))
+        except Exception as e:
+            pytest.skip(f"no v5e topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, one_chip, *shapes):
+    """Lower for the TPU and compile; x64 off, as on the chip."""
+    with jax.enable_x64(False):
+        specs = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                 for s, d in shapes]
+        return jax.jit(fn).trace(*specs).lower(
+            lowering_platforms=("tpu",)).compile()
+
+
+# B, H, T, S, D, Dv, dtype, blocks, causal, key mask
+_SHAPES = {
+    # kanana's cell: keys 192, values 128, 2 x 32 heads of 4,096 tokens
+    "kanana": (2, 32, 4096, 4096, 192, 128, jnp.bfloat16, (512, 1024),
+               True, False),
+    # a ragged masked batch as the tier pads it, float32
+    "masked_f32": (2, 4, 2560, 2048, 128, 128, jnp.float32, (512, 1024),
+                   True, True),
+    # few queries over many keys: the queries (lanes of the backward's
+    # tile) are a block of 48
+    "short_q": (2, 4, 48, 2048, 64, 64, jnp.bfloat16, (48, 1024), False,
+                True),
+}
+
+
+@pytest.mark.parametrize("case", list(_SHAPES))
+def test_flash_forward_compiles_for_v5e(case, one_chip):
+    B, H, T, S, D, Dv, dt, (bq, bk), causal, masked = _SHAPES[case]
+    shapes = [((B, H, T, D), dt), ((B, H, S, D), dt), ((B, H, S, Dv), dt)]
+    if masked:
+        shapes.append(((B, S), dt))
+    compiled = _compile(
+        lambda q, k, v, mask=None: ak.flash_attention_tpu(
+            q, k, v, causal=causal, block_q=bq, block_k=bk, return_lse=True,
+            mask=mask),
+        one_chip, *shapes)
+    assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("case", list(_SHAPES))
+def test_flash_backward_is_one_kernel_and_compiles_for_v5e(case, one_chip):
+    B, H, T, S, D, Dv, dt, (bq, bk), causal, masked = _SHAPES[case]
+    shapes = [((B, H, T, D), dt), ((B, H, S, D), dt), ((B, H, S, Dv), dt),
+              ((B, H, T, Dv), dt), ((B * H, T), jnp.float32),
+              ((B, H, T, Dv), dt)]
+    if masked:
+        shapes.append(((B, S), dt))
+    compiled = _compile(
+        lambda q, k, v, out, lse, g, mask=None: ak.flash_attention_bwd_tpu(
+            q, k, v, out, lse, g, causal=causal, block_q=bq, block_k=bk,
+            mask=mask),
+        one_chip, *shapes)
+    assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+def test_backward_in_spans_compiles_for_v5e(one_chip, monkeypatch):
+    """Queries cut into spans (budget forced to 1,024 rows of 4,096)."""
+    monkeypatch.setattr(ak, "_BWD_DQ_VMEM", 1024 * 192 * 8)
+    B, H, T, D, Dv, dt = 1, 4, 4096, 192, 128, jnp.bfloat16
+    compiled = _compile(
+        lambda q, k, v, out, lse, g: ak.flash_attention_bwd_tpu(
+            q, k, v, out, lse, g, causal=True, block_q=512, block_k=1024),
+        one_chip, ((B, H, T, D), dt), ((B, H, T, D), dt), ((B, H, T, Dv), dt),
+        ((B, H, T, Dv), dt), ((B * H, T), jnp.float32), ((B, H, T, Dv), dt))
+    assert compiled.as_text().count("tpu_custom_call") == 4
