@@ -25,6 +25,14 @@ attention layers reshape from [B, T, F]).  `q` and `k` share the key width
 D; `v` (and the output) may be narrower or wider, [B, H, S, Dv] — latent
 attention's expanded form has keys of 192 and values of 128.  The default
 scale is over the key width.
+
+Grouped-query heads: `k` and `v` may lie over fewer heads than `q`, [B, Hk,
+S, ...] with H a multiple of Hk; query head `h` attends key-value head
+`h // (H // Hk)`, and dK, dV are sums over a group's query heads.  The
+Mosaic kernels never hold keys or values repeated in HBM: the forward
+addresses their blocks by `head // group`, the backward folds a group's
+query heads into one grid row so that dK and dV accumulate over them in the
+kernel's scratch; the two XLA paths repeat them, where XLA pleases.
 """
 from __future__ import annotations
 
@@ -40,11 +48,22 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
+def _over_query_heads(q, k, v):
+    """`k` and `v` [B, Hk, ...] repeated over the query's H heads, head `h`
+    from `h // (H // Hk)`; themselves where H == Hk.  Autodiff sums a
+    group's gradients."""
+    group = q.shape[1] // k.shape[1]
+    if group == 1:
+        return k, v
+    return jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+
+
 def mha_reference(q, k, v, mask=None, causal=False, scale=None):
     """Naive attention (ground truth).  mask: [B, T] of 1/0 over KV
     positions."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    k, v = _over_query_heads(q, k, v)
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
     if causal:
         T, S = q.shape[2], k.shape[2]
@@ -59,6 +78,7 @@ def mha_reference(q, k, v, mask=None, causal=False, scale=None):
 
 def _blockwise_fwd(q, k, v, mask, causal, scale, block_k):
     """Online-softmax scan over KV blocks; returns (out, (m, l))."""
+    k, v = _over_query_heads(q, k, v)
     B, H, T, D = q.shape
     S, Dv = k.shape[2], v.shape[3]
     nblocks = S // block_k
@@ -213,12 +233,13 @@ def _mask_bias3(mask, B, S):
 def flash_attention_tpu(q, k, v, causal=False, scale=None,
                         block_q=256, block_k=256, interpret=False,
                         return_lse=False, mask=None):
-    """Pallas flash-attention forward.  q [B, H, T, D], k [B, H, S, D],
-    v [B, H, S, Dv] -> [B, H, T, Dv]; T and S divisible by the block sizes
-    (dispatcher checks).  With ``return_lse`` also returns the
-    row logsumexp [B*H, T] (f32) for the backward kernel.  ``mask``:
-    optional [B, S] 1/0 keep-mask over KV positions (padding/segment
-    mask), shared across heads."""
+    """Pallas flash-attention forward.  q [B, H, T, D], k [B, Hk, S, D],
+    v [B, Hk, S, Dv] -> [B, H, T, Dv]; T and S divisible by the block sizes
+    (dispatcher checks), H a multiple of Hk (a key-value head's blocks are
+    fetched for each of its query heads, from where they lie).  With
+    ``return_lse`` also returns the row logsumexp [B*H, T] (f32) for the
+    backward kernel.  ``mask``: optional [B, S] 1/0 keep-mask over KV
+    positions (padding/segment mask), shared across heads."""
     B, H, T, D = q.shape
     S, Dv = k.shape[2], v.shape[3]
     if scale is None:
@@ -226,17 +247,22 @@ def flash_attention_tpu(q, k, v, causal=False, scale=None,
     bq = min(block_q, T)
     bk = min(block_k, S)
     nkv = S // bk
+    Hk = k.shape[1]
+    group = H // Hk
+    # grid row b = batch * H + head reads the row b // group of k and v
+    # folded to [B * Hk, S, .]: the heads of a group are neighbours
+    kv_row = (lambda b: b) if group == 1 else (lambda b: b // group)
     qf = q.reshape(B * H, T, D)
-    kf = k.reshape(B * H, S, D)
-    vf = v.reshape(B * H, S, Dv)
+    kf = k.reshape(B * Hk, S, D)
+    vf = v.reshape(B * Hk, S, Dv)
     has_mask = mask is not None
     kernel = functools.partial(_flash_kernel, block_q=bq, block_k=bk,
                                nkv=nkv, causal=causal, scale=scale,
                                has_mask=has_mask)
     in_specs = [
         pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
-        pl.BlockSpec((1, bk, Dv), lambda b, i, j: (b, j, 0)),
+        pl.BlockSpec((1, bk, D), lambda b, i, j: (kv_row(b), j, 0)),
+        pl.BlockSpec((1, bk, Dv), lambda b, i, j: (kv_row(b), j, 0)),
     ]
     inputs = [qf, kf, vf]
     if has_mask:
@@ -285,8 +311,8 @@ _NT = (((1,), (1,)), ((), ()))                 # a [m, c], b [n, c] -> [m, n]
 
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                       block_q: int, block_k: int, nq: int, nkv: int,
-                      q_offset: int, causal: bool, scale: float,
-                      has_mask: bool):
+                      head_blocks: int, q_offset: int, causal: bool,
+                      scale: float, has_mask: bool):
     """dQ, dK and dV over grid (batch*head, kv-block, q-block), the q blocks
     innermost.  A live tile recomputes P from the saved logsumexp and
     computes dP and dS once; from them dV += P^T dO and dK += dS^T Q into
@@ -296,7 +322,10 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     ([bk, bq]), so that dV and dK are plain products, dQ's is the one
     transposed operand and the row statistics lie along lanes ([1, bq]).
     ``q_offset`` is the first query's position (a span of a longer
-    sequence)."""
+    sequence).  The ``nq`` q blocks of a grid row are ``head_blocks`` blocks
+    of each query head of a group, head after head (``nq`` itself where
+    every head has its own keys): a block's positions start anew with each
+    head, and dK/dV sum over all of them."""
     if has_mask:
         bias_ref, dq_ref, dk_ref, dv_ref, dq_sc, dk_sc, dv_sc = rest
     else:
@@ -305,6 +334,9 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     j = pl.program_id(1)
     i = pl.program_id(2)
     rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+
+    def first_q():      # the block's first query position in the span
+        return (i if head_blocks == nq else i % head_blocks) * block_q
 
     @pl.when(i == 0)
     def _init_dkv():
@@ -316,7 +348,7 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         dq_sc[rows, :] = jnp.zeros((block_q, dq_sc.shape[1]), jnp.float32)
 
     # causal: q blocks strictly above the kv block's diagonal see nothing
-    live = (q_offset + i * block_q + block_q - 1 >= j * block_k) \
+    live = (q_offset + first_q() + block_q - 1 >= j * block_k) \
         if causal else True
 
     @pl.when(live)
@@ -333,7 +365,7 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
             keys = (j * block_k
                     + jax.lax.broadcasted_iota(jnp.int32,
                                                (block_k, block_q), 0))
-            queries = (q_offset + i * block_q
+            queries = (q_offset + first_q()
                        + jax.lax.broadcasted_iota(jnp.int32,
                                                   (block_k, block_q), 1))
             s = jnp.where(queries >= keys, s, NEG_INF)
@@ -357,12 +389,13 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         dq_ref[0, rows, :] = (dq_sc[rows, :] * scale).astype(dq_ref.dtype)
 
 
-def _bwd_plan(T, S, D, Dv, itemsize, block_q, block_k):
+def _bwd_plan(T, S, D, Dv, itemsize, block_q, block_k, group=1):
     """(bq, bk, span) of the backward kernel, from the shapes alone: the
     asked blocks, the larger halved until the tile's four f32 [bk, bq]
     temporaries, the double-buffered operands and dK/dV's accumulators and
     output blocks fit `_BWD_VMEM_LIMIT` less `_BWD_DQ_VMEM`; and the most
-    query rows (whole blocks) whose resident dQ fits `_BWD_DQ_VMEM`."""
+    query rows (whole blocks) of each of a group's `group` heads whose
+    resident dQ fits `_BWD_DQ_VMEM`."""
     bq, bk = min(block_q, T), min(block_k, S)
 
     def tile_bytes(bq, bk):
@@ -376,7 +409,7 @@ def _bwd_plan(T, S, D, Dv, itemsize, block_q, block_k):
             bq //= 2
         else:
             break
-    rows = _BWD_DQ_VMEM // (D * (4 + 2 * itemsize))
+    rows = _BWD_DQ_VMEM // (D * (4 + 2 * itemsize)) // group
     return bq, bk, min(T, max(bq, rows // bq * bq))
 
 
@@ -393,16 +426,25 @@ def flash_attention_bwd_tpu(q, k, v, out, lse, g, causal=False, scale=None,
     budget of 16 MiB (`_BWD_DQ_VMEM`: 10,922 rows at keys of 192 in
     bfloat16); a longer sequence is cut into spans of queries that fit, one
     call each over all the keys, and dK/dV are summed over the spans in
-    float32."""
+    float32.  Grouped-query heads (k, v [B, Hk, S, .]): a grid row is a
+    KEY-VALUE head and its q blocks are those of the group's query heads,
+    head after head, so dK and dV accumulate over the group in the kernel's
+    scratch and dQ of the whole group is resident (32,768 rows of 64: the
+    whole budget at `[1, 32, 8192, 64]` over 8).  Measured there against
+    per-head dK/dV written in float32 and summed after: 9.68 against 10.37
+    ms (PERF.md, PR 31)."""
     B, H, T, D = q.shape
     S, Dv = k.shape[2], v.shape[3]
+    Hk = k.shape[1]
+    G = H // Hk
     if scale is None:
         scale = D ** -0.5
-    bq, bk, span = _bwd_plan(T, S, D, Dv, q.dtype.itemsize, block_q, block_k)
+    bq, bk, span = _bwd_plan(T, S, D, Dv, q.dtype.itemsize, block_q, block_k,
+                             G)
     nkv = S // bk
     qf = q.reshape(B * H, T, D)
-    kf = k.reshape(B * H, S, D)
-    vf = v.reshape(B * H, S, Dv)
+    kf = k.reshape(B * Hk, S, D)
+    vf = v.reshape(B * Hk, S, Dv)
     gf = g.reshape(B * H, T, Dv)
     # delta_i = rowsum(dO_i * O_i) — cheap elementwise reduce, XLA-fused
     delta = jnp.sum(gf.astype(jnp.float32)
@@ -412,36 +454,44 @@ def flash_attention_bwd_tpu(q, k, v, out, lse, g, causal=False, scale=None,
     extra_in, extra_specs = [], []
     if has_mask:
         # bias [B, S, 1] along the tile's rows: per-batch, shared across the
-        # H heads folded into grid dim 0 — the index map divides the head out
+        # heads folded into grid dim 0 — the index map divides the head out
         extra_in = [_mask_bias3(mask, B, S).reshape(B, S, 1)]
         extra_specs = [pl.BlockSpec((1, bk, 1),
-                                    lambda b, j, i, H=H: (b // H, j, 0))]
+                                    lambda b, j, i, H=Hk: (b // H, j, 0))]
 
     def one_span(t0, t1, part_dtype=None):
         """The kernel over queries [t0, t1) and all the keys; dK and dV in
         ``part_dtype`` where they are one span's part of a sum."""
         n = t1 - t0
-        nq = n // bq
+        nq = n // bq                    # q blocks of one head
 
         def qi(j, i):
-            # a dead causal tile names the kv block's first live q block,
-            # which the next step needs anyway: nothing is fetched for it
+            # a dead causal tile names the kv block's first live q block of
+            # its head, which the next step needs anyway: nothing is fetched
+            # for it
             if not causal:
                 return i
-            first = jnp.maximum(j * bk - t0, 0) // bq
-            return jnp.maximum(i, jnp.minimum(first, nq - 1))
+            first = jnp.minimum(jnp.maximum(j * bk - t0, 0) // bq, nq - 1)
+            if G == 1:
+                return jnp.maximum(i, first)
+            return i - i % nq + jnp.maximum(i % nq, first)
 
-        def stat(a):                    # [B*H, n] -> [1, bq] blocks
-            return a[:, t0:t1].reshape(B * H, nq, 1, bq)
+        def rows(a):        # [B*H, T, .] -> the span's rows of a group,
+            a = a[:, t0:t1]                         # head after head
+            return a if G == 1 else a.reshape(B * Hk, G * n, a.shape[-1])
+
+        def stat(a):                    # [B*H, T] -> [1, bq] blocks
+            return a[:, t0:t1].reshape(B * Hk, G * nq, 1, bq)
 
         kernel = functools.partial(
-            _flash_bwd_kernel, block_q=bq, block_k=bk, nq=nq, nkv=nkv,
-            q_offset=t0, causal=causal, scale=scale, has_mask=has_mask)
+            _flash_bwd_kernel, block_q=bq, block_k=bk, nq=G * nq, nkv=nkv,
+            head_blocks=nq, q_offset=t0, causal=causal, scale=scale,
+            has_mask=has_mask)
         stat_spec = pl.BlockSpec((1, 1, 1, bq),
                                  lambda b, j, i: (b, qi(j, i), 0, 0))
         return pl.pallas_call(
             kernel,
-            grid=(B * H, nkv, nq),
+            grid=(B * Hk, nkv, G * nq),
             in_specs=[
                 pl.BlockSpec((1, bq, D), lambda b, j, i: (b, qi(j, i), 0)),
                 pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
@@ -450,37 +500,38 @@ def flash_attention_bwd_tpu(q, k, v, out, lse, g, causal=False, scale=None,
                 stat_spec, stat_spec,
             ] + extra_specs,
             out_specs=[
-                # dQ: one block a (batch, head), written back when it ends
-                pl.BlockSpec((1, n, D), lambda b, j, i: (b, 0, 0)),
+                # dQ: one block a (batch, key-value head), written back
+                # when it ends
+                pl.BlockSpec((1, G * n, D), lambda b, j, i: (b, 0, 0)),
                 pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
                 pl.BlockSpec((1, bk, Dv), lambda b, j, i: (b, j, 0)),
             ],
             out_shape=[
-                jax.ShapeDtypeStruct((B * H, n, D), q.dtype),
-                jax.ShapeDtypeStruct((B * H, S, D), part_dtype or k.dtype),
-                jax.ShapeDtypeStruct((B * H, S, Dv), part_dtype or v.dtype),
+                jax.ShapeDtypeStruct((B * Hk, G * n, D), q.dtype),
+                jax.ShapeDtypeStruct((B * Hk, S, D), part_dtype or k.dtype),
+                jax.ShapeDtypeStruct((B * Hk, S, Dv), part_dtype or v.dtype),
             ],
             scratch_shapes=[
-                pltpu.VMEM((n, D), jnp.float32),
+                pltpu.VMEM((G * n, D), jnp.float32),
                 pltpu.VMEM((bk, D), jnp.float32),
                 pltpu.VMEM((bk, Dv), jnp.float32),
             ],
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=_BWD_VMEM_LIMIT),
             interpret=interpret,
-        )(qf[:, t0:t1], kf, vf, gf[:, t0:t1], stat(lse), stat(delta),
-          *extra_in)
+        )(rows(qf), kf, vf, rows(gf), stat(lse), stat(delta), *extra_in)
 
     if span >= T:
         dq, dk, dv = one_span(0, T)
     else:
         parts = [one_span(t0, min(t0 + span, T), jnp.float32)
                  for t0 in range(0, T, span)]
-        dq = jnp.concatenate([part[0] for part in parts], axis=1)
+        dq = jnp.concatenate(
+            [part[0].reshape(B * H, -1, D) for part in parts], axis=1)
         dk = sum(part[1] for part in parts).astype(k.dtype)
         dv = sum(part[2] for part in parts).astype(v.dtype)
-    return (dq.reshape(B, H, T, D), dk.reshape(B, H, S, D),
-            dv.reshape(B, H, S, Dv))
+    return (dq.reshape(B, H, T, D), dk.reshape(B, Hk, S, D),
+            dv.reshape(B, Hk, S, Dv))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))

@@ -7,8 +7,11 @@ shared experts that see every token.
 
     s = sigmoid(f32(x) W_g)                      [T, E]
     chosen = the k largest of s + b              (b picks, it does not weigh)
-    w = s[chosen] / (sum s[chosen] + 1e-20) * scale
+    w = s[chosen] / (sum s[chosen] + eps) * scale
     y = sum_k w_k E_chosen_k(x) + S(x)
+
+`eps` is 1e-20 in DeepSeek-V3's code and 1e-6 in other families'; a layer
+may have no shared expert (`S` is then left out).
 
 Under expert parallelism a chip holds the experts `first .. first + held`
 of a layer.  The router here keeps all `E` outputs and the published top-k;
@@ -38,17 +41,18 @@ import jax.numpy as jnp
 # the router
 # ---------------------------------------------------------------------------
 
-def router(x, w_router, bias, top_k: int, scale: float):
+def router(x, w_router, bias, top_k: int, scale: float, eps: float = 1e-20):
     """(chosen [T, k] int32, weights [T, k] f32) of the tokens `x` [T, H].
     Scores in float32 at full matmul precision: a sixth and a seventh score
     often lie within a bf16 rounding of each other.  `bias` [E] only picks;
-    it gets no gradient and is not in the weights."""
+    it gets no gradient and is not in the weights.  `eps` is what the
+    normalisation adds to the sum of the chosen scores."""
     s = jax.nn.sigmoid(jnp.dot(
         x.astype(jnp.float32), w_router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
     _, chosen = jax.lax.top_k(s + jax.lax.stop_gradient(bias), top_k)
     w = jnp.take_along_axis(s, chosen, axis=-1)
-    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * scale
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps) * scale
     return chosen.astype(jnp.int32), w
 
 
@@ -156,7 +160,8 @@ def swiglu(x, w_gate, w_up, w_down):
     return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
 
 
-def expert_layer(x, p, bias, *, top_k: int, scale: float, first_held: int):
+def expert_layer(x, p, bias, *, top_k: int, scale: float, first_held: int,
+                 eps: float = 1e-20):
     """This chip's share of the layer for tokens `x` [T, H], and the
     step's count of tokens that chose each of the E experts ([E] int32, held
     or not: the router's load, which the bias update balances).
@@ -164,17 +169,20 @@ def expert_layer(x, p, bias, *, top_k: int, scale: float, first_held: int):
     `p`: `router` [H, E]; `w_gate`, `w_up` [held, H, I] and `w_down`
     [held, I, H]; `shared_gate`, `shared_up` [H, S] and `shared_down`
     [S, H], the shared experts side by side as one SwiGLU of their summed
-    width.  `bias` [E] is the router's selection bias.
+    width — or none of the three, for a layer without shared experts.
+    `bias` [E] is the router's selection bias, `eps` the router's.
 
     The routed part's T*k-row buffers are the layer's largest arrays and
     its arithmetic the smallest: a caller short of memory recomputes the
     layer in the backward pass (`jax.checkpoint`) before anything else."""
     with jax.named_scope("moe"):
         with jax.named_scope("router"):
-            chosen, weights = router(x, p["router"], bias, top_k, scale)
+            chosen, weights = router(x, p["router"], bias, top_k, scale, eps)
             counts = expert_counts(chosen, p["router"].shape[1])
         routed = routed_experts(x, chosen, weights, p["w_gate"], p["w_up"],
                                 p["w_down"], first_held)
+        if "shared_gate" not in p:
+            return routed, counts
         with jax.named_scope("shared"):
             shared = swiglu(x, p["shared_gate"], p["shared_up"],
                             p["shared_down"])

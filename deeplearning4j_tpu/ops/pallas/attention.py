@@ -38,10 +38,10 @@ def _q_sublane(dtype) -> int:
 def flash_attention(q, k, v, mask=None, causal: bool = False, scale=None,
                     tile: Optional[TileConfig] = None,
                     interpret: bool = False):
-    """Flash attention of q [B, H, T, D], k [B, H, S, D], v [B, H, S, Dv]
+    """Flash attention of q [B, H, T, D], k [B, Hk, S, D], v [B, Hk, S, Dv]
     -> [B, H, T, Dv] with TileConfig-driven blocks and masked-tail padding
     for ragged T/S (the padding is along T and S only, so it holds for any
-    value width).  Differentiable."""
+    value width and any number of key-value heads).  Differentiable."""
     import deeplearning4j_tpu.ops.attention_kernels as ak
 
     tile = tile or DEFAULT_TILES["attention"]
@@ -86,10 +86,14 @@ def attention_supports(q, k, v, mask=None, causal: bool = False,
         return False
     if k.dtype != q.dtype or v.dtype != q.dtype:
         return False
-    # q and k share the key width; v may have a width of its own but lies
-    # over the same batch, heads and positions as k
+    # q and k share the batch and the key width; v may have a width of its
+    # own but lies over the same batch, heads and positions as k; the
+    # query's heads are a multiple of k's (grouped-query heads: query head h
+    # attends key-value head h // group), equal where every head has its own
     if getattr(k, "ndim", 0) != 4 or getattr(v, "ndim", 0) != 4 \
-            or k.shape[3] != q.shape[3] or v.shape[:3] != k.shape[:3]:
+            or k.shape[3] != q.shape[3] or v.shape[:3] != k.shape[:3] \
+            or k.shape[0] != q.shape[0] or k.shape[1] == 0 \
+            or q.shape[1] % k.shape[1]:
         return False
     if mask is not None:
         B, _, _, _ = q.shape

@@ -1,19 +1,31 @@
-"""A decoder-only causal language model with latent attention and sparse
-experts, on the train path.
+"""A decoder-only causal language model over a per-layer list of token
+mixers, with dense or sparse-expert feed-forward layers, on the train path.
 
-The block of DeepSeek-V2/V3 (arXiv:2405.04434, arXiv:2412.19437) as public
-`deepseek_v3` configs describe it:
+Pre-norm residual blocks `h = x + Op_l(RMSNorm(x))`, `y = h + F_l(RMSNorm(h))`
+(RMSNorm in float32, `ops/norm_kernels.rms_norm`), no position embedding,
+next-token cross-entropy.  `DecoderConfig.layer_types` names `Op_l` layer by
+layer; two published models are the presets the tests and the benchmark
+build:
 
-- pre-norm residual blocks, RMSNorm (float32, `ops/norm_kernels.rms_norm`),
-  no position embedding, untied output head, next-token cross-entropy;
-- multi-head latent attention in its expanded form: queries of
-  `qk_nope + qk_rope` dims, keys rebuilt from a `kv_lora_rank`-wide latent
-  plus one rotary head shared by all, values of `v_head` dims — so keys
-  are wider than values, through `ops/attention_kernels.fused_attention`
-  (the flash kernels from 2k tokens on the chip);
-- `n_dense_layers` SwiGLU layers first, then `ops/moe.expert_layer`s: a
-  sigmoid router over `n_experts` with a selection bias that the step
-  updates (no auxiliary loss), top-k, shared experts.
+- `deepseek_v3` configs (DeepSeek-V2/V3, arXiv:2405.04434, arXiv:2412.19437;
+  kanana-2-30b-a3b): `latent_attention` in every layer (the default list) —
+  multi-head latent attention in its expanded form: queries of `qk_nope +
+  qk_rope` dims, keys rebuilt from a `kv_lora_rank`-wide latent plus one
+  rotary head shared by all (interleaved rotary), values of `v_head` dims,
+  so keys are wider than values; shared experts beside the routed ones; an
+  untied head.
+- `lfm2_moe` configs (LFM2-24B-A2B): `conv` layers — the gated short
+  convolution of `ops/short_conv.py`, linear in the sequence — beside
+  `full_attention` layers — grouped-query heads (`n_heads` queries over
+  `n_kv_heads` keys and values of `head_dim`), RMSNorm of every query and
+  key head, half-split rotary — in a repeating pattern; no shared expert;
+  the head is the embedding's transpose (`tie_embeddings`).
+
+All of them through `ops/attention_kernels.fused_attention` (the flash
+kernels from 2k tokens on the chip).  `F_l` is a SwiGLU for the first
+`n_dense_layers` layers and `ops/moe.expert_layer` after: a sigmoid router
+over `n_experts` with a selection bias that the step updates (no auxiliary
+loss), top-k.
 
 `first_expert`/`n_experts_held` say which routed experts this process
 holds of each layer (all of them by default).  Held alone, the layer
@@ -23,28 +35,43 @@ vocabulary may be a slice likewise: `vocab_size` rows of embedding and head,
 ids, logits and loss over the slice.
 
 TPU-native choices, as `zoo/bert.py`: one jitted, donated train step;
-float32 master parameters cast to `compute_dtype` a layer at a time; the
-identical expert layers STACKED `[L, ...]` under one `lax.scan`, so compile
-time is flat in depth.  What is saved for the backward pass is fixed here,
-by measurement (PERF.md, PR 27 and PR 28): each block's input and the
-attention kernel's output and logsumexp.  The rest of the block is computed
-again in the backward pass, the flash forward kernel is not: its two
-results are what its backward kernel needs and 68 MB a layer at 2 x 4,096
-tokens, where q, k and v are 268 MB and come back from the projections.  At
-8,192 tokens a step beside 9.2 GB of training state saving those too does
-not fit a 16 GB chip.  Where `fused_attention` takes no Pallas kernel (the
-CPU, short sequences) there is nothing of that name and the block is
-recomputed whole.
+float32 master parameters cast to `compute_dtype` a layer at a time.  The
+expert layers are cut into whole PERIODS of their type list (`a c c c` x 9
+and a remainder `a c` for the published LFM2-24B-A2B; a period of one layer
+where all are alike) and the periods STACKED `[n, ...]` under one
+`lax.scan`, so compile time is flat in depth; inside a period the layers are
+unrolled, each with its own stacked parameters (an inner scan over three
+like layers compiled slower for the chip, 43.6 s against 25-29 s, a real
+loop inside a scan of one trip: PERF.md, PR 31); what is left of the list
+after the last
+whole period is unrolled after the scan.  The leading dense layers are of
+one kind, stacked and unrolled.  What is saved for the backward pass is
+fixed here, by measurement (PERF.md, PR 27 and PR 28): each block's input
+and the attention kernel's output and logsumexp.  The rest of the block is
+computed again in the backward pass, the flash forward kernel is not: its
+two results are what its backward kernel needs and 68 MB a layer at 2 x
+4,096 tokens, where q, k and v are 268 MB and come back from the
+projections.  At 8,192 tokens a step beside 9.2 GB of training state saving
+those too does not fit a 16 GB chip.  Where `fused_attention` takes no
+Pallas kernel (the CPU, short sequences) there is nothing of that name and
+the block is recomputed whole; a `conv` block has no such result and always
+is.
 
-Not here yet: prefill/decode through a cache, absorbed latent attention,
-the experts' exchange over several chips.
+Named scopes mark each part's device ops, forward and backward:
+`mla_attention`, `gqa_attention`, `short_conv` (beneath it `in_proj`, `mix`,
+`out_proj`), `dense_mlp`, `moe` (`ops/moe.py`), `lm_head`.
+
+Not here yet: prefill/decode through a cache (growing pages for the
+attention layers beside a fixed `conv_kernel - 1` positions of state for the
+convolutions), absorbed latent attention, the experts' exchange over
+several chips.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -56,8 +83,13 @@ from deeplearning4j_tpu.ops.attention_kernels import (FLASH_LSE, FLASH_OUT,
 from deeplearning4j_tpu.ops.moe import (expert_layer, swiglu,
                                         update_router_bias)
 from deeplearning4j_tpu.ops.norm_kernels import rms_norm
-from deeplearning4j_tpu.ops.rotary import rotary_interleaved
+from deeplearning4j_tpu.ops.rotary import (rotary_half_split,
+                                           rotary_interleaved)
+from deeplearning4j_tpu.ops.short_conv import gated_short_conv
 from deeplearning4j_tpu.train.updaters import AdamW, IUpdater
+
+
+LAYER_KINDS = ("latent_attention", "full_attention", "conv")
 
 
 @dataclasses.dataclass
@@ -66,17 +98,24 @@ class DecoderConfig:
     hidden: int = 2048
     n_layers: int = 48
     n_dense_layers: int = 1            # leading layers with a dense SwiGLU
+    # each layer's token mixer, one of `LAYER_KINDS`; None: latent attention
+    # in every layer
+    layer_types: Optional[Sequence[str]] = None
     n_heads: int = 32
-    qk_nope_dim: int = 128
+    n_kv_heads: int = 8                # `full_attention`: key-value heads
+    head_dim: int = 64                 # and the width of all its heads
+    conv_kernel: int = 3               # `conv`: taps of the causal convolution
+    qk_nope_dim: int = 128             # `latent_attention`, down to the rank
     qk_rope_dim: int = 64
     v_head_dim: int = 128
     kv_lora_rank: int = 512
     intermediate: int = 6144           # the dense layers' SwiGLU width
     expert_intermediate: int = 768
     n_experts: int = 128               # the router's width
-    n_shared_experts: int = 2
+    n_shared_experts: int = 2          # 0: a layer of routed experts alone
     top_k: int = 6
     routed_scale: float = 2.448
+    router_eps: float = 1e-20          # added to the chosen scores' sum
     first_expert: int = 0              # the routed experts held here:
     n_experts_held: Optional[int] = None   # first .. first + held (None: all)
     rope_base: float = 1e6
@@ -84,6 +123,7 @@ class DecoderConfig:
     bias_update_speed: float = 1e-3
     init_std: float = 0.02             # every matrix but the embedding
     embedding_init_std: float = 1.0    # see `_init`
+    tie_embeddings: bool = False       # the head is the embedding's transpose
     compute_dtype: str = "float32"     # "bfloat16" for TPU throughput
 
     @property
@@ -91,16 +131,60 @@ class DecoderConfig:
         return self.n_experts if self.n_experts_held is None \
             else self.n_experts_held
 
+    @property
+    def kinds(self) -> Tuple[str, ...]:
+        """The token mixer of each of the `n_layers` layers."""
+        if self.layer_types is None:
+            return ("latent_attention",) * self.n_layers
+        return tuple(self.layer_types)
+
+    def layout(self):
+        """`(dense kind, period, periods, rest)`: the expert layers' kinds
+        are `period` repeated `periods` times and then `rest`, a proper
+        start of one more period (empty where the list ends on a whole
+        one); `period` is the shortest that does it."""
+        kinds = self.kinds
+        experts = kinds[self.n_dense_layers:]
+        p = next(p for p in range(1, len(experts) + 1)
+                 if all(k == experts[i % p] for i, k in enumerate(experts)))
+        n = len(experts) // p
+        return kinds[0], experts[:p], n, experts[n * p:]
+
     @staticmethod
     def tiny(**kw) -> "DecoderConfig":
-        """Test-sized config: one dense and two expert layers, 8 experts
-        top-2, keys wider than values."""
+        """Test-sized kanana-2 / DeepSeek-V3: latent attention in every
+        layer, one dense and two expert layers, 8 experts top-2 and two
+        shared, keys wider than values, untied head."""
         d = dict(vocab_size=96, hidden=32, n_layers=3, n_dense_layers=1,
                  n_heads=2, qk_nope_dim=8, qk_rope_dim=4, v_head_dim=8,
                  kv_lora_rank=16, intermediate=64, expert_intermediate=16,
                  n_experts=8, n_shared_experts=2, top_k=2)
         d.update(kw)
         return DecoderConfig(**d)
+
+    @staticmethod
+    def tiny_hybrid(**kw) -> "DecoderConfig":
+        """Test-sized LFM2-MoE: a dense `conv` layer, then one period
+        `full_attention, conv, conv, conv` of expert layers; 4 query heads
+        over 2 key-value heads of 8, 8 experts top-2 with no shared expert,
+        scale 1, epsilon 1e-6, tied head."""
+        d = dict(vocab_size=96, hidden=32, n_layers=5, n_dense_layers=1,
+                 layer_types=("conv", "full_attention", "conv", "conv",
+                              "conv"),
+                 n_heads=4, n_kv_heads=2, head_dim=8, conv_kernel=3,
+                 intermediate=64, expert_intermediate=16, n_experts=8,
+                 n_shared_experts=0, top_k=2, routed_scale=1.0,
+                 router_eps=1e-6, eps=1e-5, tie_embeddings=True)
+        d.update(kw)
+        return DecoderConfig(**d)
+
+
+def _key_stream(key):
+    """Keys for `_init`, 32 to a split: a model that draws no more than 32
+    (one kind of block) draws what `split(key, 32)` always gave it."""
+    while True:
+        yield from jax.random.split(key, 32)
+        key = jax.random.fold_in(key, 32)
 
 
 class DecoderModel:
@@ -115,8 +199,20 @@ class DecoderModel:
             raise ValueError(
                 f"experts {c.first_expert}..{c.first_expert + c.held} are "
                 f"not among the router's {c.n_experts}")
+        kinds = c.kinds
+        if len(kinds) != c.n_layers or set(kinds) - set(LAYER_KINDS):
+            raise ValueError(
+                f"layer_types names {len(kinds)} layers {sorted(set(kinds))}"
+                f"; need {c.n_layers} of {LAYER_KINDS}")
         if not 0 < c.n_dense_layers < c.n_layers:
             raise ValueError("need at least one dense and one expert layer")
+        if len(set(kinds[:c.n_dense_layers])) != 1:
+            raise ValueError(
+                f"the {c.n_dense_layers} leading dense layers are stacked: "
+                f"they share one kind, not {kinds[:c.n_dense_layers]}")
+        if "full_attention" in kinds and c.n_heads % c.n_kv_heads:
+            raise ValueError(f"{c.n_heads} query heads are no multiple of "
+                             f"{c.n_kv_heads} key-value heads")
         self.updater = updater or AdamW(2.2e-4, weight_decay=0.1)
         self.iteration = 0
         self.epoch = 0
@@ -142,41 +238,73 @@ class DecoderModel:
         experts in every layer, and an expert's load is all or nothing
         (measured, PERF.md PR 27): the first steps of a run, before the
         selection bias has done its work, not the routing by token that
-        trained experts show (OpenMoE, arXiv:2402.01739, section 4)."""
+        trained experts show (OpenMoE, arXiv:2402.01739, section 4).  A
+        tied head is the same matrix: its logits have the root mean square
+        `embedding_init_std * sqrt(hidden)`, so a tied model states a scale
+        that serves both (benchmark/configs/lfm2_24b_a2b.json, `assumed`)."""
         c = self.config
         H, nh = c.hidden, c.n_heads
-        keys = iter(jax.random.split(key, 32))
+        keys = _key_stream(key)
 
         def nrm(*shape, std=c.init_std):
             return (jax.random.normal(next(keys), shape) * std
                     ).astype(jnp.float32)
 
-        def block(L):
-            return {
-                "norm1": jnp.ones((L, H)), "norm2": jnp.ones((L, H)),
-                "Wq": nrm(L, H, nh * (c.qk_nope_dim + c.qk_rope_dim)),
-                "Wkva": nrm(L, H, c.kv_lora_rank + c.qk_rope_dim),
-                "kv_norm": jnp.ones((L, c.kv_lora_rank)),
-                "Wkvb": nrm(L, c.kv_lora_rank,
-                            nh * (c.qk_nope_dim + c.v_head_dim)),
-                "Wo": nrm(L, nh * c.v_head_dim, H)}
+        def operator(kind, L):
+            norms = {"norm1": jnp.ones((L, H)), "norm2": jnp.ones((L, H))}
+            if kind == "latent_attention":
+                return {
+                    **norms,
+                    "Wq": nrm(L, H, nh * (c.qk_nope_dim + c.qk_rope_dim)),
+                    "Wkva": nrm(L, H, c.kv_lora_rank + c.qk_rope_dim),
+                    "kv_norm": jnp.ones((L, c.kv_lora_rank)),
+                    "Wkvb": nrm(L, c.kv_lora_rank,
+                                nh * (c.qk_nope_dim + c.v_head_dim)),
+                    "Wo": nrm(L, nh * c.v_head_dim, H)}
+            if kind == "full_attention":
+                # queries, keys and values side by side: one product
+                return {
+                    **norms,
+                    "Wqkv": nrm(L, H, (nh + 2 * c.n_kv_heads) * c.head_dim),
+                    "q_norm": jnp.ones((L, c.head_dim)),
+                    "k_norm": jnp.ones((L, c.head_dim)),
+                    "Wo": nrm(L, nh * c.head_dim, H)}
+            return {**norms, "conv_in": nrm(L, H, 3 * H),
+                    "conv_kernel": nrm(L, c.conv_kernel, H),
+                    "conv_out": nrm(L, H, H)}
 
-        Ld, Lm = c.n_dense_layers, c.n_layers - c.n_dense_layers
         I, Ie, S = (c.intermediate, c.expert_intermediate,
                     c.n_shared_experts * c.expert_intermediate)
-        return {
+
+        def dense(kind, L):
+            return {**operator(kind, L), "mlp_gate": nrm(L, H, I),
+                    "mlp_up": nrm(L, H, I), "mlp_down": nrm(L, I, H)}
+
+        def experts(kind, L):
+            p = {**operator(kind, L), "router": nrm(L, H, c.n_experts),
+                 "w_gate": nrm(L, c.held, H, Ie),
+                 "w_up": nrm(L, c.held, H, Ie),
+                 "w_down": nrm(L, c.held, Ie, H)}
+            if S:
+                p.update(shared_gate=nrm(L, H, S), shared_up=nrm(L, H, S),
+                         shared_down=nrm(L, S, H))
+            return p
+
+        dense_kind, period, n, rest = c.layout()
+        params = {
             "tok_emb": nrm(c.vocab_size, H, std=c.embedding_init_std),
-            "dense": {**block(Ld), "mlp_gate": nrm(Ld, H, I),
-                      "mlp_up": nrm(Ld, H, I), "mlp_down": nrm(Ld, I, H)},
-            "moe": {**block(Lm), "router": nrm(Lm, H, c.n_experts),
-                    "w_gate": nrm(Lm, c.held, H, Ie),
-                    "w_up": nrm(Lm, c.held, H, Ie),
-                    "w_down": nrm(Lm, c.held, Ie, H),
-                    "shared_gate": nrm(Lm, H, S), "shared_up": nrm(Lm, H, S),
-                    "shared_down": nrm(Lm, S, H)},
-            "final_norm": jnp.ones((H,)),
-            "head": nrm(H, c.vocab_size),
-        }
+            "dense": dense(dense_kind, c.n_dense_layers),
+            # a period of one layer: its stacked parameters themselves
+            "moe": experts(period[0], n) if len(period) == 1
+            else tuple(experts(kind, n) for kind in period),
+            "final_norm": jnp.ones((H,))}
+        if rest:
+            params["rest"] = tuple(
+                jax.tree_util.tree_map(lambda a: a[0], experts(kind, 1))
+                for kind in rest)
+        if not c.tie_embeddings:
+            params["head"] = nrm(H, c.vocab_size)
+        return params
 
     # ---- forward ----
     def _qkv(self, x, lp):
@@ -212,6 +340,48 @@ class DecoderModel:
             o = o.transpose(0, 2, 1, 3).reshape(B, T, -1)
             return x + (o @ lp["Wo"]).astype(x.dtype)
 
+    def _gqa_attention(self, x, lp):
+        """`x + GQA(RMSNorm(x))` for `x` [B, T, H], causal: `n_heads` query
+        heads over `n_kv_heads` key-value heads, every query and key head
+        RMS-normed over its `head_dim` (one gain each, shared by the heads),
+        then half-split rotary on all of it."""
+        c = self.config
+        B, T, _ = x.shape
+        nh, nkv, hd = c.n_heads, c.n_kv_heads, c.head_dim
+        with jax.named_scope("gqa_attention"):
+            dt = lp["Wo"].dtype
+            qkv = (rms_norm(x, lp["norm1"], c.eps).astype(dt) @ lp["Wqkv"]
+                   ).reshape(B, T, nh + 2 * nkv, hd)
+            pos = jnp.arange(T)
+            q = rotary_half_split(
+                rms_norm(qkv[:, :, :nh], lp["q_norm"], c.eps), pos,
+                c.rope_base)
+            k = rotary_half_split(
+                rms_norm(qkv[:, :, nh:nh + nkv], lp["k_norm"], c.eps), pos,
+                c.rope_base)
+            heads_first = (0, 2, 1, 3)
+            o = fused_attention(q.transpose(heads_first),
+                                k.transpose(heads_first),
+                                qkv[:, :, nh + nkv:].transpose(heads_first),
+                                causal=True)
+            o = o.transpose(0, 2, 1, 3).reshape(B, T, -1)
+            return x + (o @ lp["Wo"]).astype(x.dtype)
+
+    def _short_conv(self, x, lp):
+        """`x + (C * conv(B * X)) W_out` on `RMSNorm(x)`, `x` [B, T, H]."""
+        with jax.named_scope("short_conv"):
+            dt = lp["conv_out"].dtype
+            y = gated_short_conv(
+                rms_norm(x, lp["norm1"], self.config.eps).astype(dt),
+                lp["conv_in"], lp["conv_kernel"], lp["conv_out"])
+            return x + y.astype(x.dtype)
+
+    def _operator(self, kind: str):
+        """`(x, lp) -> x + Op(RMSNorm(x))` of a layer kind."""
+        return {"latent_attention": self._attention,
+                "full_attention": self._gqa_attention,
+                "conv": self._short_conv}[kind]
+
     def _trunk(self, params, router_bias, ids):
         """Hidden states [B, T, H] after the last block (float32: the blocks
         compute in `compute_dtype`, the residual stream they add to does
@@ -233,7 +403,7 @@ class DecoderModel:
             y, counts = expert_layer(
                 rms_norm(x, lp["norm2"], c.eps).astype(dt).reshape(B * T, H),
                 lp, bias, top_k=c.top_k, scale=c.routed_scale,
-                first_held=c.first_expert)
+                first_held=c.first_expert, eps=c.router_eps)
             return x + y.reshape(B, T, H).astype(x.dtype), counts
 
         # each block keeps its input and the flash kernel's two results for
@@ -241,29 +411,59 @@ class DecoderModel:
         # docstring)
         keep = jax.checkpoint_policies.save_only_these_names(FLASH_OUT,
                                                              FLASH_LSE)
+        dense_kind, period, n, rest = c.layout()
 
         @functools.partial(jax.checkpoint, policy=keep)
         def dense_block(x, lp):
-            return dense_ffn(self._attention(x, lp), lp)
+            return dense_ffn(self._operator(dense_kind)(x, lp), lp)
 
-        @functools.partial(jax.checkpoint, policy=keep,
-                           prevent_cse=False)                  # under scan
-        def moe_block(x, layer):
-            lp, bias = layer
-            lp = {**cast(lp), "router": lp["router"]}   # it stays float32
-            return moe_ffn(self._attention(x, lp), lp, bias)
+        def expert_block(kind, under_scan):
+            def moe_block(x, layer):
+                lp, bias = layer
+                lp = {**cast(lp), "router": lp["router"]}   # stays float32
+                return moe_ffn(self._operator(kind)(x, lp), lp, bias)
+            return jax.checkpoint(moe_block, policy=keep,
+                                  prevent_cse=not under_scan)
 
         x = params["tok_emb"][ids]          # the residual stream is float32
         for i in range(c.n_dense_layers):
             x = dense_block(x, cast(jax.tree_util.tree_map(
                 lambda a: a[i], params["dense"])))
-        return jax.lax.scan(moe_block, x, (params["moe"], router_bias))
+        scanned = {kind: expert_block(kind, True) for kind in period}
+        if len(period) == 1:                # all alike: no remainder either
+            return jax.lax.scan(scanned[period[0]], x,
+                                (params["moe"], router_bias))
+
+        def whole_period(x, layers):
+            lps, bias = layers
+            counts = []
+            for j, kind in enumerate(period):
+                x, c_j = scanned[kind](x, (lps[j], bias[j]))
+                counts.append(c_j)
+            return x, jnp.stack(counts)
+
+        in_periods = n * len(period)
+        x, counts = jax.lax.scan(
+            whole_period, x,
+            (params["moe"],
+             router_bias[:in_periods].reshape(n, len(period), -1)))
+        counts = [counts.reshape(in_periods, -1)]
+        unrolled = {kind: expert_block(kind, False) for kind in rest}
+        for j, kind in enumerate(rest):
+            x, c_j = unrolled[kind](
+                x, (params["rest"][j], router_bias[in_periods + j]))
+            counts.append(c_j[None])
+        return x, jnp.concatenate(counts)
 
     def _logits(self, params, hidden):
         """float32 logits [..., vocab] over the vocabulary held."""
         dt = jnp.dtype(self.config.compute_dtype)
         h = rms_norm(hidden, params["final_norm"], self.config.eps)
         with jax.named_scope("lm_head"):
+            if self.config.tie_embeddings:  # the embedding's rows, untransposed
+                return jnp.einsum("...h,vh->...v", h.astype(dt),
+                                  params["tok_emb"].astype(dt),
+                                  preferred_element_type=jnp.float32)
             return jnp.dot(h.astype(dt), params["head"].astype(dt),
                            preferred_element_type=jnp.float32)
 

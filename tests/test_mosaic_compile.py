@@ -99,3 +99,28 @@ def test_backward_in_spans_compiles_for_v5e(one_chip, monkeypatch):
         one_chip, ((B, H, T, D), dt), ((B, H, T, D), dt), ((B, H, T, Dv), dt),
         ((B, H, T, Dv), dt), ((B * H, T), jnp.float32), ((B, H, T, Dv), dt))
     assert compiled.as_text().count("tpu_custom_call") == 4
+
+
+def test_grouped_query_kernels_compile_for_v5e(one_chip):
+    """LFM2-24B-A2B's attention layer: 32 query heads over 8 key-value heads
+    of 64, one sequence of 8,192 tokens, the tier's 512 x 1024 tile.  Keys
+    and values enter both kernels as 8 heads; the backward is one kernel
+    over key-value heads that holds dQ of a whole group (32,768 rows of 64,
+    all of its VMEM budget) and sums dK and dV over the group itself."""
+    B, H, Hk, T, D, dt = 1, 32, 8, 8192, 64, jnp.bfloat16
+    q, kv = ((B, H, T, D), dt), ((B, Hk, T, D), dt)
+    fwd = _compile(
+        lambda q, k, v: ak.flash_attention_tpu(
+            q, k, v, causal=True, block_q=512, block_k=1024, return_lse=True),
+        one_chip, q, kv, kv)
+    assert fwd.as_text().count("tpu_custom_call") == 1
+    bwd = _compile(
+        lambda q, k, v, out, lse, g: ak.flash_attention_bwd_tpu(
+            q, k, v, out, lse, g, causal=True, block_q=512, block_k=1024),
+        one_chip, q, kv, kv, q, ((B * H, T), jnp.float32), q)
+    text = bwd.as_text()
+    assert text.count("tpu_custom_call") == 1
+    for compiled in (fwd, bwd):         # keys and values as 8 heads
+        assert f"bf16[{B * Hk},{T},{D}]" in compiled.as_text()
+    # dQ over the query's heads, dK and dV over the key-value heads
+    assert [o.shape for o in bwd.out_info] == [q[0], kv[0], kv[0]]
