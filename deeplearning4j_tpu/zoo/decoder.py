@@ -30,7 +30,10 @@ loss), top-k.
 `first_expert`/`n_experts_held` say which routed experts this process
 holds of each layer (all of them by default).  Held alone, the layer
 computes its experts' part of the result and the partial sum goes on — the
-share one chip runs under expert parallelism, less the exchange.  The
+share one chip runs under expert parallelism, less the exchange; the routed
+part's buffers are then twice the chip's even share of the (token, expert)
+pairs long, not all pairs (`ops/moe.row_bound`), and `routed_rows()` counts
+the steps whose routing needed a further pass over them.  The
 vocabulary may be a slice likewise: `vocab_size` rows of embedding and head,
 ids, logits and loss over the slice.
 
@@ -80,7 +83,7 @@ import numpy as np
 from deeplearning4j_tpu.monitor.spans import note, span
 from deeplearning4j_tpu.ops.attention_kernels import (FLASH_LSE, FLASH_OUT,
                                                       fused_attention)
-from deeplearning4j_tpu.ops.moe import (expert_layer, swiglu,
+from deeplearning4j_tpu.ops.moe import (expert_layer, row_bound, swiglu,
                                         update_router_bias)
 from deeplearning4j_tpu.ops.norm_kernels import rms_norm
 from deeplearning4j_tpu.ops.rotary import (rotary_half_split,
@@ -221,11 +224,14 @@ class DecoderModel:
         self.opt_state_ = jax.jit(self.updater.init_state)(self.params_)
         n_moe = c.n_layers - c.n_dense_layers
         # what the step carries beside the parameters: the routers'
-        # selection bias (a buffer, no gradient) and, per expert layer, how
-        # many tokens chose each expert since the model was built
+        # selection bias (a buffer, no gradient) and, per expert layer since
+        # the model was built, how many tokens chose each expert and in how
+        # many steps the held pairs were more than the routed rows' bound
         self.state_ = {
             "router_bias": jnp.zeros((n_moe, c.n_experts), jnp.float32),
-            "expert_load": jnp.zeros((n_moe, c.n_experts), jnp.int32)}
+            "expert_load": jnp.zeros((n_moe, c.n_experts), jnp.int32),
+            "rows_over_bound": jnp.zeros((n_moe,), jnp.int32)}
+        self._pairs = 0      # (token, chosen expert) pairs of the newest step
         self._steps: Dict[str, Any] = {}
 
     # ---- init ----
@@ -385,7 +391,10 @@ class DecoderModel:
     def _trunk(self, params, router_bias, ids):
         """Hidden states [B, T, H] after the last block (float32: the blocks
         compute in `compute_dtype`, the residual stream they add to does
-        not), and the expert layers' token counts [L_moe, E]."""
+        not), and what the expert layers counted in this step:
+        `expert_load` [L_moe, E] tokens that chose each expert,
+        `rows_over_bound` [L_moe] whether the layer's held pairs were more
+        than its row bound (`ops/moe.routed_experts`)."""
         c = self.config
         dt = jnp.dtype(c.compute_dtype)
 
@@ -400,11 +409,12 @@ class DecoderModel:
 
         def moe_ffn(x, lp, bias):
             B, T, H = x.shape
-            y, counts = expert_layer(
+            y, counts, over = expert_layer(
                 rms_norm(x, lp["norm2"], c.eps).astype(dt).reshape(B * T, H),
                 lp, bias, top_k=c.top_k, scale=c.routed_scale,
                 first_held=c.first_expert, eps=c.router_eps)
-            return x + y.reshape(B, T, H).astype(x.dtype), counts
+            return (x + y.reshape(B, T, H).astype(x.dtype),
+                    {"expert_load": counts, "rows_over_bound": over})
 
         # each block keeps its input and the flash kernel's two results for
         # the backward pass; the rest is computed again there (module
@@ -434,26 +444,30 @@ class DecoderModel:
             return jax.lax.scan(scanned[period[0]], x,
                                 (params["moe"], router_bias))
 
+        def over_layers(join, seen):
+            return jax.tree_util.tree_map(lambda *a: join(a), *seen)
+
         def whole_period(x, layers):
             lps, bias = layers
-            counts = []
+            seen = []
             for j, kind in enumerate(period):
-                x, c_j = scanned[kind](x, (lps[j], bias[j]))
-                counts.append(c_j)
-            return x, jnp.stack(counts)
+                x, s_j = scanned[kind](x, (lps[j], bias[j]))
+                seen.append(s_j)
+            return x, over_layers(jnp.stack, seen)
 
         in_periods = n * len(period)
-        x, counts = jax.lax.scan(
+        x, seen = jax.lax.scan(
             whole_period, x,
             (params["moe"],
              router_bias[:in_periods].reshape(n, len(period), -1)))
-        counts = [counts.reshape(in_periods, -1)]
+        seen = [jax.tree_util.tree_map(
+            lambda a: a.reshape(in_periods, *a.shape[2:]), seen)]
         unrolled = {kind: expert_block(kind, False) for kind in rest}
         for j, kind in enumerate(rest):
-            x, c_j = unrolled[kind](
+            x, s_j = unrolled[kind](
                 x, (params["rest"][j], router_bias[in_periods + j]))
-            counts.append(c_j[None])
-        return x, jnp.concatenate(counts)
+            seen.append(jax.tree_util.tree_map(lambda a: a[None], s_j))
+        return x, over_layers(jnp.concatenate, seen)
 
     def _logits(self, params, hidden):
         """float32 logits [..., vocab] over the vocabulary held."""
@@ -471,18 +485,18 @@ class DecoderModel:
         """Mean next-token cross-entropy over every position but the last
         of each sequence (`labels[:, t]` is the id at `t + 1`; the last
         column is ignored), logits and `log_softmax` in float32."""
-        hidden, counts = self._trunk(params, router_bias, ids)
+        hidden, seen = self._trunk(params, router_bias, ids)
         logp = jax.nn.log_softmax(self._logits(params, hidden), axis=-1)
         nll = -jnp.take_along_axis(
             logp, labels[..., None].astype(jnp.int32), axis=-1)[..., 0]
-        return jnp.mean(nll[:, :-1]), counts
+        return jnp.mean(nll[:, :-1]), seen
 
     # ---- compiled steps ----
     def _step_body(self):
         speed = self.config.bias_update_speed
 
         def step(params, opt_state, state, iteration, epoch, ids, labels):
-            (loss, counts), grads = jax.value_and_grad(
+            (loss, seen), grads = jax.value_and_grad(
                 self._loss, has_aux=True)(params, state["router_bias"],
                                           ids, labels)
             upd, new_opt = self.updater.apply(opt_state, grads, iteration,
@@ -490,9 +504,9 @@ class DecoderModel:
             new_params = jax.tree_util.tree_map(lambda p, u: p - u,
                                                 params, upd)
             new_state = {
-                "router_bias": update_router_bias(state["router_bias"],
-                                                  counts, speed),
-                "expert_load": state["expert_load"] + counts}
+                "router_bias": update_router_bias(
+                    state["router_bias"], seen["expert_load"], speed),
+                **{name: state[name] + seen[name] for name in seen}}
             return new_params, new_opt, new_state, loss, iteration + 1
 
         return step
@@ -527,10 +541,10 @@ class DecoderModel:
             self.epoch += 1
         return self
 
-    @staticmethod
-    def _batch(mds):
+    def _batch(self, mds):
         (ids,) = [jnp.asarray(f) for f in mds.features]
         (labels,) = [jnp.asarray(l) for l in mds.labels]
+        self._pairs = ids.shape[-2] * ids.shape[-1] * self.config.top_k
         return ids.astype(jnp.int32), labels.astype(jnp.int32)
 
     def fit_batch(self, mds):
@@ -581,6 +595,19 @@ class DecoderModel:
         """[expert layers, n_experts] tokens that chose each expert over all
         train steps so far: one device read of the step's own counter."""
         return np.asarray(self.state_["expert_load"])
+
+    def routed_rows(self) -> Dict[str, Any]:
+        """What the routed experts' row bound did over all train steps so
+        far (one device read): `steps`; `steps_over_bound` [expert layers],
+        the steps in which the layer's held pairs were more than `bound`
+        rows and it took more than one pass over them; `bound` and `pairs`
+        at the newest batch shape (0 before the first step)."""
+        c = self.config
+        return {"steps": self.iteration,
+                "steps_over_bound": np.asarray(
+                    self.state_["rows_over_bound"]),
+                "bound": row_bound(self._pairs, c.held, c.n_experts),
+                "pairs": self._pairs}
 
     def num_params(self) -> int:
         return sum(int(np.prod(l.shape))

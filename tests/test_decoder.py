@@ -3,6 +3,7 @@ router, the expert layer's shares and its dropless dispatch, the grouped
 product, attention with values narrower than keys, the routing counter, and
 `zoo.DecoderModel`'s surface."""
 import io
+import re
 
 import jax
 import jax.numpy as jnp
@@ -16,6 +17,7 @@ from deeplearning4j_tpu.ops import moe
 from deeplearning4j_tpu.ops import pallas as tier
 from deeplearning4j_tpu.ops.norm_kernels import rms_norm
 from deeplearning4j_tpu.ops.rotary import rotary_interleaved
+from deeplearning4j_tpu.utils.counters import device_counters
 from deeplearning4j_tpu.zoo import DecoderConfig, DecoderModel
 from tests.test_attention_kernels import _equations
 
@@ -184,8 +186,8 @@ def test_the_shares_add_up_to_the_uncut_layer():
     shared = moe.swiglu(x, p["shared_gate"], p["shared_up"], p["shared_down"])
     total, counts = shared, None
     for first in range(0, E, 2):
-        y, counts = moe.expert_layer(x, _share(p, first, 2), bias, top_k=K,
-                                     scale=2.448, first_held=first)
+        y, counts, _ = moe.expert_layer(x, _share(p, first, 2), bias, top_k=K,
+                                        scale=2.448, first_held=first)
         total = total + (y - shared)              # this share's routed part
         # one share against the reference given the same share
         np.testing.assert_allclose(
@@ -212,7 +214,7 @@ def test_dropless_under_imbalance(case):
         return moe.expert_layer(x, p, bias, top_k=K, scale=2.448,
                                 first_held=2)
 
-    y, counts = system(x, p)
+    y, counts, _ = system(x, p)
     held_counts = np.asarray(counts)[2:4]
     assert list(held_counts) == ([0, T] if case == "all_choose_one_held"
                                  else [0, 0])
@@ -226,6 +228,96 @@ def test_dropless_under_imbalance(case):
     for a, b in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(a, b, atol=2e-5)
+
+
+# the routed part on a row bound: 24 tokens top-3 of 8 (72 pairs), experts
+# 2, 3, 4 held, buffers of 16 rows
+K_BOUND, ROWS = 3, 16
+# case -> (tokens with all three pairs held, with two, with one, overflows)
+BOUND_CASES = {"below": (2, 1, 2, 0), "exactly_at": (3, 2, 3, 0),
+               "one_above": (3, 3, 2, 1), "every_pair_held": (24, 0, 0, 1),
+               "none_held": (0, 0, 0, 0),
+               "whole_tokens_beside_tokens_with_none": (5, 0, 0, 0)}
+
+
+def _chosen_with(whole, two, one):
+    """[T, 3] distinct experts a token: `whole` tokens choose the held 2, 3,
+    4, `two` tokens two of them, `one` tokens one, the rest none; the kinds
+    lie scattered over the batch."""
+    rows = ([[2, 3, 4]] * whole + [[7, 4, 2]] * two + [[0, 3, 6]] * one
+            + [[5, 0, 1]] * (T - whole - two - one))
+    return jnp.asarray(np.asarray(rows, np.int32)[
+        np.random.default_rng(0).permutation(T)])
+
+
+def _bounded(x, p, chosen, g):
+    """Result, overflow flag and gradients of the routed part at `ROWS`
+    rows, the pairs' weights from the router's scores as the layer makes
+    them."""
+    def routed(x, p):
+        s = jnp.take_along_axis(jax.nn.sigmoid(x @ p["router"]), chosen, -1)
+        w = s / (jnp.sum(s, -1, keepdims=True) + 1e-20) * 2.448
+        y, over = moe.routed_experts(x, chosen, w, p["w_gate"], p["w_up"],
+                                     p["w_down"], 2, ROWS)
+        return jnp.sum(y * g), (y, over)
+
+    (_, (y, over)), grads = jax.value_and_grad(routed, (0, 1),
+                                               has_aux=True)(x, p)
+    return y, over, grads
+
+
+_bounded_jit = jax.jit(_bounded)
+
+
+@pytest.mark.parametrize("case", list(BOUND_CASES))
+def test_routed_rows_on_a_bound_against_the_uncut_layer(case):
+    """Held pairs below the bound, at it, over it (two passes of 16 rows
+    then; five where every pair is held, the last over rows past the 72
+    pairs): values and the gradients in x, the router (through the weights)
+    and the three expert matrices are the reference's, in float32; the
+    cases share one compilation."""
+    whole, two, one, overflows = BOUND_CASES[case]
+    assert (3 * whole + 2 * two + one > ROWS) == bool(overflows)
+    f32 = lambda tree: jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.float32), tree)
+    p = f32({n: v for n, v in _share(_layer_params(3), 2, 3).items()
+             if not n.startswith("shared")})
+    x = f32(jax.random.normal(jax.random.PRNGKey(4), (T, H)))
+    g = f32(jax.random.normal(jax.random.PRNGKey(5), (T, H)))
+    chosen = _chosen_with(whole, two, one)
+    y, over, got = _bounded_jit(x, p, chosen, g)
+    assert _bounded_jit._cache_size() == 1
+    assert int(over) == overflows and y.dtype == jnp.float32
+    zero = {n: jnp.zeros_like(v) for n, v in _layer_params().items()
+            if n.startswith("shared")}
+
+    def reference(x, p):
+        return _uncut_layer(x, {**p, **zero}, None, K_BOUND, 2.448,
+                            held=(2, 3), chosen=np.asarray(chosen))
+
+    np.testing.assert_allclose(y, reference(x, p), atol=1e-5)
+    want = jax.grad(lambda x, p: jnp.sum(reference(x, p) * g), (0, 1))(x, p)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(a, b, atol=2e-5,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
+def test_a_layer_that_holds_all_its_experts_has_one_path():
+    """The row bound is T*k where all experts are held (and in any small
+    batch): one pass and no loop in the program.  An eighth held of 2,048
+    pairs: passes of 512 rows under one `while`."""
+    x = jnp.zeros((1024, H), jnp.float32)
+
+    def program(held):
+        p = _share(_layer_params(experts=16), 0, held)
+        return str(jax.make_jaxpr(lambda x, p: moe.expert_layer(
+            x, p, jnp.zeros(16), top_k=2, scale=1.0, first_held=0))(x, p))
+
+    assert moe.row_bound(2048, 16, 16) == 2048
+    assert "while[" not in program(16) and "cond[" not in program(16)
+    assert moe.row_bound(2048, 2, 16) == 512
+    assert program(2).count("while[") == 1 and "cond[" not in program(2)
 
 
 @pytest.mark.parametrize("sizes", [[100, 0, 60], [0, 0, 0], [256, 0, 0]])
@@ -437,6 +529,101 @@ def test_save_load_round_trip():
                                   np.asarray(m2.output(ids)))
     np.testing.assert_array_equal(m.expert_load(), m2.expert_load())
     assert float(m.fit_batch(_batch(1))) == float(m2.fit_batch(_batch(1)))
+
+
+def routed_part_op_names(jaxpr, stack="", inside=False):
+    """The scope path of every `while` equation of a step's jaxpr (the
+    layers' `scan` is none), and of every equation inside their bodies
+    (inner jaxprs entered, each equation's name stack put after its
+    enclosing equations', as the lowering composes an instruction's
+    `op_name`)."""
+    loops, inner = [], []
+    for eqn in jaxpr.eqns:
+        path = f"{stack}/{eqn.source_info.name_stack}/{eqn.primitive.name}"
+        is_loop = eqn.primitive.name == "while"
+        if is_loop:
+            loops.append(path)
+        if inside:
+            inner.append(path)
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    l, i = routed_part_op_names(sub, path, inside or is_loop)
+                    loops += l
+                    inner += i
+    return loops, inner
+
+
+def bounded_model_against_the_full_pass(config, blocks, monkeypatch):
+    """A model whose row bound (512) is below its T*k (2 x 512 tokens top-2:
+    2,048 pairs, 2 of 16 experts held) against the same model with the bound
+    forced to T*k: loss and every gradient leaf, four steps of `fit` of
+    which the last two overflow the bound in the first expert layer alone
+    (four passes there), the counter, and the step's `while`s, two a
+    distinct expert block (forward and backward), with `moe` in the scope
+    path of every op in their bodies."""
+    c = config
+    assert (c.hidden, c.n_experts, c.held, c.top_k) == (16, 16, 2, 2)
+    batches = [_batch(s, rows=2, t=512) for s in (1, 2, 3)]
+    ids, labels = (jnp.asarray(batches[0].features[0]),
+                   jnp.asarray(batches[0].labels[0]))
+    held = slice(c.first_expert, c.first_expert + c.held)
+
+    def run(m):
+        bias = m.state_["router_bias"]
+        (loss, _), grads = jax.jit(jax.value_and_grad(
+            m._loss, has_aux=True))(m.params_, bias, ids, labels)
+        losses = [float(m.fit_batch(b)) for b in batches[:2]]
+        # every token of the first expert layer now picks both held experts
+        m.state_["router_bias"] = m.state_["router_bias"].at[0, held].set(10.)
+        losses += [float(m.fit_batch(batches[2])),
+                   float(m.fit_batch(batches[0]))]
+        return loss, grads, losses, m.expert_load(), m.routed_rows()
+
+    bounded = DecoderModel(c, seed=3)
+    step_args = (bounded.params_, bounded.opt_state_, bounded.state_,
+                 *device_counters(bounded), ids, labels)
+    lowered = bounded._step().lower(*step_args)
+    step_jaxpr = jax.make_jaxpr(bounded._step_body())(*step_args)
+    loss, grads, losses, load, rows = run(bounded)
+    with monkeypatch.context() as mp:
+        mp.setattr(moe, "row_bound", lambda pairs, held, n: pairs)
+        want = run(DecoderModel(c, seed=3))
+    np.testing.assert_allclose(loss, want[0], rtol=1e-6)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(grads),
+                            jax.tree_util.tree_leaves(want[1])):
+        scale = float(jnp.max(jnp.abs(b))) + 1e-12
+        assert float(jnp.max(jnp.abs(a - b))) <= 1e-5 * scale, \
+            jax.tree_util.keystr(path)
+    np.testing.assert_allclose(losses, want[2], rtol=1e-5)
+    np.testing.assert_array_equal(load, want[3])
+    n_moe = c.n_layers - c.n_dense_layers
+    assert rows["steps"] == 4
+    assert (rows["bound"], rows["pairs"]) == (512, 2048)
+    np.testing.assert_array_equal(rows["steps_over_bound"],
+                                  [2] + [0] * (n_moe - 1))
+    assert not np.any(want[4]["steps_over_bound"])
+    in_moe = re.compile(r"(?<![\w.\-])moe(?![\w.\-])")
+    loops, names = routed_part_op_names(step_jaxpr.jaxpr)
+    assert len(loops) == 2 * blocks and len(names) > 100 * blocks
+    assert [n for n in loops + names if not in_moe.search(n)] == []
+    for part in ("dispatch", "experts", "combine"):
+        assert any(part in n and "transpose(" in n for n in names), part
+        assert any(part in n and "transpose(" not in n for n in names), part
+    # and in the compiled program's text: the recomputed forward rule's
+    # loop is gone, no `conditional` anywhere
+    hlo = lowered.compile().as_text()
+    made = [re.search(r'op_name="([^"]*)"', line).group(1)
+            for line in re.findall(r" while\(.*", hlo)]
+    assert len([n for n in made if in_moe.search(n)]) == 2 * blocks
+    assert " conditional(" not in hlo
+
+
+def test_a_model_on_a_row_bound_is_the_model_on_the_full_pass(monkeypatch):
+    bounded_model_against_the_full_pass(
+        DecoderConfig.tiny(hidden=16, n_experts=16, n_experts_held=2,
+                           first_expert=4), 1, monkeypatch)
 
 
 def test_a_share_outside_the_routers_width_is_refused():

@@ -22,6 +22,7 @@ from deeplearning4j_tpu.ops.short_conv import (causal_depthwise_conv,
                                                gated_short_conv)
 from deeplearning4j_tpu.zoo import DecoderConfig, DecoderModel
 from tests.test_attention_kernels import _equations
+from tests.test_decoder import bounded_model_against_the_full_pass
 
 
 @pytest.fixture(autouse=True)
@@ -301,8 +302,8 @@ def test_expert_layer_without_shared_experts_is_the_routed_part():
          "w_up": jnp.asarray(rng.normal(size=(8, 6, 4))),
          "w_down": jnp.asarray(rng.normal(size=(8, 4, 6)))}
     bias = jnp.zeros((8,))
-    y, counts = moe.expert_layer(x, p, bias, top_k=2, scale=1.0,
-                                 first_held=0, eps=1e-6)
+    y, counts, _ = moe.expert_layer(x, p, bias, top_k=2, scale=1.0,
+                                    first_held=0, eps=1e-6)
     chosen, weights = moe.router(x, p["router"], bias, 2, 1.0, 1e-6)
     want = np.zeros((12, 6))
     for t in range(12):
@@ -315,8 +316,8 @@ def test_expert_layer_without_shared_experts_is_the_routed_part():
     shared = {"shared_gate": jnp.asarray(rng.normal(size=(6, 4))),
               "shared_up": jnp.asarray(rng.normal(size=(6, 4))),
               "shared_down": jnp.asarray(rng.normal(size=(4, 6)))}
-    y2, _ = moe.expert_layer(x, {**p, **shared}, bias, top_k=2, scale=1.0,
-                             first_held=0, eps=1e-6)
+    y2, _, _ = moe.expert_layer(x, {**p, **shared}, bias, top_k=2,
+                                scale=1.0, first_held=0, eps=1e-6)
     np.testing.assert_allclose(
         y2 - y, moe.swiglu(x, shared["shared_gate"], shared["shared_up"],
                            shared["shared_down"]), rtol=1e-6, atol=1e-9)
@@ -347,8 +348,8 @@ def test_the_shares_add_up_without_a_shared_expert():
     for first in range(0, e, 2):
         share = {**p, **{n: p[n][first:first + 2]
                          for n in ("w_gate", "w_up", "w_down")}}
-        y, counts = moe.expert_layer(x, share, bias, top_k=k, scale=1.0,
-                                     first_held=first, eps=1e-6)
+        y, counts, _ = moe.expert_layer(x, share, bias, top_k=k, scale=1.0,
+                                        first_held=first, eps=1e-6)
         total = total + y
         assert int(counts.sum()) == t * k         # every share counts all E
     np.testing.assert_allclose(total, want, atol=1e-6)
@@ -419,6 +420,14 @@ def test_hybrid_model_trains_through_fit_and_its_trees_follow_the_list():
     assert np.all(np.abs(bias) <= 8 * 1e-3 + 1e-9) and np.all(
         np.any(bias != 0, axis=1))
     assert m.output(_batch().features[0]).shape == (2, 16, 96)
+
+
+def test_a_hybrid_on_a_row_bound_is_the_hybrid_on_the_full_pass(monkeypatch):
+    """`tests/test_decoder.py`'s case for the period `a c c c`: four
+    distinct expert blocks under one scan."""
+    bounded_model_against_the_full_pass(
+        DecoderConfig.tiny_hybrid(hidden=16, n_experts=16, n_experts_held=2,
+                                  first_expert=4), 4, monkeypatch)
 
 
 def test_a_tied_head_is_the_embeddings_transpose():
