@@ -33,6 +33,17 @@ Mosaic kernels never hold keys or values repeated in HBM: the forward
 addresses their blocks by `head // group`, the backward folds a group's
 query heads into one grid row so that dK and dV accumulate over them in the
 kernel's scratch; the two XLA paths repeat them, where XLA pleases.
+
+Masks: none, `causal`, a [B, S] keep-mask over key positions, or
+`block_diffusion=(L, B)`, the mask of block-diffusion training (BD3-LM,
+arXiv:2503.09573, section 3): the last L rows are a clean sequence in blocks
+of B tokens and the T - L rows before them — L of them, or none — its noisy
+copy (T == S).  A clean row sees the clean rows of its own and earlier
+blocks; a noisy row sees the noisy rows of its own block and the clean rows
+of earlier blocks; no clean row sees a noisy one.  With no noisy rows that
+is attention causal over blocks and bidirectional inside one.  Every branch
+builds it from row and column numbers; the Mosaic kernels skip the tiles it
+leaves empty as they skip causal's, and no [T, S] array reaches HBM.
 """
 from __future__ import annotations
 
@@ -46,6 +57,68 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+_NO_LIMIT = 1 << 30
+
+
+def _block_of(pos, B: int):
+    """`pos // B` for non-negative int32 positions (a shift where it can
+    be: Mosaic's vector unit has no integer division to spare)."""
+    if B & (B - 1) == 0:
+        return jnp.right_shift(pos, B.bit_length() - 1)
+    return jax.lax.div(pos, jnp.int32(B))
+
+
+def block_diffusion_keep(rows, cols, T: int, L: int, B: int):
+    """Whether row `rows` may attend column `cols` (int32 arrays that
+    broadcast) under `block_diffusion=(L, B)` over T rows: see the module
+    docstring.  With `d` = the row's block less the column's: noisy on noisy
+    `d == 0`, noisy on clean `d >= 1`, clean on clean `d >= 0`."""
+    o = T - L                               # the noisy rows come first
+    qn, kn = rows < o, cols < o
+    d = (_block_of(rows - jnp.where(qn, 0, o), B)
+         - _block_of(cols - jnp.where(kn, 0, o), B))
+    return jnp.where(kn, qn & (d == 0), jnp.where(qn, d >= 1, d >= 0))
+
+
+def _check_block_diffusion(T: int, S: int, block_diffusion):
+    L, B = block_diffusion
+    if T != S or T not in (L, 2 * L) or L % B:
+        raise ValueError(
+            f"block_diffusion=({L}, {B}) is over {L} or {2 * L} rows and "
+            f"columns in blocks of {B}, not [{T}, {S}]")
+
+
+def _bd_tile(q0, k0, bq: int, bk: int, o: int, B: int):
+    """A [bq, bk] tile of the block-diffusion mask whose first row is `q0`
+    and first column `k0` (scalars; no tile lies across `o`, the first clean
+    row): `(live, qp, kp, dmin, dmax)` — whether any pair in it is kept; the
+    first row's and column's position in its own half; and the kept pairs
+    are those with `dmin <= block(row) - block(column) <= dmax`."""
+    qn, kn = q0 < o, k0 < o
+    qp = q0 - jnp.where(qn, 0, o)
+    kp = k0 - jnp.where(kn, 0, o)
+    dmin = jnp.where(qn & ~kn, 1, 0)
+    dmax = jnp.where(kn, 0, _NO_LIMIT)
+    most = _block_of(qp + bq - 1, B) - _block_of(kp, B)
+    least = _block_of(qp, B) - _block_of(kp + bk - 1, B)
+    live = (most >= dmin) & (least <= dmax) & (qn | ~kn)
+    return live, qp, kp, dmin, dmax
+
+
+def _bd_keep_tile(qp, kp, dmin, dmax, bq: int, bk: int, B: int,
+                  keys_first: bool = False):
+    """The kept pairs of that tile, [bq, bk] ([bk, bq] with `keys_first`),
+    from iota: blocks along each side, one subtraction over the tile."""
+    if keys_first:
+        q_shape, k_shape = (1, bq), (bk, 1)
+    else:
+        q_shape, k_shape = (bq, 1), (1, bk)
+    q_blk = _block_of(qp + jax.lax.broadcasted_iota(
+        jnp.int32, q_shape, 1 if keys_first else 0), B)
+    k_blk = _block_of(kp + jax.lax.broadcasted_iota(
+        jnp.int32, k_shape, 0 if keys_first else 1), B)
+    d = q_blk - k_blk
+    return (d >= dmin) & (d <= dmax)
 
 
 def _over_query_heads(q, k, v):
@@ -58,9 +131,10 @@ def _over_query_heads(q, k, v):
     return jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
 
 
-def mha_reference(q, k, v, mask=None, causal=False, scale=None):
+def mha_reference(q, k, v, mask=None, causal=False, scale=None,
+                  block_diffusion=None):
     """Naive attention (ground truth).  mask: [B, T] of 1/0 over KV
-    positions."""
+    positions; `block_diffusion`: (L, B), the module docstring's mask."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     k, v = _over_query_heads(q, k, v)
@@ -72,11 +146,19 @@ def mha_reference(q, k, v, mask=None, causal=False, scale=None):
         scores = jnp.where(qi >= ki, scores, NEG_INF)
     if mask is not None:
         scores = jnp.where(mask[:, None, None, :] > 0, scores, NEG_INF)
+    if block_diffusion is not None:
+        T, S = q.shape[2], k.shape[2]
+        _check_block_diffusion(T, S, block_diffusion)
+        keep = block_diffusion_keep(
+            jnp.arange(T, dtype=jnp.int32)[:, None],
+            jnp.arange(S, dtype=jnp.int32)[None, :], T, *block_diffusion)
+        scores = jnp.where(keep, scores, NEG_INF)
     p = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 
-def _blockwise_fwd(q, k, v, mask, causal, scale, block_k):
+def _blockwise_fwd(q, k, v, mask, causal, scale, block_k,
+                   block_diffusion=None):
     """Online-softmax scan over KV blocks; returns (out, (m, l))."""
     k, v = _over_query_heads(q, k, v)
     B, H, T, D = q.shape
@@ -104,6 +186,12 @@ def _blockwise_fwd(q, k, v, mask, causal, scale, block_k):
             qi = jnp.arange(T)[:, None]
             ki = j * block_k + jnp.arange(block_k)[None, :]
             s = jnp.where(qi >= ki, s, NEG_INF)
+        if block_diffusion is not None:
+            qi = jnp.arange(T, dtype=jnp.int32)[:, None]
+            ki = jnp.asarray(j * block_k, jnp.int32) + jnp.arange(
+                block_k, dtype=jnp.int32)[None, :]
+            s = jnp.where(block_diffusion_keep(qi, ki, T, *block_diffusion),
+                          s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         p = jnp.exp(s - m_new[..., None])
         corr = jnp.exp(m - m_new)
@@ -120,25 +208,28 @@ def _blockwise_fwd(q, k, v, mask, causal, scale, block_k):
     return (acc / l[..., None]).astype(q.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def blockwise_attention(q, k, v, mask=None, causal=False, scale=None,
-                        block_k=128):
+                        block_k=128, block_diffusion=None):
     """O(T)-memory attention via lax.scan (the 'flash' recurrence in pure
     JAX).  Differentiable with recompute-based backward."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     bk = min(block_k, k.shape[2])
     if k.shape[2] % bk:
-        return mha_reference(q, k, v, mask, causal, scale)
-    return _blockwise_fwd(q, k, v, mask, causal, scale, bk)
+        return mha_reference(q, k, v, mask, causal, scale, block_diffusion)
+    if block_diffusion is not None:
+        _check_block_diffusion(q.shape[2], k.shape[2], block_diffusion)
+    return _blockwise_fwd(q, k, v, mask, causal, scale, bk, block_diffusion)
 
 
-def _bw_fwd(q, k, v, mask, causal, scale, block_k):
-    out = blockwise_attention(q, k, v, mask, causal, scale, block_k)
+def _bw_fwd(q, k, v, mask, causal, scale, block_k, block_diffusion=None):
+    out = blockwise_attention(q, k, v, mask, causal, scale, block_k,
+                              block_diffusion)
     return out, (q, k, v, mask)
 
 
-def _bw_bwd(causal, scale, block_k, res, g):
+def _bw_bwd(causal, scale, block_k, block_diffusion, res, g):
     """Flash-style backward: recompute attention under jax.grad of the
     scan — XLA rematerializes blockwise, never storing [T,T]."""
     q, k, v, mask = res
@@ -150,9 +241,10 @@ def _bw_bwd(causal, scale, block_k, res, g):
             s = scale
         bk = min(block_k, k_.shape[2])
         if k_.shape[2] % bk:
-            out = mha_reference(q_, k_, v_, mask, causal, s)
+            out = mha_reference(q_, k_, v_, mask, causal, s, block_diffusion)
         else:
-            out = _blockwise_fwd(q_, k_, v_, mask, causal, s, bk)
+            out = _blockwise_fwd(q_, k_, v_, mask, causal, s, bk,
+                                 block_diffusion)
         return jnp.sum(out * g)
 
     dq, dk, dv = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
@@ -168,13 +260,14 @@ blockwise_attention.defvjp(_bw_fwd, _bw_bwd)
 
 def _flash_kernel(q_ref, k_ref, v_ref, *rest,
                   block_q: int, block_k: int, nkv: int, causal: bool,
-                  scale: float, has_mask: bool):
+                  scale: float, has_mask: bool, block_diffusion=None):
     """3D grid (batch*head, q-block, kv-block): Pallas pipelines the KV
     block fetches (double-buffered HBM→VMEM) while online-softmax state
     lives in VMEM scratch across the kv dimension.  Emits per-row
     logsumexp for the backward kernel.  With ``has_mask`` an additive
     f32 bias block [1, 1, bk] (0 keep / NEG_INF drop over KV positions)
-    precedes the outputs."""
+    precedes the outputs.  ``block_diffusion``: (noisy rows, block length)
+    of that mask; a tile it leaves empty is skipped like causal's."""
     if has_mask:
         bias_ref, o_ref, lse_ref, acc_sc, m_sc, l_sc = rest
     else:
@@ -191,6 +284,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest,
 
     # causal: kv blocks fully above the diagonal contribute nothing
     live = (j * block_k <= qi * block_q + block_q - 1) if causal else True
+    if block_diffusion is not None:
+        live, *kept = _bd_tile(qi * block_q, j * block_k, block_q, block_k,
+                               *block_diffusion)
 
     @pl.when(live)
     def _step():
@@ -200,6 +296,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest,
         s = jnp.dot(q, kj.T, preferred_element_type=jnp.float32) * scale
         if has_mask:
             s = s + bias_ref[0]                            # [1,bk] → rows
+        if block_diffusion is not None:
+            s = jnp.where(_bd_keep_tile(*kept, block_q, block_k,
+                                        block_diffusion[1]), s, NEG_INF)
         if causal:
             rows = (qi * block_q
                     + jax.lax.broadcasted_iota(jnp.int32,
@@ -230,16 +329,31 @@ def _mask_bias3(mask, B, S):
         jnp.float32).reshape(B, 1, S)
 
 
+def _bd_static(T, S, bq, bk, block_diffusion) -> dict:
+    """The kernels' `block_diffusion` keyword, (noisy rows, block length),
+    checked against the tiles; none where the mask is not asked for."""
+    if block_diffusion is None:
+        return {}
+    _check_block_diffusion(T, S, block_diffusion)
+    L, B = block_diffusion
+    if L % bq or L % bk:
+        raise ValueError(f"tiles of {bq} x {bk} lie across the first clean "
+                         f"row of block_diffusion=({L}, {B})")
+    return {"block_diffusion": (T - L, B)}
+
+
 def flash_attention_tpu(q, k, v, causal=False, scale=None,
                         block_q=256, block_k=256, interpret=False,
-                        return_lse=False, mask=None):
+                        return_lse=False, mask=None, block_diffusion=None):
     """Pallas flash-attention forward.  q [B, H, T, D], k [B, Hk, S, D],
     v [B, Hk, S, Dv] -> [B, H, T, Dv]; T and S divisible by the block sizes
     (dispatcher checks), H a multiple of Hk (a key-value head's blocks are
     fetched for each of its query heads, from where they lie).  With
     ``return_lse`` also returns the row logsumexp [B*H, T] (f32) for the
     backward kernel.  ``mask``: optional [B, S] 1/0 keep-mask over KV
-    positions (padding/segment mask), shared across heads."""
+    positions (padding/segment mask), shared across heads.
+    ``block_diffusion``: (L, B) of the module docstring's mask; the blocks
+    divide L, so that no tile lies across the first clean row."""
     B, H, T, D = q.shape
     S, Dv = k.shape[2], v.shape[3]
     if scale is None:
@@ -247,6 +361,7 @@ def flash_attention_tpu(q, k, v, causal=False, scale=None,
     bq = min(block_q, T)
     bk = min(block_k, S)
     nkv = S // bk
+    bd = _bd_static(T, S, bq, bk, block_diffusion)
     Hk = k.shape[1]
     group = H // Hk
     # grid row b = batch * H + head reads the row b // group of k and v
@@ -258,11 +373,25 @@ def flash_attention_tpu(q, k, v, causal=False, scale=None,
     has_mask = mask is not None
     kernel = functools.partial(_flash_kernel, block_q=bq, block_k=bk,
                                nkv=nkv, causal=causal, scale=scale,
-                               has_mask=has_mask)
+                               has_mask=has_mask, **bd)
+
+    def kv_block(i, j):
+        # an empty tile of the block-diffusion mask names a block the row
+        # of tiles needs anyway (the q block's own noisy keys, the first
+        # clean keys of a clean one): nothing new is fetched for it
+        if not bd:
+            return j
+        o = bd["block_diffusion"][0]
+        live = _bd_tile(i * bq, j * bk, bq, bk, *bd["block_diffusion"])[0]
+        return jnp.where(live, j,
+                         jnp.where(i * bq < o, i * bq // bk, o // bk))
+
     in_specs = [
         pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, bk, D), lambda b, i, j: (kv_row(b), j, 0)),
-        pl.BlockSpec((1, bk, Dv), lambda b, i, j: (kv_row(b), j, 0)),
+        pl.BlockSpec((1, bk, D),
+                     lambda b, i, j: (kv_row(b), kv_block(i, j), 0)),
+        pl.BlockSpec((1, bk, Dv),
+                     lambda b, i, j: (kv_row(b), kv_block(i, j), 0)),
     ]
     inputs = [qf, kf, vf]
     if has_mask:
@@ -312,7 +441,7 @@ _NT = (((1,), (1,)), ((), ()))                 # a [m, c], b [n, c] -> [m, n]
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                       block_q: int, block_k: int, nq: int, nkv: int,
                       head_blocks: int, q_offset: int, causal: bool,
-                      scale: float, has_mask: bool):
+                      scale: float, has_mask: bool, block_diffusion=None):
     """dQ, dK and dV over grid (batch*head, kv-block, q-block), the q blocks
     innermost.  A live tile recomputes P from the saved logsumexp and
     computes dP and dS once; from them dV += P^T dO and dK += dS^T Q into
@@ -325,7 +454,8 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     sequence).  The ``nq`` q blocks of a grid row are ``head_blocks`` blocks
     of each query head of a group, head after head (``nq`` itself where
     every head has its own keys): a block's positions start anew with each
-    head, and dK/dV sum over all of them."""
+    head, and dK/dV sum over all of them.  ``block_diffusion``: (noisy rows,
+    block length) of that mask, as in the forward kernel."""
     if has_mask:
         bias_ref, dq_ref, dk_ref, dv_ref, dq_sc, dk_sc, dv_sc = rest
     else:
@@ -350,6 +480,9 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     # causal: q blocks strictly above the kv block's diagonal see nothing
     live = (q_offset + first_q() + block_q - 1 >= j * block_k) \
         if causal else True
+    if block_diffusion is not None:
+        live, *kept = _bd_tile(q_offset + first_q(), j * block_k, block_q,
+                               block_k, *block_diffusion)
 
     @pl.when(live)
     def _step():
@@ -361,6 +494,10 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
             kj, q, _NT, preferred_element_type=jnp.float32) * scale
         if has_mask:
             s = s + bias_ref[0]                            # [bk, 1] → cols
+        if block_diffusion is not None:
+            s = jnp.where(
+                _bd_keep_tile(*kept, block_q, block_k, block_diffusion[1],
+                              keys_first=True), s, NEG_INF)
         if causal:
             keys = (j * block_k
                     + jax.lax.broadcasted_iota(jnp.int32,
@@ -415,7 +552,7 @@ def _bwd_plan(T, S, D, Dv, itemsize, block_q, block_k, group=1):
 
 def flash_attention_bwd_tpu(q, k, v, out, lse, g, causal=False, scale=None,
                             block_q=256, block_k=256, interpret=False,
-                            mask=None):
+                            mask=None, block_diffusion=None):
     """Pallas flash-attention backward: delta precomputed on-device, then
     ONE kernel (`_flash_bwd_kernel`) that computes the scores, P, dP and dS
     of a tile once and takes dQ, dK and dV from them — no [T, T] array, no
@@ -442,6 +579,7 @@ def flash_attention_bwd_tpu(q, k, v, out, lse, g, causal=False, scale=None,
     bq, bk, span = _bwd_plan(T, S, D, Dv, q.dtype.itemsize, block_q, block_k,
                              G)
     nkv = S // bk
+    bd = _bd_static(T, S, bq, bk, block_diffusion)
     qf = q.reshape(B * H, T, D)
     kf = k.reshape(B * Hk, S, D)
     vf = v.reshape(B * Hk, S, Dv)
@@ -486,7 +624,7 @@ def flash_attention_bwd_tpu(q, k, v, out, lse, g, causal=False, scale=None,
         kernel = functools.partial(
             _flash_bwd_kernel, block_q=bq, block_k=bk, nq=G * nq, nkv=nkv,
             head_blocks=nq, q_offset=t0, causal=causal, scale=scale,
-            has_mask=has_mask)
+            has_mask=has_mask, **bd)
         stat_spec = pl.BlockSpec((1, 1, 1, bq),
                                  lambda b, j, i: (b, qi(j, i), 0, 0))
         return pl.pallas_call(
@@ -534,11 +672,12 @@ def flash_attention_bwd_tpu(q, k, v, out, lse, g, causal=False, scale=None,
             dv.reshape(B, Hk, S, Dv))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
 def _flash_attention_diff(q, k, v, mask, causal, scale, block_q=256,
-                          block_k=256, interpret=False):
+                          block_k=256, interpret=False, block_diffusion=None):
     return flash_attention_tpu(q, k, v, causal, scale, block_q, block_k,
-                               mask=mask, interpret=interpret)
+                               mask=mask, interpret=interpret,
+                               block_diffusion=block_diffusion)
 
 
 # What `_fa_fwd` names for a caller's `jax.checkpoint` policy.
@@ -547,7 +686,7 @@ FLASH_LSE = "flash_attention_lse"
 
 
 def _fa_fwd(q, k, v, mask, causal, scale, block_q, block_k,
-            interpret=False):
+            interpret=False, block_diffusion=None):
     """The forward kernel, once; residuals for `_fa_bwd`.
 
     `out` [B, H, T, Dv] and the row logsumexp [B*H, T] (float32) are the two
@@ -564,17 +703,20 @@ def _fa_fwd(q, k, v, mask, causal, scale, block_q, block_k,
     residual.  Without such a policy a name is an identity."""
     out, lse = flash_attention_tpu(q, k, v, causal, scale, block_q, block_k,
                                    return_lse=True, mask=mask,
-                                   interpret=interpret)
+                                   interpret=interpret,
+                                   block_diffusion=block_diffusion)
     out = checkpoint_name(out, FLASH_OUT)
     lse = checkpoint_name(lse, FLASH_LSE)
     return out, (q, k, v, mask, out, lse)
 
 
-def _fa_bwd(causal, scale, block_q, block_k, interpret, res, g):
+def _fa_bwd(causal, scale, block_q, block_k, interpret, block_diffusion, res,
+            g):
     q, k, v, mask, out, lse = res
     dq, dk, dv = flash_attention_bwd_tpu(q, k, v, out, lse, g, causal, scale,
                                          block_q, block_k, mask=mask,
-                                         interpret=interpret)
+                                         interpret=interpret,
+                                         block_diffusion=block_diffusion)
     return dq, dk, dv, None
 
 
@@ -597,7 +739,8 @@ _FLASH_MIN_SEQ = 2048
 _XLA_SCORE_BYTES_MAX = 2 << 30   # beyond ~2GB of scores, never take XLA path
 
 
-def fused_attention(q, k, v, mask=None, causal=False, scale=None):
+def fused_attention(q, k, v, mask=None, causal=False, scale=None,
+                    block_diffusion=None):
     """Dispatcher (the platform-helper pattern — cuDNN-attention role):
 
     - kernel tier (`ops/pallas/dispatch`): Pallas flash kernels (fwd +
@@ -609,21 +752,28 @@ def fused_attention(q, k, v, mask=None, causal=False, scale=None):
       on v5e below ~2k).
     - the rest → blockwise scan (O(T) memory).
 
-    Differentiable everywhere."""
+    `block_diffusion=(L, B)`: the mask of block-diffusion training over L
+    or 2L rows (module docstring), in every branch; not with `mask` or
+    `causal`.  Differentiable everywhere."""
     from deeplearning4j_tpu.ops import pallas as _tier
     B, H, T, D = q.shape
     S = k.shape[2]
+    masks = {} if block_diffusion is None else {
+        "block_diffusion": tuple(int(n) for n in block_diffusion)}
+    if masks and (causal or mask is not None):
+        raise ValueError("block_diffusion is a mask of its own: neither "
+                         "`causal` nor `mask` goes with it")
     if _tier.dispatch.resolve("attention", q, k, v, mask=mask,
-                              causal=causal) == "pallas":
+                              causal=causal, **masks) == "pallas":
         sc = _tier.shape_class(t=T, s=S, d=D)
         return _tier.attention.flash_attention(
             q, k, v, mask=mask, causal=causal, scale=scale,
             tile=_tier.dispatch.get_tile("attention", sc),
-            interpret=_tier.dispatch.interpret_mode())
+            interpret=_tier.dispatch.interpret_mode(), **masks)
     score_bytes = B * H * T * S * q.dtype.itemsize
     if score_bytes <= _XLA_SCORE_BYTES_MAX:
-        return mha_reference(q, k, v, mask, causal, scale)
-    return blockwise_attention(q, k, v, mask, causal, scale)
+        return mha_reference(q, k, v, mask, causal, scale, **masks)
+    return blockwise_attention(q, k, v, mask, causal, scale, **masks)
 
 
 # ---------------------------------------------------------------------------

@@ -13,6 +13,12 @@ shared experts that see every token.
 `eps` is 1e-20 in DeepSeek-V3's code and 1e-6 in other families'; a layer
 may have no shared expert (`S` is then left out).
 
+The older router of Switch/Mixtral/Qwen3-MoE is the other `score`: `p =
+softmax(f32(x) W_g)` over all `E`, the k largest probabilities chosen (no
+bias), the same normalisation; its load is balanced by an auxiliary loss
+(`balance_loss`: Switch Transformer, arXiv:2101.03961, eq. 4), for which it
+also returns each expert's mean probability.
+
 Under expert parallelism a chip holds the experts `first .. first + held`
 of a layer.  The router here keeps all `E` outputs and the published top-k;
 `routed_experts` computes the terms of the sum whose expert is held and
@@ -53,18 +59,32 @@ import jax.numpy as jnp
 # the router
 # ---------------------------------------------------------------------------
 
-def router(x, w_router, bias, top_k: int, scale: float, eps: float = 1e-20):
+def router(x, w_router, bias, top_k: int, scale: float, eps: float = 1e-20,
+           score: str = "sigmoid"):
     """(chosen [T, k] int32, weights [T, k] f32) of the tokens `x` [T, H].
     Scores in float32 at full matmul precision: a sixth and a seventh score
     often lie within a bf16 rounding of each other.  `bias` [E] only picks;
     it gets no gradient and is not in the weights.  `eps` is what the
-    normalisation adds to the sum of the chosen scores."""
-    s = jax.nn.sigmoid(jnp.dot(
+    normalisation adds to the sum of the chosen scores.
+
+    `score="softmax"`: the scores are probabilities over all E, `bias` is
+    not used, and a third result is each expert's mean probability over
+    the tokens, [E] f32 — what `balance_loss` needs beside the counts."""
+    logits = jnp.dot(
         x.astype(jnp.float32), w_router.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
-    _, chosen = jax.lax.top_k(s + jax.lax.stop_gradient(bias), top_k)
+        precision=jax.lax.Precision.HIGHEST)
+    if score == "softmax":
+        s = jax.nn.softmax(logits, axis=-1)
+        _, chosen = jax.lax.top_k(s, top_k)
+    elif score == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+        _, chosen = jax.lax.top_k(s + jax.lax.stop_gradient(bias), top_k)
+    else:
+        raise ValueError(f"score {score!r}; want 'sigmoid' or 'softmax'")
     w = jnp.take_along_axis(s, chosen, axis=-1)
     w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps) * scale
+    if score == "softmax":
+        return chosen.astype(jnp.int32), w, jnp.mean(s, axis=0)
     return chosen.astype(jnp.int32), w
 
 
@@ -72,6 +92,15 @@ def expert_counts(chosen, n_experts: int):
     """How many tokens chose each of the `n_experts`: [E] int32."""
     hit = chosen[..., None] == jnp.arange(n_experts, dtype=jnp.int32)
     return jnp.sum(hit, axis=(0, 1), dtype=jnp.int32)
+
+
+def balance_loss(counts, mean_prob, tokens: int):
+    """`E * sum_e f_e P_e`: `f_e` the share of the step's `tokens` tokens
+    that chose expert e (`counts` [E] — no gradient), `P_e` its mean
+    probability.  `top_k` under even routing, larger the more the hot
+    experts are also the likely ones."""
+    f = counts.astype(jnp.float32) / tokens
+    return counts.shape[-1] * jnp.sum(f * mean_prob)
 
 
 def update_router_bias(bias, counts, speed: float):
@@ -364,12 +393,14 @@ def swiglu(x, w_gate, w_up, w_down):
 
 
 def expert_layer(x, p, bias, *, top_k: int, scale: float, first_held: int,
-                 eps: float = 1e-20):
+                 eps: float = 1e-20, score: str = "sigmoid"):
     """This chip's share of the layer for tokens `x` [T, H]; the step's
     count of tokens that chose each of the E experts ([E] int32, held or
     not: the router's load, which the bias update balances); and whether
     the step's held pairs were more than the routed part's row bound (int32,
-    0 or 1: it then took more than one pass of that many rows).
+    0 or 1: it then took more than one pass of that many rows).  Under
+    `score="softmax"` a fourth result: the layer's `balance_loss`, over all
+    E outputs of the router (a chip has them whole).
 
     `p`: `router` [H, E]; `w_gate`, `w_up` [held, H, I] and `w_down`
     [held, I, H]; `shared_gate`, `shared_up` [H, S] and `shared_down`
@@ -384,14 +415,17 @@ def expert_layer(x, p, bias, *, top_k: int, scale: float, first_held: int,
     rows = row_bound(x.shape[0] * top_k, p["w_gate"].shape[0], n_experts)
     with jax.named_scope("moe"):
         with jax.named_scope("router"):
-            chosen, weights = router(x, p["router"], bias, top_k, scale, eps)
+            chosen, weights, *mean_prob = router(
+                x, p["router"], bias, top_k, scale, eps, score)
             counts = expert_counts(chosen, n_experts)
+            balance = [balance_loss(counts, m, x.shape[0])
+                       for m in mean_prob]
         routed, over = routed_experts(x, chosen, weights, p["w_gate"],
                                       p["w_up"], p["w_down"], first_held,
                                       rows)
         if "shared_gate" not in p:
-            return routed, counts, over
+            return (routed, counts, over, *balance)
         with jax.named_scope("shared"):
             shared = swiglu(x, p["shared_gate"], p["shared_up"],
                             p["shared_down"])
-        return routed + shared, counts, over
+        return (routed + shared, counts, over, *balance)

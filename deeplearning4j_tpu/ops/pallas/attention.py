@@ -35,18 +35,33 @@ def _q_sublane(dtype) -> int:
     return 16 if jnp.dtype(dtype) == jnp.dtype(jnp.bfloat16) else 8
 
 
+def _dividing(block: int, n: int) -> int:
+    """The asked block, halved until it divides `n` rows."""
+    b = min(block, n)
+    while n % b:
+        b //= 2
+    return b
+
+
 def flash_attention(q, k, v, mask=None, causal: bool = False, scale=None,
                     tile: Optional[TileConfig] = None,
-                    interpret: bool = False):
+                    interpret: bool = False, block_diffusion=None):
     """Flash attention of q [B, H, T, D], k [B, Hk, S, D], v [B, Hk, S, Dv]
     -> [B, H, T, Dv] with TileConfig-driven blocks and masked-tail padding
     for ragged T/S (the padding is along T and S only, so it holds for any
-    value width and any number of key-value heads).  Differentiable."""
+    value width and any number of key-value heads).  Under
+    `block_diffusion=(L, B)` the blocks divide L — no tile lies across the
+    first clean row — and nothing is padded.  Differentiable."""
     import deeplearning4j_tpu.ops.attention_kernels as ak
 
     tile = tile or DEFAULT_TILES["attention"]
     B, H, T, D = q.shape
     S = k.shape[2]
+    if block_diffusion is not None:
+        L = block_diffusion[0]
+        return ak._flash_attention_diff(
+            q, k, v, None, False, scale, _dividing(tile.block_q, L),
+            _dividing(tile.block_kv, L), interpret, block_diffusion)
     bq = min(tile.block_q, _round_up(T, _q_sublane(q.dtype)))
     bk = min(tile.block_kv, _round_up(S, 128))
     Tp, Sp = _round_up(T, bq), _round_up(S, bk)
@@ -69,14 +84,15 @@ def flash_attention(q, k, v, mask=None, causal: bool = False, scale=None,
 
 
 def attention_reference(q, k, v, mask=None, causal: bool = False,
-                        scale=None):
+                        scale=None, block_diffusion=None):
     import deeplearning4j_tpu.ops.attention_kernels as ak
 
-    return ak.mha_reference(q, k, v, mask=mask, causal=causal, scale=scale)
+    return ak.mha_reference(q, k, v, mask=mask, causal=causal, scale=scale,
+                            block_diffusion=block_diffusion)
 
 
 def attention_supports(q, k, v, mask=None, causal: bool = False,
-                       **kw) -> bool:
+                       block_diffusion=None, **kw) -> bool:
     """Hard constraints only — forced-pallas mode must work on the small
     shapes the conformance suite uses."""
     if getattr(q, "ndim", 0) != 4:
@@ -99,6 +115,14 @@ def attention_supports(q, k, v, mask=None, causal: bool = False,
         B, _, _, _ = q.shape
         S = k.shape[2]
         if getattr(mask, "ndim", 0) != 2 or mask.shape != (B, S):
+            return False
+    if block_diffusion is not None:
+        # the clean rows alone or both copies, in whole blocks; the tiles
+        # are whole sublanes of L (`flash_attention` halves them to fit)
+        L, blk = block_diffusion
+        if mask is not None or causal or q.shape[2] != k.shape[2] \
+                or q.shape[2] not in (L, 2 * L) or L % blk \
+                or L % _q_sublane(q.dtype):
             return False
     return True
 

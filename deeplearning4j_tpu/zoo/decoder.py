@@ -1,11 +1,12 @@
-"""A decoder-only causal language model over a per-layer list of token
-mixers, with dense or sparse-expert feed-forward layers, on the train path.
+"""A decoder-only language model over a per-layer list of token mixers,
+with dense or sparse-expert feed-forward layers, on the train path.
 
 Pre-norm residual blocks `h = x + Op_l(RMSNorm(x))`, `y = h + F_l(RMSNorm(h))`
 (RMSNorm in float32, `ops/norm_kernels.rms_norm`), no position embedding,
-next-token cross-entropy.  `DecoderConfig.layer_types` names `Op_l` layer by
-layer; two published models are the presets the tests and the benchmark
-build:
+next-token cross-entropy under a causal mask or (`objective`) the
+block-diffusion loss below.  `DecoderConfig.layer_types` names `Op_l` layer
+by layer; three published models are the presets the tests and the
+benchmark build:
 
 - `deepseek_v3` configs (DeepSeek-V2/V3, arXiv:2405.04434, arXiv:2412.19437;
   kanana-2-30b-a3b): `latent_attention` in every layer (the default list) —
@@ -20,12 +21,29 @@ build:
   `n_kv_heads` keys and values of `head_dim`), RMSNorm of every query and
   key head, half-split rotary — in a repeating pattern; no shared expert;
   the head is the embedding's transpose (`tie_embeddings`).
+- `sdar_moe` configs (SDAR-30B-A3B: a Qwen3-MoE block trained by block
+  diffusion): `full_attention` in every layer and NO dense layer; a softmax
+  router (`router_score`) balanced by an auxiliary loss (`aux_loss_coef`);
+  `objective="block_diffusion"` (BD3-LM, arXiv:2503.09573; SDAR,
+  arXiv:2510.06303): the step draws, for each block of `block_length`
+  tokens, `t ~ U(noise_eps, 1)` and replaces each of its tokens by
+  `mask_token_id` with probability `t`; the model runs on the 2L rows
+  `[noisy ; clean]`, both halves at positions `0 .. L-1`, under
+  `fused_attention(block_diffusion=(L, block_length))` — a noisy row sees
+  its own noisy block and the clean blocks before it, a clean row the clean
+  blocks up to its own — and the loss is the cross-entropy of the noisy
+  half's logits against the clean token AT THE SAME position (no shift),
+  over the replaced positions, each weighted `1/t`, over L.  The noise
+  comes from `fold_in(PRNGKey(seed), iteration)` on the device, so
+  `fit(iterator)` consumes `[ids]` as ever.  Without a noisy copy
+  (`output(ids)`) the same mask is attention causal over blocks: the
+  forward a block-wise denoising decode would run.
 
 All of them through `ops/attention_kernels.fused_attention` (the flash
 kernels from 2k tokens on the chip).  `F_l` is a SwiGLU for the first
-`n_dense_layers` layers and `ops/moe.expert_layer` after: a sigmoid router
-over `n_experts` with a selection bias that the step updates (no auxiliary
-loss), top-k.
+`n_dense_layers` layers (there may be none) and `ops/moe.expert_layer`
+after: a sigmoid router over `n_experts` with a selection bias that the step
+updates (no auxiliary loss), or the softmax router; top-k.
 
 `first_expert`/`n_experts_held` say which routed experts this process
 holds of each layer (all of them by default).  Held alone, the layer
@@ -62,12 +80,14 @@ is.
 
 Named scopes mark each part's device ops, forward and backward:
 `mla_attention`, `gqa_attention`, `short_conv` (beneath it `in_proj`, `mix`,
-`out_proj`), `dense_mlp`, `moe` (`ops/moe.py`), `lm_head`.
+`out_proj`), `dense_mlp`, `moe` (`ops/moe.py`), `lm_head`, and for the
+diffusion objective `bd_noise` (the draws, the replaced ids, the 2L input)
+and `diffusion_loss` (the weighted cross-entropy and the auxiliary term).
 
 Not here yet: prefill/decode through a cache (growing pages for the
 attention layers beside a fixed `conv_kernel - 1` positions of state for the
 convolutions), absorbed latent attention, the experts' exchange over
-several chips.
+several chips, the decode loop that denoises a block over several passes.
 """
 from __future__ import annotations
 
@@ -128,6 +148,12 @@ class DecoderConfig:
     embedding_init_std: float = 1.0    # see `_init`
     tie_embeddings: bool = False       # the head is the embedding's transpose
     compute_dtype: str = "float32"     # "bfloat16" for TPU throughput
+    router_score: str = "sigmoid"      # or "softmax": no bias, and
+    aux_loss_coef: float = 0.0         # this much of `ops/moe.balance_loss`
+    objective: str = "next_token"      # or "block_diffusion", with
+    block_length: int = 4              # tokens that share a noise level,
+    mask_token_id: Optional[int] = None    # the id a replaced token gets,
+    noise_eps: float = 1e-3            # and t ~ U(noise_eps, 1) a block
 
     @property
     def held(self) -> int:
@@ -166,6 +192,23 @@ class DecoderConfig:
         return DecoderConfig(**d)
 
     @staticmethod
+    def tiny_diffusion(**kw) -> "DecoderConfig":
+        """Test-sized SDAR-MoE: two `full_attention` expert layers and no
+        dense one, 4 query heads over 2 key-value heads of 8, a softmax
+        router over 8 experts top-2 with an auxiliary loss, untied head,
+        trained by block diffusion in blocks of 4 with the last id as the
+        mask token."""
+        d = dict(vocab_size=96, hidden=32, n_layers=2, n_dense_layers=0,
+                 layer_types=("full_attention",) * 2, n_heads=4,
+                 n_kv_heads=2, head_dim=8, expert_intermediate=16,
+                 n_experts=8, n_shared_experts=0, top_k=2, routed_scale=1.0,
+                 router_eps=0.0, router_score="softmax", aux_loss_coef=1e-3,
+                 objective="block_diffusion", block_length=4,
+                 mask_token_id=95)
+        d.update(kw)
+        return DecoderConfig(**d)
+
+    @staticmethod
     def tiny_hybrid(**kw) -> "DecoderConfig":
         """Test-sized LFM2-MoE: a dense `conv` layer, then one period
         `full_attention, conv, conv, conv` of expert layers; 4 query heads
@@ -191,9 +234,10 @@ def _key_stream(key):
 
 
 class DecoderModel:
-    """Causal LM over `DecoderConfig`.  `fit(iterator)` consumes
-    MultiDataSets with features `[ids]` and labels `[next ids]`, both
-    [B, T] int; `output(ids)` returns the logits."""
+    """LM over `DecoderConfig`.  `fit(iterator)` consumes MultiDataSets with
+    features `[ids]` and labels `[next ids]`, both [B, T] int (the
+    block-diffusion objective reads the ids alone); `output(ids)` returns
+    the logits."""
 
     def __init__(self, config: DecoderConfig, seed: int = 0,
                  updater: Optional[IUpdater] = None):
@@ -207,15 +251,31 @@ class DecoderModel:
             raise ValueError(
                 f"layer_types names {len(kinds)} layers {sorted(set(kinds))}"
                 f"; need {c.n_layers} of {LAYER_KINDS}")
-        if not 0 < c.n_dense_layers < c.n_layers:
-            raise ValueError("need at least one dense and one expert layer")
-        if len(set(kinds[:c.n_dense_layers])) != 1:
+        if not 0 <= c.n_dense_layers < c.n_layers:
+            raise ValueError("need at least one expert layer")
+        if c.n_dense_layers and len(set(kinds[:c.n_dense_layers])) != 1:
             raise ValueError(
                 f"the {c.n_dense_layers} leading dense layers are stacked: "
                 f"they share one kind, not {kinds[:c.n_dense_layers]}")
         if "full_attention" in kinds and c.n_heads % c.n_kv_heads:
             raise ValueError(f"{c.n_heads} query heads are no multiple of "
                              f"{c.n_kv_heads} key-value heads")
+        if c.router_score not in ("sigmoid", "softmax"):
+            raise ValueError(f"router_score {c.router_score!r}")
+        if c.objective not in ("next_token", "block_diffusion"):
+            raise ValueError(f"objective {c.objective!r}")
+        self._diffusion = c.objective == "block_diffusion"
+        if self._diffusion:
+            if "conv" in kinds:
+                raise ValueError(
+                    "a convolution would run across the noisy and the clean "
+                    "copy of a sequence: block diffusion takes attention "
+                    "layers")
+            if c.mask_token_id is None \
+                    or not 0 <= c.mask_token_id < c.vocab_size:
+                raise ValueError(f"mask_token_id {c.mask_token_id} is not "
+                                 f"among the {c.vocab_size} ids held")
+        self.seed = int(seed)       # of the parameters and of the noise
         self.updater = updater or AdamW(2.2e-4, weight_decay=0.1)
         self.iteration = 0
         self.epoch = 0
@@ -231,6 +291,9 @@ class DecoderModel:
             "router_bias": jnp.zeros((n_moe, c.n_experts), jnp.float32),
             "expert_load": jnp.zeros((n_moe, c.n_experts), jnp.int32),
             "rows_over_bound": jnp.zeros((n_moe,), jnp.int32)}
+        if self._diffusion:         # positions that carried loss, all steps
+            self.state_["masked_positions"] = jnp.zeros((), jnp.int32)
+        self._tokens = 0     # clean tokens of the newest step
         self._pairs = 0      # (token, chosen expert) pairs of the newest step
         self._steps: Dict[str, Any] = {}
 
@@ -297,13 +360,13 @@ class DecoderModel:
             return p
 
         dense_kind, period, n, rest = c.layout()
-        params = {
-            "tok_emb": nrm(c.vocab_size, H, std=c.embedding_init_std),
-            "dense": dense(dense_kind, c.n_dense_layers),
-            # a period of one layer: its stacked parameters themselves
-            "moe": experts(period[0], n) if len(period) == 1
-            else tuple(experts(kind, n) for kind in period),
-            "final_norm": jnp.ones((H,))}
+        params = {"tok_emb": nrm(c.vocab_size, H, std=c.embedding_init_std)}
+        if c.n_dense_layers:
+            params["dense"] = dense(dense_kind, c.n_dense_layers)
+        # a period of one layer: its stacked parameters themselves
+        params["moe"] = experts(period[0], n) if len(period) == 1 \
+            else tuple(experts(kind, n) for kind in period)
+        params["final_norm"] = jnp.ones((H,))
         if rest:
             params["rest"] = tuple(
                 jax.tree_util.tree_map(lambda a: a[0], experts(kind, 1))
@@ -313,13 +376,25 @@ class DecoderModel:
         return params
 
     # ---- forward ----
-    def _qkv(self, x, lp):
+    def _rows(self, T: int, L: Optional[int]):
+        """`(positions [T], the mask as `fused_attention` takes it)` of T
+        rows: causal at `0 .. T-1`, or for the diffusion objective the
+        block mask over a clean sequence of `L` tokens (T by default) and,
+        where T is 2L, its noisy copy before it, both at `0 .. L-1`."""
+        if not self._diffusion:
+            return jnp.arange(T), {"causal": True}
+        L = L or T
+        return jnp.arange(T) % L, {
+            "block_diffusion": (L, self.config.block_length)}
+
+    def _qkv(self, x, lp, L=None):
         """Queries and keys [B, heads, T, nope + rope] and values
-        [B, heads, T, v] of expanded latent attention for `x` [B, T, H]."""
+        [B, heads, T, v] of expanded latent attention for `x` [B, T, H],
+        and the mask (`_rows`)."""
         c = self.config
         B, T, _ = x.shape
         nh, dn, dr, dv = c.n_heads, c.qk_nope_dim, c.qk_rope_dim, c.v_head_dim
-        pos = jnp.arange(T)
+        pos, mask = self._rows(T, L)
         q = (x @ lp["Wq"]).reshape(B, T, nh, dn + dr)
         kva = x @ lp["Wkva"]
         latent = rms_norm(kva[..., :c.kv_lora_rank], lp["kv_norm"], c.eps)
@@ -332,25 +407,26 @@ class DecoderModel:
             [kv[..., :dn], jnp.broadcast_to(k_rope, (B, T, nh, dr))], -1)
         heads_first = (0, 2, 1, 3)
         return (q.transpose(heads_first), k.transpose(heads_first),
-                kv[..., dn:].transpose(heads_first))
+                kv[..., dn:].transpose(heads_first), mask)
 
-    def _attention(self, x, lp):
-        """`x + MLA(RMSNorm(x))` for `x` [B, T, H], causal."""
+    def _attention(self, x, lp, L=None):
+        """`x + MLA(RMSNorm(x))` for `x` [B, T, H], under `_rows`' mask."""
         c = self.config
         B, T, _ = x.shape
         with jax.named_scope("mla_attention"):
             dt = lp["Wo"].dtype
-            q, k, v = self._qkv(
-                rms_norm(x, lp["norm1"], c.eps).astype(dt), lp)
-            o = fused_attention(q, k, v, causal=True)
+            q, k, v, mask = self._qkv(
+                rms_norm(x, lp["norm1"], c.eps).astype(dt), lp, L)
+            o = fused_attention(q, k, v, **mask)
             o = o.transpose(0, 2, 1, 3).reshape(B, T, -1)
             return x + (o @ lp["Wo"]).astype(x.dtype)
 
-    def _gqa_attention(self, x, lp):
-        """`x + GQA(RMSNorm(x))` for `x` [B, T, H], causal: `n_heads` query
-        heads over `n_kv_heads` key-value heads, every query and key head
-        RMS-normed over its `head_dim` (one gain each, shared by the heads),
-        then half-split rotary on all of it."""
+    def _gqa_attention(self, x, lp, L=None):
+        """`x + GQA(RMSNorm(x))` for `x` [B, T, H], under `_rows`' mask and
+        at its positions: `n_heads` query heads over `n_kv_heads` key-value
+        heads, every query and key head RMS-normed over its `head_dim` (one
+        gain each, shared by the heads), then half-split rotary on all of
+        it."""
         c = self.config
         B, T, _ = x.shape
         nh, nkv, hd = c.n_heads, c.n_kv_heads, c.head_dim
@@ -358,7 +434,7 @@ class DecoderModel:
             dt = lp["Wo"].dtype
             qkv = (rms_norm(x, lp["norm1"], c.eps).astype(dt) @ lp["Wqkv"]
                    ).reshape(B, T, nh + 2 * nkv, hd)
-            pos = jnp.arange(T)
+            pos, mask = self._rows(T, L)
             q = rotary_half_split(
                 rms_norm(qkv[:, :, :nh], lp["q_norm"], c.eps), pos,
                 c.rope_base)
@@ -369,11 +445,11 @@ class DecoderModel:
             o = fused_attention(q.transpose(heads_first),
                                 k.transpose(heads_first),
                                 qkv[:, :, nh + nkv:].transpose(heads_first),
-                                causal=True)
+                                **mask)
             o = o.transpose(0, 2, 1, 3).reshape(B, T, -1)
             return x + (o @ lp["Wo"]).astype(x.dtype)
 
-    def _short_conv(self, x, lp):
+    def _short_conv(self, x, lp, L=None):
         """`x + (C * conv(B * X)) W_out` on `RMSNorm(x)`, `x` [B, T, H]."""
         with jax.named_scope("short_conv"):
             dt = lp["conv_out"].dtype
@@ -382,19 +458,22 @@ class DecoderModel:
                 lp["conv_in"], lp["conv_kernel"], lp["conv_out"])
             return x + y.astype(x.dtype)
 
-    def _operator(self, kind: str):
-        """`(x, lp) -> x + Op(RMSNorm(x))` of a layer kind."""
-        return {"latent_attention": self._attention,
-                "full_attention": self._gqa_attention,
-                "conv": self._short_conv}[kind]
+    def _operator(self, kind: str, L=None):
+        """`(x, lp) -> x + Op(RMSNorm(x))` of a layer kind; `L`: `_rows`."""
+        return functools.partial(
+            {"latent_attention": self._attention,
+             "full_attention": self._gqa_attention,
+             "conv": self._short_conv}[kind], L=L)
 
-    def _trunk(self, params, router_bias, ids):
+    def _trunk(self, params, router_bias, ids, L=None):
         """Hidden states [B, T, H] after the last block (float32: the blocks
         compute in `compute_dtype`, the residual stream they add to does
         not), and what the expert layers counted in this step:
         `expert_load` [L_moe, E] tokens that chose each expert,
         `rows_over_bound` [L_moe] whether the layer's held pairs were more
-        than its row bound (`ops/moe.routed_experts`)."""
+        than its row bound (`ops/moe.routed_experts`), and under the softmax
+        router `balance_loss` [L_moe].  `L`: the clean sequence's length
+        where `ids` hold more than it (`_rows`)."""
         c = self.config
         dt = jnp.dtype(c.compute_dtype)
 
@@ -409,12 +488,15 @@ class DecoderModel:
 
         def moe_ffn(x, lp, bias):
             B, T, H = x.shape
-            y, counts, over = expert_layer(
+            y, counts, over, *balance = expert_layer(
                 rms_norm(x, lp["norm2"], c.eps).astype(dt).reshape(B * T, H),
                 lp, bias, top_k=c.top_k, scale=c.routed_scale,
-                first_held=c.first_expert, eps=c.router_eps)
-            return (x + y.reshape(B, T, H).astype(x.dtype),
-                    {"expert_load": counts, "rows_over_bound": over})
+                first_held=c.first_expert, eps=c.router_eps,
+                score=c.router_score)
+            seen = {"expert_load": counts, "rows_over_bound": over}
+            if balance:
+                seen["balance_loss"] = balance[0]
+            return x + y.reshape(B, T, H).astype(x.dtype), seen
 
         # each block keeps its input and the flash kernel's two results for
         # the backward pass; the rest is computed again there (module
@@ -425,13 +507,13 @@ class DecoderModel:
 
         @functools.partial(jax.checkpoint, policy=keep)
         def dense_block(x, lp):
-            return dense_ffn(self._operator(dense_kind)(x, lp), lp)
+            return dense_ffn(self._operator(dense_kind, L)(x, lp), lp)
 
         def expert_block(kind, under_scan):
             def moe_block(x, layer):
                 lp, bias = layer
                 lp = {**cast(lp), "router": lp["router"]}   # stays float32
-                return moe_ffn(self._operator(kind)(x, lp), lp, bias)
+                return moe_ffn(self._operator(kind, L)(x, lp), lp, bias)
             return jax.checkpoint(moe_block, policy=keep,
                                   prevent_cse=not under_scan)
 
@@ -489,23 +571,97 @@ class DecoderModel:
         logp = jax.nn.log_softmax(self._logits(params, hidden), axis=-1)
         nll = -jnp.take_along_axis(
             logp, labels[..., None].astype(jnp.int32), axis=-1)[..., 0]
-        return jnp.mean(nll[:, :-1]), seen
+        return self._balanced(jnp.mean(nll[:, :-1]), seen)
+
+    def _balanced(self, loss, seen):
+        """`loss` plus `aux_loss_coef` times the expert layers' mean
+        `balance_loss`, where the router is one that has it; `seen` without
+        it (what is left are the step's counters)."""
+        if "balance_loss" not in seen:
+            return loss, seen
+        seen = dict(seen)
+        return (loss + self.config.aux_loss_coef
+                * jnp.mean(seen.pop("balance_loss")), seen)
+
+    # ---- the block-diffusion objective ----
+    def _noise(self, key, ids):
+        """`(noisy ids, weight)` for clean `ids` [B, L], both [B, L]: each
+        block of `block_length` tokens draws `t ~ U(noise_eps, 1)` and each
+        of its tokens becomes `mask_token_id` with probability `t`; `weight`
+        is `1/t` at a replaced position and 0 elsewhere (float32)."""
+        c = self.config
+        rows, L = ids.shape
+        if L % c.block_length:
+            raise ValueError(f"{L} tokens are no whole blocks of "
+                             f"{c.block_length}")
+        with jax.named_scope("bd_noise"):
+            k_t, k_mask = jax.random.split(key)
+            t = jnp.repeat(
+                jax.random.uniform(k_t, (rows, L // c.block_length),
+                                   jnp.float32, c.noise_eps, 1.0),
+                c.block_length, axis=1)
+            replaced = jax.random.uniform(k_mask, (rows, L)) < t
+            return (jnp.where(replaced, jnp.int32(c.mask_token_id), ids),
+                    jnp.where(replaced, 1.0 / t, 0.0))
+
+    def _noisy_half(self, params, router_bias, ids, noisy_ids):
+        """float32 logits [B, L, vocab] of the noisy copy's rows from the
+        forward over `[noisy ; clean]` (the clean half needs no head), and
+        what the expert layers counted."""
+        L = ids.shape[1]
+        with jax.named_scope("bd_noise"):
+            both = jnp.concatenate([noisy_ids, ids], axis=1)
+        hidden, seen = self._trunk(params, router_bias, both, L)
+        return self._logits(params, hidden[:, :L]), seen
+
+    def _masked_token_loss(self, params, router_bias, ids, noisy_ids,
+                           weight):
+        """`mean over sequences of (1/L) sum_i weight_i * -log
+        softmax(logits_i)[ids_i]`: a replaced position predicts its own
+        token, weighted by `1/t` of its block.  Beside `_trunk`'s counters,
+        `masked_positions`: how many positions carried loss."""
+        logits, seen = self._noisy_half(params, router_bias, ids, noisy_ids)
+        with jax.named_scope("diffusion_loss"):
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            nll = -jnp.take_along_axis(logp, ids[..., None], axis=-1)[..., 0]
+            loss = jnp.mean(jnp.sum(weight * nll, axis=1) / ids.shape[1])
+            return loss, {**seen, "masked_positions": jnp.sum(
+                weight > 0, dtype=jnp.int32)}
+
+    def _diffusion_loss(self, params, router_bias, ids, noisy_ids, weight):
+        """The step's loss: `_masked_token_loss` and the balance term."""
+        loss, seen = self._masked_token_loss(params, router_bias, ids,
+                                             noisy_ids, weight)
+        with jax.named_scope("diffusion_loss"):
+            return self._balanced(loss, seen)
+
+    def noise_key(self, iteration):
+        """The key of the step at `iteration`: what `fit` draws there."""
+        return jax.random.fold_in(jax.random.PRNGKey(self.seed), iteration)
 
     # ---- compiled steps ----
     def _step_body(self):
         speed = self.config.bias_update_speed
 
         def step(params, opt_state, state, iteration, epoch, ids, labels):
+            if self._diffusion:     # `labels` repeat the ids: not read
+                loss_of, targets = self._diffusion_loss, self._noise(
+                    self.noise_key(iteration), ids)
+            else:
+                loss_of, targets = self._loss, (labels,)
             (loss, seen), grads = jax.value_and_grad(
-                self._loss, has_aux=True)(params, state["router_bias"],
-                                          ids, labels)
+                loss_of, has_aux=True)(params, state["router_bias"], ids,
+                                       *targets)
             upd, new_opt = self.updater.apply(opt_state, grads, iteration,
                                               epoch, params=params)
             new_params = jax.tree_util.tree_map(lambda p, u: p - u,
                                                 params, upd)
             new_state = {
+                # the selection bias is the sigmoid router's
                 "router_bias": update_router_bias(
-                    state["router_bias"], seen["expert_load"], speed),
+                    state["router_bias"], seen["expert_load"], speed)
+                if self.config.router_score == "sigmoid"
+                else state["router_bias"],
                 **{name: state[name] + seen[name] for name in seen}}
             return new_params, new_opt, new_state, loss, iteration + 1
 
@@ -544,7 +700,10 @@ class DecoderModel:
     def _batch(self, mds):
         (ids,) = [jnp.asarray(f) for f in mds.features]
         (labels,) = [jnp.asarray(l) for l in mds.labels]
-        self._pairs = ids.shape[-2] * ids.shape[-1] * self.config.top_k
+        self._tokens = ids.shape[-2] * ids.shape[-1]
+        # every row goes through the experts: under diffusion both copies
+        self._pairs = (self._tokens * self.config.top_k
+                       * (2 if self._diffusion else 1))
         return ids.astype(jnp.int32), labels.astype(jnp.int32)
 
     def fit_batch(self, mds):
@@ -583,13 +742,47 @@ class DecoderModel:
         s = getattr(self, "_score", None)
         return float(s) if s is not None else float("nan")
 
-    def output(self, ids):
-        """float32 logits [B, T, vocab held] of the inference forward."""
+    def output(self, ids, noisy_ids=None):
+        """float32 logits [B, T, vocab held] of the inference forward
+        (under the diffusion objective: causal over blocks, bidirectional
+        inside one).  With `noisy_ids` [B, T], the clean `ids` with some
+        replaced by the mask token: the training forward over both copies,
+        the logits of the noisy copy's rows."""
+        bias = self.state_["router_bias"]
+        if noisy_ids is not None:
+            if not self._diffusion:
+                raise ValueError("noisy_ids go with the block_diffusion "
+                                 "objective")
+            if "noisy_half" not in self._steps:
+                self._steps["noisy_half"] = jax.jit(
+                    lambda p, b, i, n: self._noisy_half(p, b, i, n)[0])
+            return self._steps["noisy_half"](
+                self.params_, bias, jnp.asarray(ids, jnp.int32),
+                jnp.asarray(noisy_ids, jnp.int32))
         if "output" not in self._steps:
             self._steps["output"] = jax.jit(
                 lambda p, b, i: self._logits(p, self._trunk(p, b, i)[0]))
-        return self._steps["output"](self.params_, self.state_["router_bias"],
+        return self._steps["output"](self.params_, bias,
                                      jnp.asarray(ids, jnp.int32))
+
+    def noise(self, ids, key):
+        """`(noisy ids, weight)` as a step whose key is `key` draws them for
+        `ids` [B, L] (`_noise`; `noise_key(iteration)` is a step's key)."""
+        if "noise" not in self._steps:
+            self._steps["noise"] = jax.jit(self._noise)
+        return self._steps["noise"](key, jnp.asarray(ids, jnp.int32))
+
+    def diffusion_loss(self, ids, key):
+        """The masked-token loss of `ids` [B, L] under the noise of `key`,
+        without the balance term: a device scalar.  A fixed key compares
+        like with like across steps."""
+        if "diffusion_loss" not in self._steps:
+            self._steps["diffusion_loss"] = jax.jit(
+                lambda p, b, i, key: self._masked_token_loss(
+                    p, b, i, *self._noise(key, i))[0])
+        return self._steps["diffusion_loss"](
+            self.params_, self.state_["router_bias"],
+            jnp.asarray(ids, jnp.int32), key)
 
     def expert_load(self) -> np.ndarray:
         """[expert layers, n_experts] tokens that chose each expert over all
@@ -609,6 +802,16 @@ class DecoderModel:
                 "bound": row_bound(self._pairs, c.held, c.n_experts),
                 "pairs": self._pairs}
 
+    def noise_stats(self) -> Dict[str, Any]:
+        """What the diffusion objective's noise did over all train steps so
+        far (one device read): `steps`; `masked_positions`, the positions
+        that were replaced and carried loss; `share` of the clean tokens
+        that is, at the newest batch shape (about a half: the mean of t)."""
+        masked = int(self.state_["masked_positions"])
+        seen = self.iteration * self._tokens
+        return {"steps": self.iteration, "masked_positions": masked,
+                "share": masked / seen if seen else 0.0}
+
     def num_params(self) -> int:
         return sum(int(np.prod(l.shape))
                    for l in jax.tree_util.tree_leaves(self.params_))
@@ -621,7 +824,7 @@ class DecoderModel:
         import io, json, zipfile
         with zipfile.ZipFile(path, "w", zipfile.ZIP_DEFLATED) as z:
             z.writestr("config.json", json.dumps(
-                {**dataclasses.asdict(self.config),
+                {**dataclasses.asdict(self.config), "seed": self.seed,
                  "iteration": self.iteration, "epoch": self.epoch}))
             for name in self._TREES:
                 buf = io.BytesIO()
@@ -636,7 +839,9 @@ class DecoderModel:
         with zipfile.ZipFile(path) as z:
             meta = json.loads(z.read("config.json").decode())
             iteration, epoch = meta.pop("iteration"), meta.pop("epoch")
-            model = DecoderModel(DecoderConfig(**meta), updater=updater)
+            seed = meta.pop("seed", 0)      # (files from before it was kept)
+            model = DecoderModel(DecoderConfig(**meta), seed=seed,
+                                 updater=updater)
             for name in model._TREES:
                 leaves, treedef = jax.tree_util.tree_flatten(
                     getattr(model, name))

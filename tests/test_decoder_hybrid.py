@@ -466,7 +466,7 @@ def test_hybrid_fit_steps_and_save_load_round_trip():
     (dict(layer_types=(C, A, C)), "names 3 layers"),
     (dict(layer_types=(C, A, C, C, "mamba")), "mamba"),
     (dict(layer_types=(C, A, C, C, C), n_dense_layers=2), "share one kind"),
-    (dict(n_dense_layers=5), "at least one dense and one expert"),
+    (dict(n_dense_layers=5), "at least one expert layer"),
     (dict(n_heads=3), "no multiple"),
 ])
 def test_a_layer_list_the_model_cannot_build_is_refused(changes, message):

@@ -124,3 +124,31 @@ def test_grouped_query_kernels_compile_for_v5e(one_chip):
         assert f"bf16[{B * Hk},{T},{D}]" in compiled.as_text()
     # dQ over the query's heads, dK and dV over the key-value heads
     assert [o.shape for o in bwd.out_info] == [q[0], kv[0], kv[0]]
+
+
+def test_block_diffusion_kernels_compile_for_v5e(one_chip):
+    """SDAR-30B-A3B's attention layer under the block-diffusion mask: 32
+    query heads over 4 key-value heads of 128, the noisy and the clean copy
+    of one 4,096-token sequence (8,192 rows), blocks of 4 tokens, the tier's
+    512 x 1024 tile.  The mask comes from iota inside the kernels (the only
+    operands are q, k and v); dQ of a group of 8 heads of 128 fits the
+    backward's VMEM 2,048 rows at a time, so it goes in four spans."""
+    B, H, Hk, L, D, dt = 1, 32, 4, 4096, 128, jnp.bfloat16
+    T, mask = 2 * L, (L, 4)
+    q, kv = ((B, H, T, D), dt), ((B, Hk, T, D), dt)
+    fwd = _compile(
+        lambda q, k, v: ak.flash_attention_tpu(
+            q, k, v, block_q=512, block_k=1024, return_lse=True,
+            block_diffusion=mask),
+        one_chip, q, kv, kv)
+    assert fwd.as_text().count("tpu_custom_call") == 1
+    bwd = _compile(
+        lambda q, k, v, out, lse, g: ak.flash_attention_bwd_tpu(
+            q, k, v, out, lse, g, block_q=512, block_k=1024,
+            block_diffusion=mask),
+        one_chip, q, kv, kv, q, ((B * H, T), jnp.float32), q)
+    assert bwd.as_text().count("tpu_custom_call") == 4
+    assert [o.shape for o in bwd.out_info] == [q[0], kv[0], kv[0]]
+    # no [T, T] array in either program
+    for compiled in (fwd, bwd):
+        assert f"{T},{T}]" not in compiled.as_text()
