@@ -9,13 +9,18 @@ cuDNN fused attention plays for the reference's platform helpers:
 - `blockwise_attention`: online-softmax `lax.scan` over KV blocks — O(T)
   memory, XLA-fusable everywhere (CPU tests, any accelerator), and the
   building block ring attention reuses across chips.
-- `flash_attention_tpu` + `flash_attention_bwd_tpu`: Pallas TPU kernels,
-  3D grid (batch*heads, Q blocks, KV blocks) with online-softmax state in
-  VMEM scratch; the forward saves per-row logsumexp and the backward is
-  ONE kernel over (batch*heads, KV blocks, Q blocks) that recomputes P from
-  the logsumexp once a tile and takes dV, dK (tile scratch) and dQ
-  (resident in VMEM across the KV blocks) from the same pass — no [T,T]
-  materialization in either direction, no partial dQ in HBM.
+- `flash_attention_tpu` + `flash_attention_bwd_tpu`: Pallas TPU kernels
+  over a grid (batch*heads, live tile): the second axis walks a
+  `tile_schedule` — the (query block, key block) tiles of which the mask
+  keeps a pair, listed in numpy while the call is traced and handed to the
+  kernel as scalar-prefetch operands that its index maps read — with
+  online-softmax state in VMEM scratch; the forward saves per-row
+  logsumexp and the backward is ONE kernel, key block outer and q blocks
+  inner, that recomputes P from the logsumexp once a tile and takes dV, dK
+  (tile scratch) and dQ (resident in VMEM across the KV blocks) from the
+  same pass — no [T,T] materialization in either direction, no partial dQ
+  in HBM.  A tile the mask leaves empty is no grid step; a tile it keeps
+  whole runs without mask arithmetic.
 - `fused_attention`: measured dispatcher — XLA-fused naive path for short
   sequences (fastest on v5e below ~2k), Pallas kernels for long unmasked
   tiling shapes, blockwise scan for the rest; differentiable everywhere.
@@ -42,16 +47,26 @@ copy (T == S).  A clean row sees the clean rows of its own and earlier
 blocks; a noisy row sees the noisy rows of its own block and the clean rows
 of earlier blocks; no clean row sees a noisy one.  With no noisy rows that
 is attention causal over blocks and bidirectional inside one.  Every branch
-builds it from row and column numbers; the Mosaic kernels skip the tiles it
-leaves empty as they skip causal's, and no [T, S] array reaches HBM.
+builds it from row and column numbers, and no [T, S] array reaches HBM.
+
+Which tiles of a mask the Mosaic kernels visit is said in ONE place,
+`tile_kinds` (numpy, from T, S, the tile, the mask and a span's offset):
+empty, partial or full, for `causal` and the block mask alike (at 512 x
+1024: 48 of a head's 128 tiles live and 24 of them full under the block mask
+on 2 x 4,096 rows; 20 of 32 and 12 causal at 4,096; 72 of 128 and 56 at
+8,192).  `tile_schedule` lists the live ones in the forward's or the
+backward's order with the flags the accumulators need and its own count,
+`(tiles, live, full)`; the kernels hold no liveness test.  With a padding
+`mask` every live tile is partial (the bias is added everywhere).
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -88,21 +103,20 @@ def _check_block_diffusion(T: int, S: int, block_diffusion):
             f"columns in blocks of {B}, not [{T}, {S}]")
 
 
-def _bd_tile(q0, k0, bq: int, bk: int, o: int, B: int):
-    """A [bq, bk] tile of the block-diffusion mask whose first row is `q0`
-    and first column `k0` (scalars; no tile lies across `o`, the first clean
-    row): `(live, qp, kp, dmin, dmax)` — whether any pair in it is kept; the
-    first row's and column's position in its own half; and the kept pairs
-    are those with `dmin <= block(row) - block(column) <= dmax`."""
+def _bd_quadrant(q0, k0, o: int, where):
+    """A tile of the block-diffusion mask whose first row is `q0` and first
+    column `k0` (no tile lies across `o`, the first clean row): `(qp, kp,
+    dmin, dmax)` — the first row's and column's position in its own half,
+    and the kept pairs are those with `dmin <= block(row) - block(column) <=
+    dmax` (none where a clean row meets noisy columns).  Scalars and
+    `jnp.where` inside a kernel, numpy arrays and `np.where` in
+    `tile_kinds`."""
     qn, kn = q0 < o, k0 < o
-    qp = q0 - jnp.where(qn, 0, o)
-    kp = k0 - jnp.where(kn, 0, o)
-    dmin = jnp.where(qn & ~kn, 1, 0)
-    dmax = jnp.where(kn, 0, _NO_LIMIT)
-    most = _block_of(qp + bq - 1, B) - _block_of(kp, B)
-    least = _block_of(qp, B) - _block_of(kp + bk - 1, B)
-    live = (most >= dmin) & (least <= dmax) & (qn | ~kn)
-    return live, qp, kp, dmin, dmax
+    qp = q0 - where(qn, 0, o)
+    kp = k0 - where(kn, 0, o)
+    dmin = where(qn & ~kn, 1, 0)
+    dmax = where(kn, where(qn, 0, -1), _NO_LIMIT)
+    return qp, kp, dmin, dmax
 
 
 def _bd_keep_tile(qp, kp, dmin, dmax, bq: int, bk: int, B: int,
@@ -255,51 +269,160 @@ blockwise_attention.defvjp(_bw_fwd, _bw_bwd)
 
 
 # ---------------------------------------------------------------------------
-# Pallas TPU kernel
+# The tile schedule: which tiles of the mask the kernels visit, and in what
+# order.  Built in numpy while a call is traced, from what is static there.
 # ---------------------------------------------------------------------------
 
-def _flash_kernel(q_ref, k_ref, v_ref, *rest,
-                  block_q: int, block_k: int, nkv: int, causal: bool,
+# bits of `TileSchedule.flags`
+PARTIAL, Q_FIRST, Q_LAST, K_FIRST, K_LAST = 1, 2, 4, 8, 16
+
+
+class TileSchedule(NamedTuple):
+    """The live tiles of one kernel call in the order its grid walks them:
+    int32 arrays of one length that the kernels take as scalar-prefetch
+    operands."""
+    q: np.ndarray       # the tile's query block
+    k: np.ndarray       # its key block
+    flags: np.ndarray   # PARTIAL: the mask cuts the tile (else every pair is
+    #                     kept); Q_FIRST / Q_LAST: the walk's first / last
+    #                     tile of that query block; K_FIRST / K_LAST: of that
+    #                     key block
+    counts: tuple       # (tiles, live, full) of one head's [rows, S]
+    keys_seen: tuple    # the key blocks that hold a live tile, ascending
+
+    @property
+    def kinds(self):
+        """(some tile is partial, some tile is full)."""
+        partial = (self.flags & PARTIAL) != 0
+        return bool(partial.any()), bool((~partial).any())
+
+
+def tile_kinds(T: int, S: int, bq: int, bk: int, causal=False,
+               block_diffusion=None, q_offset=0, rows=None):
+    """int8 [rows // bq, S // bk]: 0 where the mask keeps no pair of the
+    [bq, bk] tile, 1 where it keeps some, 2 where it keeps all.  The queries
+    are `rows` rows from `q_offset` on (a span) of a [T, S] problem; the
+    mask is `causal`, `block_diffusion=(L, B)` or neither.  Both masks keep
+    a pair by `d` = block(row) - block(column) (blocks of 1 for causal), and
+    `d` takes every value between its least and its most over a tile: so the
+    tile's two far corners decide."""
+    rows = T - q_offset if rows is None else rows
+    q0 = q_offset + bq * np.arange(rows // bq)[:, None]
+    k0 = bk * np.arange(S // bk)[None, :]
+    if block_diffusion is not None:
+        L, B = block_diffusion
+        qp, kp, dmin, dmax = _bd_quadrant(q0, k0, T - L, np.where)
+    elif causal:
+        qp, kp, dmin, dmax, B = q0, k0, 0, _NO_LIMIT, 1
+    else:
+        return np.full((rows // bq, S // bk), 2, np.int8)
+    most = (qp + bq - 1) // B - kp // B
+    least = qp // B - (kp + bk - 1) // B
+    live = np.maximum(least, dmin) <= np.minimum(most, dmax)
+    full = (least >= dmin) & (most <= dmax)
+    return live.astype(np.int8) + full
+
+
+def _ends(a):
+    """(first, last) occurrence of each value of `a`, as boolean masks."""
+    first, last = np.zeros(len(a), bool), np.zeros(len(a), bool)
+    first[np.unique(a, return_index=True)[1]] = True
+    last[len(a) - 1 - np.unique(a[::-1], return_index=True)[1]] = True
+    return first, last
+
+
+@functools.lru_cache(maxsize=None)
+def tile_schedule(T: int, S: int, bq: int, bk: int, causal=False,
+                  block_diffusion=None, masked=False, q_offset=0, rows=None,
+                  group=1, keys_outer=False) -> TileSchedule:
+    """The live tiles of `tile_kinds(...)`, the one place that says which
+    tiles a kernel visits and which of them need the mask.  `masked`: a key
+    padding mask rides along, so every live tile is partial.  Forward order
+    (`keys_outer` false): query block outer, key blocks ascending.  Backward
+    order: key block outer, then the `group` query heads of a key-value
+    head, then query blocks; `q` there counts blocks of the group's rows,
+    head after head.  Every query block holds a live tile (asserted: both
+    masks keep a row's own block), a key block need not (`keys_seen`)."""
+    kinds = tile_kinds(T, S, bq, bk, causal, block_diffusion, q_offset, rows)
+    if masked:
+        kinds = np.minimum(kinds, 1)
+    nq, nk = kinds.shape
+    assert (kinds > 0).any(axis=1).all(), "a query block with no live tile"
+    if keys_outer:
+        k, head, q = np.nonzero(
+            np.broadcast_to(kinds.T[:, None, :], (nk, group, nq)))
+    else:
+        q, k = np.nonzero(kinds)
+        head = 0
+    partial = kinds[q, k] == 1
+    q = head * nq + q
+    (q_first, q_last), (k_first, k_last) = _ends(q), _ends(k)
+    flags = (PARTIAL * partial + Q_FIRST * q_first + Q_LAST * q_last
+             + K_FIRST * k_first + K_LAST * k_last)
+    arrays = [np.asarray(a, np.int32) for a in (q, k, flags)]
+    for a in arrays:
+        a.setflags(write=False)
+    return TileSchedule(
+        *arrays, (nq * nk, int((kinds > 0).sum()), int((kinds == 2).sum())),
+        tuple(int(j) for j in np.flatnonzero((kinds > 0).any(axis=0))))
+
+
+def _by_kind(flags, kinds, tile):
+    """`tile(partial)` as this grid step's tile asks; where the schedule
+    holds one kind only (`TileSchedule.kinds`), without asking."""
+    partial, full = kinds
+    if partial and full:
+        pl.when((flags & PARTIAL) != 0)(lambda: tile(True))
+        pl.when((flags & PARTIAL) == 0)(lambda: tile(False))
+    else:
+        tile(partial)
+
+
+# ---------------------------------------------------------------------------
+# Pallas TPU kernels
+# ---------------------------------------------------------------------------
+
+def _flash_kernel(qb_ref, kb_ref, flags_ref, q_ref, k_ref, v_ref, *rest,
+                  block_q: int, block_k: int, kinds, causal: bool,
                   scale: float, has_mask: bool, block_diffusion=None):
-    """3D grid (batch*head, q-block, kv-block): Pallas pipelines the KV
-    block fetches (double-buffered HBM→VMEM) while online-softmax state
-    lives in VMEM scratch across the kv dimension.  Emits per-row
-    logsumexp for the backward kernel.  With ``has_mask`` an additive
-    f32 bias block [1, 1, bk] (0 keep / NEG_INF drop over KV positions)
-    precedes the outputs.  ``block_diffusion``: (noisy rows, block length)
-    of that mask; a tile it leaves empty is skipped like causal's."""
+    """Grid (batch*head, live tile): step `t` is tile (`qb_ref[t]`,
+    `kb_ref[t]`) of a forward `TileSchedule` — a query block's live tiles
+    one after another, key blocks ascending — so a tile the mask leaves
+    empty costs no grid step.  Pallas pipelines the KV block fetches
+    (double-buffered HBM→VMEM) while online-softmax state lives in VMEM
+    scratch from a query block's first tile to its last.  A full tile takes
+    no mask arithmetic; a partial one builds `causal`'s or
+    ``block_diffusion``'s ((noisy rows, block length)) kept pairs from iota.
+    Emits per-row logsumexp for the backward kernel.  With ``has_mask`` an
+    additive f32 bias block [1, 1, bk] (0 keep / NEG_INF drop over KV
+    positions) precedes the outputs and every tile is partial."""
     if has_mask:
         bias_ref, o_ref, lse_ref, acc_sc, m_sc, l_sc = rest
     else:
         o_ref, lse_ref, acc_sc, m_sc, l_sc = rest
         bias_ref = None
-    qi = pl.program_id(1)
-    j = pl.program_id(2)
+    t = pl.program_id(1)
+    qi, j, flags = qb_ref[t], kb_ref[t], flags_ref[t]
 
-    @pl.when(j == 0)
+    @pl.when((flags & Q_FIRST) != 0)
     def _init():
         acc_sc[...] = jnp.zeros_like(acc_sc)
         m_sc[...] = jnp.full_like(m_sc, NEG_INF)
         l_sc[...] = jnp.zeros_like(l_sc)
 
-    # causal: kv blocks fully above the diagonal contribute nothing
-    live = (j * block_k <= qi * block_q + block_q - 1) if causal else True
-    if block_diffusion is not None:
-        live, *kept = _bd_tile(qi * block_q, j * block_k, block_q, block_k,
-                               *block_diffusion)
-
-    @pl.when(live)
-    def _step():
+    def tile(partial):
         q = q_ref[0]                                       # [bq, D]
         kj = k_ref[0]                                      # [bk, D]
         vj = v_ref[0]
         s = jnp.dot(q, kj.T, preferred_element_type=jnp.float32) * scale
         if has_mask:
             s = s + bias_ref[0]                            # [1,bk] → rows
-        if block_diffusion is not None:
-            s = jnp.where(_bd_keep_tile(*kept, block_q, block_k,
-                                        block_diffusion[1]), s, NEG_INF)
-        if causal:
+        if partial and block_diffusion is not None:
+            o, B = block_diffusion
+            s = jnp.where(_bd_keep_tile(
+                *_bd_quadrant(qi * block_q, j * block_k, o, jnp.where),
+                block_q, block_k, B), s, NEG_INF)
+        if partial and causal:
             rows = (qi * block_q
                     + jax.lax.broadcasted_iota(jnp.int32,
                                                (block_q, block_k), 0))
@@ -316,7 +439,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest,
             p.astype(vj.dtype), vj, preferred_element_type=jnp.float32)
         m_sc[...] = m_new
 
-    @pl.when(j == nkv - 1)
+    _by_kind(flags, kinds, tile)
+
+    @pl.when((flags & Q_LAST) != 0)
     def _finalize():
         l = l_sc[...]
         o_ref[0] = (acc_sc[...] / l).astype(o_ref.dtype)
@@ -348,19 +473,19 @@ def flash_attention_tpu(q, k, v, causal=False, scale=None,
     """Pallas flash-attention forward.  q [B, H, T, D], k [B, Hk, S, D],
     v [B, Hk, S, Dv] -> [B, H, T, Dv]; T and S divisible by the block sizes
     (dispatcher checks), H a multiple of Hk (a key-value head's blocks are
-    fetched for each of its query heads, from where they lie).  With
-    ``return_lse`` also returns the row logsumexp [B*H, T] (f32) for the
-    backward kernel.  ``mask``: optional [B, S] 1/0 keep-mask over KV
-    positions (padding/segment mask), shared across heads.
-    ``block_diffusion``: (L, B) of the module docstring's mask; the blocks
-    divide L, so that no tile lies across the first clean row."""
+    fetched for each of its query heads, from where they lie).  The grid's
+    second axis walks `tile_schedule`'s live tiles.  With ``return_lse``
+    also returns the row logsumexp [B*H, T] (f32) for the backward kernel.
+    ``mask``: optional [B, S] 1/0 keep-mask over KV positions
+    (padding/segment mask), shared across heads.  ``block_diffusion``:
+    (L, B) of the module docstring's mask; the blocks divide L, so that no
+    tile lies across the first clean row."""
     B, H, T, D = q.shape
     S, Dv = k.shape[2], v.shape[3]
     if scale is None:
         scale = D ** -0.5
     bq = min(block_q, T)
     bk = min(block_k, S)
-    nkv = S // bk
     bd = _bd_static(T, S, bq, bk, block_diffusion)
     Hk = k.shape[1]
     group = H // Hk
@@ -371,56 +496,48 @@ def flash_attention_tpu(q, k, v, causal=False, scale=None,
     kf = k.reshape(B * Hk, S, D)
     vf = v.reshape(B * Hk, S, Dv)
     has_mask = mask is not None
+    sched = tile_schedule(T, S, bq, bk, causal, block_diffusion, has_mask)
     kernel = functools.partial(_flash_kernel, block_q=bq, block_k=bk,
-                               nkv=nkv, causal=causal, scale=scale,
+                               kinds=sched.kinds, causal=causal, scale=scale,
                                has_mask=has_mask, **bd)
-
-    def kv_block(i, j):
-        # an empty tile of the block-diffusion mask names a block the row
-        # of tiles needs anyway (the q block's own noisy keys, the first
-        # clean keys of a clean one): nothing new is fetched for it
-        if not bd:
-            return j
-        o = bd["block_diffusion"][0]
-        live = _bd_tile(i * bq, j * bk, bq, bk, *bd["block_diffusion"])[0]
-        return jnp.where(live, j,
-                         jnp.where(i * bq < o, i * bq // bk, o // bk))
-
+    # the index maps read step t's blocks from the schedule (in SMEM)
+    q_rows = lambda b, t, qb, kb, _: (b, qb[t], 0)
+    k_rows = lambda b, t, qb, kb, _: (kv_row(b), kb[t], 0)
     in_specs = [
-        pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, bk, D),
-                     lambda b, i, j: (kv_row(b), kv_block(i, j), 0)),
-        pl.BlockSpec((1, bk, Dv),
-                     lambda b, i, j: (kv_row(b), kv_block(i, j), 0)),
+        pl.BlockSpec((1, bq, D), q_rows),
+        pl.BlockSpec((1, bk, D), k_rows),
+        pl.BlockSpec((1, bk, Dv), k_rows),
     ]
     inputs = [qf, kf, vf]
     if has_mask:
         # bias [B, 1, S]: per-batch, shared across the H heads folded into
         # grid dim 0 — the index map divides the head out
-        in_specs.append(pl.BlockSpec((1, 1, bk),
-                                     lambda b, i, j, H=H: (b // H, 0, j)))
+        in_specs.append(pl.BlockSpec(
+            (1, 1, bk), lambda b, t, qb, kb, _, H=H: (b // H, 0, kb[t])))
         inputs.append(_mask_bias3(mask, B, S))
     out, lse = pl.pallas_call(
         kernel,
-        grid=(B * H, T // bq, nkv),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0)),
-            # lse rides a trailing singleton lane dim — (1, bq, 1) blocks
-            # satisfy the TPU (8, 128)-or-full tiling rule
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B * H, len(sched.q)),
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((1, bq, Dv), q_rows),
+                # lse rides a trailing singleton lane dim — (1, bq, 1)
+                # blocks satisfy the TPU (8, 128)-or-full tiling rule
+                pl.BlockSpec((1, bq, 1), q_rows),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bq, Dv), jnp.float32),
+                pltpu.VMEM((bq, 1), jnp.float32),
+                pltpu.VMEM((bq, 1), jnp.float32),
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct((B * H, T, Dv), q.dtype),
             jax.ShapeDtypeStruct((B * H, T, 1), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, Dv), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-        ],
         interpret=interpret,
-    )(*inputs)
+    )(sched.q, sched.k, sched.flags, *inputs)
     out = out.reshape(B, H, T, Dv)
     return (out, lse.reshape(B * H, T)) if return_lse else out
 
@@ -438,54 +555,45 @@ _BWD_DQ_VMEM = 16 << 20
 _NT = (((1,), (1,)), ((), ()))                 # a [m, c], b [n, c] -> [m, n]
 
 
-def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-                      block_q: int, block_k: int, nq: int, nkv: int,
-                      head_blocks: int, q_offset: int, causal: bool,
+def _flash_bwd_kernel(qb_ref, kb_ref, flags_ref, q_ref, k_ref, v_ref, do_ref,
+                      lse_ref, delta_ref, *rest, block_q: int, block_k: int,
+                      kinds, head_blocks: int, q_offset: int, causal: bool,
                       scale: float, has_mask: bool, block_diffusion=None):
-    """dQ, dK and dV over grid (batch*head, kv-block, q-block), the q blocks
-    innermost.  A live tile recomputes P from the saved logsumexp and
-    computes dP and dS once; from them dV += P^T dO and dK += dS^T Q into
-    the kv block's scratch and dQ += dS K into the rows of a [span, D]
-    scratch that stays in VMEM across the kv axis: five products, no [T, T]
-    array and no partial dQ in HBM.  The tile is held keys-by-queries
-    ([bk, bq]), so that dV and dK are plain products, dQ's is the one
-    transposed operand and the row statistics lie along lanes ([1, bq]).
-    ``q_offset`` is the first query's position (a span of a longer
-    sequence).  The ``nq`` q blocks of a grid row are ``head_blocks`` blocks
-    of each query head of a group, head after head (``nq`` itself where
-    every head has its own keys): a block's positions start anew with each
-    head, and dK/dV sum over all of them.  ``block_diffusion``: (noisy rows,
-    block length) of that mask, as in the forward kernel."""
+    """dQ, dK and dV over grid (batch*key-value head, live tile): step `t`
+    is tile (`qb_ref[t]`, `kb_ref[t]`) of a backward `TileSchedule` — a key
+    block's live tiles one after another, the q blocks innermost.  A tile
+    recomputes P from the saved logsumexp and computes dP and dS once; from
+    them dV += P^T dO and dK += dS^T Q into the kv block's scratch (zeroed
+    at the block's first tile, written at its last) and dQ += dS K into the
+    rows of a [span, D] scratch that stays in VMEM across the key blocks
+    (zeroed at a q block's first visit, written at its last): five products,
+    no [T, T] array and no partial dQ in HBM.  The tile is held
+    keys-by-queries ([bk, bq]), so that dV and dK are plain products, dQ's
+    is the one transposed operand and the row statistics lie along lanes
+    ([1, bq]).  ``q_offset`` is the first query's position (a span of a
+    longer sequence).  The q blocks of a grid row are ``head_blocks`` blocks
+    of each query head of a group, head after head: a block's positions
+    start anew with each head, and dK/dV sum over all of them.  Full and
+    partial tiles, ``block_diffusion``: as in the forward kernel."""
     if has_mask:
         bias_ref, dq_ref, dk_ref, dv_ref, dq_sc, dk_sc, dv_sc = rest
     else:
         dq_ref, dk_ref, dv_ref, dq_sc, dk_sc, dv_sc = rest
         bias_ref = None
-    j = pl.program_id(1)
-    i = pl.program_id(2)
+    t = pl.program_id(1)
+    i, j, flags = qb_ref[t], kb_ref[t], flags_ref[t]
     rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
 
-    def first_q():      # the block's first query position in the span
-        return (i if head_blocks == nq else i % head_blocks) * block_q
-
-    @pl.when(i == 0)
+    @pl.when((flags & K_FIRST) != 0)
     def _init_dkv():
         dk_sc[...] = jnp.zeros_like(dk_sc)
         dv_sc[...] = jnp.zeros_like(dv_sc)
 
-    @pl.when(j == 0)
+    @pl.when((flags & Q_FIRST) != 0)
     def _init_dq():
         dq_sc[rows, :] = jnp.zeros((block_q, dq_sc.shape[1]), jnp.float32)
 
-    # causal: q blocks strictly above the kv block's diagonal see nothing
-    live = (q_offset + first_q() + block_q - 1 >= j * block_k) \
-        if causal else True
-    if block_diffusion is not None:
-        live, *kept = _bd_tile(q_offset + first_q(), j * block_k, block_q,
-                               block_k, *block_diffusion)
-
-    @pl.when(live)
-    def _step():
+    def tile(partial):
         q = q_ref[0]                                       # [bq, D]
         do = do_ref[0]                                     # [bq, Dv]
         kj = k_ref[0]                                      # [bk, D]
@@ -494,15 +602,18 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
             kj, q, _NT, preferred_element_type=jnp.float32) * scale
         if has_mask:
             s = s + bias_ref[0]                            # [bk, 1] → cols
-        if block_diffusion is not None:
-            s = jnp.where(
-                _bd_keep_tile(*kept, block_q, block_k, block_diffusion[1],
-                              keys_first=True), s, NEG_INF)
-        if causal:
+        # the block's first query position: blocks start anew with a head
+        q0 = q_offset + i % head_blocks * block_q
+        if partial and block_diffusion is not None:
+            o, B = block_diffusion
+            s = jnp.where(_bd_keep_tile(
+                *_bd_quadrant(q0, j * block_k, o, jnp.where),
+                block_q, block_k, B, keys_first=True), s, NEG_INF)
+        if partial and causal:
             keys = (j * block_k
                     + jax.lax.broadcasted_iota(jnp.int32,
                                                (block_k, block_q), 0))
-            queries = (q_offset + first_q()
+            queries = (q0
                        + jax.lax.broadcasted_iota(jnp.int32,
                                                   (block_k, block_q), 1))
             s = jnp.where(queries >= keys, s, NEG_INF)
@@ -516,12 +627,14 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         dq_sc[rows, :] += jnp.dot(ds.T, kj,
                                   preferred_element_type=jnp.float32)
 
-    @pl.when(i == nq - 1)
+    _by_kind(flags, kinds, tile)
+
+    @pl.when((flags & K_LAST) != 0)
     def _write_dkv():
         dk_ref[0] = (dk_sc[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
 
-    @pl.when(j == nkv - 1)
+    @pl.when((flags & Q_LAST) != 0)
     def _write_dq():
         dq_ref[0, rows, :] = (dq_sc[rows, :] * scale).astype(dq_ref.dtype)
 
@@ -550,6 +663,24 @@ def _bwd_plan(T, S, D, Dv, itemsize, block_q, block_k, group=1):
     return bq, bk, min(T, max(bq, rows // bq * bq))
 
 
+def _sum_over_spans(parts, seen, bk, dtype):
+    """dK or dV [B*Hk, S, .] from the spans' `parts`, of which span `s`
+    wrote the key blocks `seen[s]` alone: their sum under each span's static
+    mask of the rows it wrote — a select, so that what an unwritten block
+    holds never reaches the sum, and keys that no query sees get zeros
+    (causal with S > T).  (Slicing the written blocks out and concatenating
+    the runs reads half the bytes, but XLA makes three passes of it: 0.67 ms
+    a step slower on SDAR's cell than this one fusion, PERF.md, PR 34.)"""
+    nkv = parts[0].shape[1] // bk
+    if len(parts) == 1 and len(seen[0]) == nkv:
+        return parts[0]
+    wrote = [np.repeat(np.isin(np.arange(nkv), blocks), bk)[None, :, None]
+             for blocks in seen]
+    return functools.reduce(jnp.add, (
+        part if mask.all() else jnp.where(mask, part, 0)
+        for part, mask in zip(parts, wrote))).astype(dtype)
+
+
 def flash_attention_bwd_tpu(q, k, v, out, lse, g, causal=False, scale=None,
                             block_q=256, block_k=256, interpret=False,
                             mask=None, block_diffusion=None):
@@ -562,14 +693,16 @@ def flash_attention_bwd_tpu(q, k, v, out, lse, g, causal=False, scale=None,
     whole (batch, head) stays in VMEM while its kv blocks pass, within a
     budget of 16 MiB (`_BWD_DQ_VMEM`: 10,922 rows at keys of 192 in
     bfloat16); a longer sequence is cut into spans of queries that fit, one
-    call each over all the keys, and dK/dV are summed over the spans in
-    float32.  Grouped-query heads (k, v [B, Hk, S, .]): a grid row is a
-    KEY-VALUE head and its q blocks are those of the group's query heads,
-    head after head, so dK and dV accumulate over the group in the kernel's
-    scratch and dQ of the whole group is resident (32,768 rows of 64: the
-    whole budget at `[1, 32, 8192, 64]` over 8).  Measured there against
-    per-head dK/dV written in float32 and summed after: 9.68 against 10.37
-    ms (PERF.md, PR 31)."""
+    call each over the key blocks its `tile_schedule` holds a live tile of,
+    and dK/dV are summed in float32 over the spans that saw a block (a span
+    writes no other block of its dK/dV, and `_sum_over_spans` masks the
+    rest out).
+    Grouped-query heads (k, v [B, Hk, S, .]): a grid row is a KEY-VALUE head
+    and its q blocks are those of the group's query heads, head after head,
+    so dK and dV accumulate over the group in the kernel's scratch and dQ of
+    the whole group is resident (32,768 rows of 64: the whole budget at `[1,
+    32, 8192, 64]` over 8).  Measured there against per-head dK/dV written
+    in float32 and summed after: 9.68 against 10.37 ms (PERF.md, PR 31)."""
     B, H, T, D = q.shape
     S, Dv = k.shape[2], v.shape[3]
     Hk = k.shape[1]
@@ -578,7 +711,6 @@ def flash_attention_bwd_tpu(q, k, v, out, lse, g, causal=False, scale=None,
         scale = D ** -0.5
     bq, bk, span = _bwd_plan(T, S, D, Dv, q.dtype.itemsize, block_q, block_k,
                              G)
-    nkv = S // bk
     bd = _bd_static(T, S, bq, bk, block_diffusion)
     qf = q.reshape(B * H, T, D)
     kf = k.reshape(B * Hk, S, D)
@@ -594,25 +726,17 @@ def flash_attention_bwd_tpu(q, k, v, out, lse, g, causal=False, scale=None,
         # bias [B, S, 1] along the tile's rows: per-batch, shared across the
         # heads folded into grid dim 0 — the index map divides the head out
         extra_in = [_mask_bias3(mask, B, S).reshape(B, S, 1)]
-        extra_specs = [pl.BlockSpec((1, bk, 1),
-                                    lambda b, j, i, H=Hk: (b // H, j, 0))]
+        extra_specs = [pl.BlockSpec(
+            (1, bk, 1), lambda b, t, qb, kb, _, H=Hk: (b // H, kb[t], 0))]
 
     def one_span(t0, t1, part_dtype=None):
-        """The kernel over queries [t0, t1) and all the keys; dK and dV in
-        ``part_dtype`` where they are one span's part of a sum."""
+        """The kernel over queries [t0, t1) and the keys they see: `(dq, dk,
+        dv), key blocks seen`; dK and dV in ``part_dtype`` where they are
+        one span's part of a sum."""
         n = t1 - t0
         nq = n // bq                    # q blocks of one head
-
-        def qi(j, i):
-            # a dead causal tile names the kv block's first live q block of
-            # its head, which the next step needs anyway: nothing is fetched
-            # for it
-            if not causal:
-                return i
-            first = jnp.minimum(jnp.maximum(j * bk - t0, 0) // bq, nq - 1)
-            if G == 1:
-                return jnp.maximum(i, first)
-            return i - i % nq + jnp.maximum(i % nq, first)
+        sched = tile_schedule(T, S, bq, bk, causal, block_diffusion,
+                              has_mask, t0, n, G, True)
 
         def rows(a):        # [B*H, T, .] -> the span's rows of a group,
             a = a[:, t0:t1]                         # head after head
@@ -622,52 +746,57 @@ def flash_attention_bwd_tpu(q, k, v, out, lse, g, causal=False, scale=None,
             return a[:, t0:t1].reshape(B * Hk, G * nq, 1, bq)
 
         kernel = functools.partial(
-            _flash_bwd_kernel, block_q=bq, block_k=bk, nq=G * nq, nkv=nkv,
+            _flash_bwd_kernel, block_q=bq, block_k=bk, kinds=sched.kinds,
             head_blocks=nq, q_offset=t0, causal=causal, scale=scale,
             has_mask=has_mask, **bd)
+        q_rows = lambda b, t, qb, kb, _: (b, qb[t], 0)
+        k_rows = lambda b, t, qb, kb, _: (b, kb[t], 0)
         stat_spec = pl.BlockSpec((1, 1, 1, bq),
-                                 lambda b, j, i: (b, qi(j, i), 0, 0))
+                                 lambda b, t, qb, kb, _: (b, qb[t], 0, 0))
         return pl.pallas_call(
             kernel,
-            grid=(B * Hk, nkv, G * nq),
-            in_specs=[
-                pl.BlockSpec((1, bq, D), lambda b, j, i: (b, qi(j, i), 0)),
-                pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
-                pl.BlockSpec((1, bk, Dv), lambda b, j, i: (b, j, 0)),
-                pl.BlockSpec((1, bq, Dv), lambda b, j, i: (b, qi(j, i), 0)),
-                stat_spec, stat_spec,
-            ] + extra_specs,
-            out_specs=[
-                # dQ: one block a (batch, key-value head), written back
-                # when it ends
-                pl.BlockSpec((1, G * n, D), lambda b, j, i: (b, 0, 0)),
-                pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
-                pl.BlockSpec((1, bk, Dv), lambda b, j, i: (b, j, 0)),
-            ],
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3,
+                grid=(B * Hk, len(sched.q)),
+                in_specs=[
+                    pl.BlockSpec((1, bq, D), q_rows),
+                    pl.BlockSpec((1, bk, D), k_rows),
+                    pl.BlockSpec((1, bk, Dv), k_rows),
+                    pl.BlockSpec((1, bq, Dv), q_rows),
+                    stat_spec, stat_spec,
+                ] + extra_specs,
+                out_specs=[
+                    # dQ: one block a (batch, key-value head), written back
+                    # when it ends
+                    pl.BlockSpec((1, G * n, D),
+                                 lambda b, t, qb, kb, _: (b, 0, 0)),
+                    pl.BlockSpec((1, bk, D), k_rows),
+                    pl.BlockSpec((1, bk, Dv), k_rows),
+                ],
+                scratch_shapes=[
+                    pltpu.VMEM((G * n, D), jnp.float32),
+                    pltpu.VMEM((bk, D), jnp.float32),
+                    pltpu.VMEM((bk, Dv), jnp.float32),
+                ]),
             out_shape=[
                 jax.ShapeDtypeStruct((B * Hk, G * n, D), q.dtype),
                 jax.ShapeDtypeStruct((B * Hk, S, D), part_dtype or k.dtype),
                 jax.ShapeDtypeStruct((B * Hk, S, Dv), part_dtype or v.dtype),
             ],
-            scratch_shapes=[
-                pltpu.VMEM((G * n, D), jnp.float32),
-                pltpu.VMEM((bk, D), jnp.float32),
-                pltpu.VMEM((bk, Dv), jnp.float32),
-            ],
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=_BWD_VMEM_LIMIT),
             interpret=interpret,
-        )(rows(qf), kf, vf, rows(gf), stat(lse), stat(delta), *extra_in)
+        )(sched.q, sched.k, sched.flags, rows(qf), kf, vf, rows(gf),
+          stat(lse), stat(delta), *extra_in), sched.keys_seen
 
-    if span >= T:
-        dq, dk, dv = one_span(0, T)
-    else:
-        parts = [one_span(t0, min(t0 + span, T), jnp.float32)
-                 for t0 in range(0, T, span)]
-        dq = jnp.concatenate(
-            [part[0].reshape(B * H, -1, D) for part in parts], axis=1)
-        dk = sum(part[1] for part in parts).astype(k.dtype)
-        dv = sum(part[2] for part in parts).astype(v.dtype)
+    spans = range(0, T, span)
+    parts, seen = zip(*(
+        one_span(t0, min(t0 + span, T), jnp.float32 if len(spans) > 1 else None)
+        for t0 in spans))
+    dq = parts[0][0] if len(spans) == 1 else jnp.concatenate(
+        [part[0].reshape(B * H, -1, D) for part in parts], axis=1)
+    dk = _sum_over_spans([part[1] for part in parts], seen, bk, k.dtype)
+    dv = _sum_over_spans([part[2] for part in parts], seen, bk, v.dtype)
     return (dq.reshape(B, H, T, D), dk.reshape(B, Hk, S, D),
             dv.reshape(B, Hk, S, Dv))
 

@@ -40,7 +40,11 @@ benchmark build:
   forward a block-wise denoising decode would run.
 
 All of them through `ops/attention_kernels.fused_attention` (the flash
-kernels from 2k tokens on the chip).  `F_l` is a SwiGLU for the first
+kernels from 2k tokens on the chip: forward and backward walk the mask's
+live tiles by `tile_schedule`, so neither causal's upper triangle nor the
+block mask's empty quadrant and off-diagonal costs a grid step, and the
+backward's spans of queries write dK/dV for the key blocks they see alone).
+`F_l` is a SwiGLU for the first
 `n_dense_layers` layers (there may be none) and `ops/moe.expert_layer`
 after: a sigmoid router over `n_experts` with a selection bias that the step
 updates (no auxiliary loss), or the softmax router; top-k.

@@ -460,6 +460,206 @@ def test_gradient_through_the_padded_ragged_tail(causal, masked):
 
 
 # ---------------------------------------------------------------------------
+# The tile schedule (`tile_schedule`): the live tiles the kernels walk
+# ---------------------------------------------------------------------------
+
+def _keep_by_rule(T, S, causal, block_diffusion):
+    """The mask as a boolean [T, S], pair by pair, from the rules the XLA
+    branches apply."""
+    from deeplearning4j_tpu.ops.attention_kernels import block_diffusion_keep
+    rows = np.arange(T, dtype=np.int32)[:, None]
+    cols = np.arange(S, dtype=np.int32)[None, :]
+    if block_diffusion is not None:
+        return np.asarray(block_diffusion_keep(jnp.asarray(rows),
+                                               jnp.asarray(cols), T,
+                                               *block_diffusion))
+    return rows >= cols if causal else np.ones((T, S), bool)
+
+
+# (T, S, D, query heads, key-value heads, mask, the tier's tile) of a cell's
+# attention layer, and what its schedules hold: (tiles, live, full) a head
+# forward; per backward span (first query, live tiles, key blocks seen)
+_CELLS = {
+    "sdar": ((8192, 8192, 128, 32, 4, dict(block_diffusion=(4096, 4))),
+             (128, 48, 24),
+             [(0, 10, (0, 1, 4, 5)), (2048, 18, (2, 3, 4, 5, 6, 7)),
+              (4096, 6, (4, 5)), (6144, 14, (4, 5, 6, 7))]),
+    "kanana": ((4096, 4096, 192, 32, 32, dict(causal=True)),
+               (32, 20, 12), [(0, 20, (0, 1, 2, 3))]),
+    "lfm2": ((8192, 8192, 64, 32, 8, dict(causal=True)),
+             (128, 72, 56), [(0, 72, tuple(range(8)))]),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELLS))
+def test_schedule_counts_of_the_decoder_cells(cell):
+    """What the three decoder cells' kernels walk at the tier's 512 x 1024
+    tile, pinned: pure numpy, no kernel."""
+    from deeplearning4j_tpu.ops import pallas as tier
+    from deeplearning4j_tpu.ops.attention_kernels import (_bwd_plan,
+                                                          tile_schedule)
+    (T, S, D, H, Hk, mask), forward, spans = _CELLS[cell]
+    tile = tier.dispatch.get_tile("attention", tier.shape_class(t=T, s=S, d=D))
+    assert (tile.block_q, tile.block_kv) == (512, 1024)
+    fwd = tile_schedule(T, S, 512, 1024, **mask)
+    assert fwd.counts == forward and len(fwd.q) == forward[1]
+    bq, bk, span = _bwd_plan(T, S, D, 128 if cell == "kanana" else D, 2,
+                             512, 1024, H // Hk)
+    assert (bq, bk) == (512, 1024) and [t0 for t0, _, _ in spans] == list(
+        range(0, T, span))
+    for t0, live, keys in spans:
+        bwd = tile_schedule(T, S, bq, bk, q_offset=t0, rows=span,
+                            group=H // Hk, keys_outer=True, **mask)
+        assert (bwd.counts[1], bwd.keys_seen) == (live, keys)
+        assert len(bwd.q) == live * (H // Hk)
+    assert sum(live for _, live, _ in spans) == forward[1]
+
+
+@pytest.mark.parametrize("blocks", [(16, 16), (8, 32), (32, 8)],
+                         ids=lambda b: f"{b[0]}x{b[1]}")
+@pytest.mark.parametrize("case", [
+    dict(T=64, S=64, causal=True),
+    dict(T=32, S=96, causal=True),
+    dict(T=96, S=32, causal=True),
+    dict(T=64, S=64),
+    dict(T=64, S=64, block_diffusion=(32, 4)),
+    dict(T=64, S=64, block_diffusion=(64, 4)),
+    dict(T=64, S=64, block_diffusion=(32, 32)),
+    dict(T=192, S=192, block_diffusion=(96, 3)),
+    dict(T=64, S=64, block_diffusion=(32, 1)),
+], ids=lambda c: "-".join(f"{k}{v}" for k, v in c.items()).replace(" ", ""))
+@pytest.mark.parametrize("span", [None, 32], ids=["whole", "spans"])
+def test_schedule_against_brute_force(case, span, blocks):
+    """A tile is live iff the mask keeps a pair of it and full iff it keeps
+    them all; the forward walks the live tiles a query block at a time, key
+    blocks ascending, the backward a key block at a time, then the heads of
+    a group, then query blocks; the flags mark each block's first and last
+    tile of the walk.  Over causal, the block mask on L and 2L rows (blocks
+    of 1, 3, 4 and L), T != S, tiles wider than tall and taller than wide,
+    and spans of queries with an offset."""
+    from deeplearning4j_tpu.ops.attention_kernels import (
+        K_FIRST, K_LAST, PARTIAL, Q_FIRST, Q_LAST, tile_kinds, tile_schedule)
+    case = dict(case)
+    T, S = case.pop("T"), case.pop("S")
+    bq, bk = blocks
+    keep = _keep_by_rule(T, S, case.get("causal", False),
+                         case.get("block_diffusion"))
+    n = span or T
+    for t0 in range(0, T, n):
+        tiles = keep[t0:t0 + n].reshape(n // bq, bq, S // bk, bk)
+        kinds = tiles.any((1, 3)).astype(int) + tiles.all((1, 3))
+        np.testing.assert_array_equal(
+            tile_kinds(T, S, bq, bk, q_offset=t0, rows=n, **case), kinds)
+        fwd = tile_schedule(T, S, bq, bk, q_offset=t0, rows=n, **case)
+        assert fwd.counts == (kinds.size, (kinds > 0).sum(), (kinds == 2).sum())
+        want = [(i, j) for i in range(n // bq) for j in range(S // bk)
+                if kinds[i, j]]
+        assert list(zip(fwd.q, fwd.k)) == want
+        for t, (i, j) in enumerate(want):
+            assert bool(fwd.flags[t] & PARTIAL) == (kinds[i, j] == 1)
+            assert bool(fwd.flags[t] & Q_FIRST) == (t == 0 or want[t - 1][0] != i)
+            assert bool(fwd.flags[t] & Q_LAST) == (
+                t == len(want) - 1 or want[t + 1][0] != i)
+        G, nq = 2, n // bq
+        bwd = tile_schedule(T, S, bq, bk, q_offset=t0, rows=n, group=G,
+                            keys_outer=True, **case)
+        want = [(h * nq + i, j) for j in range(S // bk) for h in range(G)
+                for i in range(nq) if kinds[i, j]]
+        assert list(zip(bwd.q, bwd.k)) == want and bwd.counts == fwd.counts
+        assert bwd.keys_seen == tuple(np.flatnonzero(kinds.any(0)))
+        for t, (i, j) in enumerate(want):
+            before, after = want[:t], want[t + 1:]
+            assert bool(bwd.flags[t] & PARTIAL) == (kinds[i % nq, j] == 1)
+            assert bool(bwd.flags[t] & K_FIRST) == all(b != j for _, b in before)
+            assert bool(bwd.flags[t] & K_LAST) == all(b != j for _, b in after)
+            assert bool(bwd.flags[t] & Q_FIRST) == all(a != i for a, _ in before)
+            assert bool(bwd.flags[t] & Q_LAST) == all(a != i for a, _ in after)
+
+
+def test_a_padding_mask_makes_every_live_tile_partial():
+    """With a key mask the bias is added in every tile, so none is full, and
+    liveness is causal's alone."""
+    from deeplearning4j_tpu.ops.attention_kernels import tile_schedule
+    plain = tile_schedule(64, 64, 16, 16, True)
+    masked = tile_schedule(64, 64, 16, 16, True, None, True)
+    assert plain.counts == (16, 10, 6) and masked.counts == (16, 10, 0)
+    assert plain.kinds == (True, True) and masked.kinds == (True, False)
+    assert tile_schedule(64, 64, 16, 16).kinds == (False, True)
+    np.testing.assert_array_equal(plain.q, masked.q)
+    np.testing.assert_array_equal(plain.k, masked.k)
+
+
+def _lse_under(keep, q, k, scale):
+    group = q.shape[1] // k.shape[1]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, jnp.repeat(k, group, 1)) * scale
+    return jax.nn.logsumexp(jnp.where(keep, s, -jnp.inf), -1)
+
+
+@pytest.mark.parametrize("case", [
+    dict(causal=True),
+    dict(block_diffusion=(128, 4)),
+    dict(T=128, S=128, block_diffusion=(128, 8)),
+    dict(masked=True),
+    dict(masked=True, causal=True),
+    dict(H=8, Hk=2, causal=True),
+    dict(H=8, Hk=2, block_diffusion=(128, 4), dq_rows=64),
+    dict(causal=True, dq_rows=64),
+    dict(T=128, S=256, causal=True),
+    dict(T=256, S=128, causal=True),
+], ids=lambda c: "-".join(f"{k}{v}" for k, v in c.items()).replace(" ", ""))
+@pytest.mark.parametrize("blocks", [(32, 64), (64, 32)],
+                         ids=lambda b: f"{b[0]}x{b[1]}")
+def test_kernels_on_the_schedule_match_reference(case, blocks, monkeypatch):
+    """Forward (`out`, logsumexp) and backward (dQ, dK, dV) of the kernels
+    that walk the live tiles, against `mha_reference`: causal, the block
+    mask on 2L and on L rows, a padding mask, padding + causal, grouped
+    heads, a backward cut into spans some of which see only some key blocks
+    (a block no query of a span sees is never written: reading it would
+    read NaN here), and keys that no query sees at all (dK = dV = 0)."""
+    import deeplearning4j_tpu.ops.attention_kernels as ak
+    case = dict(case)
+    T, S = case.pop("T", 256), case.pop("S", 256)
+    H, Hk = case.pop("H", 2), case.pop("Hk", 2)
+    dq_rows, masked = case.pop("dq_rows", None), case.pop("masked", False)
+    bq, bk = blocks
+    rng = np.random.RandomState(5)
+    q, k, v, g = (jnp.asarray(rng.randn(1, h, n, 32).astype(np.float32) * 0.3)
+                  for h, n in ((H, T), (Hk, S), (Hk, S), (H, T)))
+    if masked:
+        keep = np.ones((1, S), np.float32)
+        keep[0, S - 70:] = 0.0
+        case["mask"] = jnp.asarray(keep)
+    if dq_rows:
+        monkeypatch.setattr(ak, "_BWD_DQ_VMEM", dq_rows * (H // Hk) * 32 * 12)
+        assert ak._bwd_plan(T, S, 32, 32, 4, bq, bk, H // Hk)[2] == dq_rows
+        seen = [ak.tile_schedule(
+            T, S, bq, bk, case.get("causal", False),
+            case.get("block_diffusion"), masked, t0, dq_rows, H // Hk,
+            True).keys_seen for t0 in range(0, T, dq_rows)]
+        assert any(len(blocks) < S // bk for blocks in seen), seen
+    out, lse = ak.flash_attention_tpu(q, k, v, block_q=bq, block_k=bk,
+                                      interpret=True, return_lse=True, **case)
+    got = ak.flash_attention_bwd_tpu(q, k, v, out, lse, g, block_q=bq,
+                                     block_k=bk, interpret=True, **case)
+    ref = lambda q, k, v: mha_reference(q, k, v, **case)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref(q, k, v)),
+                               rtol=2e-5, atol=2e-5)
+    keep = _keep_by_rule(T, S, case.get("causal", False),
+                         case.get("block_diffusion"))
+    if masked:
+        keep = keep & (np.asarray(case["mask"])[0] > 0)[None, :]
+    np.testing.assert_allclose(
+        np.asarray(lse).reshape(1, H, T),
+        np.asarray(_lse_under(keep, q, k, 32 ** -0.5)), rtol=2e-5, atol=2e-5)
+    want = jax.grad(lambda q, k, v: jnp.sum(ref(q, k, v) * g),
+                    (0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
 # Pallas fused LayerNorm (ops/norm_kernels.py) — interpret-mode correctness
 # vs the jnp reference, values and gradients
 # ---------------------------------------------------------------------------

@@ -158,6 +158,94 @@ def test_backward_in_spans_under_the_block_mask(monkeypatch):
         np.testing.assert_allclose(a, b, atol=1e-5)
 
 
+@pytest.mark.parametrize("blocks", [(16, 32), (32, 16), (8, 8)],
+                         ids=lambda b: f"{b[0]}x{b[1]}")
+@pytest.mark.parametrize("T,L,B", [(64, 32, 4), (32, 32, 4), (64, 32, 32),
+                                   (64, 32, 1), (192, 96, 3)])
+def test_the_schedule_of_live_tiles_is_the_mask_written_out(T, L, B, blocks):
+    """`tile_schedule` under the block mask against the mask pair by pair:
+    it walks the tiles that keep a pair and no other, and calls full those
+    that keep every pair."""
+    bq, bk = blocks
+    tiles = _mask_by_rules(T, L, B).reshape(T // bq, bq, T // bk, bk)
+    live, full = tiles.any((1, 3)), tiles.all((1, 3))
+    sched = ak.tile_schedule(T, T, bq, bk, block_diffusion=(L, B))
+    assert sched.counts == (live.size, live.sum(), full.sum())
+    assert sorted(zip(sched.q, sched.k)) == list(zip(*np.nonzero(live)))
+    assert [not f & ak.PARTIAL for f in sched.flags] == list(
+        full[sched.q, sched.k])
+
+
+def test_a_span_writes_and_the_sum_keeps_the_key_blocks_it_saw(monkeypatch):
+    """The backward in four spans of 16 queries under the block mask: the
+    spans of clean rows see no noisy key, so their dK/dV parts hold rows that
+    were never written (NaN in interpret mode); the sum over the spans keeps
+    the written blocks alone and is `mha_reference`'s gradient."""
+    L, B, T = 32, 4, 64
+    monkeypatch.setattr(ak, "_BWD_DQ_VMEM", 16 * 2 * 16 * 12)
+    seen = [ak.tile_schedule(T, T, 16, 32, False, (L, B), False, t0, 16, 2,
+                             True).keys_seen for t0 in range(0, T, 16)]
+    assert seen == [(0, 1), (0, 1), (1,), (1,)]
+    keys = jax.random.split(jax.random.PRNGKey(5), 4)
+    q, g = (jax.random.normal(key, (1, 4, T, 16), jnp.float32)
+            for key in keys[:2])
+    k, v = (jax.random.normal(key, (1, 2, T, 16), jnp.float32)
+            for key in keys[2:])
+    parts = []
+    real = ak._sum_over_spans
+    monkeypatch.setattr(ak, "_sum_over_spans", lambda p, *a: (
+        parts.append(p), real(p, *a))[1])
+    out, lse = ak.flash_attention_tpu(q, k, v, block_q=16, block_k=32,
+                                      interpret=True, return_lse=True,
+                                      block_diffusion=(L, B))
+    got = ak.flash_attention_bwd_tpu(q, k, v, out, lse, g, block_q=16,
+                                     block_k=32, interpret=True,
+                                     block_diffusion=(L, B))
+    for dk_or_dv in parts:
+        assert [bool(np.isnan(np.asarray(part[:, :32])).all())
+                for part in dk_or_dv] == [False, False, True, True]
+        assert not any(np.isnan(np.asarray(part[:, 32:])).any()
+                       for part in dk_or_dv)
+    want = jax.grad(lambda *a: jnp.sum(ak.mha_reference(
+        *a, block_diffusion=(L, B)) * g), (0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_allclose(a, b, atol=1e-5)
+
+
+def test_the_decoders_policy_holds_one_forward_kernel_under_the_block_mask(
+        monkeypatch):
+    """`jax.grad` through `fused_attention(block_diffusion=)` under the
+    decoder blocks' checkpoint policy: the forward kernel once and the
+    backward's spans (two here), where a bare checkpoint runs the forward
+    again; the same gradients."""
+    L, B, T = 32, 4, 64
+    monkeypatch.setattr(ak, "_BWD_DQ_VMEM", 32 * 2 * 16 * 12)
+    tier.dispatch.set_dispatch_mode("pallas")
+    tier.dispatch.set_tile("attention", tier.TileConfig(block_q=16,
+                                                        block_kv=32))
+    keys = jax.random.split(jax.random.PRNGKey(9), 3)
+    q = jax.random.normal(keys[0], (1, 4, T, 16), jnp.float32)
+    k = jax.random.normal(keys[1], (1, 2, T, 16), jnp.float32)
+    v = jax.random.normal(keys[2], (1, 2, T, 16), jnp.float32)
+    f = lambda q, k, v: jnp.sum(
+        ak.fused_attention(q, k, v, block_diffusion=(L, B)) ** 2)
+    keep = jax.checkpoint(f, policy=jax.checkpoint_policies
+                          .save_only_these_names(ak.FLASH_OUT, ak.FLASH_LSE))
+
+    def kernels(f):
+        text = str(jax.make_jaxpr(jax.grad(f, (0, 1, 2)))(q, k, v))
+        return text.count("pallas_call")
+
+    assert (kernels(keep), kernels(jax.checkpoint(f)), kernels(f)) == (3, 4, 3)
+    want = jax.grad(lambda *a: jnp.sum(ak.mha_reference(
+        *a, block_diffusion=(L, B)) ** 2), (0, 1, 2))(q, k, v)
+    for a, b, c in zip(jax.grad(keep, (0, 1, 2))(q, k, v),
+                       jax.grad(f, (0, 1, 2))(q, k, v), want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_allclose(a, c, atol=1e-4)
+
+
 @pytest.mark.parametrize("kwargs,message", [
     (dict(block_diffusion=(32, 4), causal=True), "mask of its own"),
     (dict(block_diffusion=(24, 4)), "24 or 48 rows"),

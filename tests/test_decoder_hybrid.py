@@ -194,9 +194,10 @@ def test_fused_attention_grouped_query_heads(branch, group, monkeypatch):
 
 def test_flash_kernels_read_key_value_heads_where_they_lie():
     """The kernels' operands: keys and values enter both Mosaic calls folded
-    to [B * Hk, S, D], not repeated over the query's heads.  The forward's
-    grid rows are query heads; the backward's are key-value heads, each with
-    its group's four query heads one after the other."""
+    to [B * Hk, S, D], not repeated over the query's heads, after the tile
+    schedule's three arrays.  The forward's grid rows are query heads; the
+    backward's are key-value heads, each with its group's four query heads
+    one after the other."""
     q = jnp.zeros((1, 8, 64, 16), jnp.float32)
     kv = jnp.zeros((1, 2, 64, 16), jnp.float32)
     jaxpr = jax.make_jaxpr(lambda q, k, v: jax.grad(
@@ -206,11 +207,14 @@ def test_flash_kernels_read_key_value_heads_where_they_lie():
     forward, backward = [
         [v.aval.shape for v in e.invars] for e in jaxpr.jaxpr.eqns
         if e.primitive.name == "pallas_call"]
-    assert forward == [(8, 64, 16), (2, 64, 16), (2, 64, 16)]   # q, k, v
+    # the schedule first (query block, key block, flags of the 6 live tiles
+    # of 8; the backward walks them for each of a group's four heads)
+    assert forward[:3] == [(6,)] * 3 and backward[:3] == [(4 * 6,)] * 3
+    assert forward[3:] == [(8, 64, 16), (2, 64, 16), (2, 64, 16)]   # q, k, v
     # q, k, v, dO, and the row statistics as [1, bq] blocks
-    assert backward[:4] == [(2, 4 * 64, 16), (2, 64, 16), (2, 64, 16),
-                            (2, 4 * 64, 16)]
-    assert backward[4:] == [(2, 4 * 4, 1, 16)] * 2
+    assert backward[3:7] == [(2, 4 * 64, 16), (2, 64, 16), (2, 64, 16),
+                             (2, 4 * 64, 16)]
+    assert backward[7:] == [(2, 4 * 4, 1, 16)] * 2
 
 
 def test_attention_predicates_state_the_head_rule():
