@@ -78,6 +78,11 @@ class AotStepFunction:
             self._table[sig] = fn
         return fn(*args)
 
+    def trace(self, *args):
+        """`jax.jit(body).trace`: what `monitor.note_step` asks of a step
+        function."""
+        return self._jit.trace(*args)
+
     def _cache_size(self) -> int:
         """Actual compile events (monitor.check_compile contract); a disk
         hit deserializes without compiling and does not count."""

@@ -15,7 +15,10 @@ and PerformanceTracker (SURVEY.md §5.1), collapsed into:
                   (`recorded`, `clear_recorded`), which the benchmark lays
                   over the device trace's idle gaps.  `span` also feeds
                   `jax.profiler.TraceAnnotation`, visible only in a
-                  capture with the profiler's host tracer on
+                  capture with the profiler's host tracer on.
+                  `lowered_step()`: the train step compiled last, lowered
+                  when asked — its compiled text names each device op's
+                  layer (`jax.named_scope`)
     instrument  — cached hot-path handle bundles (training / pipeline /
                   parallel) and the metric-name contract
 
@@ -29,4 +32,5 @@ from deeplearning4j_tpu.monitor.registry import (  # noqa: F401
     Counter, Gauge, Histogram, MetricsRegistry, enabled, registry,
     set_enabled)
 from deeplearning4j_tpu.monitor.spans import (  # noqa: F401
-    clear_recorded, current_span, note, recorded, span, span_stack)
+    clear_recorded, current_span, lowered_step, note, note_step, recorded,
+    span, span_stack)

@@ -198,27 +198,30 @@ class TrainingInstruments:
         self.dispatches.inc()
         self.step_ms.observe(dt_s * 1000.0 / max(steps, 1))
 
-    def check_compile(self, jit_fn, model=None) -> None:
+    def check_compile(self, jit_fn, model=None) -> bool:
         """Detect executable-cache growth on the model's jitted step — each
         fill is one trace+compile event (a new input shape/dtype or a step
         rebuild).  On a compile event, sample the donated-buffer footprint
         (params/state/opt-state leaves) so HBM reuse is visible; walking
-        the tree only on compile events keeps the steady state free of it."""
+        the tree only on compile events keeps the steady state free of it.
+        True on a compile event, for the caller's own once-a-compile work
+        (`monitor.note_step`)."""
         if not enabled() or jit_fn is None:
-            return
+            return False
         try:
             n = jit_fn._cache_size()
         except Exception:      # non-jit callable (e.g. scan wrapper fn)
-            return
+            return False
         key = id(jit_fn)       # a rebuilt step (set_normalizer) is a new fn
         prev = self._cache_sizes.get(key, 0)
         if n == prev:
-            return
+            return False
         if n > prev:
             self.compiles.inc(n - prev)
             if model is not None:
                 self.donated_bytes.set(_donated_nbytes(model))
         self._cache_sizes[key] = n
+        return n > prev
 
     def record_epoch(self) -> None:
         if not enabled():

@@ -21,8 +21,9 @@ The ring.  A process-wide `collections.deque(maxlen=8192)` of
 - `n` is an identifier the site gives, or None.  On the train path:
   `input_wait` / `input_stage` (`data/pipeline.DevicePrefetchIterator`)
   carry the batch's ordinal since that `__iter__` began, 0, 1, 2, ...;
-  `step_dispatch` (`nn/graph.py`, `nn/multilayer.py`, `zoo/bert.py`) carries
-  the model's iteration count before the step.  Within one `fit_epoch` at
+  `step_dispatch` (`nn/trainer.py:_dispatched` for the layer-wise models,
+  `zoo/bert.py`, `zoo/decoder.py`) carries the model's iteration count
+  before the step.  Within one `fit_epoch` at
   one step per dispatch, the k-th `step_dispatch` (k from 0) consumes the
   batch whose `input_*` spans carry `n = k`; a fused dispatch of k steps is
   one span, and the next one's `n` is k higher.
@@ -54,13 +55,34 @@ Cost when telemetry is off (`monitor.set_enabled(False)`): one flag check —
 no clock read, no TraceAnnotation, no allocation beyond the context-manager
 object itself; `note` is the flag check alone.  When on, the ring costs one
 tuple and one append per interval.
+
+The step's text.  Beside the ring sits one slot: the train step this process
+compiled last.  A front end calls `note_step(fn, args)` right after the call
+that compiled a step, where it notices a new step anyway —
+`TrainingInstruments.check_compile`'s compile event in
+`nn/trainer.py:_dispatched`, the first call of the function that the
+building branch of `BertModel._step` / `DecoderModel._step` made — never
+once a step: a steady-state dispatch pays one attribute test.  What is kept
+is `fn.trace(...)` for the arguments as `jax.ShapeDtypeStruct`s, with the
+sharding of every committed array (a mesh step is lowered as it ran): the
+`jax.stages.Traced` around the jaxpr that jit made a moment ago (a hit in
+its tracing cache, milliseconds), which refers to neither the model nor its
+arrays, so no device buffer outlives its model on the slot's account — and
+the slot still answers after a benchmark's driver has dropped its model.
+`lowered_step()` lowers it WHEN ASKED and returns the `jax.stages.Lowered`,
+or None (no step yet; telemetry off when the step was compiled).
+`lowered_step().compile().as_text()` is the running program's text: each
+instruction with the `op_name` that says which layer asked for it
+(`jax.named_scope`, docs/observability.md "Which layer is `fusion.35`");
+with jax's persistent compilation cache on, that compile is a hit.  It is
+what `benchmark/trace/step_scopes.py` reads.
 """
 from __future__ import annotations
 
 import collections
 import threading
 import time
-from typing import List, NamedTuple, Optional
+from typing import Any, List, NamedTuple, Optional
 
 from deeplearning4j_tpu.monitor.registry import (MetricsRegistry, enabled,
                                                  registry)
@@ -125,6 +147,41 @@ def recorded(since: Optional[float] = None,
 
 def clear_recorded() -> None:
     _ring.clear()
+
+
+_step = None            # the `jax.stages.Traced` of the step compiled last
+
+
+def _spec(a) -> Any:
+    """What tracing needs of one argument: shape, dtype, weak type, and
+    the sharding where the caller committed to one (an uncommitted array
+    lowers with none, as the call did).  Anything but an array stays."""
+    import jax
+    if isinstance(a, jax.Array):
+        return jax.ShapeDtypeStruct(
+            a.shape, a.dtype, weak_type=a.weak_type,
+            sharding=a.sharding if a.committed else None)
+    if hasattr(a, "shape") and hasattr(a, "dtype"):       # numpy
+        return jax.ShapeDtypeStruct(a.shape, a.dtype)
+    return a
+
+
+def note_step(fn, args: tuple) -> None:
+    """Remember `fn` (a `jax.jit` function or a
+    `compile.step_cache.AotStepFunction`: anything with `trace`) as the
+    train step compiled last, by the call `fn(*args)` that has just
+    returned.  Donated arrays are fine: only their shapes are read."""
+    global _step
+    if enabled():
+        import jax
+        _step = fn.trace(*jax.tree_util.tree_map(_spec, args))
+
+
+def lowered_step():
+    """The `jax.stages.Lowered` of the train step this process compiled
+    last, lowered now for the shapes, dtypes and shardings it runs at; None
+    where there is none (module docstring)."""
+    return None if _step is None else _step.lower()
 
 
 class span:

@@ -526,8 +526,9 @@ class ComputationGraph(LayerwiseTrainer):
             dt = jnp.dtype(cd)
             cast = (lambda a: a.astype(dt)
                     if jnp.issubdtype(a.dtype, jnp.floating) else a)
-            params = jax.tree_util.tree_map(cast, params)
-            inputs = {k: cast(jnp.asarray(v)) for k, v in inputs.items()}
+            with jax.named_scope("param_cast"):
+                params = jax.tree_util.tree_map(cast, params)
+                inputs = {k: cast(jnp.asarray(v)) for k, v in inputs.items()}
         acts: Dict[str, jnp.ndarray] = dict(inputs)
         head_inputs: Dict[str, jnp.ndarray] = {}
         new_state = dict(state)
@@ -541,15 +542,19 @@ class ComputationGraph(LayerwiseTrainer):
             if (want_head_inputs and name in self.conf.network_outputs
                     and layer is not None and hasattr(layer, "compute_loss")):
                 head_inputs[name] = xs[0]
-            if self.conf.remat and train:
-                # train only (see MultiLayerNetwork._forward)
-                def _apply(p_, s_, xs_, r_, _v=vertex, _train=train):
-                    return _v.apply(p_, s_, xs_, train=_train, rng=r_)
-                acts[name], new_state[name] = jax.checkpoint(_apply)(
-                    params[name], state[name], xs, vrng)
-            else:
-                acts[name], new_state[name] = vertex.apply(
-                    params[name], state[name], xs, train=train, rng=vrng)
+            # the device ops' `op_name` says which vertex asked for them
+            # (docs/observability.md): `ConvolutionLayer/res2a_branch2a`
+            kind = type(vertex if layer is None else layer).__name__
+            with jax.named_scope(f"{kind}/{name}"):
+                if self.conf.remat and train:
+                    # train only (see MultiLayerNetwork._forward)
+                    def _apply(p_, s_, xs_, r_, _v=vertex, _train=train):
+                        return _v.apply(p_, s_, xs_, train=_train, rng=r_)
+                    acts[name], new_state[name] = jax.checkpoint(_apply)(
+                        params[name], state[name], xs, vrng)
+                else:
+                    acts[name], new_state[name] = vertex.apply(
+                        params[name], state[name], xs, train=train, rng=vrng)
         if want_head_inputs:
             return acts, new_state, head_inputs
         return acts, new_state
@@ -562,16 +567,20 @@ class ComputationGraph(LayerwiseTrainer):
         acts, new_state, head_inputs = self._forward(
             params, state, inputs, train=train, rng=rng, want_head_inputs=True)
         loss = 0.0
-        for j, name in enumerate(self.conf.network_outputs):
-            layer = self._layer_of(name)
-            if layer is None or not hasattr(layer, "compute_loss"):
-                raise ValueError(f"Output vertex '{name}' is not a loss head")
-            lrng = None if rng is None else jax.random.fold_in(rng, 10_000 + j)
-            lmask = labels_masks[j] if labels_masks else None
-            loss = loss + layer.compute_loss(
-                params[name], state[name], head_inputs[name], labels[j],
-                train=train, rng=lrng, mask=lmask)
-        return loss + self._reg_penalty(params), new_state
+        with jax.named_scope("loss"):
+            for j, name in enumerate(self.conf.network_outputs):
+                layer = self._layer_of(name)
+                if layer is None or not hasattr(layer, "compute_loss"):
+                    raise ValueError(
+                        f"Output vertex '{name}' is not a loss head")
+                lrng = (None if rng is None
+                        else jax.random.fold_in(rng, 10_000 + j))
+                lmask = labels_masks[j] if labels_masks else None
+                loss = loss + layer.compute_loss(
+                    params[name], state[name], head_inputs[name], labels[j],
+                    train=train, rng=lrng, mask=lmask)
+            loss = loss + self._reg_penalty(params)
+        return loss, new_state
 
     def _reg_penalty(self, params: Params):
         penalty = 0.0

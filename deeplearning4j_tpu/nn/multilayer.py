@@ -279,8 +279,10 @@ class MultiLayerNetwork(LayerwiseTrainer):
         dt = jnp.dtype(cd)
         cast = lambda a: a.astype(dt) if jnp.issubdtype(a.dtype,
                                                         jnp.floating) else a
-        return (jax.tree_util.tree_map(cast, params),
-                x.astype(dt) if jnp.issubdtype(x.dtype, jnp.floating) else x)
+        with jax.named_scope("param_cast"):
+            return (jax.tree_util.tree_map(cast, params),
+                    x.astype(dt) if jnp.issubdtype(x.dtype, jnp.floating)
+                    else x)
 
     def _forward(self, params: Params, state: Params, x, *, train: bool,
                  rng: Optional[jax.Array], mask=None,
@@ -294,17 +296,21 @@ class MultiLayerNetwork(LayerwiseTrainer):
             lrng = None
             if rng is not None and layer.STOCHASTIC:
                 rng, lrng = jax.random.split(rng)
-            if self.conf.remat and train:
-                # train only: inference is never differentiated, and
-                # jax.checkpoint's CSE barrier would just slow it down
-                def _apply(p_, s_, x_, r_, m_, _layer=layer, _train=train):
-                    return _layer.apply(p_, s_, x_, train=_train, rng=r_,
-                                        mask=m_)
-                x, s = jax.checkpoint(_apply)(params[name], state[name], x,
-                                              lrng, mask)
-            else:
-                x, s = layer.apply(params[name], state[name], x, train=train,
-                                   rng=lrng, mask=mask)
+            # the device ops' `op_name` says which layer asked for them
+            # (docs/observability.md): `DenseLayer/layer_0`
+            with jax.named_scope(f"{type(layer).__name__}/{name}"):
+                if self.conf.remat and train:
+                    # train only: inference is never differentiated, and
+                    # jax.checkpoint's CSE barrier would just slow it down
+                    def _apply(p_, s_, x_, r_, m_, _layer=layer,
+                               _train=train):
+                        return _layer.apply(p_, s_, x_, train=_train, rng=r_,
+                                            mask=m_)
+                    x, s = jax.checkpoint(_apply)(params[name], state[name],
+                                                  x, lrng, mask)
+                else:
+                    x, s = layer.apply(params[name], state[name], x,
+                                       train=train, rng=lrng, mask=mask)
             new_state[name] = s
             if mask is not None and self._layer_types:
                 # Mask propagation (the reference's feedForwardMaskArray):
@@ -339,9 +345,10 @@ class MultiLayerNetwork(LayerwiseTrainer):
         name = self.conf.layer_name(out_idx)
         hrng = None if rng is None else jax.random.fold_in(rng, out_idx)
         hp, h = self._cast_compute(params[name], h)  # head matmul bf16 too
-        loss = head.compute_loss(hp, state[name], h, y, train=train,
-                                 rng=hrng, mask=labels_mask)
-        loss = loss + self._reg_penalty(params)
+        with jax.named_scope("loss"):
+            loss = head.compute_loss(hp, state[name], h, y, train=train,
+                                     rng=hrng, mask=labels_mask)
+            loss = loss + self._reg_penalty(params)
         return loss, new_state
 
     def _reg_penalty(self, params: Params):

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.monitor.instrument import TrainingInstruments
-from deeplearning4j_tpu.monitor.spans import note, span
+from deeplearning4j_tpu.monitor.spans import note, note_step, span
 from deeplearning4j_tpu.train.updaters import (
     IUpdater, apply_gradient_normalization)
 from deeplearning4j_tpu.utils.counters import advance, device_counters
@@ -67,39 +67,42 @@ def apply_layer_updates(entries: Iterable[Tuple[str, Any, IUpdater]], conf,
             # no update applied, updater state untouched.
             new_params[name], new_opt[name] = master[name], opt_state[name]
             continue
-        g = grads[name]
-        if zt is not None and grads_in_update_layout:
-            g = zt.constrain_update(name, g)
-        own = layer is not None and layer.gradient_normalization is not None
-        gn = (layer.gradient_normalization if own
-              else conf.gradient_normalization)
-        if gn:
-            thr = (layer.gradient_normalization_threshold if own
-                   else conf.gradient_normalization_threshold)
-            g = apply_gradient_normalization(g, gn, thr)
-        if zt is None:
-            p_upd = master[name]
-        else:
-            if not grads_in_update_layout:
-                # reduce-scatter the (already normalized) grads
-                g = zt.scatter(name, g)
-            # the updater runs on this device's shard of params/moments
-            p_upd = zt.update_view(name, master[name])
-        upd, new_o = upd_cfg.apply(opt_state[name], g, iteration, epoch,
-                                   params=p_upd)
-        # decoupled weight decay (reference WeightDecay regularization,
-        # applyLR=true): update += lr * coeff * w for regularizable params
-        wd = (layer.weight_decay if layer is not None
-              and layer.weight_decay is not None else conf.weight_decay)
-        if wd and layer is not None:
-            lr = upd_cfg.lr_at(iteration, epoch)
-            upd = _add_scaled_where(upd, p_upd,
-                                    layer.regularizable_mask(p_upd), lr * wd)
-        new_p = jax.tree_util.tree_map(lambda p_, u_: p_ - u_, p_upd, upd)
-        if zt is not None:
-            new_p = zt.restore(name, new_p)
-            new_o = zt.constrain_opt(name, new_o)
-        new_params[name], new_opt[name] = new_p, new_o
+        with jax.named_scope(f"updater/{name}"):
+            g = grads[name]
+            if zt is not None and grads_in_update_layout:
+                g = zt.constrain_update(name, g)
+            own = (layer is not None
+                   and layer.gradient_normalization is not None)
+            gn = (layer.gradient_normalization if own
+                  else conf.gradient_normalization)
+            if gn:
+                thr = (layer.gradient_normalization_threshold if own
+                       else conf.gradient_normalization_threshold)
+                g = apply_gradient_normalization(g, gn, thr)
+            if zt is None:
+                p_upd = master[name]
+            else:
+                if not grads_in_update_layout:
+                    # reduce-scatter the (already normalized) grads
+                    g = zt.scatter(name, g)
+                # the updater runs on this device's shard of params/moments
+                p_upd = zt.update_view(name, master[name])
+            upd, new_o = upd_cfg.apply(opt_state[name], g, iteration,
+                                       epoch, params=p_upd)
+            # decoupled weight decay (reference WeightDecay regularization,
+            # applyLR=true): update += lr * coeff * w for regularizable params
+            wd = (layer.weight_decay if layer is not None
+                  and layer.weight_decay is not None else conf.weight_decay)
+            if wd and layer is not None:
+                lr = upd_cfg.lr_at(iteration, epoch)
+                upd = _add_scaled_where(
+                    upd, p_upd, layer.regularizable_mask(p_upd), lr * wd)
+            new_p = jax.tree_util.tree_map(lambda p_, u_: p_ - u_, p_upd,
+                                           upd)
+            if zt is not None:
+                new_p = zt.restore(name, new_p)
+                new_o = zt.constrain_opt(name, new_o)
+            new_params[name], new_opt[name] = new_p, new_o
     return new_params, new_opt
 
 
@@ -215,7 +218,8 @@ class LayerwiseTrainer(CompiledStepOwner):
         with zero host ETL, data.pipeline), the rng split, the all-gather of
         ZeRO-1 master params, forward and backward."""
         zt = self._step_transform
-        batch = self._normalize_batch(batch)
+        with jax.named_scope("input_normalize"):
+            batch = self._normalize_batch(batch)
         # split inside the compiled step: keeps the per-step host work at
         # zero device round-trips (the carry key + iteration counter live
         # on device and flow step→step without fresh H2D transfers)
@@ -372,17 +376,20 @@ class LayerwiseTrainer(CompiledStepOwner):
 
     # ---- dispatch ----
     def _dispatched(self, t0, steps_fns, loss, new_it, rows_of, axis=0,
-                    steps=1):
+                    steps=1, args=None):
         """Bookkeeping after a dispatch that started at `t0`: the
         `step_dispatch` span and series, compile detection on the step
         functions used, the loss (a device array, never read here), the
-        batch rows (`rows_of` along `axis`), the counters, the listeners."""
+        batch rows (`rows_of` along `axis`), the counters, the listeners.
+        `args`: what the one step function was called with, so that a
+        compile event can tell `monitor.lowered_step` which step runs."""
         t1 = time.perf_counter()
         note("step_dispatch", t0, t1, self.iteration)
         ins = self._instruments()
         ins.record_dispatch(t1 - t0, steps=steps)
         for fn in steps_fns:
-            ins.check_compile(fn, self)
+            if ins.check_compile(fn, self) and args is not None:
+                note_step(fn, args)
         self._score = loss
         self._last_batch_size = self._batch_rows(rows_of, axis)
         advance(self, new_it, steps=steps)
@@ -397,11 +404,12 @@ class LayerwiseTrainer(CompiledStepOwner):
             return self._fit_batch_shared(*batch)
         step = self._get_train_step()
         it_dev, ep_dev = device_counters(self)
+        args = (self.params_, self.state_, self.opt_state_, *batch,
+                self._rng, it_dev, ep_dev)
         t0 = time.perf_counter()
         (self.params_, self.state_, self.opt_state_, loss, self._rng,
-         new_it) = step(self.params_, self.state_, self.opt_state_, *batch,
-                        self._rng, it_dev, ep_dev)
-        self._dispatched(t0, (step,), loss, new_it, batch)
+         new_it) = step(*args)
+        self._dispatched(t0, (step,), loss, new_it, batch, args=args)
 
     def _fit_batch_shared(self, *batch):
         """One training step through the hierarchical path: compiled grad

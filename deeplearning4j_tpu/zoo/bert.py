@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deeplearning4j_tpu.monitor.spans import note, span
+from deeplearning4j_tpu.monitor.spans import note, note_step, span
 from deeplearning4j_tpu.ops.attention_kernels import fused_attention
 from deeplearning4j_tpu.train.updaters import Adam, IUpdater
 
@@ -106,6 +106,7 @@ class BertModel:
         self.params_ = self._init(jax.random.PRNGKey(seed))
         self.opt_state_ = self.updater.init_state(self.params_)
         self._steps: Dict[str, Any] = {}
+        self._unnoted_step = False    # a step built and not yet run
         self._mlm_head = jnp.zeros((4,), jnp.int32)    # see `_fold_head`
 
     # ---- init ----
@@ -146,16 +147,20 @@ class BertModel:
         c = self.config
         dt = jnp.dtype(c.compute_dtype)
         T = ids.shape[1]
-        x = (params["tok_emb"][ids]
-             + params["pos_emb"][:T][None]
-             + (params["type_emb"][segment_ids] if segment_ids is not None
-                else params["type_emb"][0]))
-        x = _ln(x, params["emb_ln_g"], params["emb_ln_b"], c.eps)
-        x = x.astype(dt)
-        mask = input_mask.astype(dt)
+        # the scopes name the device ops by the layer that asked for them
+        # (docs/observability.md)
+        with jax.named_scope("embeddings"):
+            x = (params["tok_emb"][ids]
+                 + params["pos_emb"][:T][None]
+                 + (params["type_emb"][segment_ids]
+                    if segment_ids is not None else params["type_emb"][0]))
+            x = _ln(x, params["emb_ln_g"], params["emb_ln_b"], c.eps)
+            x = x.astype(dt)
+            mask = input_mask.astype(dt)
 
         def block(x, lp):
-            lp = jax.tree_util.tree_map(lambda a: a.astype(dt), lp)
+            with jax.named_scope("param_cast"):
+                lp = jax.tree_util.tree_map(lambda a: a.astype(dt), lp)
             B, T, H = x.shape
             nh = c.n_heads
             dh = H // nh
@@ -163,16 +168,18 @@ class BertModel:
             def split(y):
                 return y.reshape(B, T, nh, dh).transpose(0, 2, 1, 3)
 
-            q = split(x @ lp["Wq"] + lp["bq"])
-            k = split(x @ lp["Wk"] + lp["bk"])
-            v = split(x @ lp["Wv"] + lp["bv"])
-            a = fused_attention(q, k, v, mask=mask)
-            a = a.transpose(0, 2, 1, 3).reshape(B, T, H)
-            a = a @ lp["Wo"] + lp["bo"]
-            x = _ln(x + a, lp["ln1_g"], lp["ln1_b"], c.eps)
-            h = jax.nn.gelu(x @ lp["Wi"] + lp["bi"])
-            h = h @ lp["Wf"] + lp["bf"]
-            x = _ln(x + h, lp["ln2_g"], lp["ln2_b"], c.eps)
+            with jax.named_scope("self_attention"):
+                q = split(x @ lp["Wq"] + lp["bq"])
+                k = split(x @ lp["Wk"] + lp["bk"])
+                v = split(x @ lp["Wv"] + lp["bv"])
+                a = fused_attention(q, k, v, mask=mask)
+                a = a.transpose(0, 2, 1, 3).reshape(B, T, H)
+                a = a @ lp["Wo"] + lp["bo"]
+                x = _ln(x + a, lp["ln1_g"], lp["ln1_b"], c.eps)
+            with jax.named_scope("ffn"):
+                h = jax.nn.gelu(x @ lp["Wi"] + lp["bi"])
+                h = h @ lp["Wf"] + lp["bf"]
+                x = _ln(x + h, lp["ln2_g"], lp["ln2_b"], c.eps)
             return x.astype(dt), None
 
         x, _ = jax.lax.scan(block, x, params["layers"])
@@ -278,10 +285,11 @@ class BertModel:
         h = self._encode(params, ids, input_mask)
         labels, label_mask = jnp.asarray(labels), jnp.asarray(label_mask)
         positions = h.shape[0] * h.shape[1]
-        return self._mlm_head_loss(
-            {k: params[k] for k in _HEAD_KEYS}, h.reshape(positions, -1),
-            labels.reshape(positions, *labels.shape[2:]),
-            label_mask.reshape(positions))
+        with jax.named_scope("mlm_head"):
+            return self._mlm_head_loss(
+                {k: params[k] for k in _HEAD_KEYS}, h.reshape(positions, -1),
+                labels.reshape(positions, *labels.shape[2:]),
+                label_mask.reshape(positions))
 
     def _cls_loss(self, params, ids, input_mask, labels):
         h = self._encode(params, ids, input_mask)
@@ -296,10 +304,11 @@ class BertModel:
         def step(params, opt_state, iteration, epoch, *batch):
             (loss, report), grads = jax.value_and_grad(
                 lambda p: loss_fn(p, *batch), has_aux=True)(params)
-            upd, new_opt = self.updater.apply(opt_state, grads, iteration,
-                                              epoch, params=params)
-            new_params = jax.tree_util.tree_map(lambda p, u: p - u,
-                                                params, upd)
+            with jax.named_scope("updater"):
+                upd, new_opt = self.updater.apply(opt_state, grads, iteration,
+                                                  epoch, params=params)
+                new_params = jax.tree_util.tree_map(lambda p, u: p - u,
+                                                    params, upd)
             return new_params, new_opt, loss, iteration + 1, report
 
         return step
@@ -308,6 +317,7 @@ class BertModel:
         if kind not in self._steps:
             self._steps[kind] = jax.jit(self._step_body(kind),
                                         donate_argnums=(0, 1))
+            self._unnoted_step = True     # `fit_batch` tells `monitor`
         return self._steps[kind]
 
     def _scan_step(self, kind: str):
@@ -374,18 +384,18 @@ class BertModel:
         (labels,) = [jnp.asarray(l) for l in mds.labels]
         it, ep = device_counters(self)
         t0 = time.perf_counter()
+        args = (self.params_, self.opt_state_, it, ep, ids.astype(jnp.int32),
+                input_mask, labels)
         if mds.labels_masks is not None:                 # masked LM
-            lmask = jnp.asarray(mds.labels_masks[0])
             step = self._step("mlm")
-            self.params_, self.opt_state_, loss, new_it, report = step(
-                self.params_, self.opt_state_, it, ep,
-                ids.astype(jnp.int32), input_mask, labels, lmask)
+            args += (jnp.asarray(mds.labels_masks[0]),)
         else:                                            # classification
             step = self._step("cls")
-            self.params_, self.opt_state_, loss, new_it, report = step(
-                self.params_, self.opt_state_, it, ep,
-                ids.astype(jnp.int32), input_mask, labels)
+        self.params_, self.opt_state_, loss, new_it, report = step(*args)
         note("step_dispatch", t0, time.perf_counter(), self.iteration)
+        if self._unnoted_step:      # the call compiled it: its text is
+            self._unnoted_step = False      # `monitor.lowered_step()`
+            note_step(step, args)
         if report is not None:                           # masked LM
             self._mlm_head = _fold_head_jit(self._mlm_head, report)
         self._score = loss

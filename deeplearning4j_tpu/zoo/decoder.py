@@ -104,7 +104,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deeplearning4j_tpu.monitor.spans import note, span
+from deeplearning4j_tpu.monitor.spans import note, note_step, span
 from deeplearning4j_tpu.ops.attention_kernels import (FLASH_LSE, FLASH_OUT,
                                                       fused_attention)
 from deeplearning4j_tpu.ops.moe import (expert_layer, row_bound, swiglu,
@@ -300,6 +300,7 @@ class DecoderModel:
         self._tokens = 0     # clean tokens of the newest step
         self._pairs = 0      # (token, chosen expert) pairs of the newest step
         self._steps: Dict[str, Any] = {}
+        self._unnoted_step = False    # a step built and not yet run
 
     # ---- init ----
     def _init(self, key) -> Dict[str, Any]:
@@ -482,7 +483,8 @@ class DecoderModel:
         dt = jnp.dtype(c.compute_dtype)
 
         def cast(lp):
-            return jax.tree_util.tree_map(lambda a: a.astype(dt), lp)
+            with jax.named_scope("param_cast"):
+                return jax.tree_util.tree_map(lambda a: a.astype(dt), lp)
 
         def dense_ffn(x, lp):
             with jax.named_scope("dense_mlp"):
@@ -521,7 +523,8 @@ class DecoderModel:
             return jax.checkpoint(moe_block, policy=keep,
                                   prevent_cse=not under_scan)
 
-        x = params["tok_emb"][ids]          # the residual stream is float32
+        with jax.named_scope("embed"):
+            x = params["tok_emb"][ids]      # the residual stream is float32
         for i in range(c.n_dense_layers):
             x = dense_block(x, cast(jax.tree_util.tree_map(
                 lambda a: a[i], params["dense"])))
@@ -572,10 +575,12 @@ class DecoderModel:
         of each sequence (`labels[:, t]` is the id at `t + 1`; the last
         column is ignored), logits and `log_softmax` in float32."""
         hidden, seen = self._trunk(params, router_bias, ids)
-        logp = jax.nn.log_softmax(self._logits(params, hidden), axis=-1)
-        nll = -jnp.take_along_axis(
-            logp, labels[..., None].astype(jnp.int32), axis=-1)[..., 0]
-        return self._balanced(jnp.mean(nll[:, :-1]), seen)
+        logits = self._logits(params, hidden)
+        with jax.named_scope("loss"):
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            nll = -jnp.take_along_axis(
+                logp, labels[..., None].astype(jnp.int32), axis=-1)[..., 0]
+            return self._balanced(jnp.mean(nll[:, :-1]), seen)
 
     def _balanced(self, loss, seen):
         """`loss` plus `aux_loss_coef` times the expert layers' mean
@@ -656,17 +661,19 @@ class DecoderModel:
             (loss, seen), grads = jax.value_and_grad(
                 loss_of, has_aux=True)(params, state["router_bias"], ids,
                                        *targets)
-            upd, new_opt = self.updater.apply(opt_state, grads, iteration,
-                                              epoch, params=params)
-            new_params = jax.tree_util.tree_map(lambda p, u: p - u,
-                                                params, upd)
-            new_state = {
-                # the selection bias is the sigmoid router's
-                "router_bias": update_router_bias(
-                    state["router_bias"], seen["expert_load"], speed)
-                if self.config.router_score == "sigmoid"
-                else state["router_bias"],
-                **{name: state[name] + seen[name] for name in seen}}
+            with jax.named_scope("updater"):
+                upd, new_opt = self.updater.apply(opt_state, grads, iteration,
+                                                  epoch, params=params)
+                new_params = jax.tree_util.tree_map(lambda p, u: p - u,
+                                                    params, upd)
+            with jax.named_scope("router_bias"):
+                new_state = {
+                    # the selection bias is the sigmoid router's
+                    "router_bias": update_router_bias(
+                        state["router_bias"], seen["expert_load"], speed)
+                    if self.config.router_score == "sigmoid"
+                    else state["router_bias"],
+                    **{name: state[name] + seen[name] for name in seen}}
             return new_params, new_opt, new_state, loss, iteration + 1
 
         return step
@@ -675,6 +682,7 @@ class DecoderModel:
         if "step" not in self._steps:
             self._steps["step"] = jax.jit(self._step_body(),
                                           donate_argnums=(0, 1, 2))
+            self._unnoted_step = True     # `fit_batch` tells `monitor`
         return self._steps["step"]
 
     def _scan_step(self):
@@ -715,10 +723,15 @@ class DecoderModel:
         ids, labels = self._batch(mds)
         it, ep = device_counters(self)
         t0 = time.perf_counter()
+        step = self._step()
+        args = (self.params_, self.opt_state_, self.state_, it, ep, ids,
+                labels)
         (self.params_, self.opt_state_, self.state_, loss,
-         new_it) = self._step()(self.params_, self.opt_state_, self.state_,
-                                it, ep, ids, labels)
+         new_it) = step(*args)
         note("step_dispatch", t0, time.perf_counter(), self.iteration)
+        if self._unnoted_step:      # the call compiled it: its text is
+            self._unnoted_step = False      # `monitor.lowered_step()`
+            note_step(step, args)
         self._score = loss
         advance(self, new_it)
         return loss          # a device scalar: `score()` reads it lazily
