@@ -12,6 +12,20 @@ A checkpoint trained under one is wrong under the other, so the form is part
 of a model's definition.  They are the same rotation on a permuted axis:
 half-split of `x` is interleaved of `x` with `[0, d/2, 1, d/2 + 1, ...]`
 gathered, scattered back.
+
+`rotary_pairs` is the rotation itself with the axis left to the caller: the
+two members of every pair arrive as two arrays, whole lanes each, and the
+turn is four multiplies and two adds over them — no `[..., d/2, 2]` array,
+no stack, no lane that changes place.  A caller whose `x` comes from a
+product gets the two arrays for nothing by taking the product's weight
+columns apart (`w[..., 0::2]`, `w[..., 1::2]`: interleaved; `w[..., :d/2]`,
+`w[..., d/2:]`: half-split), and may lay the results side by side in any
+order, provided queries and keys share it: rotary lanes enter nothing but
+`q . k`, a sum over the same products in whatever order they stand.
+`zoo/decoder.py`'s latent attention does that (PERF.md, PR 36: on the chip
+the pair-stack of `rotary_interleaved` cost five passes over the queries,
+through arrays with a minor dimension of 2); `rotary_interleaved` stays the
+definition, and the tests hold `rotary_pairs` to it.
 """
 from __future__ import annotations
 
@@ -36,6 +50,19 @@ def rotary_interleaved(x, positions, base: float = 10000.0):
     a, b = pairs[..., 0], pairs[..., 1]
     out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
     return out.reshape(x.shape).astype(x.dtype)
+
+
+def rotary_pairs(a, b, positions, base: float = 10000.0):
+    """Pair i is `(a[..., i], b[..., i])`, `a` and `b` [..., T, d/2]: both
+    members turned by `pos * base**(-2i/d)`, returned as two arrays.
+    `positions` [T], or any shape that broadcasts against `a`'s leading axes
+    (`[B, 1, T]` for `a` [B, heads, T, d/2]).  Angles, sines and the
+    rotation in float32; returned in `a`'s dtype."""
+    ang = rotary_angles(positions, 2 * a.shape[-1], base)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    af, bf = a.astype(jnp.float32), b.astype(jnp.float32)
+    return ((af * cos - bf * sin).astype(a.dtype),
+            (af * sin + bf * cos).astype(a.dtype))
 
 
 def rotary_half_split(x, positions, base: float = 10000.0):

@@ -39,6 +39,25 @@ benchmark build:
   (`output(ids)`) the same mask is attention causal over blocks: the
   forward a block-wise denoising decode would run.
 
+Between a product and a kernel (latent attention; measured in PERF.md,
+PR 36).  The kernels read [B, heads, T, d] with `d` in the lanes; a product
+`x @ W` writes [B, T, heads * d]; every slice, concatenate or transpose of a
+100 MB array between the two is a pass over it.  So each piece is a product
+of its own.  `v`: `Wkvb`'s value columns, an `einsum` to `btnd` and a
+transpose, which XLA's TPU layout assignment turns into a product that writes
+[B, heads, T, 128] itself (it does not for `->bntd`, nor for any 192-wide
+result: those it lays out with the tokens in the lanes and copies once).
+q: three products — `Wq`'s nope columns, its rotary pairs' first members,
+their second (the rotary columns taken apart by a 0/1 matrix) — so
+`ops/rotary.rotary_pairs` turns two whole arrays in the products' epilogue
+and no lane changes place; k likewise from `Wkvb`'s key columns and the one
+rotary head of `Wkva`; one concatenate each for q and k, which XLA follows
+with one transposing copy.  The kernel's output enters `Wo` as it stands (a
+transpose and a reshape before a product that contracts over both are no
+copy).  The weights are taken apart inside the block, in the compute dtype,
+and autodiff puts their gradients back together: the parameter tree and a
+checkpoint know nothing of it.
+
 All of them through `ops/attention_kernels.fused_attention` (the flash
 kernels from 2k tokens on the chip: forward and backward walk the mask's
 live tiles by `tile_schedule`, so neither causal's upper triangle nor the
@@ -110,8 +129,7 @@ from deeplearning4j_tpu.ops.attention_kernels import (FLASH_LSE, FLASH_OUT,
 from deeplearning4j_tpu.ops.moe import (expert_layer, row_bound, swiglu,
                                         update_router_bias)
 from deeplearning4j_tpu.ops.norm_kernels import rms_norm
-from deeplearning4j_tpu.ops.rotary import (rotary_half_split,
-                                           rotary_interleaved)
+from deeplearning4j_tpu.ops.rotary import rotary_half_split, rotary_pairs
 from deeplearning4j_tpu.ops.short_conv import gated_short_conv
 from deeplearning4j_tpu.train.updaters import AdamW, IUpdater
 
@@ -395,27 +413,51 @@ class DecoderModel:
     def _qkv(self, x, lp, L=None):
         """Queries and keys [B, heads, T, nope + rope] and values
         [B, heads, T, v] of expanded latent attention for `x` [B, T, H],
-        and the mask (`_rows`)."""
+        and the mask (`_rows`).  Head-first from the products, the rotary
+        pairs' two members from products of their own (module docstring:
+        the layouts between a product and a kernel), so the rotary lanes of
+        q and k stand de-interleaved, all the pairs' first members and then
+        their second: every `q . k` is what it is in the checkpoint's order."""
         c = self.config
-        B, T, _ = x.shape
+        B, T, H = x.shape
         nh, dn, dr, dv = c.n_heads, c.qk_nope_dim, c.qk_rope_dim, c.v_head_dim
+        r = c.kv_lora_rank
         pos, mask = self._rows(T, L)
-        q = (x @ lp["Wq"]).reshape(B, T, nh, dn + dr)
+
+        def heads(x, w):
+            # `->btnd` and a transpose, not `->bntd`: only so does XLA's TPU
+            # layout assignment let a 128-wide product write [B, heads, T, d]
+            # itself (PERF.md, PR 36)
+            return jnp.einsum("bth,hnd->btnd", x, w).transpose(0, 2, 1, 3)
+
+        Wq = lp["Wq"].reshape(H, nh, dn + dr)
+        Wkvb = lp["Wkvb"].reshape(r, nh, dn + dv)
         kva = x @ lp["Wkva"]
-        latent = rms_norm(kva[..., :c.kv_lora_rank], lp["kv_norm"], c.eps)
-        kv = (latent @ lp["Wkvb"]).reshape(B, T, nh, dn + dv)
-        q_rope = rotary_interleaved(q[..., dn:], pos, c.rope_base)
-        k_rope = rotary_interleaved(          # one rotary head for all
-            kva[..., None, c.kv_lora_rank:], pos, c.rope_base)
-        q = jnp.concatenate([q[..., :dn], q_rope], -1)
+        latent = rms_norm(kva[..., :r], lp["kv_norm"], c.eps)
+        # Wq's rotary columns, even ones then odd ones, picked by a 0/1
+        # matrix: exact, and cheaper on the chip than two strided slices of
+        # a bfloat16 weight and their scatter in the backward
+        apart = np.zeros((dr, dr), np.float32)
+        apart[np.r_[0:dr:2, 1:dr:2], np.arange(dr)] = 1
+        Wq_rope = jnp.einsum("hnd,de->hne", Wq[..., dn:],
+                             jnp.asarray(apart, Wq.dtype),
+                             precision=jax.lax.Precision.HIGHEST)
+        q_rope = rotary_pairs(heads(x, Wq_rope[..., :dr // 2]),
+                              heads(x, Wq_rope[..., dr // 2:]), pos,
+                              c.rope_base)
+        k_rope = rotary_pairs(kva[..., r::2], kva[..., r + 1::2], pos,
+                              c.rope_base)          # one rotary head for all
+        q = jnp.concatenate([heads(x, Wq[..., :dn]), *q_rope], -1)
         k = jnp.concatenate(
-            [kv[..., :dn], jnp.broadcast_to(k_rope, (B, T, nh, dr))], -1)
-        heads_first = (0, 2, 1, 3)
-        return (q.transpose(heads_first), k.transpose(heads_first),
-                kv[..., dn:].transpose(heads_first), mask)
+            [heads(latent, Wkvb[..., :dn]),
+             jnp.broadcast_to(jnp.concatenate(k_rope, -1)[:, None],
+                              (B, nh, T, dr))], -1)
+        return q, k, heads(latent, Wkvb[..., dn:]), mask
 
     def _attention(self, x, lp, L=None):
-        """`x + MLA(RMSNorm(x))` for `x` [B, T, H], under `_rows`' mask."""
+        """`x + MLA(RMSNorm(x))` for `x` [B, T, H], under `_rows`' mask.
+        The kernel's [B, heads, T, v] enters `Wo` with no copy: the product
+        contracts over heads and `v` both, whatever their order."""
         c = self.config
         B, T, _ = x.shape
         with jax.named_scope("mla_attention"):

@@ -1,7 +1,9 @@
 """The decoder's parts against hand-written cases: RMSNorm, rotary, the
 router, the expert layer's shares and its dropless dispatch, the grouped
-product, attention with values narrower than keys, the routing counter, and
-`zoo.DecoderModel`'s surface."""
+product, attention with values narrower than keys, the routing counter,
+`zoo.DecoderModel`'s surface, and latent attention against the form it had
+before its products wrote head-first (PR 36)."""
+import hashlib
 import io
 import re
 
@@ -16,7 +18,7 @@ from deeplearning4j_tpu.ops import attention_kernels as ak
 from deeplearning4j_tpu.ops import moe
 from deeplearning4j_tpu.ops import pallas as tier
 from deeplearning4j_tpu.ops.norm_kernels import rms_norm
-from deeplearning4j_tpu.ops.rotary import rotary_interleaved
+from deeplearning4j_tpu.ops.rotary import rotary_interleaved, rotary_pairs
 from deeplearning4j_tpu.utils.counters import device_counters
 from deeplearning4j_tpu.zoo import DecoderConfig, DecoderModel
 from tests.test_attention_kernels import _equations
@@ -71,6 +73,65 @@ def test_rotary_scores_depend_on_the_distance_only():
                        * rotary_interleaved(kk, jnp.array([pk]), 1e4))
 
     np.testing.assert_allclose(score(7, 3), score(104, 100), rtol=1e-4)
+
+
+def _pair_stack_rotary(x, positions, base):
+    """The interleaved rotation as `_qkv` called it until PR 36, written
+    out: pairs pulled apart through a `[..., d/2, 2]` view, turned, stacked.
+    `x` [..., T, heads, d]."""
+    d = x.shape[-1]
+    inv_freq = base ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = (positions.astype(jnp.float32)[..., None] * inv_freq)[..., None, :]
+    pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], d // 2, 2)
+    a, b = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([a * jnp.cos(ang) - b * jnp.sin(ang),
+                     a * jnp.sin(ang) + b * jnp.cos(ang)], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def _by_pairs(x, positions, base):
+    """`rotary_pairs` on the even and the odd lanes of `x` [..., T, heads,
+    d], laid back in the interleaved order."""
+    a, b = rotary_pairs(x[..., 0::2], x[..., 1::2], positions[..., None],
+                        base)
+    return jnp.stack([a, b], -1).reshape(x.shape)
+
+
+def test_rotary_pairs_hand_case():
+    """`rotary_interleaved`'s hand case, the two members of each pair
+    handed over apart: d = 4, base 100, pair 0 = (x0, x1) turns by pos,
+    pair 1 = (x2, x3) by pos / 10."""
+    a = jnp.array([[1.0, 0.0], [1.0, 0.0]])         # [T, d/2]: x0, x2
+    b = jnp.array([[0.0, 2.0], [0.0, 2.0]])         #            x1, x3
+    ra, rb = (np.asarray(r) for r in rotary_pairs(a, b, jnp.arange(2), 100.0))
+    np.testing.assert_allclose(ra[0], [1, 0], atol=1e-7)
+    np.testing.assert_allclose(rb[0], [0, 2], atol=1e-7)
+    np.testing.assert_allclose(ra[1], [np.cos(1.0), -2 * np.sin(0.1)],
+                               rtol=1e-6)
+    np.testing.assert_allclose(rb[1], [np.sin(1.0), 2 * np.cos(0.1)],
+                               rtol=1e-6)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("positions", ["arange", "repeated", "batch"])
+def test_rotary_pairs_is_the_interleaved_rotation(positions, dtype):
+    """Against `rotary_interleaved` and against the pair-stack formula
+    written out above: the same multiplies and adds in float32, so the same
+    bits, at positions `0..T-1`, at positions that repeat (the diffusion
+    objective's `arange(2L) % L`) and at a batch of positions."""
+    T, nh, d = 12, 3, 8
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, T, nh, d)).astype(dtype)
+    pos = {"arange": jnp.arange(T), "repeated": jnp.arange(T) % 6,
+           "batch": jnp.stack([jnp.arange(T), 100 + 3 * jnp.arange(T)])
+           }[positions]
+    got = _by_pairs(x, pos, 1e6)
+    assert got.dtype == x.dtype
+    for want in (rotary_interleaved(x, pos, 1e6),
+                 _pair_stack_rotary(x, pos, 1e6)):
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+    assert np.abs(np.asarray(got, np.float32)
+                  - np.asarray(x, np.float32)).max() > 0.1
 
 
 # ---------------------------------------------------------------------------
@@ -708,7 +769,7 @@ def test_a_blocks_gradient_runs_the_flash_forward_once(block, scheme,
 
 @pytest.mark.parametrize("block", ["dense", "expert", "scanned_experts"])
 def test_a_block_saves_its_input_and_the_kernels_two_results(block, capsys):
-    """Beside the block's arguments (and rotary's two-element constants)
+    """Beside the block's arguments (and the constants of the rotation)
     the backward pass is handed the kernel's output [B, H, T, Dv] and the
     logsumexp as [B*H, T] — not the kernel's own [B*H, T, 1], which pads to
     128 lanes on the chip — and nothing else."""
@@ -765,3 +826,139 @@ def test_without_the_kernels_a_block_is_recomputed_whole(capsys):
     lines = capsys.readouterr().out.strip().splitlines()
     assert lines and all(" from the argument " in l
                          or " from a constant" in l for l in lines), lines
+
+
+# ---------------------------------------------------------------------------
+# latent attention against the form it had until PR 36
+# ---------------------------------------------------------------------------
+
+def _qkv_before(model, x, lp):
+    """`DecoderModel._qkv` as it stood before PR 36, in plain jnp: one
+    product for q and one for k-nope and v together, the rotary lanes
+    sliced, turned through the pair-stack, concatenated back, then the
+    head-first transposes.  The reference of the tests below."""
+    c = model.config
+    B, T, _ = x.shape
+    nh, dn, dr, dv = c.n_heads, c.qk_nope_dim, c.qk_rope_dim, c.v_head_dim
+    pos = jnp.arange(T)
+    q = (x @ lp["Wq"]).reshape(B, T, nh, dn + dr)
+    kva = x @ lp["Wkva"]
+    latent = rms_norm(kva[..., :c.kv_lora_rank], lp["kv_norm"], c.eps)
+    kv = (latent @ lp["Wkvb"]).reshape(B, T, nh, dn + dv)
+    q_rope = _pair_stack_rotary(q[..., dn:], pos, c.rope_base)
+    k_rope = _pair_stack_rotary(kva[..., None, c.kv_lora_rank:], pos,
+                                c.rope_base)
+    q = jnp.concatenate([q[..., :dn], q_rope], -1)
+    k = jnp.concatenate(
+        [kv[..., :dn], jnp.broadcast_to(k_rope, (B, T, nh, dr))], -1)
+    heads_first = (0, 2, 1, 3)
+    return (q.transpose(heads_first), k.transpose(heads_first),
+            kv[..., dn:].transpose(heads_first))
+
+
+def _attention_before(model, x, lp, L=None):
+    """`DecoderModel._attention` as it stood before PR 36."""
+    c = model.config
+    B, T, _ = x.shape
+    dt = lp["Wo"].dtype
+    q, k, v = _qkv_before(model, rms_norm(x, lp["norm1"], c.eps).astype(dt),
+                          lp)
+    o = ak.mha_reference(q, k, v, causal=True)
+    o = o.transpose(0, 2, 1, 3).reshape(B, T, -1)
+    return x + (o @ lp["Wo"]).astype(x.dtype)
+
+
+def _latent_layer(seed=11):
+    """A model, its dense layer's parameters with gains that are not all
+    one, and a batch of residual-stream rows."""
+    m = DecoderModel(DecoderConfig.tiny(), seed=seed)
+    lp = jax.tree_util.tree_map(lambda a: a[0], m.params_["dense"])
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
+    lp = {**lp,
+          "kv_norm": 1 + 0.2 * jax.random.normal(k1, lp["kv_norm"].shape),
+          "norm1": 1 + 0.2 * jax.random.normal(k2, lp["norm1"].shape)}
+    return m, lp, jax.random.normal(k3, (2, 16, m.config.hidden))
+
+
+def _rotary_lanes_apart(c):
+    """Where each lane of a head of `_qkv`'s q and k stands in the
+    checkpoint's order: the nope lanes, every pair's first member, every
+    pair's second."""
+    dn, dr = c.qk_nope_dim, c.qk_rope_dim
+    return np.concatenate([np.arange(dn), dn + np.arange(0, dr, 2),
+                           dn + np.arange(1, dr, 2)])
+
+
+def _close(got, want, tol=1e-5):
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.shape == want.shape
+    assert np.abs(got - want).max() <= tol * np.abs(want).max()
+
+
+@pytest.mark.parametrize("what", ["q", "k", "v", "scores", "out"])
+def test_latent_attention_is_what_it_was(what):
+    """float32, within 1e-5 of the largest value: v and the layer's output
+    as they were; q and k as they were with the rotary lanes of every head
+    de-interleaved alike, so every `q . k` as it was."""
+    m, lp, x = _latent_layer()
+    q0, k0, v0 = _qkv_before(m, x, lp)
+    q, k, v, mask = m._qkv(x, lp)
+    assert mask == {"causal": True}
+    lanes = _rotary_lanes_apart(m.config)
+    if what == "q":
+        _close(q, q0[..., lanes])
+    elif what == "k":
+        _close(k, k0[..., lanes])
+    elif what == "v":
+        _close(v, v0)
+    elif what == "scores":
+        _close(jnp.einsum("bhqd,bhkd->bhqk", q, k),
+               jnp.einsum("bhqd,bhkd->bhqk", q0, k0))
+    else:
+        _close(m._attention(x, lp), _attention_before(m, x, lp))
+
+
+@pytest.mark.parametrize("wrt", ["Wq", "Wkva", "Wkvb", "Wo", "kv_norm",
+                                 "norm1", "x"])
+def test_latent_attention_gradients_are_what_they_were(wrt):
+    m, lp, x = _latent_layer()
+    ct = jax.random.normal(jax.random.PRNGKey(12), x.shape)
+
+    def grads(layer):
+        return jax.grad(lambda x, lp: jnp.sum(layer(x, lp) * ct),
+                        argnums=(0, 1))(x, lp)
+
+    (gx, glp), (gx0, glp0) = grads(m._attention), grads(
+        lambda x, lp: _attention_before(m, x, lp))
+    _close(*((gx, gx0) if wrt == "x" else (glp[wrt], glp0[wrt])))
+
+
+# sha256 over the leaves of `DecoderModel(DecoderConfig.tiny(), seed=3)
+# .params_` in tree order, read on the tree before PR 36 under the tests' x64
+# (the gains are float64 there; with x64 off it reads fe126cdd2423b2d9...)
+TINY_PARAMS_SHA256 = (
+    "9a061ba339f1eef3b9171ec7d4aba10c3ea7accb0ee4ce6ff3c689010403db20")
+
+
+def test_a_checkpoint_from_before_gives_the_logits_from_before():
+    """Same model: the tree, the shapes and the init draws are what they
+    were (the hash was read on the parent's tree), `save`/`load` carry
+    them, and the loaded model's logits are those of the same parameters
+    under the layer as it stood before."""
+    m = DecoderModel(DecoderConfig.tiny(), seed=3)
+    h = hashlib.sha256()
+    for leaf in jax.tree_util.tree_leaves(m.params_):
+        h.update(np.asarray(leaf).tobytes())
+    assert h.hexdigest() == TINY_PARAMS_SHA256
+    assert {k: v.shape for k, v in m.params_["dense"].items()
+            if k.startswith(("W", "kv"))} == {
+        "Wq": (1, 32, 24), "Wkva": (1, 32, 20), "kv_norm": (1, 16),
+        "Wkvb": (1, 16, 32), "Wo": (1, 16, 32)}
+    f = io.BytesIO()
+    m.save(f)
+    f.seek(0)
+    loaded = DecoderModel.load(f)
+    before = DecoderModel(DecoderConfig.tiny(), seed=3)
+    before._attention = lambda x, lp, L=None: _attention_before(before, x, lp)
+    ids = _batch(8).features[0]
+    _close(loaded.output(ids), before.output(ids))

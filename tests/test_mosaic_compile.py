@@ -1,17 +1,22 @@
 """The attention kernels of the train path, compiled by Mosaic for a
 DESCRIBED TPU v5e (no chip attached, nothing runs): what interpret mode
 cannot see — a block the tiling rule refuses, more VMEM than the call
-states, an index map Mosaic cannot lower.  A compile that passes is not a
-chip run and gives no time.
+states, an index map Mosaic cannot lower — and, for the latent-attention
+layer around them, which arrays XLA copies between a product and a kernel.
+A compile that passes is not a chip run and gives no time.
 
 The topology is described inside a fixture and only in this file: the
 process that describes it loads libtpu and keeps it (see the
 `on-chip-measurement` guide, section 2)."""
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 
 from deeplearning4j_tpu.ops import attention_kernels as ak
+from deeplearning4j_tpu.ops import pallas as tier
+from deeplearning4j_tpu.zoo import DecoderConfig, DecoderModel
 
 
 @pytest.fixture(scope="module")
@@ -36,10 +41,14 @@ def one_chip():
 
 
 def _compile(fn, one_chip, *shapes):
-    """Lower for the TPU and compile; x64 off, as on the chip."""
+    """Lower for the TPU and compile; x64 off, as on the chip.  A shape is
+    `(dims, dtype)` or a tree of them."""
+    def spec(s):
+        return jax.ShapeDtypeStruct(s[0], s[1], sharding=one_chip)
+
     with jax.enable_x64(False):
-        specs = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
-                 for s, d in shapes]
+        specs = [jax.tree_util.tree_map(
+            spec, s, is_leaf=lambda s: isinstance(s, tuple)) for s in shapes]
         return jax.jit(fn).trace(*specs).lower(
             lowering_platforms=("tpu",)).compile()
 
@@ -152,3 +161,63 @@ def test_block_diffusion_kernels_compile_for_v5e(one_chip):
     # no [T, T] array in either program
     for compiled in (fwd, bwd):
         assert f"{T},{T}]" not in compiled.as_text()
+
+
+# a `transpose` or `copy` whose result is a whole q, k, v or kernel output of
+# kanana's cell, in either order of tokens and heads
+_HEADS_COPY = re.compile(
+    r"= \w+\[(?:2,32,4096|2,4096,32),\d+\]\S* (?:transpose|copy)\(")
+_MINOR_TWO = re.compile(r"\[(?:\d+,)+2\]")
+
+
+@pytest.fixture
+def forced_kernels(monkeypatch):
+    """`fused_attention` takes the Mosaic kernels, compiled: the CPU
+    backend the tests run on would pick the XLA branch, or interpret mode."""
+    tier.dispatch.set_dispatch_mode("pallas")
+    monkeypatch.setattr(tier.dispatch, "interpret_mode", lambda: False)
+    yield
+    tier.dispatch.reset()
+
+
+# (forward, forward + backward): what the layer compiled to when this was
+# written (PR 36); the parent's form read (5, 16) and held 22 and 64 arrays
+# with a minor dimension of 2
+_HEADS_COPIES = (2, 9)
+
+
+def test_latent_attention_layer_compiles_for_v5e(one_chip, forced_kernels):
+    """kanana's latent-attention layer, `[2, 4096, 2048]` in, 32 heads of
+    128 + 64 / 128 over a latent of 512, bf16 over a float32 stream, under
+    the decoder's checkpoint policy, forward and gradient.  Between a
+    product and a kernel XLA copies q and k once each (a 192-wide result
+    leaves its product with the tokens in the lanes) and v never; the
+    backward adds dq, dk, dv and the saved output on their way into the
+    weight gradients.  No array has a minor dimension of 2: the rotation
+    works on whole lanes."""
+    c = DecoderConfig(hidden=2048, n_heads=32, qk_nope_dim=128,
+                      qk_rope_dim=64, v_head_dim=128, kv_lora_rank=512,
+                      rope_base=1e6, compute_dtype="bfloat16")
+    model = object.__new__(DecoderModel)    # the layer needs no parameters
+    model.config, model._diffusion = c, False
+    nh, qk = c.n_heads, c.qk_nope_dim + c.qk_rope_dim
+    dt = jnp.bfloat16
+    lp = {"norm1": ((c.hidden,), dt),
+          "Wq": ((c.hidden, nh * qk), dt),
+          "Wkva": ((c.hidden, c.kv_lora_rank + c.qk_rope_dim), dt),
+          "kv_norm": ((c.kv_lora_rank,), dt),
+          "Wkvb": ((c.kv_lora_rank, nh * (c.qk_nope_dim + c.v_head_dim)), dt),
+          "Wo": ((nh * c.v_head_dim, c.hidden), dt)}
+    x = ((2, 4096, c.hidden), jnp.float32)
+    layer = jax.checkpoint(
+        model._attention, policy=jax.checkpoint_policies.
+        save_only_these_names(ak.FLASH_OUT, ak.FLASH_LSE))
+    fwd = _compile(layer, one_chip, x, lp).as_text()
+    grad = _compile(
+        jax.grad(lambda x, lp, ct: jnp.sum(layer(x, lp) * ct), (0, 1)),
+        one_chip, x, lp, x).as_text()
+    assert fwd.count("tpu_custom_call") == 1
+    assert grad.count("tpu_custom_call") == 2   # no second forward kernel
+    for text, most in zip((fwd, grad), _HEADS_COPIES):
+        assert not _MINOR_TWO.findall(text)
+        assert len(_HEADS_COPY.findall(text)) <= most
