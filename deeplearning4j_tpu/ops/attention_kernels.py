@@ -49,6 +49,17 @@ of earlier blocks; no clean row sees a noisy one.  With no noisy rows that
 is attention causal over blocks and bidirectional inside one.  Every branch
 builds it from row and column numbers, and no [T, S] array reaches HBM.
 
+A `selection` is the one mask that is DATA: which keys each query may see,
+chosen on the device (`ops/sparse_index.py`: learned sparse attention), the
+same for every head, a subset of the causal pairs.  It travels as a
+`Selection`, one bit a pair, packed twice — 32 queries to a word
+(`by_query` [B, T/32, S], what a forward tile, queries by keys, unpacks
+along its sublanes) and 32 keys to a word (`by_key` [B, S/32, T], the same
+for the backward's tiles, keys by queries) — 2 x 33.5 MB at 16,384 tokens
+where one byte a pair is 268 MB.  The kernels walk `causal`'s schedule (a
+tile above the diagonal holds no selected pair) and mask every live tile by
+its bits; the two XLA paths unpack it to a [B, T, S] keep-mask.
+
 Which tiles of a mask the Mosaic kernels visit is said in ONE place,
 `tile_kinds` (numpy, from T, S, the tile, the mask and a span's offset):
 empty, partial or full, for `causal` and the block mask alike (at 512 x
@@ -145,10 +156,67 @@ def _over_query_heads(q, k, v):
     return jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
 
 
+class Selection(NamedTuple):
+    """Which (query, key) pairs attention keeps, one bit a pair, the same
+    for every head (module docstring).  Bit `r` of `by_query[b, i, s]` is
+    pair `(32 i + r, s)`; bit `r` of `by_key[b, j, t]` is pair `(t, 32 j +
+    r)`.  int32 both."""
+    by_query: jax.Array     # [B, T/32, S]
+    by_key: jax.Array       # [B, S/32, T]
+
+
+def _pack_bits(keep, axis: int):
+    """bool [...] -> int32 with `axis` cut to a 32nd: bit r of word i is
+    element `32 i + r` along it."""
+    shape = keep.shape
+    keep = keep.reshape(*shape[:axis], shape[axis] // 32, 32,
+                        *shape[axis + 1:])
+    r = jnp.arange(32, dtype=jnp.uint32).reshape(
+        (32,) + (1,) * (len(shape) - axis - 1))
+    return jax.lax.bitcast_convert_type(
+        jnp.sum(keep.astype(jnp.uint32) << r, axis=axis + 1,
+                dtype=jnp.uint32), jnp.int32)
+
+
+def _unpack_bits(words, axis: int = -2):
+    """The inverse of `_pack_bits`: int32 [..., n, m] -> bool [..., 32 n, m]
+    (`axis` -2, the one the kernels use on a tile), from a sublane
+    broadcast, a shift by the row's number in its word and a test."""
+    axis %= words.ndim
+    shape = words.shape
+    n = shape[axis]
+    bits = jnp.broadcast_to(
+        jnp.expand_dims(words, axis + 1),
+        (*shape[:axis], n, 32, *shape[axis + 1:])).reshape(
+        *shape[:axis], 32 * n, *shape[axis + 1:])
+    r = jax.lax.broadcasted_iota(jnp.int32, bits.shape, axis) & 31
+    return ((bits >> r) & 1) != 0
+
+
+def pack_selection(keep) -> Selection:
+    """`Selection` of a boolean keep-mask [B, T, S] (T and S multiples of
+    32)."""
+    return Selection(_pack_bits(keep, 1),
+                     _pack_bits(keep, 2).transpose(0, 2, 1))
+
+
+def unpack_selection(selection: Selection):
+    """The boolean keep-mask [B, T, S] of a `Selection`."""
+    return _unpack_bits(selection.by_query, 1)
+
+
+def _keep(mask):
+    """A keep-mask over keys [B, S] or over pairs [B, T, S], against scores
+    [B, H, T, S]."""
+    return (mask[:, None, None, :] if mask.ndim == 2 else mask[:, None]) > 0
+
+
 def mha_reference(q, k, v, mask=None, causal=False, scale=None,
-                  block_diffusion=None):
-    """Naive attention (ground truth).  mask: [B, T] of 1/0 over KV
-    positions; `block_diffusion`: (L, B), the module docstring's mask."""
+                  block_diffusion=None, return_lse=False):
+    """Naive attention (ground truth).  mask: [B, S] of 1/0 over KV
+    positions, or [B, T, S] over (query, key) pairs; `block_diffusion`: (L,
+    B), the module docstring's mask.  With `return_lse` also the rows'
+    logsumexp [B, H, T] (float32)."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     k, v = _over_query_heads(q, k, v)
@@ -159,7 +227,7 @@ def mha_reference(q, k, v, mask=None, causal=False, scale=None,
         ki = jnp.arange(S)[None, :]
         scores = jnp.where(qi >= ki, scores, NEG_INF)
     if mask is not None:
-        scores = jnp.where(mask[:, None, None, :] > 0, scores, NEG_INF)
+        scores = jnp.where(_keep(mask), scores, NEG_INF)
     if block_diffusion is not None:
         T, S = q.shape[2], k.shape[2]
         _check_block_diffusion(T, S, block_diffusion)
@@ -168,7 +236,10 @@ def mha_reference(q, k, v, mask=None, causal=False, scale=None,
             jnp.arange(S, dtype=jnp.int32)[None, :], T, *block_diffusion)
         scores = jnp.where(keep, scores, NEG_INF)
     p = jax.nn.softmax(scores, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+    out = jnp.einsum("bhqk,bhkd->bhqd", p, v)
+    if return_lse:
+        return out, jax.nn.logsumexp(scores.astype(jnp.float32), axis=-1)
+    return out
 
 
 def _blockwise_fwd(q, k, v, mask, causal, scale, block_k,
@@ -182,10 +253,12 @@ def _blockwise_fwd(q, k, v, mask, causal, scale, block_k,
 
     kb = k.reshape(B, H, nblocks, block_k, D).transpose(2, 0, 1, 3, 4)
     vb = v.reshape(B, H, nblocks, block_k, Dv).transpose(2, 0, 1, 3, 4)
-    if mask is not None:
-        mb = mask.reshape(B, nblocks, block_k).transpose(1, 0, 2)
-    else:
+    if mask is None:
         mb = jnp.ones((nblocks, B, block_k), q.dtype)
+    elif mask.ndim == 2:
+        mb = mask.reshape(B, nblocks, block_k).transpose(1, 0, 2)
+    else:                                   # over pairs: [B, T, S]
+        mb = mask.reshape(B, T, nblocks, block_k).transpose(2, 0, 1, 3)
 
     def step(carry, blk):
         acc, m, l, j = carry
@@ -195,7 +268,7 @@ def _blockwise_fwd(q, k, v, mask, causal, scale, block_k,
         # degrades the softmax normalizer)
         s = jnp.einsum("bhqd,bhkd->bhqk", qs, kj,
                        preferred_element_type=jnp.float32)  # [B,H,T,bk]
-        s = jnp.where(mj[:, None, None, :] > 0, s, NEG_INF)
+        s = jnp.where(_keep(mj), s, NEG_INF)
         if causal:
             qi = jnp.arange(T)[:, None]
             ki = j * block_k + jnp.arange(block_k)[None, :]
@@ -384,7 +457,8 @@ def _by_kind(flags, kinds, tile):
 
 def _flash_kernel(qb_ref, kb_ref, flags_ref, q_ref, k_ref, v_ref, *rest,
                   block_q: int, block_k: int, kinds, causal: bool,
-                  scale: float, has_mask: bool, block_diffusion=None):
+                  scale: float, has_mask: bool, block_diffusion=None,
+                  has_selection: bool = False):
     """Grid (batch*head, live tile): step `t` is tile (`qb_ref[t]`,
     `kb_ref[t]`) of a forward `TileSchedule` — a query block's live tiles
     one after another, key blocks ascending — so a tile the mask leaves
@@ -395,12 +469,14 @@ def _flash_kernel(qb_ref, kb_ref, flags_ref, q_ref, k_ref, v_ref, *rest,
     ``block_diffusion``'s ((noisy rows, block length)) kept pairs from iota.
     Emits per-row logsumexp for the backward kernel.  With ``has_mask`` an
     additive f32 bias block [1, 1, bk] (0 keep / NEG_INF drop over KV
-    positions) precedes the outputs and every tile is partial."""
-    if has_mask:
-        bias_ref, o_ref, lse_ref, acc_sc, m_sc, l_sc = rest
-    else:
-        o_ref, lse_ref, acc_sc, m_sc, l_sc = rest
-        bias_ref = None
+    positions) precedes the outputs and every tile is partial.  With
+    ``has_selection`` the tile's bits of `Selection.by_query`, [1, bq/32,
+    bk], come last of the inputs: every tile is partial and the bits are
+    its whole mask (a selection holds causal pairs only)."""
+    rest = list(rest)
+    bias_ref = rest.pop(0) if has_mask else None
+    sel_ref = rest.pop(0) if has_selection else None
+    o_ref, lse_ref, acc_sc, m_sc, l_sc = rest
     t = pl.program_id(1)
     qi, j, flags = qb_ref[t], kb_ref[t], flags_ref[t]
 
@@ -422,7 +498,9 @@ def _flash_kernel(qb_ref, kb_ref, flags_ref, q_ref, k_ref, v_ref, *rest,
             s = jnp.where(_bd_keep_tile(
                 *_bd_quadrant(qi * block_q, j * block_k, o, jnp.where),
                 block_q, block_k, B), s, NEG_INF)
-        if partial and causal:
+        if has_selection:
+            s = jnp.where(_unpack_bits(sel_ref[0]), s, NEG_INF)
+        elif partial and causal:
             rows = (qi * block_q
                     + jax.lax.broadcasted_iota(jnp.int32,
                                                (block_q, block_k), 0))
@@ -467,9 +545,25 @@ def _bd_static(T, S, bq, bk, block_diffusion) -> dict:
     return {"block_diffusion": (T - L, B)}
 
 
+def _check_selection(selection, causal, mask, block_diffusion,
+                     block: int) -> bool:
+    """Whether a kernel call carries a selection; one that does is causal,
+    has no other mask and unpacks whole words along `block`."""
+    if selection is None:
+        return False
+    if not causal or mask is not None or block_diffusion is not None:
+        raise ValueError("a selection holds causal pairs: it goes with "
+                         "`causal` and no other mask")
+    if block % 32:
+        raise ValueError(f"a block of {block} is no whole 32-bit words of "
+                         f"a selection")
+    return True
+
+
 def flash_attention_tpu(q, k, v, causal=False, scale=None,
                         block_q=256, block_k=256, interpret=False,
-                        return_lse=False, mask=None, block_diffusion=None):
+                        return_lse=False, mask=None, block_diffusion=None,
+                        selection: Optional[Selection] = None):
     """Pallas flash-attention forward.  q [B, H, T, D], k [B, Hk, S, D],
     v [B, Hk, S, Dv] -> [B, H, T, Dv]; T and S divisible by the block sizes
     (dispatcher checks), H a multiple of Hk (a key-value head's blocks are
@@ -479,7 +573,9 @@ def flash_attention_tpu(q, k, v, causal=False, scale=None,
     ``mask``: optional [B, S] 1/0 keep-mask over KV positions
     (padding/segment mask), shared across heads.  ``block_diffusion``:
     (L, B) of the module docstring's mask; the blocks divide L, so that no
-    tile lies across the first clean row."""
+    tile lies across the first clean row.  ``selection``: a `Selection` of
+    causal pairs (with ``causal``, whose schedule the grid walks); the query
+    block is whole words of it."""
     B, H, T, D = q.shape
     S, Dv = k.shape[2], v.shape[3]
     if scale is None:
@@ -487,6 +583,8 @@ def flash_attention_tpu(q, k, v, causal=False, scale=None,
     bq = min(block_q, T)
     bk = min(block_k, S)
     bd = _bd_static(T, S, bq, bk, block_diffusion)
+    has_selection = _check_selection(selection, causal, mask,
+                                     block_diffusion, bq)
     Hk = k.shape[1]
     group = H // Hk
     # grid row b = batch * H + head reads the row b // group of k and v
@@ -496,10 +594,12 @@ def flash_attention_tpu(q, k, v, causal=False, scale=None,
     kf = k.reshape(B * Hk, S, D)
     vf = v.reshape(B * Hk, S, Dv)
     has_mask = mask is not None
-    sched = tile_schedule(T, S, bq, bk, causal, block_diffusion, has_mask)
+    sched = tile_schedule(T, S, bq, bk, causal, block_diffusion,
+                          has_mask or has_selection)
     kernel = functools.partial(_flash_kernel, block_q=bq, block_k=bk,
                                kinds=sched.kinds, causal=causal, scale=scale,
-                               has_mask=has_mask, **bd)
+                               has_mask=has_mask,
+                               has_selection=has_selection, **bd)
     # the index maps read step t's blocks from the schedule (in SMEM)
     q_rows = lambda b, t, qb, kb, _: (b, qb[t], 0)
     k_rows = lambda b, t, qb, kb, _: (kv_row(b), kb[t], 0)
@@ -515,6 +615,11 @@ def flash_attention_tpu(q, k, v, causal=False, scale=None,
         in_specs.append(pl.BlockSpec(
             (1, 1, bk), lambda b, t, qb, kb, _, H=H: (b // H, 0, kb[t])))
         inputs.append(_mask_bias3(mask, B, S))
+    if has_selection:       # one for all heads: the index map divides them out
+        in_specs.append(pl.BlockSpec(
+            (1, bq // 32, bk),
+            lambda b, t, qb, kb, _, H=H: (b // H, qb[t], kb[t])))
+        inputs.append(selection.by_query)
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -558,7 +663,8 @@ _NT = (((1,), (1,)), ((), ()))                 # a [m, c], b [n, c] -> [m, n]
 def _flash_bwd_kernel(qb_ref, kb_ref, flags_ref, q_ref, k_ref, v_ref, do_ref,
                       lse_ref, delta_ref, *rest, block_q: int, block_k: int,
                       kinds, head_blocks: int, q_offset: int, causal: bool,
-                      scale: float, has_mask: bool, block_diffusion=None):
+                      scale: float, has_mask: bool, block_diffusion=None,
+                      has_selection: bool = False):
     """dQ, dK and dV over grid (batch*key-value head, live tile): step `t`
     is tile (`qb_ref[t]`, `kb_ref[t]`) of a backward `TileSchedule` — a key
     block's live tiles one after another, the q blocks innermost.  A tile
@@ -574,12 +680,13 @@ def _flash_bwd_kernel(qb_ref, kb_ref, flags_ref, q_ref, k_ref, v_ref, do_ref,
     longer sequence).  The q blocks of a grid row are ``head_blocks`` blocks
     of each query head of a group, head after head: a block's positions
     start anew with each head, and dK/dV sum over all of them.  Full and
-    partial tiles, ``block_diffusion``: as in the forward kernel."""
-    if has_mask:
-        bias_ref, dq_ref, dk_ref, dv_ref, dq_sc, dk_sc, dv_sc = rest
-    else:
-        dq_ref, dk_ref, dv_ref, dq_sc, dk_sc, dv_sc = rest
-        bias_ref = None
+    partial tiles, ``block_diffusion``: as in the forward kernel;
+    ``has_selection``: the tile's bits of `Selection.by_key`, [1, bk/32,
+    bq]."""
+    rest = list(rest)
+    bias_ref = rest.pop(0) if has_mask else None
+    sel_ref = rest.pop(0) if has_selection else None
+    dq_ref, dk_ref, dv_ref, dq_sc, dk_sc, dv_sc = rest
     t = pl.program_id(1)
     i, j, flags = qb_ref[t], kb_ref[t], flags_ref[t]
     rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
@@ -609,7 +716,9 @@ def _flash_bwd_kernel(qb_ref, kb_ref, flags_ref, q_ref, k_ref, v_ref, do_ref,
             s = jnp.where(_bd_keep_tile(
                 *_bd_quadrant(q0, j * block_k, o, jnp.where),
                 block_q, block_k, B, keys_first=True), s, NEG_INF)
-        if partial and causal:
+        if has_selection:
+            s = jnp.where(_unpack_bits(sel_ref[0]), s, NEG_INF)
+        elif partial and causal:
             keys = (j * block_k
                     + jax.lax.broadcasted_iota(jnp.int32,
                                                (block_k, block_q), 0))
@@ -683,7 +792,8 @@ def _sum_over_spans(parts, seen, bk, dtype):
 
 def flash_attention_bwd_tpu(q, k, v, out, lse, g, causal=False, scale=None,
                             block_q=256, block_k=256, interpret=False,
-                            mask=None, block_diffusion=None):
+                            mask=None, block_diffusion=None,
+                            selection: Optional[Selection] = None):
     """Pallas flash-attention backward: delta precomputed on-device, then
     ONE kernel (`_flash_bwd_kernel`) that computes the scores, P, dP and dS
     of a tile once and takes dQ, dK and dV from them — no [T, T] array, no
@@ -712,6 +822,8 @@ def flash_attention_bwd_tpu(q, k, v, out, lse, g, causal=False, scale=None,
     bq, bk, span = _bwd_plan(T, S, D, Dv, q.dtype.itemsize, block_q, block_k,
                              G)
     bd = _bd_static(T, S, bq, bk, block_diffusion)
+    has_selection = _check_selection(selection, causal, mask,
+                                     block_diffusion, bk)
     qf = q.reshape(B * H, T, D)
     kf = k.reshape(B * Hk, S, D)
     vf = v.reshape(B * Hk, S, Dv)
@@ -736,7 +848,13 @@ def flash_attention_bwd_tpu(q, k, v, out, lse, g, causal=False, scale=None,
         n = t1 - t0
         nq = n // bq                    # q blocks of one head
         sched = tile_schedule(T, S, bq, bk, causal, block_diffusion,
-                              has_mask, t0, n, G, True)
+                              has_mask or has_selection, t0, n, G, True)
+        sel_in, sel_specs = [], []
+        if has_selection:   # the whole array: the map finds the span's and
+            sel_in = [selection.by_key]         # the head's query block
+            sel_specs = [pl.BlockSpec(
+                (1, bk // 32, bq), lambda b, t, qb, kb, _: (
+                    b // Hk, kb[t], t0 // bq + qb[t] % nq))]
 
         def rows(a):        # [B*H, T, .] -> the span's rows of a group,
             a = a[:, t0:t1]                         # head after head
@@ -748,7 +866,7 @@ def flash_attention_bwd_tpu(q, k, v, out, lse, g, causal=False, scale=None,
         kernel = functools.partial(
             _flash_bwd_kernel, block_q=bq, block_k=bk, kinds=sched.kinds,
             head_blocks=nq, q_offset=t0, causal=causal, scale=scale,
-            has_mask=has_mask, **bd)
+            has_mask=has_mask, has_selection=has_selection, **bd)
         q_rows = lambda b, t, qb, kb, _: (b, qb[t], 0)
         k_rows = lambda b, t, qb, kb, _: (b, kb[t], 0)
         stat_spec = pl.BlockSpec((1, 1, 1, bq),
@@ -764,7 +882,7 @@ def flash_attention_bwd_tpu(q, k, v, out, lse, g, causal=False, scale=None,
                     pl.BlockSpec((1, bk, Dv), k_rows),
                     pl.BlockSpec((1, bq, Dv), q_rows),
                     stat_spec, stat_spec,
-                ] + extra_specs,
+                ] + extra_specs + sel_specs,
                 out_specs=[
                     # dQ: one block a (batch, key-value head), written back
                     # when it ends
@@ -787,7 +905,7 @@ def flash_attention_bwd_tpu(q, k, v, out, lse, g, causal=False, scale=None,
                 vmem_limit_bytes=_BWD_VMEM_LIMIT),
             interpret=interpret,
         )(sched.q, sched.k, sched.flags, rows(qf), kf, vf, rows(gf),
-          stat(lse), stat(delta), *extra_in), sched.keys_seen
+          stat(lse), stat(delta), *extra_in, *sel_in), sched.keys_seen
 
     spans = range(0, T, span)
     parts, seen = zip(*(
@@ -852,6 +970,40 @@ def _fa_bwd(causal, scale, block_q, block_k, interpret, block_diffusion, res,
 _flash_attention_diff.defvjp(_fa_fwd, _fa_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _flash_selected_diff(q, k, v, by_query, by_key, scale, block_q=256,
+                         block_k=256, interpret=False):
+    """Causal flash attention over a `Selection`'s pairs: `(out, logsumexp
+    [B, H, T])`.  The logsumexp is handed out for a reader that stops the
+    gradient (the index loss of `ops/sparse_index.py`): its cotangent is
+    dropped."""
+    return _fs_fwd(q, k, v, by_query, by_key, scale, block_q, block_k,
+                   interpret)[0]
+
+
+def _fs_fwd(q, k, v, by_query, by_key, scale, block_q, block_k, interpret):
+    """As `_fa_fwd`, the same two names; the selection is an input, so a
+    block under such a policy keeps it only if its maker names it."""
+    B, H, T, _ = q.shape
+    out, lse = flash_attention_tpu(
+        q, k, v, True, scale, block_q, block_k, return_lse=True,
+        interpret=interpret, selection=Selection(by_query, by_key))
+    out = checkpoint_name(out, FLASH_OUT)
+    lse = checkpoint_name(lse, FLASH_LSE)
+    return (out, lse.reshape(B, H, T)), (q, k, v, by_query, by_key, out, lse)
+
+
+def _fs_bwd(scale, block_q, block_k, interpret, res, g):
+    q, k, v, by_query, by_key, out, lse = res
+    dq, dk, dv = flash_attention_bwd_tpu(
+        q, k, v, out, lse, g[0], True, scale, block_q, block_k,
+        interpret=interpret, selection=Selection(by_query, by_key))
+    return dq, dk, dv, None, None
+
+
+_flash_selected_diff.defvjp(_fs_fwd, _fs_bwd)
+
+
 def _pick_block(x: int, prefer: int) -> Optional[int]:
     for b in (prefer, 512, 256, 128):
         if b <= prefer and x % b == 0:
@@ -869,7 +1021,9 @@ _XLA_SCORE_BYTES_MAX = 2 << 30   # beyond ~2GB of scores, never take XLA path
 
 
 def fused_attention(q, k, v, mask=None, causal=False, scale=None,
-                    block_diffusion=None):
+                    block_diffusion=None,
+                    selection: Optional[Selection] = None,
+                    return_lse: bool = False):
     """Dispatcher (the platform-helper pattern — cuDNN-attention role):
 
     - kernel tier (`ops/pallas/dispatch`): Pallas flash kernels (fwd +
@@ -883,10 +1037,19 @@ def fused_attention(q, k, v, mask=None, causal=False, scale=None,
 
     `block_diffusion=(L, B)`: the mask of block-diffusion training over L
     or 2L rows (module docstring), in every branch; not with `mask` or
-    `causal`.  Differentiable everywhere."""
+    `causal`.  `selection`: a `Selection` of causal pairs, with `causal`
+    and no other mask, in every branch (the XLA paths unpack it to a [B, T,
+    S] keep-mask); `return_lse` then also gives the rows' logsumexp [B, H,
+    T] in float32, for a reader that stops the gradient.  Differentiable
+    everywhere."""
     from deeplearning4j_tpu.ops import pallas as _tier
     B, H, T, D = q.shape
     S = k.shape[2]
+    if selection is not None:
+        return _selected_attention(q, k, v, selection, causal, mask,
+                                   block_diffusion, scale, return_lse)
+    if return_lse:
+        raise ValueError("the logsumexp is handed out with a selection")
     masks = {} if block_diffusion is None else {
         "block_diffusion": tuple(int(n) for n in block_diffusion)}
     if masks and (causal or mask is not None):
@@ -903,6 +1066,27 @@ def fused_attention(q, k, v, mask=None, causal=False, scale=None,
     if score_bytes <= _XLA_SCORE_BYTES_MAX:
         return mha_reference(q, k, v, mask, causal, scale, **masks)
     return blockwise_attention(q, k, v, mask, causal, scale, **masks)
+
+
+def _selected_attention(q, k, v, selection, causal, mask, block_diffusion,
+                        scale, return_lse):
+    """`fused_attention` over a `Selection`: the flash kernels where the
+    tier takes them, else the naive path on the unpacked mask (the CPU, short
+    sequences), the logsumexp from the same scores."""
+    from deeplearning4j_tpu.ops import pallas as _tier
+    _check_selection(selection, causal, mask, block_diffusion, 32)
+    T, S, D = q.shape[2], k.shape[2], q.shape[3]
+    if _tier.dispatch.resolve("attention", q, k, v, causal=True,
+                              selection=selection) == "pallas":
+        out, lse = _tier.attention.flash_attention(
+            q, k, v, causal=True, scale=scale,
+            tile=_tier.dispatch.get_tile(
+                "attention", _tier.shape_class(t=T, s=S, d=D)),
+            interpret=_tier.dispatch.interpret_mode(), selection=selection)
+    else:
+        out, lse = mha_reference(q, k, v, unpack_selection(selection),
+                                 scale=scale, return_lse=True)
+    return (out, jax.lax.stop_gradient(lse)) if return_lse else out
 
 
 # ---------------------------------------------------------------------------

@@ -14,7 +14,8 @@ Importing this package registers the kernel set; call sites go through
 """
 from deeplearning4j_tpu.ops.pallas import (attention, dispatch,
                                            grouped_matmul, matmul,
-                                           paged_attention, tiles)
+                                           paged_attention, sparse_index,
+                                           tiles)
 from deeplearning4j_tpu.ops.pallas.tiles import (  # noqa: F401
     DEFAULT_TILES,
     TILE_FORMAT,
@@ -66,6 +67,13 @@ dispatch.register(
     supports=grouped_matmul.grouped_supports,
     profitable=grouped_matmul.grouped_profitable,
 )
+# three kernels, one answer: `ops/sparse_index.py` asks once a call
+dispatch.register(
+    "sparse_index",
+    pallas_fn=sparse_index.index_scores,
+    reference_fn=sparse_index.index_scores_reference,
+    supports=sparse_index.index_supports,
+)
 
 __all__ = [
     "attention",
@@ -73,6 +81,7 @@ __all__ = [
     "grouped_matmul",
     "matmul",
     "paged_attention",
+    "sparse_index",
     "tiles",
     "TileConfig",
     "DEFAULT_TILES",

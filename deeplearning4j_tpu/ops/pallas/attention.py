@@ -45,18 +45,27 @@ def _dividing(block: int, n: int) -> int:
 
 def flash_attention(q, k, v, mask=None, causal: bool = False, scale=None,
                     tile: Optional[TileConfig] = None,
-                    interpret: bool = False, block_diffusion=None):
+                    interpret: bool = False, block_diffusion=None,
+                    selection=None):
     """Flash attention of q [B, H, T, D], k [B, Hk, S, D], v [B, Hk, S, Dv]
     -> [B, H, T, Dv] with TileConfig-driven blocks and masked-tail padding
     for ragged T/S (the padding is along T and S only, so it holds for any
     value width and any number of key-value heads).  Under
     `block_diffusion=(L, B)` the blocks divide L — no tile lies across the
-    first clean row — and nothing is padded.  Differentiable."""
+    first clean row — and nothing is padded.  With a `selection`
+    (`attention_kernels.Selection`, causal pairs) the blocks divide T and
+    S, nothing is padded either, and the result is `(out, logsumexp [B, H,
+    T])`.  Differentiable."""
     import deeplearning4j_tpu.ops.attention_kernels as ak
 
     tile = tile or DEFAULT_TILES["attention"]
     B, H, T, D = q.shape
     S = k.shape[2]
+    if selection is not None:
+        return ak._flash_selected_diff(
+            q, k, v, selection.by_query, selection.by_key, scale,
+            _dividing(tile.block_q, T), _dividing(tile.block_kv, S),
+            interpret)
     if block_diffusion is not None:
         L = block_diffusion[0]
         return ak._flash_attention_diff(
@@ -84,15 +93,18 @@ def flash_attention(q, k, v, mask=None, causal: bool = False, scale=None,
 
 
 def attention_reference(q, k, v, mask=None, causal: bool = False,
-                        scale=None, block_diffusion=None):
+                        scale=None, block_diffusion=None, selection=None):
     import deeplearning4j_tpu.ops.attention_kernels as ak
 
+    if selection is not None:
+        return ak.mha_reference(q, k, v, ak.unpack_selection(selection),
+                                scale=scale, return_lse=True)
     return ak.mha_reference(q, k, v, mask=mask, causal=causal, scale=scale,
                             block_diffusion=block_diffusion)
 
 
 def attention_supports(q, k, v, mask=None, causal: bool = False,
-                       block_diffusion=None, **kw) -> bool:
+                       block_diffusion=None, selection=None, **kw) -> bool:
     """Hard constraints only — forced-pallas mode must work on the small
     shapes the conformance suite uses."""
     if getattr(q, "ndim", 0) != 4:
@@ -115,6 +127,12 @@ def attention_supports(q, k, v, mask=None, causal: bool = False,
         B, _, _, _ = q.shape
         S = k.shape[2]
         if getattr(mask, "ndim", 0) != 2 or mask.shape != (B, S):
+            return False
+    if selection is not None:
+        # causal pairs in whole 32-bit words along both sides: a block that
+        # divides T or S is then whole words too
+        if mask is not None or not causal or block_diffusion is not None \
+                or q.shape[2] % 32 or k.shape[2] % 32:
             return False
     if block_diffusion is not None:
         # the clean rows alone or both copies, in whole blocks; the tiles

@@ -1,0 +1,282 @@
+"""The dense pieces of learned sparse attention's index, tile by tile.
+
+`ops/sparse_index.py` scores every causal (query, key) pair with a small
+second attention (`n` index heads of `d` dims over ONE key head), and for its
+loss needs the main heads' probabilities summed over the heads, pair by pair.
+Written in XLA each is a stack of per-head [queries, keys] arrays — 16 or 32
+of them, 17 to 34 GB a layer at 16,384 tokens — reduced by the next op.
+Here the heads are summed in the tile and only the [queries, keys] float32
+result reaches HBM:
+
+- `index_scores`: `I[t, s] = sum_j w[t, j] relu(q_idx[t, j] . k_idx[s])`;
+- `index_scores_bwd`: its three gradients from one recomputation of a
+  tile's dots (dQ resident across a query block's key blocks, dK written a
+  (query block, key block) and summed outside, dW a lane a head);
+- `head_summed_probs`: `sum_a exp(q[a] . k[a // group] * scale - lse[a])`.
+
+Each takes `q_offset`, the first query's position (an int32 scalar, known
+on the device only: the caller walks a sequence in chunks), and skips the
+tiles that lie wholly above the diagonal, which it leaves zero (a tile on
+the diagonal is computed whole: the caller masks, as a selection holds
+causal pairs only).  The `*_reference` twins are the definitions (plain
+jnp, every pair computed).
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_BLOCK_Q, _BLOCK_K = 512, 1024
+_VMEM_LIMIT = 64 << 20
+_NT = (((1,), (1,)), ((), ()))                 # a [m, c], b [n, c] -> [m, n]
+_TN = (((0,), (0,)), ((), ()))                 # a [c, m], b [c, n] -> [m, n]
+
+
+# ---------------------------------------------------------------------------
+# definitions
+# ---------------------------------------------------------------------------
+
+def index_scores_reference(q_idx, k_idx, w, q_offset=None):
+    """`I` [B, C, S] float32 of `q_idx` [B, n, C, d], `k_idx` [B, S, d] and
+    `w` [B, C, n] (float32, the scale folded in)."""
+    dots = jnp.einsum("bnqd,bkd->bnqk", q_idx, k_idx,
+                      preferred_element_type=jnp.float32)
+    return jnp.einsum("bqn,bnqk->bqk", w.astype(jnp.float32),
+                      jnp.maximum(dots, 0.0))
+
+
+def index_scores_bwd_reference(d_scores, q_idx, k_idx, w, q_offset=None):
+    """`(d q_idx, d k_idx, d w)` of `index_scores` for the cotangent
+    `d_scores` [B, C, S] (zero above the diagonal)."""
+    return jax.vjp(index_scores_reference, q_idx, k_idx, w)[1](d_scores)
+
+
+def head_summed_probs_reference(q, k, lse, scale, q_offset=None):
+    """`sum_a exp(q[a] . k[a // group] * scale - lse[a])` [B, C, S] float32
+    of `q` [B, H, C, D], `k` [B, Hk, S, D], `lse` [B, H, C]."""
+    k = jnp.repeat(k, q.shape[1] // k.shape[1], axis=1)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   preferred_element_type=jnp.float32) * scale
+    return jnp.sum(jnp.exp(s - lse[..., None]), axis=1)
+
+
+def index_supports(q_idx, k_idx, *args, **kw) -> bool:
+    """Whole tiles: the chunk's queries and the keys in blocks that are
+    multiples of the sublane and lane tiling (or the whole side)."""
+    C, S = q_idx.shape[2], k_idx.shape[1]
+    return (q_idx.ndim == 4 and k_idx.ndim == 3 and C % 8 == 0
+            and (S % 128 == 0 or S <= _BLOCK_K))
+
+
+def _blocks(C: int, S: int):
+    bq, bk = min(_BLOCK_Q, C), min(_BLOCK_K, S)
+    while C % bq:
+        bq //= 2
+    while S % bk:
+        bk //= 2
+    return bq, bk
+
+
+def _live(off_ref, i, j, bq, bk):
+    """Whether tile (i, j) holds a pair at or under the diagonal."""
+    return j * bk <= off_ref[0] + i * bq + (bq - 1)
+
+
+def _last_key_block(off, i, bq, bk):
+    """The last key block a query block sees: the index maps clamp to it, so
+    a tile above the diagonal fetches nothing new."""
+    return (off[0] + i * bq + (bq - 1)) // bk
+
+
+def _offset(q_offset):
+    return jnp.asarray(0 if q_offset is None else q_offset,
+                       jnp.int32).reshape(1)
+
+
+# ---------------------------------------------------------------------------
+# index scores
+# ---------------------------------------------------------------------------
+
+def _scores_kernel(off_ref, q_ref, k_ref, w_ref, o_ref, *, heads, bq, bk):
+    i, j = pl.program_id(1), pl.program_id(2)
+    live = _live(off_ref, i, j, bq, bk)
+
+    @pl.when(live)
+    def _():
+        k = k_ref[0]                                       # [bk, d]
+        w = w_ref[0]                                       # [bq, n] f32
+        acc = jnp.zeros((bq, bk), jnp.float32)
+        for h in range(heads):
+            dots = jax.lax.dot_general(
+                q_ref[0, h], k, _NT, preferred_element_type=jnp.float32)
+            acc = acc + w[:, h:h + 1] * jnp.maximum(dots, 0.0)
+        o_ref[0] = acc
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        o_ref[0] = jnp.zeros((bq, bk), jnp.float32)
+
+
+def index_scores(q_idx, k_idx, w, q_offset=None, interpret=False):
+    """`index_scores_reference` in the tiles that reach under the diagonal
+    of queries `q_offset ..`, zero in the others."""
+    B, n, C, d = q_idx.shape
+    S = k_idx.shape[1]
+    bq, bk = _blocks(C, S)
+    keys = lambda b, i, j, off: (
+        b, jnp.minimum(j, _last_key_block(off, i, bq, bk)), 0)
+    return pl.pallas_call(
+        functools.partial(_scores_kernel, heads=n, bq=bq, bk=bk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, C // bq, S // bk),
+            in_specs=[
+                pl.BlockSpec((1, n, bq, d), lambda b, i, j, off: (b, 0, i, 0)),
+                pl.BlockSpec((1, bk, d), keys),
+                pl.BlockSpec((1, bq, n), lambda b, i, j, off: (b, i, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, bq, bk),
+                                   lambda b, i, j, off: (b, i, j))),
+        out_shape=jax.ShapeDtypeStruct((B, C, S), jnp.float32),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+    )(_offset(q_offset), q_idx, k_idx, w.astype(jnp.float32))
+
+
+# ---------------------------------------------------------------------------
+# their gradients
+# ---------------------------------------------------------------------------
+
+def _scores_bwd_kernel(off_ref, g_ref, q_ref, k_ref, w_ref, dq_ref, dk_ref,
+                       dw_ref, dq_sc, dw_sc, *, heads, bq, bk):
+    i, j = pl.program_id(1), pl.program_id(2)
+    live = _live(off_ref, i, j, bq, bk)
+
+    @pl.when(j == 0)
+    def _():
+        dq_sc[...] = jnp.zeros_like(dq_sc)
+        dw_sc[...] = jnp.zeros_like(dw_sc)
+
+    @pl.when(live)
+    def _():
+        g = g_ref[0]                                       # [bq, bk] f32
+        k = k_ref[0]                                       # [bk, d]
+        w = w_ref[0]                                       # [bq, n] f32
+        lane = jax.lax.broadcasted_iota(jnp.int32, dw_sc.shape, 1)
+        dk = jnp.zeros(dk_ref.shape[2:], jnp.float32)
+        for h in range(heads):
+            q = q_ref[0, h]                                # [bq, d]
+            dots = jax.lax.dot_general(
+                q, k, _NT, preferred_element_type=jnp.float32)
+            # d I / d dots = w where the head fired
+            gh = jnp.where(dots > 0, g * w[:, h:h + 1], 0.0).astype(k.dtype)
+            dq_sc[h] += jnp.dot(gh, k, preferred_element_type=jnp.float32)
+            dk = dk + jax.lax.dot_general(
+                gh, q, _TN, preferred_element_type=jnp.float32)
+            dw_h = jnp.sum(g * jnp.maximum(dots, 0.0), axis=1, keepdims=True)
+            dw_sc[...] += jnp.where(lane == h, dw_h, 0.0)
+        dk_ref[0, 0] = dk
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        dk_ref[0, 0] = jnp.zeros(dk_ref.shape[2:], jnp.float32)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        dq_ref[0] = dq_sc[...].astype(dq_ref.dtype)
+        dw_ref[0] = dw_sc[...]
+
+
+def index_scores_bwd(d_scores, q_idx, k_idx, w, q_offset=None,
+                     interpret=False):
+    """`index_scores_bwd_reference` for a cotangent that is zero above the
+    diagonal: `(d q_idx, d k_idx, d w)`, the key's in float32."""
+    B, n, C, d = q_idx.shape
+    S = k_idx.shape[1]
+    bq, bk = _blocks(C, S)
+    lanes = max(128, n)
+    keys = lambda b, i, j, off: (
+        b, jnp.minimum(j, _last_key_block(off, i, bq, bk)), 0)
+    q_rows = lambda b, i, j, off: (b, 0, i, 0)
+    dq, dk, dw = pl.pallas_call(
+        functools.partial(_scores_bwd_kernel, heads=n, bq=bq, bk=bk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, C // bq, S // bk),
+            in_specs=[
+                pl.BlockSpec((1, bq, bk), lambda b, i, j, off: (
+                    b, i, jnp.minimum(j, _last_key_block(off, i, bq, bk)))),
+                pl.BlockSpec((1, n, bq, d), q_rows),
+                pl.BlockSpec((1, bk, d), keys),
+                pl.BlockSpec((1, bq, n), lambda b, i, j, off: (b, i, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, n, bq, d), q_rows),
+                # a (query block, key block) its own: summed outside
+                pl.BlockSpec((1, 1, bk, d), lambda b, i, j, off: (b, i, j, 0)),
+                pl.BlockSpec((1, bq, lanes), lambda b, i, j, off: (b, i, 0)),
+            ],
+            scratch_shapes=[pltpu.VMEM((n, bq, d), jnp.float32),
+                            pltpu.VMEM((bq, lanes), jnp.float32)]),
+        out_shape=[
+            jax.ShapeDtypeStruct((B, n, C, d), q_idx.dtype),
+            jax.ShapeDtypeStruct((B, C // bq, S, d), jnp.float32),
+            jax.ShapeDtypeStruct((B, C, lanes), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+    )(_offset(q_offset), d_scores.astype(jnp.float32), q_idx, k_idx,
+      w.astype(jnp.float32))
+    return dq, jnp.sum(dk, axis=1), dw[..., :n].astype(w.dtype)
+
+
+# ---------------------------------------------------------------------------
+# the main heads' probabilities, summed over the heads
+# ---------------------------------------------------------------------------
+
+def _probs_kernel(off_ref, q_ref, k_ref, lse_ref, o_ref, *, scale, bq, bk):
+    i, j, a = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+
+    @pl.when(a == 0)
+    def _():
+        o_ref[0] = jnp.zeros((bq, bk), jnp.float32)
+
+    @pl.when(_live(off_ref, i, j, bq, bk))
+    def _():
+        s = jax.lax.dot_general(
+            q_ref[0, 0], k_ref[0, 0], _NT,
+            preferred_element_type=jnp.float32) * scale
+        o_ref[0] += jnp.exp(s - lse_ref[0, 0])            # [bq, 1] -> lanes
+
+
+def head_summed_probs(q, k, lse, scale, q_offset=None, interpret=False):
+    """`head_summed_probs_reference` in the tiles that reach under the
+    diagonal of queries `q_offset ..`, zero in the others.  The heads are the grid's innermost axis
+    and the result's tile stays in VMEM while they pass."""
+    B, H, C, D = q.shape
+    Hk, S = k.shape[1], k.shape[2]
+    group = H // Hk
+    bq, bk = _blocks(C, S)
+    q_rows = lambda b, i, j, a, off: (b, a, i, 0)
+    return pl.pallas_call(
+        functools.partial(_probs_kernel, scale=scale, bq=bq, bk=bk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, C // bq, S // bk, H),
+            in_specs=[
+                pl.BlockSpec((1, 1, bq, D), q_rows),
+                pl.BlockSpec((1, 1, bk, D), lambda b, i, j, a, off: (
+                    b, a // group,
+                    jnp.minimum(j, _last_key_block(off, i, bq, bk)), 0)),
+                pl.BlockSpec((1, 1, bq, 1), q_rows),
+            ],
+            out_specs=pl.BlockSpec((1, bq, bk),
+                                   lambda b, i, j, a, off: (b, i, j))),
+        out_shape=jax.ShapeDtypeStruct((B, C, S), jnp.float32),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+    )(_offset(q_offset), q, k, lse.astype(jnp.float32)[..., None])

@@ -26,10 +26,18 @@ order, provided queries and keys share it: rotary lanes enter nothing but
 the pair-stack of `rotary_interleaved` cost five passes over the queries,
 through arrays with a minor dimension of 2); `rotary_interleaved` stays the
 definition, and the tests hold `rotary_pairs` to it.
+
+`rotary_sections` is the half-split form over several position streams
+(multimodal rotary: `mrope_section` of a public config, e.g. [16, 24, 24] of
+a head's 64 frequencies): frequency `i` takes its position from the stream
+whose section holds `i`, the sections laid end to end in the order given
+(the chunked layout).  Text gives every stream the token's index, and the
+result is then `rotary_half_split`'s, bit for bit.
 """
 from __future__ import annotations
 
 import jax.numpy as jnp
+import numpy as np
 
 
 def rotary_angles(positions, dim: int, base: float = 10000.0):
@@ -72,6 +80,27 @@ def rotary_half_split(x, positions, base: float = 10000.0):
     `x`'s dtype."""
     d = x.shape[-1]
     ang = rotary_angles(positions, d, base)[..., None, :]   # over the heads
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32)
+    a, b = xf[..., :d // 2], xf[..., d // 2:]
+    out = jnp.concatenate([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    return out.astype(x.dtype)
+
+
+def rotary_sections(x, positions, sections, base: float = 10000.0):
+    """`rotary_half_split` of `x` [..., T, heads, d] whose frequency `i`
+    turns by `positions[j] * base**(-2i/d)`, `j` the section that holds `i`:
+    `positions` [streams, T] (or [streams, ..., T]), `sections` the number
+    of frequencies each stream drives, `d/2` in all."""
+    d = x.shape[-1]
+    if sum(sections) != d // 2 or len(sections) != positions.shape[0]:
+        raise ValueError(
+            f"sections {tuple(sections)} over {positions.shape[0]} position "
+            f"streams do not make the {d // 2} frequencies of a head of {d}")
+    ang = rotary_angles(positions, d, base)           # [streams, ..., T, d/2]
+    stream = np.repeat(np.arange(len(sections)), sections)
+    ang = sum(jnp.where(stream == j, ang[j], 0.0)
+              for j in range(len(sections)))[..., None, :]   # over the heads
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     xf = x.astype(jnp.float32)
     a, b = xf[..., :d // 2], xf[..., d // 2:]

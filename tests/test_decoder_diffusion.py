@@ -378,11 +378,10 @@ def test_softmax_router_by_hand_and_its_balance_loss():
         moe.router(x, w, None, k, 1.0, 0.0, "tanh")
 
 
-def test_the_eight_shares_of_a_softmax_routed_layer_add_up():
-    """128 experts cut into 8 shares of 16 (`first_expert` 0, 16, .., 112),
-    softmax over all 128, top-8 renormalised, no bias, no shared expert: the
-    shares' parts sum to the uncut layer, token by token and expert by
-    expert; every share counts all 128 and reads the same balance loss."""
+def _shares_of_the_expert_layer():
+    """The softmax-routed expert layer alone: `(the eight shares' parts,
+    the uncut layer by hand)`; every share counts all 128 and reads the same
+    balance loss."""
     rng = np.random.default_rng(8)
     t, h, i, e, k = 24, 16, 8, 128, 8
     x = jnp.asarray(rng.normal(size=(t, h)))
@@ -401,18 +400,72 @@ def test_the_eight_shares_of_a_softmax_routed_layer_add_up():
         for j, ex in enumerate(chosen[tok]):
             want[tok] += w[j] * np.asarray(moe.swiglu(
                 x[tok], p["w_gate"][ex], p["w_up"][ex], p["w_down"][ex]))
-    total, balances = 0.0, []
+    parts, balances = [], []
     for first in range(0, e, 16):
         share = {**p, **{n: p[n][first:first + 16]
                          for n in ("w_gate", "w_up", "w_down")}}
         y, counts, _, balance = moe.expert_layer(
             x, share, None, top_k=k, scale=1.0, first_held=first, eps=0.0,
             score="softmax")
-        total = total + y
+        parts.append(y)
         balances.append(float(balance))
         assert int(counts.sum()) == t * k
-    np.testing.assert_allclose(total, want, atol=1e-6)
     assert len(set(balances)) == 1 and balances[0] >= k - 1e-6
+    return parts, want, 1e-6
+
+
+def _shares_of_a_sparse_attention_layer():
+    """A whole `sparse_attention` layer — grouped-query attention over the
+    indexer's selection, then the routed experts — of which every chip
+    computes attention, indexer and router alike: `(that part once and the
+    eight shares' expert terms, the benchmark family's uncut reference
+    layer)`.  Every share selects the same keys and reads the same indexer
+    loss."""
+    from benchmark.models import keye_vl2_moe as family
+    from deeplearning4j_tpu.ops.norm_kernels import rms_norm
+    c = DecoderConfig.tiny_sparse(n_layers=1, layer_types=(
+        "sparse_attention",), n_experts=128, top_k=8)
+    whole = DecoderModel(c, seed=9)
+    rng = np.random.default_rng(9)
+    x = jnp.asarray(rng.normal(size=(2, 64, c.hidden)), jnp.float32)
+    lp = jax.tree_util.tree_map(lambda a: a[0], whole.params_["moe"])
+    cfg = {"hidden_size": c.hidden, "num_attention_heads": c.n_heads,
+           "num_key_value_heads": c.n_kv_heads, "head_dim": c.head_dim,
+           "rope_theta": c.rope_base, "rms_norm_eps": c.eps,
+           "rope_scaling": {"mrope_section": list(c.rope_sections)},
+           "sa_config": {"indexer_num_heads": c.index_heads,
+                         "indexer_head_dim": c.index_head_dim,
+                         "indexer_num_kv_heads": 1, "topk": c.index_topk},
+           "num_experts_per_tok": c.top_k, "first_expert_held": 0}
+    want, _, want_kl = family.reference_block(cfg, x, lp)
+    alike, counted = whole._sparse_attention(x, lp)
+    np.testing.assert_allclose(counted["index_kl"], want_kl, rtol=1e-5)
+    assert float(counted["selected_keys"]) == 2 * 904   # sum_n min(n, 16)
+    u = rms_norm(alike, lp["norm2"], c.eps).reshape(-1, c.hidden)
+    parts = [alike]
+    for first in range(0, 128, 16):
+        held = {**lp, **{n: lp[n][first:first + 16]
+                         for n in ("w_gate", "w_up", "w_down")}}
+        y, counts, _, _ = moe.expert_layer(
+            u, held, None, top_k=c.top_k, scale=c.routed_scale,
+            first_held=first, eps=c.router_eps, score="softmax")
+        assert int(counts.sum()) == 2 * 64 * 8
+        parts.append(y.reshape(alike.shape))
+    return parts, np.asarray(want), 1e-5
+
+
+@pytest.mark.parametrize("layer", ["softmax_routed", "sparse_attention"])
+def test_the_eight_shares_of_a_softmax_routed_layer_add_up(layer):
+    """128 experts cut into 8 shares of 16 (`first_expert` 0, 16, .., 112),
+    softmax over all 128, top-8 renormalised, no bias, no shared expert: the
+    shares' parts sum to the uncut layer, token by token and expert by
+    expert — the expert layer alone against a hand count, and a whole
+    `sparse_attention` layer, what every chip computes alike counted once,
+    against the benchmark family's uncut reference."""
+    parts, want, atol = {
+        "softmax_routed": _shares_of_the_expert_layer,
+        "sparse_attention": _shares_of_a_sparse_attention_layer}[layer]()
+    np.testing.assert_allclose(sum(parts), want, atol=atol)
 
 
 # ---------------------------------------------------------------------------

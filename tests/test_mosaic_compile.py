@@ -163,6 +163,58 @@ def test_block_diffusion_kernels_compile_for_v5e(one_chip):
         assert f"{T},{T}]" not in compiled.as_text()
 
 
+def test_selection_kernels_compile_for_v5e(one_chip):
+    """Keye-VL-2.0's attention layer at one 16,384-token sequence: 32 query
+    heads over 4 key-value heads of 128 under a SELECTION, one bit a pair,
+    `by_query` [1, 512, 16384] for the forward's tiles and `by_key` for the
+    backward's, unpacked along the sublanes of a 512 x 1024 tile; causal's
+    schedule, so the backward goes in eight spans of 2,048 queries."""
+    B, H, Hk, T, D, dt = 1, 32, 4, 16384, 128, jnp.bfloat16
+    q, kv = ((B, H, T, D), dt), ((B, Hk, T, D), dt)
+    bits = ((B, T // 32, T), jnp.int32)
+    fwd = _compile(
+        lambda q, k, v, a, b: ak.flash_attention_tpu(
+            q, k, v, causal=True, block_q=512, block_k=1024, return_lse=True,
+            selection=ak.Selection(a, b)),
+        one_chip, q, kv, kv, bits, bits)
+    assert fwd.as_text().count("tpu_custom_call") == 1
+    bwd = _compile(
+        lambda q, k, v, out, lse, g, a, b: ak.flash_attention_bwd_tpu(
+            q, k, v, out, lse, g, causal=True, block_q=512, block_k=1024,
+            selection=ak.Selection(a, b)),
+        one_chip, q, kv, kv, q, ((B * H, T), jnp.float32), q, bits, bits)
+    assert bwd.as_text().count("tpu_custom_call") == 8
+    assert [o.shape for o in bwd.out_info] == [q[0], kv[0], kv[0]]
+    # the selection stays packed: no [T, T] array in either program
+    for compiled in (fwd, bwd):
+        assert f"{T},{T}]" not in compiled.as_text()
+
+
+def test_index_kernels_compile_for_v5e(one_chip):
+    """The indexer's three kernels on a chunk of 1,024 queries over 16,384
+    keys: 16 index heads of 64 over one key head summed in a 512 x 1024
+    tile, their gradients, and the 32 main heads' probabilities summed over
+    the grid's innermost axis; the chunk's first position a scalar operand."""
+    from deeplearning4j_tpu.ops.pallas import sparse_index as kernels
+    B, n, C, d, S, dt = 1, 16, 1024, 64, 16384, jnp.bfloat16
+    q_idx, k_idx, w = ((B, n, C, d), dt), ((B, S, d), dt), ((B, C, n),
+                                                            jnp.float32)
+    offset, dense = ((), jnp.int32), ((B, C, S), jnp.float32)
+    scores = _compile(kernels.index_scores, one_chip, q_idx, k_idx, w, offset)
+    assert scores.out_info.shape == dense[0]
+    bwd = _compile(kernels.index_scores_bwd, one_chip, dense, q_idx, k_idx, w,
+                   offset)
+    assert [(o.shape, o.dtype) for o in bwd.out_info] == [
+        (q_idx[0], dt), (k_idx[0], jnp.float32), (w[0], jnp.float32)]
+    probs = _compile(
+        lambda q, k, lse, off: kernels.head_summed_probs(
+            q, k, lse, 128 ** -0.5, off),
+        one_chip, ((B, 32, C, 128), dt), ((B, 4, S, 128), dt),
+        ((B, 32, C), jnp.float32), offset)
+    for compiled in (scores, bwd, probs):
+        assert compiled.as_text().count("tpu_custom_call") == 1
+
+
 # a `transpose` or `copy` whose result is a whole q, k, v or kernel output of
 # kanana's cell, in either order of tokens and heads
 _HEADS_COPY = re.compile(
