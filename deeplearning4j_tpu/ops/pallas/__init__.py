@@ -67,7 +67,8 @@ dispatch.register(
     supports=grouped_matmul.grouped_supports,
     profitable=grouped_matmul.grouped_profitable,
 )
-# three kernels, one answer: `ops/sparse_index.py` asks once a call
+# one answer for the tier's kernels: `ops/sparse_index.py` asks once a call
+# (`pack_by_key` alone also asks `pack_by_key_supports` for its words' tiles)
 dispatch.register(
     "sparse_index",
     pallas_fn=sparse_index.index_scores,

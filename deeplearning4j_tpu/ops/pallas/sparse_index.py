@@ -18,8 +18,20 @@ Each takes `q_offset`, the first query's position (an int32 scalar, known
 on the device only: the caller walks a sequence in chunks), and skips the
 tiles that lie wholly above the diagonal, which it leaves zero (a tile on
 the diagonal is computed whole: the caller masks, as a selection holds
-causal pairs only).  The `*_reference` twins are the definitions (plain
-jnp, every pair computed).
+causal pairs only).
+
+The fourth piece works on the selection's bits, so that no boolean or
+widened [queries, keys] array stands between the top-k and the flash
+kernels:
+
+- `pack_by_key`: an `attention_kernels.Selection`'s second array from its
+  first, 32 queries to a word in, 32 keys to a word out: a tile of words is
+  unpacked along its sublanes (as the flash kernels do), turned (one 32-bit
+  transpose, as the flash backward's `ds.T`) and packed along its sublanes
+  again.  Once a sequence; 2 x 33.5 MB move at 16,384 tokens.
+
+The `*_reference` twins are the definitions (plain jnp, every pair
+computed).
 """
 from __future__ import annotations
 
@@ -29,6 +41,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from deeplearning4j_tpu.ops.attention_kernels import (_pack_bits,
+                                                      _unpack_bits)
 
 _BLOCK_Q, _BLOCK_K = 512, 1024
 _VMEM_LIMIT = 64 << 20
@@ -64,12 +79,29 @@ def head_summed_probs_reference(q, k, lse, scale, q_offset=None):
     return jnp.sum(jnp.exp(s - lse[..., None]), axis=1)
 
 
+def pack_by_key_reference(by_query):
+    """`by_key` [B, S/32, T] of an `attention_kernels.Selection` from its
+    `by_query` [B, T/32, S] (int32 both): bit r of `by_key[b, j, t]` is
+    bit `t % 32` of `by_query[b, t // 32, 32 j + r]`."""
+    return _pack_bits(_unpack_bits(by_query, 1), 2).transpose(0, 2, 1)
+
+
 def index_supports(q_idx, k_idx, *args, **kw) -> bool:
     """Whole tiles: the chunk's queries and the keys in blocks that are
     multiples of the sublane and lane tiling (or the whole side)."""
     C, S = q_idx.shape[2], k_idx.shape[1]
     return (q_idx.ndim == 4 and k_idx.ndim == 3 and C % 8 == 0
             and (S % 128 == 0 or S <= _BLOCK_K))
+
+
+def pack_by_key_supports(T: int, S: int) -> bool:
+    """Whether `pack_by_key` takes a selection of `T` queries over `S`
+    keys: a block's 32nd is a tile's rows on one side and its length its
+    lanes on the other, so both blocks are multiples of 32 x 8 (or the
+    whole side)."""
+    return T % 32 == 0 and S % 32 == 0 and all(
+        b == side or b % 256 == 0
+        for b, side in zip(_blocks(T, S), (T, S)))
 
 
 def _blocks(C: int, S: int):
@@ -280,3 +312,42 @@ def head_summed_probs(q, k, lse, scale, q_offset=None, interpret=False):
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(_offset(q_offset), q, k, lse.astype(jnp.float32)[..., None])
+
+
+# ---------------------------------------------------------------------------
+# the selection's bits, turned
+# ---------------------------------------------------------------------------
+
+def _by_key_kernel(words_ref, o_ref, *, bq, bk):
+    i, j = pl.program_id(1), pl.program_id(2)
+    live = j * bk <= i * bq + (bq - 1)
+
+    @pl.when(live)
+    def _():
+        keep = _unpack_bits(words_ref[0]).astype(jnp.int32).T   # [bk, bq]
+        r = jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0) & 31
+        o_ref[0] = jnp.sum(
+            jnp.left_shift(keep, r).reshape(bk // 32, 32, bq), axis=1)
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        o_ref[0] = jnp.zeros((bk // 32, bq), jnp.int32)
+
+
+def pack_by_key(by_query, interpret=False):
+    """`pack_by_key_reference` of a selection that holds causal pairs only:
+    a tile wholly above the diagonal reads nothing new and writes zero
+    words."""
+    B, words, S = by_query.shape
+    T = 32 * words
+    bq, bk = _blocks(T, S)
+    return pl.pallas_call(
+        functools.partial(_by_key_kernel, bq=bq, bk=bk),
+        grid=(B, T // bq, S // bk),
+        in_specs=[pl.BlockSpec((1, bq // 32, bk), lambda b, i, j: (
+            b, i, jnp.minimum(j, (i * bq + (bq - 1)) // bk)))],
+        out_specs=pl.BlockSpec((1, bk // 32, bq), lambda b, i, j: (b, j, i)),
+        out_shape=jax.ShapeDtypeStruct((B, S // 32, T), jnp.int32),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+    )(by_query)

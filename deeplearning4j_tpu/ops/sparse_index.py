@@ -17,9 +17,21 @@ sample, `approx_max_k` or a choice of key blocks is another model.
 as an operand.  Exact selection without a sort: a float's bits, the sign
 folded, order as unsigned integers, so the k-th largest of a row is built
 bit by bit from the top — 32 passes of compare-and-count over the row, each
-one fusion — and the pairs above it are kept, with as many of its ties,
-lowest key first, as make up the count (a cumulative sum that runs only
-in a chunk that has such ties to split).
+one fusion over a chunk's scores in VMEM — and the pairs above it are kept,
+with as many of its ties, lowest key first, as make up the count.  Ties AT
+the threshold are the rule, not the exception: under training whole
+stretches of a row score exactly 0 (every head's ReLU shut), and half the
+chunks of a step have a row whose threshold is shared by more keys than it
+may keep.  Such a chunk finds, per row, the LAST tie it keeps the same way
+it found the threshold: the key's number bit by bit, `log2 S` passes of
+compare-and-count (a cumulative sum over the row does the same and moves a
+[chunk, S] int32 array five times).  A chunk without such a row keeps `u
+>= kth`.  Either way the test and the packing along the queries are one
+pass over the scores' bits in VMEM that writes words — no boolean or
+widened [chunk, S] array reaches HBM — and the packing along the keys is
+ONE kernel a layer that turns the words (`pack_by_key`).  Which branch a
+chunk took is decided on the device from the scores themselves;
+`sparse_index` counts the chunks that searched (`tie_split_chunks`).
 
 `index_loss` is the indexer's own objective (the sparse training stage):
 `mean_t KL(pbar[t, .] || softmax_{s in S_t} I[t, s])`, `pbar` the main
@@ -38,8 +50,10 @@ Everything dense in (query, key) is computed `_CHUNK` queries at a time: a
 ([heads, chunk, S]) never reach HBM where the tier takes the Mosaic kernels
 of `ops/pallas/sparse_index.py` (a TPU, or the tier forced to `pallas`): the
 index heads are summed in the tile, so are the main heads' probabilities,
-and the scores' three gradients come from one recomputation of a tile's
-dots.  Elsewhere the same three functions are plain `jax.numpy`.
+the scores' three gradients come from one recomputation of a tile's dots,
+and the selection's words are turned in the tile.  Elsewhere the same four
+functions are plain `jax.numpy` (`pack_by_key` alone too, where a sequence's
+words come in no whole tiles: `pack_by_key_supports`).
 """
 from __future__ import annotations
 
@@ -71,25 +85,30 @@ def _chunk(T: int) -> int:
 
 
 # ---------------------------------------------------------------------------
-# the three dense pieces: Mosaic kernels where the tier takes them
+# the four dense pieces: Mosaic kernels where the tier takes them
 # ---------------------------------------------------------------------------
 
 def _dense(q_idx, k_idx, chunk: int):
-    """`(index_scores, index_scores_bwd, head_summed_probs)` of
+    """`(index_scores, index_scores_bwd, head_summed_probs, pack_by_key)` of
     `ops/pallas/sparse_index.py` as the tier resolves them for `chunk` of
-    these queries at a time: the kernels, or their plain definitions."""
+    these queries at a time (the last for the sequence, and only where its
+    words come in whole tiles): the kernels, or their plain definitions."""
     from deeplearning4j_tpu.ops import pallas as tier
     mod = tier.sparse_index
-    B, n, _, d = q_idx.shape
+    B, n, T, d = q_idx.shape
     if tier.dispatch.resolve(
             "sparse_index", jax.ShapeDtypeStruct((B, n, chunk, d),
                                                  q_idx.dtype),
             k_idx) != "pallas":
         return (mod.index_scores_reference, mod.index_scores_bwd_reference,
-                mod.head_summed_probs_reference)
-    return tuple(functools.partial(
-        f, interpret=tier.dispatch.interpret_mode()) for f in (
-        mod.index_scores, mod.index_scores_bwd, mod.head_summed_probs))
+                mod.head_summed_probs_reference, mod.pack_by_key_reference)
+    kernels = [mod.index_scores, mod.index_scores_bwd, mod.head_summed_probs,
+               mod.pack_by_key]
+    kernels = [functools.partial(f, interpret=tier.dispatch.interpret_mode())
+               for f in kernels]
+    if not mod.pack_by_key_supports(T, k_idx.shape[1]):
+        kernels[3] = mod.pack_by_key_reference
+    return tuple(kernels)
 
 
 # ---------------------------------------------------------------------------
@@ -105,31 +124,88 @@ def _ordered_bits(x):
     return jax.lax.bitcast_convert_type(key, jnp.uint32) ^ jnp.uint32(1 << 31)
 
 
-def select_keys(scores, q_offset, topk: int):
-    """bool [B, C, S]: for query `q_offset + i` the `min(position + 1,
-    topk)` keys at or before it of largest `scores[b, i]`, ties to the lower
-    key."""
+def _count(keep):
+    return jnp.sum(keep, axis=-1, dtype=jnp.int32)
+
+
+def _threshold(scores, q_offset, topk: int):
+    """`(u, kth, want)` of a chunk's `scores` [B, C, S]: `u` (uint32) their
+    ordered bits, 0 where the key lies after query `q_offset + i`; `want`
+    [C] = `min(position + 1, topk)`, the keys a query keeps; `kth` [B, C]
+    the `want`-th largest of a row of `u` (not 0: `want` keys lie at or
+    before the query)."""
     B, C, S = scores.shape
     t = q_offset + jnp.arange(C, dtype=jnp.int32)
     valid = jnp.arange(S, dtype=jnp.int32)[None, :] <= t[:, None]
     u = jnp.where(valid, _ordered_bits(scores), jnp.uint32(0))
     want = jnp.minimum(t + 1, topk)                      # [C], >= 1
 
-    def count(keep):
-        return jnp.sum(keep, axis=-1, dtype=jnp.int32)
-
     def bit(i, kth):       # the k-th largest of a row, from its top bit down
         cand = kth | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
-        return jnp.where(count(u >= cand[..., None]) >= want, cand, kth)
+        return jnp.where(_count(u >= cand[..., None]) >= want, cand, kth)
 
-    kth = jax.lax.fori_loop(0, 32, bit, jnp.zeros((B, C), jnp.uint32))
-    above = u > kth[..., None]
-    tie = (u == kth[..., None]) & valid
-    need = want - count(above)          # of the ties, the lowest keys first
-    return above | jax.lax.cond(
-        jnp.all(count(tie) == need), lambda: tie,
-        lambda: tie & (jnp.cumsum(tie, axis=-1, dtype=jnp.int32)
-                       <= need[..., None]))
+    return u, jax.lax.fori_loop(0, 32, bit,
+                                jnp.zeros((B, C), jnp.uint32)), want
+
+
+def _ties(u, kth, want):
+    """`(need, split)`: `need` [B, C], how many of the keys AT its threshold
+    a row keeps, `want - count(u > kth)`, and `split`, whether any row of
+    the chunk has more such keys than that."""
+    need = want - _count(u > kth[..., None])
+    return need, jnp.any(_count(u == kth[..., None]) != need)
+
+
+def _cut(u, kth, need):
+    """[B, C]: the last key a row keeps of those AT its threshold, the
+    `need`-th of them from the left: the key's number bit by bit from the
+    top, one compare-and-count over the row a bit, as the threshold was
+    found (`log2 S` passes, where a cumulative sum moves a [chunk, S] int32
+    array five times)."""
+    S = u.shape[-1]
+    under = jnp.arange(S, dtype=jnp.int32)
+    tie = u == kth[..., None]
+
+    def bit(i, cut):    # the largest `cut` with fewer than `need` ties under
+        cand = cut | (jnp.int32(1) << ((S - 1).bit_length() - 1 - i))
+        return jnp.where(
+            _count(tie & (under < cand[..., None])) < need, cand, cut)
+
+    return jax.lax.fori_loop(0, (S - 1).bit_length(), bit,
+                             jnp.zeros(kth.shape, jnp.int32))
+
+
+def _keep_to(u, kth, cut):
+    """bool like `u` [..., S]: the keys above a row's threshold and those
+    at it up to key `cut`."""
+    under = jnp.arange(u.shape[-1], dtype=jnp.int32)
+    return (u > kth[..., None]) | ((u == kth[..., None])
+                                   & (under <= cut[..., None]))
+
+
+def _selected_words(scores, q_offset, topk: int):
+    """`(by_query, split)` of a chunk's `scores` [B, C, S]: int32 [B, C/32,
+    S], bit r of word i set where query `q_offset + 32 i + r` keeps the key
+    — the `min(position + 1, topk)` keys at or before it of largest score,
+    ties to the lower key — and whether the chunk had ties to split.  Test,
+    shift and sum are ONE pass over `u`, written word by word."""
+    B, C, _ = scores.shape
+    u, kth, want = _threshold(scores, q_offset, topk)
+    need, split = _ties(u, kth, want)
+    word = lambda a: a.reshape(B, C // 32, 32, *a.shape[2:])
+    # (the barrier keeps the packing in the branches: moved out of them by
+    # XLA, each hands over 16 MB of booleans and 64 MB of shifts)
+    pack = lambda keep: jax.lax.optimization_barrier(
+        _pack_bits(keep, 2).reshape(B, C // 32, -1))
+    return jax.lax.cond(
+        split,
+        lambda: pack(_keep_to(word(u), word(kth), word(_cut(u, kth, need)))),
+        lambda: pack(word(u) >= word(kth)[..., None])), split
+
+
+def select_keys(scores, q_offset, topk: int):
+    """bool [B, C, S]: `_selected_words`' bits, a pair each."""
+    return _unpack_bits(_selected_words(scores, q_offset, topk)[0], 1)
 
 
 def _chunks(a, axis: int, chunk: int):
@@ -147,29 +223,33 @@ def _unchunk(a, axis: int):
 
 
 def sparse_index(q_idx, k_idx, w, topk: int):
-    """The selection of every query of a sequence: `(Selection, selected)`
-    for `q_idx` [B, n, T, d], `k_idx` [B, T, d], `w` [B, T, n] (float32
-    with the scale folded in); `selected` is the number of selected pairs
-    (float32: 31.5M a sequence of 16,384 tokens).  No gradient: the caller
+    """The selection of every query of a sequence: `(Selection, selected,
+    tie_split_chunks)` for `q_idx` [B, n, T, d], `k_idx` [B, T, d], `w` [B,
+    T, n] (float32 with the scale folded in); `selected` is the number of
+    selected pairs (float32: 31.5M a sequence of 16,384 tokens),
+    `tie_split_chunks` (int32) the chunks in which some query's threshold
+    was shared by more keys than it could keep.  No gradient: the caller
     stops it on the way in.  The two packed arrays carry the name
     `SELECTION`."""
-    B, _, T, _ = q_idx.shape
+    T = q_idx.shape[2]
     C = _chunk(T)
-    scores, _, _ = _dense(q_idx, k_idx, C)
+    scores, _, _, pack_by_key = _dense(q_idx, k_idx, C)
 
     def one(xs):
         q_c, w_c, q_offset = xs
-        keep = select_keys(scores(q_c, k_idx, w_c, q_offset), q_offset, topk)
-        return (_pack_bits(keep, 1), _pack_bits(keep, 2),
-                jnp.sum(keep, dtype=jnp.float32))
+        by_query, split = _selected_words(
+            scores(q_c, k_idx, w_c, q_offset), q_offset, topk)
+        return (by_query, jnp.sum(jax.lax.population_count(by_query),
+                                  dtype=jnp.float32),
+                split.astype(jnp.int32))
 
-    by_query, by_key, selected = jax.lax.map(
+    by_query, selected, split = jax.lax.map(
         one, (_chunks(q_idx, 2, C), _chunks(w, 1, C),
               jnp.arange(0, T, C, dtype=jnp.int32)))
-    selection = Selection(
-        checkpoint_name(_unchunk(by_query, 1), SELECTION),
-        checkpoint_name(_unchunk(by_key, 1).transpose(0, 2, 1), SELECTION))
-    return selection, jnp.sum(selected)
+    by_query = _unchunk(by_query, 1)
+    selection = Selection(checkpoint_name(by_query, SELECTION),
+                          checkpoint_name(pack_by_key(by_query), SELECTION))
+    return selection, jnp.sum(selected), jnp.sum(split, dtype=jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -182,8 +262,8 @@ def _loss_and_grads(q_idx, k_idx, w, by_query, q, k, lse, scale):
     B, _, T, _ = q_idx.shape
     H = q.shape[1]
     C = _chunk(T)
-    index_scores, index_scores_bwd, head_summed_probs = _dense(q_idx, k_idx,
-                                                                C)
+    index_scores, index_scores_bwd, head_summed_probs, _ = _dense(
+        q_idx, k_idx, C)
 
     def one(dk_sum, xs):
         q_c, w_c, words, qm_c, lse_c, q_offset = xs

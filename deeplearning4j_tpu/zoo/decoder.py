@@ -53,8 +53,8 @@ benchmark build:
   query's selected keys from the main heads' mean probabilities there
   (`index_loss_coef` times its mean over the layers is the loss's third
   term); nothing else learns from that term and nothing differentiates
-  through the choice of keys.  Counted on the device: `selected_keys` and
-  `index_kl` (`sparse_stats()`).
+  through the choice of keys.  Counted on the device: `selected_keys`,
+  `index_kl` and `tie_split_chunks` (`sparse_stats()`).
 
 Between a product and a kernel (latent attention; measured in PERF.md,
 PR 36).  The kernels read [B, heads, T, d] with `d` in the lanes; a product
@@ -379,10 +379,12 @@ class DecoderModel:
             self.state_["masked_positions"] = jnp.zeros((), jnp.int32)
         if self._sparse:
             # pairs the indexer kept, a layer (float32: 31.5M a layer a step
-            # at 16,384 tokens pass int32 in 68 steps), and the steps' sum of
-            # its mean loss
+            # at 16,384 tokens pass int32 in 68 steps), the steps' sum of
+            # its mean loss, and the chunks of queries whose selection had
+            # ties to split (`ops/sparse_index.py`: the searching branch)
             self.state_["selected_keys"] = jnp.zeros((n_moe,), jnp.float32)
             self.state_["index_kl"] = jnp.zeros((), jnp.float32)
+            self.state_["tie_split_chunks"] = jnp.zeros((n_moe,), jnp.int32)
         self._tokens = 0     # clean tokens of the newest step
         self._pairs = 0      # (token, chosen expert) pairs of the newest step
         self._steps: Dict[str, Any] = {}
@@ -607,12 +609,14 @@ class DecoderModel:
     def _sparse_attention(self, x, lp, L=None):
         """`x + GQA(RMSNorm(x))` in which every query attends the
         `index_topk` keys its indexer scores highest (`ops/sparse_index.py`),
-        and what the layer counted: `selected_keys`, the selected pairs, and
-        `index_kl`, the indexer's loss — the KL of its softmax over a
-        query's selected keys from the main heads' mean probabilities
-        there.  The indexer reads the normed input under `stop_gradient` and
-        the loss reads the main heads as constants: its parameters learn
-        from that loss alone, and nothing else learns from it."""
+        and what the layer counted: `selected_keys`, the selected pairs,
+        `tie_split_chunks`, the chunks of queries that had ties at a
+        threshold to split, and `index_kl`, the indexer's loss — the KL of
+        its softmax over a query's selected keys from the main heads' mean
+        probabilities there.  The indexer reads the normed input under
+        `stop_gradient` and the loss reads the main heads as constants: its
+        parameters learn from that loss alone, and nothing else learns from
+        it."""
         c = self.config
         B, T, _ = x.shape
         with jax.named_scope("gqa_attention"):
@@ -622,7 +626,7 @@ class DecoderModel:
         with jax.named_scope("sparse_index"):
             q_idx, k_idx, w = self._index(jax.lax.stop_gradient(h), lp,
                                           jnp.arange(T))
-            selection, selected = sparse_index(
+            selection, selected, split = sparse_index(
                 *jax.lax.stop_gradient((q_idx, k_idx, w)), c.index_topk)
         with jax.named_scope("gqa_attention"):
             o, lse = fused_attention(q, k, v, causal=True,
@@ -633,7 +637,8 @@ class DecoderModel:
             kl = index_loss(q_idx, k_idx, w, selection.by_query,
                             *jax.lax.stop_gradient((q, k, lse)),
                             c.head_dim ** -0.5)
-        return out, {"selected_keys": selected, "index_kl": kl}
+        return out, {"selected_keys": selected, "index_kl": kl,
+                     "tie_split_chunks": split}
 
     def _short_conv(self, x, lp, L=None):
         """`x + (C * conv(B * X)) W_out` on `RMSNorm(x)`, `x` [B, T, H]."""
@@ -661,9 +666,9 @@ class DecoderModel:
         `rows_over_bound` [L_moe] whether the layer's held pairs were more
         than its row bound (`ops/moe.routed_experts`), under the softmax
         router `balance_loss` [L_moe], and in a model with `sparse_attention`
-        layers `selected_keys` and `index_kl` [L_moe] (zero for a layer of
-        another kind).  `L`: the clean sequence's length where `ids` hold
-        more than it (`_rows`)."""
+        layers `selected_keys`, `index_kl` and `tie_split_chunks` [L_moe]
+        (zero for a layer of another kind).  `L`: the clean sequence's
+        length where `ids` hold more than it (`_rows`)."""
         c = self.config
         dt = jnp.dtype(c.compute_dtype)
 
@@ -709,7 +714,8 @@ class DecoderModel:
                     x, counted = x
                 elif self._sparse:      # every layer of a scan counts alike
                     counted = {"selected_keys": jnp.float32(0),
-                               "index_kl": jnp.float32(0)}
+                               "index_kl": jnp.float32(0),
+                               "tie_split_chunks": jnp.int32(0)}
                 else:
                     counted = {}
                 x, seen = moe_ffn(x, lp, bias)
@@ -1040,16 +1046,22 @@ class DecoderModel:
         they kept; `keys_per_query`, that over the steps' queries at the
         newest batch shape and the sparse layers (`sum_t min(t + 1,
         index_topk) / T` where nothing else binds: 1,920.06 at 16,384
-        tokens and 2,048 keys); `index_kl`, the steps' mean indexer loss."""
-        selected, kl = jax.device_get((self.state_["selected_keys"],
-                                       self.state_["index_kl"]))
+        tokens and 2,048 keys); `index_kl`, the steps' mean indexer loss;
+        `tie_split_chunks`, the chunks of queries (of `T / 1,024` a layer a
+        sequence) in which some query's threshold was shared by more keys
+        than it could keep, so that the chunk searched for each row's last
+        kept tie."""
+        selected, kl, split = jax.device_get(
+            [self.state_[name] for name in
+             ("selected_keys", "index_kl", "tie_split_chunks")])
         selected = float(selected.sum())
         queries = (self.iteration * self._tokens
                    * self.config.kinds.count("sparse_attention"))
         return {"steps": self.iteration, "selected_keys": selected,
                 "keys_per_query": selected / queries if queries else 0.0,
                 "index_kl": float(kl) / self.iteration
-                if self.iteration else 0.0}
+                if self.iteration else 0.0,
+                "tie_split_chunks": int(split.sum())}
 
     def selection(self, ids):
         """bool [B, T, T]: the keys each query of `ids` [B, T] keeps in the
@@ -1109,8 +1121,12 @@ class DecoderModel:
                 leaves, treedef = jax.tree_util.tree_flatten(
                     getattr(model, name))
                 with np.load(io.BytesIO(z.read(name + ".npz"))) as d:
+                    # (a counter newer than the file starts at zero:
+                    # `tie_split_chunks`, the state's last leaf, PR 38)
                     setattr(model, name, jax.tree_util.tree_unflatten(
-                        treedef, [jnp.asarray(d[f"arr_{i}"])
-                                  for i in range(len(leaves))]))
+                        treedef, [
+                            leaf if name == "state_" and f"arr_{i}" not in d
+                            else jnp.asarray(d[f"arr_{i}"])
+                            for i, leaf in enumerate(leaves)]))
             model.iteration, model.epoch = iteration, epoch
         return model

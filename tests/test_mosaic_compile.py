@@ -191,10 +191,12 @@ def test_selection_kernels_compile_for_v5e(one_chip):
 
 
 def test_index_kernels_compile_for_v5e(one_chip):
-    """The indexer's three kernels on a chunk of 1,024 queries over 16,384
+    """The indexer's four kernels on a chunk of 1,024 queries over 16,384
     keys: 16 index heads of 64 over one key head summed in a 512 x 1024
-    tile, their gradients, and the 32 main heads' probabilities summed over
-    the grid's innermost axis; the chunk's first position a scalar operand."""
+    tile, their gradients, the 32 main heads' probabilities summed over the
+    grid's innermost axis, the chunk's first position a scalar operand;
+    and, once a sequence, the selection's words of 32 queries turned into
+    words of 32 keys, [1, 512, 16384] both."""
     from deeplearning4j_tpu.ops.pallas import sparse_index as kernels
     B, n, C, d, S, dt = 1, 16, 1024, 64, 16384, jnp.bfloat16
     q_idx, k_idx, w = ((B, n, C, d), dt), ((B, S, d), dt), ((B, C, n),
@@ -211,7 +213,11 @@ def test_index_kernels_compile_for_v5e(one_chip):
             q, k, lse, 128 ** -0.5, off),
         one_chip, ((B, 32, C, 128), dt), ((B, 4, S, 128), dt),
         ((B, 32, C), jnp.float32), offset)
-    for compiled in (scores, bwd, probs):
+    pack = _compile(kernels.pack_by_key, one_chip,
+                    ((B, S // 32, S), jnp.int32))
+    assert (pack.out_info.shape, pack.out_info.dtype) == ((B, S // 32, S),
+                                                          jnp.int32)
+    for compiled in (scores, bwd, probs, pack):
         assert compiled.as_text().count("tpu_custom_call") == 1
 
 
@@ -273,3 +279,39 @@ def test_latent_attention_layer_compiles_for_v5e(one_chip, forced_kernels):
     for text, most in zip((fwd, grad), _HEADS_COPIES):
         assert not _MINOR_TWO.findall(text)
         assert len(_HEADS_COPY.findall(text)) <= most
+
+
+def test_the_top_ks_bits_stay_in_vmem(one_chip, forced_kernels):
+    """Keye's selection of one 16,384-token sequence, 16 chunks of 1,024
+    queries under `lax.map`, as XLA places it.  The top-k makes 32 passes
+    over a chunk's score bits `u` (64 MB): 37 ms a step while XLA keeps
+    them in VMEM (`S(1)` in a layout), 230 from HBM (PERF.md, PR 38).  What
+    sent them there: a Mosaic call that read them, and a `cond` out of whose
+    branches XLA had moved the packing, so that a [chunk, S] boolean or
+    int32 array went in or came out.  So: whatever fusion writes a [1,
+    1024, 16384] array writes it to VMEM, none is copied, the `cond` takes
+    `u` from VMEM and nothing else that size, and hands back words."""
+    from deeplearning4j_tpu.ops import sparse_index
+    B, n, T, d, C = 1, 16, 16384, 64, 1024
+    text = _compile(
+        lambda q, k, w: sparse_index.sparse_index(q, k, w, 2048), one_chip,
+        ((B, n, T, d), jnp.bfloat16), ((B, T, d), jnp.bfloat16),
+        ((B, T, n), jnp.float32)).as_text()
+    assert text.count("tpu_custom_call") == 2     # the scores, `pack_by_key`
+    dense = rf"\w+\[{B},(?:{C}|{C // 32},32),{T}\]"
+    written = re.findall(rf"= ({dense})(\S*) (fusion|copy)\(", text)
+    assert any(a.startswith("u32") for a, _, _ in written)          # `u`
+    assert all("S(1)" in layout and op == "fusion"
+               for _, layout, op in written), written
+    cond, = re.findall(
+        r"= (\S+) conditional\(.*branch_computations=\{(%[\w.]+), (%[\w.]+)\}",
+        text)
+    assert not re.search(dense, cond[0]) and f"s32[{B},{C // 32},{T}]" in cond[0]
+    for branch in cond[1:]:
+        taken, = re.findall(
+            rf"^{re.escape(branch)} \((.*)\) -> ", text, re.M)
+        assert re.findall(dense, taken) == [f"u32[{B},{C},{T}]"], taken
+        layout, = re.findall(
+            rf"^{re.escape(branch)} .*?\n(?:.*\n)*?.*= \((u32\[{B},{C},{T}\]"
+            r"\S*), .* parameter\(0\)", text, re.M)
+        assert "S(1)" in layout, layout

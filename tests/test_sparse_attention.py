@@ -145,7 +145,7 @@ def test_sparse_index_selects_chunk_by_chunk(monkeypatch):
     monkeypatch.setattr(sparse_index, "_CHUNK", 32)
     q, k, w = _normal(5, 2, 4, 64, 8), _normal(6, 2, 64, 8), _normal(7, 2, 64,
                                                                      4)
-    sel, selected = jax.jit(
+    sel, selected, _ = jax.jit(
         lambda *a: sparse_index.sparse_index(*a, 16))(q, k, w)
     want = _top_k_by_sorting(kernels.index_scores_reference(q, k, w), 0, 16)
     np.testing.assert_array_equal(ak.unpack_selection(sel), want)
@@ -307,19 +307,152 @@ def test_head_summed_probabilities_kernel(offset, small_tiles):
         assert not np.asarray(got)[:, :16, 32:].any()
 
 
+@pytest.mark.parametrize("T,tile", [(128, (32, 64)), (128, (64, 32)),
+                                    (64, (64, 64))])
+def test_pack_by_key_kernel_turns_the_bits(T, tile, monkeypatch):
+    """A causal selection's `by_key` from its `by_query` in 4 x 2, 2 x 4
+    and 1 x 1 tiles: bit-equal to `pack_selection`'s; a tile wholly above
+    the diagonal is zero words, whatever block its clamped index read."""
+    monkeypatch.setattr(kernels, "_BLOCK_Q", tile[0])
+    monkeypatch.setattr(kernels, "_BLOCK_K", tile[1])
+    want = ak.pack_selection(_random_selection(47, 2, T))
+    got = kernels.pack_by_key(want.by_query, interpret=True)
+    assert got.shape == (2, T // 32, T) and got.dtype == jnp.int32
+    np.testing.assert_array_equal(got, want.by_key)
+    np.testing.assert_array_equal(
+        kernels.pack_by_key_reference(want.by_query), want.by_key)
+    assert np.asarray(got).any()
+    # keys 64.. and queries ..63: above the diagonal
+    assert not np.asarray(got)[:, 2:, :64].any()
+
+
+def test_the_tier_answers_for_the_words_apart():
+    """The tier's one answer is for the three kernels that work a chunk of
+    scores; `pack_by_key` also needs blocks whose 32nds are whole sublane
+    tiles (or the whole side), the packed words' rows, and where a sequence
+    has none it alone falls back to its definition."""
+    spec = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    for T, ok in [(16384, True), (64, True), (2048, True), (768, True),
+                  (1152, False),            # blocks of 128: 4 rows of words
+                  (1056, False)]:           # 33 words: blocks of one
+        assert kernels.index_supports(spec(1, 16, sparse_index._chunk(T), 64),
+                                      spec(1, T, 64)) is (T != 1056), T
+        assert kernels.pack_by_key_supports(T, T) is ok, T
+    tier.dispatch.set_dispatch_mode("pallas")
+    taken = sparse_index._dense(spec(1, 3, 2048, 8), spec(1, 2048, 8), 1024)
+    assert [f.func for f in taken] == [
+        kernels.index_scores, kernels.index_scores_bwd,
+        kernels.head_summed_probs, kernels.pack_by_key]
+    # 1,152 = 9 x 128 tokens: the three take chunks of 128 queries over
+    # blocks of 128 keys, the words have 4 rows a block
+    taken = sparse_index._dense(spec(1, 3, 1152, 8), spec(1, 1152, 8), 128)
+    assert [getattr(f, "func", f) for f in taken] == [
+        kernels.index_scores, kernels.index_scores_bwd,
+        kernels.head_summed_probs, kernels.pack_by_key_reference]
+
+
+def _tied_indexer(seed, T, n, d, alike: int):
+    """An indexer's `(q_idx, k_idx, w)` for one sequence; every key stands
+    `alike` times in a row, so that equal keys score equal."""
+    q, k, w = _normal(seed, 1, n, T, d), _normal(seed + 1, 1, T, d), _normal(
+        seed + 2, 1, T, n)
+    return q, jnp.repeat(k[:, ::alike], alike, axis=1), w
+
+
+@pytest.mark.parametrize("alike,split", [(1, 0), (2, 2), (4, 2)])
+def test_the_selection_in_both_tiers_bit_for_bit(alike, split):
+    """2,048 queries in two chunks of 1,024 at the kernels' own 512 x 1024
+    tiles (interpret mode): the kernels' bits are the reference tier's, and
+    the reference tier's are the stable sort's.  On random scores no chunk
+    has a tie to split (16 heads: a score is exactly 0 only where every
+    head's ReLU is shut); where keys come in equal pairs or fours some row
+    keeps the lower ones of a group, and both chunks search for their cuts
+    (`tie_split_chunks`)."""
+    q, k, w = _tied_indexer(60, 2048, 16, 8, alike)
+    results = {}
+    for mode in ("reference", "pallas"):
+        tier.dispatch.set_dispatch_mode(mode)
+        results[mode] = jax.jit(
+            lambda *a: sparse_index.sparse_index(*a, 256))(q, k, w)
+    sel, selected, took = results["pallas"]
+    want = results["reference"][0]
+    np.testing.assert_array_equal(sel.by_query, want.by_query)
+    np.testing.assert_array_equal(sel.by_key, want.by_key)
+    assert results["reference"][1:] == (selected, took)
+    keep = np.asarray(ak.unpack_selection(want))
+    np.testing.assert_array_equal(keep, _top_k_by_sorting(
+        kernels.index_scores_reference(q, k, w), 0, 256))
+    np.testing.assert_array_equal(want.by_key,
+                                  ak.pack_selection(jnp.asarray(keep)).by_key)
+    assert float(selected) == keep.sum() == sum(
+        min(t + 1, 256) for t in range(2048))
+    assert int(took) == split
+    if alike > 1:     # some row kept the lower keys of a group and not all
+        groups = keep[0].reshape(2048, 2048 // alike, alike)
+        assert (groups[..., 0] & ~groups[..., -1])[
+            np.arange(2048)[:, None]
+            >= alike * np.arange(2048 // alike)[None] + alike - 1].any()
+
+
+@pytest.mark.parametrize("alike", [1, 2, 8])
+def test_the_cut_among_a_thresholds_ties(alike):
+    """`_ties` says whether some row has more keys at its threshold than
+    it keeps; `_cut` finds the last one a row keeps, the `need`-th of them
+    from the left, whether or not the row has a tie to split."""
+    rng = np.random.default_rng(61)
+    scores = jnp.asarray(np.repeat(rng.normal(size=(2, 32, 64 // alike)),
+                                   alike, -1), jnp.float32)
+    u, kth, want = jax.jit(lambda s: sparse_index._threshold(
+        s, jnp.int32(32), 16))(scores)
+    need, split = jax.jit(sparse_index._ties)(u, kth, want)
+    assert bool(split) == (alike > 1)
+    cut = jax.jit(sparse_index._cut)(u, kth, need)
+    u, kth, want, need, cut = (np.asarray(a)
+                               for a in (u, kth, want, need, cut))
+    for b in range(2):
+        for i in range(32):
+            ties = np.flatnonzero(u[b, i] == kth[b, i])
+            assert need[b, i] == want[i] - (u[b, i] > kth[b, i]).sum()
+            assert 1 <= need[b, i] <= len(ties)
+            assert cut[b, i] == ties[need[b, i] - 1]
+    np.testing.assert_array_equal(
+        np.asarray(sparse_index._keep_to(u, kth, cut)),
+        _top_k_by_sorting(scores, 32, 16))
+
+
 @pytest.mark.parametrize("mode", ["reference", "pallas"])
 def test_the_indexers_loss_and_its_gradients(mode, monkeypatch, small_tiles):
     """`index_loss` against the KL written out and `jax.grad` of it: the
     loss, and the gradients of the indexer's queries, key and weights; the
     main heads get none."""
-    monkeypatch.setattr(sparse_index, "_CHUNK", 32)
+    _loss_and_gradients(mode, monkeypatch, 64, 32)
+
+
+@pytest.mark.parametrize("mode", ["reference", "pallas"])
+def test_the_indexers_loss_through_many_tiles(mode, monkeypatch):
+    """The same over 256 tokens in four chunks of 64 queries, each 2 x 2
+    tiles of 32 x 128: whole lanes, so the tier does take the kernels (64
+    keys in blocks of 32 it refuses, and the case above runs the
+    definitions in both modes).  The first chunk skips the tile above the
+    diagonal; the queries' gradient sums over a row's key blocks in the
+    kernel, the key's over the query blocks and the chunks outside it."""
+    monkeypatch.setattr(kernels, "_BLOCK_Q", 32)
+    monkeypatch.setattr(kernels, "_BLOCK_K", 128)
+    _loss_and_gradients(mode, monkeypatch, 256, 64)
+
+
+def _loss_and_gradients(mode, monkeypatch, T, chunk):
+    monkeypatch.setattr(sparse_index, "_CHUNK", chunk)
     tier.dispatch.set_dispatch_mode(mode)
-    B, n, T, d, H, Hk, D, topk = 2, 3, 64, 8, 4, 2, 8, 16
+    B, n, d, H, Hk, D, topk = 2, 3, 8, 4, 2, 8, 16
     q_idx, k_idx, w = _normal(50, B, n, T, d), _normal(51, B, T, d), _normal(
         52, B, T, n)
     q, k, v = _normal(53, B, H, T, D), _normal(54, B, Hk, T, D), _normal(
         55, B, Hk, T, D)
-    sel, _ = sparse_index.sparse_index(q_idx, k_idx, w, topk)
+    if T > 64:
+        assert hasattr(sparse_index._dense(q_idx, k_idx, chunk)[0],
+                       "func") is (mode == "pallas")
+    sel, *_ = sparse_index.sparse_index(q_idx, k_idx, w, topk)
     keep = ak.unpack_selection(sel)
     _, lse = ak.fused_attention(q, k, v, causal=True, selection=sel,
                                 return_lse=True)
@@ -420,17 +553,24 @@ def test_the_model_trains_through_fit_in_both_tiers(mode):
     counters of `sparse_stats`."""
     tier.dispatch.set_dispatch_mode(mode)
     model = DecoderModel(DecoderConfig.tiny_sparse(), seed=3)
-    assert "selected_keys" in model.state_ and "index_kl" in model.state_
+    assert {"selected_keys", "index_kl", "tie_split_chunks"} <= set(
+        model.state_)
     model.fit([_batch(i) for i in range(3)])
     losses = float(model.score())
     stats = model.sparse_stats()
     assert stats["steps"] == 3 and stats["selected_keys"] == 3 * 2 * 2 * 904
     assert stats["keys_per_query"] == pytest.approx(904 / 64)
     assert 0 < stats["index_kl"] < 1
+    # one chunk a layer a step; two index heads shut their ReLUs together
+    # on a quarter of the pairs, and scores of exactly 0 tie
+    assert model.state_["tie_split_chunks"].dtype == jnp.int32
+    assert 0 < stats["tie_split_chunks"] <= 3 * 2
     tier.dispatch.set_dispatch_mode("reference")
     plain = DecoderModel(DecoderConfig.tiny_sparse(), seed=3)
     plain.fit([_batch(i) for i in range(3)])
     assert losses == pytest.approx(float(plain.score()), rel=1e-5)
+    assert plain.sparse_stats()["tie_split_chunks"] \
+        == stats["tie_split_chunks"]
     assert model.routed_rows()["steps"] == 3
 
 
@@ -503,9 +643,43 @@ def test_save_load_round_trip_keeps_the_indexer_and_its_counters():
     # the counters are the state's; a query count needs a batch's shape
     assert {**a.sparse_stats(), "keys_per_query": 0.0} == b.sparse_stats()
     assert a.sparse_stats()["selected_keys"] == 2 * 2 * 2 * 904
+    assert 0 < a.sparse_stats()["tie_split_chunks"] <= 2 * 2
+    np.testing.assert_array_equal(a.state_["tie_split_chunks"],
+                                  b.state_["tie_split_chunks"])
     ids = _batch(2).features[0]
     np.testing.assert_array_equal(np.asarray(a.output(ids)),
                                   np.asarray(b.output(ids)))
     np.testing.assert_array_equal(np.asarray(a.selection(ids)),
                                   np.asarray(b.selection(ids)))
     assert float(a.fit_batch(_batch(2))) == float(b.fit_batch(_batch(2)))
+
+
+def test_a_file_from_before_the_counter_loads_with_it_at_zero():
+    """A file saved before `tie_split_chunks` was kept lacks the state's
+    last leaf: it loads, the counter starts at zero and every other leaf is
+    the file's."""
+    import zipfile
+    a = DecoderModel(DecoderConfig.tiny_sparse(), seed=6)
+    a.fit([_batch(0)])
+    assert sorted(a.state_)[-1] == "tie_split_chunks"
+    new, old = io.BytesIO(), io.BytesIO()
+    a.save(new)
+    with zipfile.ZipFile(new) as z, zipfile.ZipFile(old, "w") as out:
+        for name in z.namelist():
+            data = z.read(name)
+            if name == "state_.npz":
+                with np.load(io.BytesIO(data)) as d:
+                    arrays = [d[f"arr_{i}"] for i in range(len(d.files) - 1)]
+                data = io.BytesIO()
+                np.savez(data, *arrays)
+                data = data.getvalue()
+            out.writestr(name, data)
+    old.seek(0)
+    b = DecoderModel.load(old)
+    assert a.sparse_stats()["tie_split_chunks"] > 0
+    assert b.sparse_stats() == {**a.sparse_stats(), "keys_per_query": 0.0,
+                                "tie_split_chunks": 0}
+    for name in set(a.state_) - {"tie_split_chunks"}:
+        np.testing.assert_array_equal(np.asarray(a.state_[name]),
+                                      np.asarray(b.state_[name]))
+    assert float(a.fit_batch(_batch(1))) == float(b.fit_batch(_batch(1)))
