@@ -12,7 +12,7 @@ shape class, and folded into AOT cache keys via
 Importing this package registers the kernel set; call sites go through
 ``dispatch.resolve`` and never import kernel modules directly.
 """
-from deeplearning4j_tpu.ops.pallas import (attention, dispatch,
+from deeplearning4j_tpu.ops.pallas import (attention, delta_rule, dispatch,
                                            grouped_matmul, matmul,
                                            paged_attention, sparse_index,
                                            tiles)
@@ -75,9 +75,17 @@ dispatch.register(
     reference_fn=sparse_index.index_scores_reference,
     supports=sparse_index.index_supports,
 )
+# the recurrence across chunks of `ops/linear_attention.py`, forward and
+# backward by one answer
+dispatch.register(
+    "delta_rule",
+    pallas_fn=delta_rule.across_chunks,
+    reference_fn=delta_rule.across_chunks_reference,
+)
 
 __all__ = [
     "attention",
+    "delta_rule",
     "dispatch",
     "grouped_matmul",
     "matmul",

@@ -5,7 +5,7 @@ Pre-norm residual blocks `h = x + Op_l(RMSNorm(x))`, `y = h + F_l(RMSNorm(h))`
 (RMSNorm in float32, `ops/norm_kernels.rms_norm`), no position embedding,
 next-token cross-entropy under a causal mask or (`objective`) the
 block-diffusion loss below.  `DecoderConfig.layer_types` names `Op_l` layer
-by layer; four published models are the presets the tests and the
+by layer; five published models are the presets the tests and the
 benchmark build:
 
 - `deepseek_v3` configs (DeepSeek-V2/V3, arXiv:2405.04434, arXiv:2412.19437;
@@ -56,6 +56,19 @@ benchmark build:
   through the choice of keys.  Counted on the device: `selected_keys`,
   `index_kl` and `tie_split_chunks` (`sparse_stats()`).
 
+- `solar_open2` configs (Solar Open 2: Kimi delta attention beside gated
+  NoPE attention): `linear_attention` in 3 of every 4 layers — a gated
+  delta rule with a decay a channel (`ops/linear_attention.py`: q, k, v
+  through a causal depthwise convolution and SiLU, q and k L2-normed, a
+  low-rank log decay `-exp(A_log) softplus(h W_fa W_fb + dt_bias)`, beta in
+  (0, 2) (negative eigenvalues, as Solar allows), the state [dk, dv] a head
+  carried chunk to chunk by a Mosaic kernel, a gated RMSNorm a head) —
+  beside `full_attention` with no rotary, no norm of queries and keys and
+  an elementwise sigmoid gate on the heads' output (`rope`, `qk_norm`,
+  `attn_output_gate`); a sigmoid router beside one shared expert in every
+  layer; an untied head.  Counted on the device: `delta_rule_updates`
+  (`linear_stats()`).
+
 Between a product and a kernel (latent attention; measured in PERF.md,
 PR 36).  The kernels read [B, heads, T, d] with `d` in the lanes; a product
 `x @ W` writes [B, T, heads * d]; every slice, concatenate or transpose of a
@@ -86,7 +99,11 @@ after: a sigmoid router over `n_experts` with a selection bias that the step
 updates (no auxiliary loss), or the softmax router; top-k.
 
 `first_expert`/`n_experts_held` say which routed experts this process
-holds of each layer (all of them by default).  Held alone, the layer
+holds of each layer (all of them by default), and `first_head` /
+`n_heads_held` which heads of a `full_attention` or `linear_attention`
+layer (query heads, with the key-value heads they share, whole): the layer
+computes its heads' part of `W_o`'s product and the partial sum goes on,
+as the experts' does.  Held alone, the layer
 computes its experts' part of the result and the partial sum goes on — the
 share one chip runs under expert parallelism, less the exchange; the routed
 part's buffers are then twice the chip's even share of the (token, expert)
@@ -118,12 +135,15 @@ two results are what its backward kernel needs and 68 MB a layer at 2 x
 projections.  At 8,192 tokens a step beside 9.2 GB of training state saving
 those too does not fit a 16 GB chip.  Where `fused_attention` takes no
 Pallas kernel (the CPU, short sequences) there is nothing of that name and
-the block is recomputed whole; a `conv` block has no such result and always
+the block is recomputed whole; a `conv` or a `linear_attention` block
+has no such result and always
 is.
 
 Named scopes mark each part's device ops, forward and backward:
 `mla_attention`, `gqa_attention`, `sparse_index` (the indexer's projections,
 scores, top-k and the packed selection) and `index_loss` beside it,
+`linear_attention` and beneath it `delta_rule` (the chunks' insides and the
+recurrence across them),
 `short_conv` (beneath it `in_proj`, `mix`,
 `out_proj`), `dense_mlp`, `moe` (`ops/moe.py`), `lm_head`, and for the
 diffusion objective `bd_noise` (the draws, the replaced ids, the 2L input)
@@ -131,8 +151,8 @@ and `diffusion_loss` (the weighted cross-entropy and the auxiliary term).
 
 Not here yet: prefill/decode through a cache (growing pages for the
 attention layers beside a fixed `conv_kernel - 1` positions of state for the
-convolutions, and a cache of the indexer's keys with the selection inside
-the paged kernel), absorbed latent attention, the experts' exchange over
+convolutions and a [dk, dv] state a head for the delta rule, and a cache of
+the indexer's keys with the selection inside the paged kernel), absorbed latent attention, the experts' exchange over
 several chips, the decode loop that denoises a block over several passes, a
 vision tower and with it position streams that differ, the indexer's dense
 warm-up stage (the main model frozen).
@@ -152,19 +172,22 @@ from deeplearning4j_tpu.monitor.spans import note, note_step, span
 from deeplearning4j_tpu.ops.attention_kernels import (FLASH_LSE, FLASH_OUT,
                                                       fused_attention,
                                                       unpack_selection)
+from deeplearning4j_tpu.ops.linear_attention import (chunk_delta_rule,
+                                                     l2_normalize)
 from deeplearning4j_tpu.ops.moe import (expert_layer, row_bound, swiglu,
                                         update_router_bias)
 from deeplearning4j_tpu.ops.norm_kernels import layer_norm_reference, rms_norm
 from deeplearning4j_tpu.ops.rotary import (rotary_half_split, rotary_pairs,
                                            rotary_sections)
-from deeplearning4j_tpu.ops.short_conv import gated_short_conv
+from deeplearning4j_tpu.ops.short_conv import (causal_depthwise_conv,
+                                               gated_short_conv)
 from deeplearning4j_tpu.ops.sparse_index import (INDEX_GRADS, SELECTION,
                                                  index_loss, sparse_index)
 from deeplearning4j_tpu.train.updaters import AdamW, IUpdater
 
 
 LAYER_KINDS = ("latent_attention", "full_attention", "conv",
-               "sparse_attention")
+               "sparse_attention", "linear_attention")
 
 
 @dataclasses.dataclass
@@ -179,7 +202,8 @@ class DecoderConfig:
     n_heads: int = 32
     n_kv_heads: int = 8                # `full_attention`: key-value heads
     head_dim: int = 64                 # and the width of all its heads
-    conv_kernel: int = 3               # `conv`: taps of the causal convolution
+    conv_kernel: int = 3               # `conv`, `linear_attention`: taps of
+                                       # the causal convolution
     qk_nope_dim: int = 128             # `latent_attention`, down to the rank
     qk_rope_dim: int = 64
     v_head_dim: int = 128
@@ -213,11 +237,26 @@ class DecoderConfig:
     index_head_dim: int = 64           # heads, over ONE key head of this width,
     index_topk: int = 2048             # the keys a query keeps,
     index_loss_coef: float = 1.0       # and this much of the indexer's loss
+    # `full_attention`: rotary, an RMSNorm of every query and key head, and
+    # an elementwise sigmoid gate on the heads' output (`W_g`)
+    rope: bool = True
+    qk_norm: bool = True
+    attn_output_gate: bool = False
+    # `full_attention` and `linear_attention`: the heads held here, first ..
+    # first + held of `n_heads` (None: all), with their key-value heads
+    first_head: int = 0
+    n_heads_held: Optional[int] = None
 
     @property
     def held(self) -> int:
         return self.n_experts if self.n_experts_held is None \
             else self.n_experts_held
+
+    @property
+    def heads_held(self) -> Tuple[int, int]:
+        """(query heads, key-value heads) held here."""
+        n = self.n_heads if self.n_heads_held is None else self.n_heads_held
+        return n, n * self.n_kv_heads // self.n_heads
 
     @property
     def kinds(self) -> Tuple[str, ...]:
@@ -285,6 +324,23 @@ class DecoderConfig:
         return DecoderConfig(**d)
 
     @staticmethod
+    def tiny_linear(**kw) -> "DecoderConfig":
+        """Test-sized Solar Open 2: one period `full_attention,
+        linear_attention x 3` of expert layers and no dense one; 4 query
+        heads over 2 key-value heads of 8 with no rotary, no norm of queries
+        and keys and an output gate; Kimi delta attention's 4 heads of 8
+        with negative eigenvalues; a sigmoid router over 8 experts top-2
+        beside one shared expert, scale 1; untied head."""
+        d = dict(vocab_size=96, hidden=32, n_layers=4, n_dense_layers=0,
+                 layer_types=("full_attention",) + ("linear_attention",) * 3,
+                 n_heads=4, n_kv_heads=2, head_dim=8, expert_intermediate=16,
+                 n_experts=8, n_shared_experts=1, top_k=2, routed_scale=1.0,
+                 rope=False, qk_norm=False, attn_output_gate=True,
+                 conv_kernel=4)
+        d.update(kw)
+        return DecoderConfig(**d)
+
+    @staticmethod
     def tiny_hybrid(**kw) -> "DecoderConfig":
         """Test-sized LFM2-MoE: a dense `conv` layer, then one period
         `full_attention, conv, conv, conv` of expert layers; 4 query heads
@@ -337,6 +393,22 @@ class DecoderModel:
                 and c.n_heads % c.n_kv_heads:
             raise ValueError(f"{c.n_heads} query heads are no multiple of "
                              f"{c.n_kv_heads} key-value heads")
+        nh, nkv = c.heads_held
+        if (c.first_head, nh) != (0, c.n_heads):
+            group = c.n_heads // c.n_kv_heads
+            if {"latent_attention", "sparse_attention"} & set(kinds) \
+                    or not 0 <= c.first_head <= c.n_heads - nh \
+                    or c.first_head % group or nh % group or nh < 1:
+                raise ValueError(
+                    f"heads {c.first_head}..{c.first_head + nh} of "
+                    f"{c.n_heads}: a share is whole key-value heads of "
+                    f"{group} query heads each, of full_attention and "
+                    f"linear_attention layers")
+        self._linear = "linear_attention" in kinds
+        if self._linear and "linear_attention" in kinds[:c.n_dense_layers]:
+            raise ValueError("a linear_attention layer counts its updates "
+                             "with the expert layers': it goes after the "
+                             "dense layers")
         if c.router_score not in ("sigmoid", "softmax"):
             raise ValueError(f"router_score {c.router_score!r}")
         if c.objective not in ("next_token", "block_diffusion"):
@@ -350,11 +422,11 @@ class DecoderModel:
                 "counts its loss with the expert layers': it goes with the "
                 "next_token objective, after the dense layers")
         if self._diffusion:
-            if "conv" in kinds:
+            if {"conv", "linear_attention"} & set(kinds):
                 raise ValueError(
-                    "a convolution would run across the noisy and the clean "
-                    "copy of a sequence: block diffusion takes attention "
-                    "layers")
+                    "a convolution or a recurrence would run across the "
+                    "noisy and the clean copy of a sequence: block diffusion "
+                    "takes softmax attention layers")
             if c.mask_token_id is None \
                     or not 0 <= c.mask_token_id < c.vocab_size:
                 raise ValueError(f"mask_token_id {c.mask_token_id} is not "
@@ -385,6 +457,11 @@ class DecoderModel:
             self.state_["selected_keys"] = jnp.zeros((n_moe,), jnp.float32)
             self.state_["index_kl"] = jnp.zeros((), jnp.float32)
             self.state_["tie_split_chunks"] = jnp.zeros((n_moe,), jnp.int32)
+        if self._linear:
+            # (token, held head) pairs whose state update ran a layer
+            # (float32: 98,304 a layer a step at 4,096 tokens and 8 heads)
+            self.state_["delta_rule_updates"] = jnp.zeros((n_moe,),
+                                                          jnp.float32)
         self._tokens = 0     # clean tokens of the newest step
         self._pairs = 0      # (token, chosen expert) pairs of the newest step
         self._steps: Dict[str, Any] = {}
@@ -405,7 +482,8 @@ class DecoderModel:
         `embedding_init_std * sqrt(hidden)`, so a tied model states a scale
         that serves both (benchmark/configs/lfm2_24b_a2b.json, `assumed`)."""
         c = self.config
-        H, nh = c.hidden, c.n_heads
+        H, nh, hd = c.hidden, c.n_heads, c.head_dim
+        nq, nkv = c.heads_held
         keys = _key_stream(key)
 
         def nrm(*shape, std=c.init_std):
@@ -427,10 +505,12 @@ class DecoderModel:
                 # queries, keys and values side by side: one product
                 p = {
                     **norms,
-                    "Wqkv": nrm(L, H, (nh + 2 * c.n_kv_heads) * c.head_dim),
-                    "q_norm": jnp.ones((L, c.head_dim)),
-                    "k_norm": jnp.ones((L, c.head_dim)),
-                    "Wo": nrm(L, nh * c.head_dim, H)}
+                    "Wqkv": nrm(L, H, (nq + 2 * nkv) * hd),
+                    **({"q_norm": jnp.ones((L, hd)),
+                        "k_norm": jnp.ones((L, hd))} if c.qk_norm else {}),
+                    "Wo": nrm(L, nq * hd, H)}
+                if c.attn_output_gate:
+                    p["Wg"] = nrm(L, H, nq * hd)
                 if kind == "sparse_attention":
                     # the indexer: its heads' queries, the one key head under
                     # a LayerNorm, a weight a head
@@ -440,6 +520,28 @@ class DecoderModel:
                              k_idx_bias=jnp.zeros((L, d)),
                              Ww_idx=nrm(L, H, n))
                 return p
+            if kind == "linear_attention":
+                # Kimi delta attention (FLA's KDA init): q, k, v side by
+                # side and their convolution's taps (the variance of
+                # PyTorch's Conv1d default at a fan-in of `taps`); the low-rank
+                # decay and output gate; A = U(1, 16); dt_bias the inverse
+                # softplus of dt = logU(1e-3, 1e-1)
+                W, taps = nq * hd, c.conv_kernel
+                lo, hi = np.log(1e-3), np.log(1e-1)
+                dt = jnp.maximum(jnp.exp(jax.random.uniform(
+                    next(keys), (L, W), jnp.float32, lo, hi)), 1e-4)
+                return {
+                    **norms,
+                    "Wqkv": nrm(L, H, 3 * W),
+                    "conv_qkv": nrm(L, taps, 3 * W, std=(3 * taps) ** -0.5),
+                    "Wf_a": nrm(L, H, hd), "Wf_b": nrm(L, hd, W),
+                    "A_log": jnp.log(jax.random.uniform(
+                        next(keys), (L, nq), jnp.float32, 1.0, 16.0)),
+                    "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+                    "Wbeta": nrm(L, H, nq),
+                    "Wg_a": nrm(L, H, hd), "Wg_b": nrm(L, hd, W),
+                    "o_norm": jnp.ones((L, hd)),
+                    "Wo": nrm(L, W, H)}
             return {**norms, "conv_in": nrm(L, H, 3 * H),
                     "conv_kernel": nrm(L, c.conv_kernel, H),
                     "conv_out": nrm(L, H, H)}
@@ -565,28 +667,76 @@ class DecoderModel:
         rotary on all of it."""
         c = self.config
         B, T, _ = h.shape
-        nh, nkv, hd = c.n_heads, c.n_kv_heads, c.head_dim
+        (nh, nkv), hd = c.heads_held, c.head_dim
         qkv = (h @ lp["Wqkv"]).reshape(B, T, nh + 2 * nkv, hd)
         pos, mask = self._rows(T, L)
-        q = self._rotary(rms_norm(qkv[:, :, :nh], lp["q_norm"], c.eps), pos)
-        k = self._rotary(
-            rms_norm(qkv[:, :, nh:nh + nkv], lp["k_norm"], c.eps), pos)
+
+        def prepared(x, gain):      # the layer's own: either may be off
+            x = rms_norm(x, lp[gain], c.eps) if c.qk_norm else x
+            return self._rotary(x, pos) if c.rope else x
+
+        q = prepared(qkv[:, :, :nh], "q_norm")
+        k = prepared(qkv[:, :, nh:nh + nkv], "k_norm")
         heads_first = (0, 2, 1, 3)
         return (q.transpose(heads_first), k.transpose(heads_first),
                 qkv[:, :, nh + nkv:].transpose(heads_first), mask)
 
     def _gqa_attention(self, x, lp, L=None):
         """`x + GQA(RMSNorm(x))` for `x` [B, T, H], under `_rows`' mask and
-        at its positions: `n_heads` query heads over `n_kv_heads` key-value
-        heads (`_gqa_qkv`)."""
+        at its positions: the held query heads over their key-value heads
+        (`_gqa_qkv`); with `attn_output_gate` the heads' output times
+        `sigmoid(h W_g)` elementwise before `W_o` (Gated Attention,
+        arXiv:2505.06708).  Of a head share, the held heads' part of `W_o`'s
+        product: the other chips' parts are not added."""
         B, T, _ = x.shape
         with jax.named_scope("gqa_attention"):
             dt = lp["Wo"].dtype
-            q, k, v, mask = self._gqa_qkv(
-                rms_norm(x, lp["norm1"], self.config.eps).astype(dt), lp, L)
+            h = rms_norm(x, lp["norm1"], self.config.eps).astype(dt)
+            q, k, v, mask = self._gqa_qkv(h, lp, L)
             o = fused_attention(q, k, v, **mask)
             o = o.transpose(0, 2, 1, 3).reshape(B, T, -1)
+            if self.config.attn_output_gate:
+                o = (o.astype(jnp.float32) * jax.nn.sigmoid(
+                    (h @ lp["Wg"]).astype(jnp.float32))).astype(dt)
             return x + (o @ lp["Wo"]).astype(x.dtype)
+
+    def _linear_attention(self, x, lp, L=None):
+        """`x + KDA(RMSNorm(x))` for `x` [B, T, H] (Kimi delta attention,
+        `ops/linear_attention.py`), and `delta_rule_updates`: the (token,
+        held head) pairs whose state update ran with a step above zero.
+        For `h` the normed input, of each held head: `q, k, v =
+        SiLU(conv(h W_{q,k,v}))` (causal, depthwise), q and k L2-normed;
+        the log decay a channel `g = -exp(A_log) softplus(h W_fa W_fb +
+        dt_bias)`; `beta = 2 sigmoid(h w_beta)` (negative eigenvalues, as
+        Solar Open 2 allows them); the delta rule; then `RMSNorm(o) *
+        sigmoid(h W_ga W_gb)` a head and the held heads' part of `W_o`'s
+        product.  Convolution, gates, norms and the rule in float32."""
+        c = self.config
+        B, T, _ = x.shape
+        n, d = c.heads_held[0], c.head_dim
+        f32 = jnp.float32
+        with jax.named_scope("linear_attention"):
+            dt = lp["Wo"].dtype
+            h = rms_norm(x, lp["norm1"], c.eps).astype(dt)
+            qkv = jax.nn.silu(causal_depthwise_conv(
+                (h @ lp["Wqkv"]).astype(f32), lp["conv_qkv"].astype(f32)))
+            q, k, v = (a.reshape(B, T, n, d).transpose(0, 2, 1, 3)
+                       for a in jnp.split(qkv, 3, axis=-1))
+            decay = ((h @ lp["Wf_a"]) @ lp["Wf_b"]).astype(f32) \
+                + lp["dt_bias"]
+            g = (-jnp.exp(lp["A_log"])[:, None]
+                 * jax.nn.softplus(decay.reshape(B, T, n, d)))
+            beta = 2.0 * jax.nn.sigmoid((h @ lp["Wbeta"]).astype(f32))
+            with jax.named_scope("delta_rule"):
+                o, _ = chunk_delta_rule(
+                    l2_normalize(q), l2_normalize(k), v,
+                    g.transpose(0, 2, 1, 3), beta.transpose(0, 2, 1))
+            gate = ((h @ lp["Wg_a"]) @ lp["Wg_b"]).astype(f32)
+            o = (rms_norm(o.transpose(0, 2, 1, 3), lp["o_norm"], c.eps)
+                 * jax.nn.sigmoid(gate.reshape(B, T, n, d)))
+            y = o.reshape(B, T, n * d).astype(dt) @ lp["Wo"]
+            return x + y.astype(x.dtype), {
+                "delta_rule_updates": jnp.sum(beta > 0, dtype=f32)}
 
     def _index(self, h, lp, pos):
         """The indexer's queries [B, n, T, d], its one key head [B, T, d]
@@ -656,7 +806,19 @@ class DecoderModel:
             {"latent_attention": self._attention,
              "full_attention": self._gqa_attention,
              "sparse_attention": self._sparse_attention,
+             "linear_attention": self._linear_attention,
              "conv": self._short_conv}[kind], L=L)
+
+    def _no_counts(self) -> Dict[str, Any]:
+        """The counters an expert layer of this model hands back, zero: a
+        layer of another kind hands these back."""
+        zero = {}
+        if self._sparse:
+            zero.update(selected_keys=jnp.float32(0), index_kl=jnp.float32(0),
+                        tie_split_chunks=jnp.int32(0))
+        if self._linear:
+            zero["delta_rule_updates"] = jnp.float32(0)
+        return zero
 
     def _trunk(self, params, router_bias, ids, L=None):
         """Hidden states [B, T, H] after the last block (float32: the blocks
@@ -708,16 +870,16 @@ class DecoderModel:
         def expert_block(kind, under_scan):
             def moe_block(x, layer):
                 lp, bias = layer
-                lp = {**cast(lp), "router": lp["router"]}   # stays float32
+                # the router and the decay's parameters stay float32
+                lp = {**cast(lp), **{name: lp[name] for name in
+                                     ("router", "A_log", "dt_bias")
+                                     if name in lp}}
                 x = self._operator(kind, L)(x, lp)
-                if kind == "sparse_attention":
+                counted = {}
+                if kind in ("sparse_attention", "linear_attention"):
                     x, counted = x
-                elif self._sparse:      # every layer of a scan counts alike
-                    counted = {"selected_keys": jnp.float32(0),
-                               "index_kl": jnp.float32(0),
-                               "tie_split_chunks": jnp.int32(0)}
-                else:
-                    counted = {}
+                # every layer of a scan counts alike
+                counted = {**self._no_counts(), **counted}
                 x, seen = moe_ffn(x, lp, bias)
                 return x, {**seen, **counted}
             return jax.checkpoint(moe_block, policy=keep,
@@ -1062,6 +1224,17 @@ class DecoderModel:
                 "index_kl": float(kl) / self.iteration
                 if self.iteration else 0.0,
                 "tie_split_chunks": int(split.sum())}
+
+    def linear_stats(self) -> Dict[str, Any]:
+        """What the `linear_attention` layers did over all train steps so
+        far (one device read): `steps`; `delta_rule_updates`, the (token,
+        held head) pairs whose state update ran with a step above zero;
+        `per_token`, that over the steps' tokens at the newest batch shape:
+        the held heads times the linear layers where nothing is skipped."""
+        updates = float(np.asarray(self.state_["delta_rule_updates"]).sum())
+        tokens = self.iteration * self._tokens
+        return {"steps": self.iteration, "delta_rule_updates": updates,
+                "per_token": updates / tokens if tokens else 0.0}
 
     def selection(self, ids):
         """bool [B, T, T]: the keys each query of `ids` [B, T] keeps in the

@@ -111,6 +111,14 @@ FRONT_ENDS = {
         ["updater", "router_bias"],
         ("embed", "param_cast", "mla_attention", "dense_mlp", "moe",
          "lm_head", "loss", "updater", "router_bias")),
+    "DecoderModel-linear": (
+        lambda: DecoderModel(DecoderConfig.tiny_linear(
+            compute_dtype="bfloat16"), seed=1), _decoder_step,
+        ["embed", "param_cast", "gqa_attention", "linear_attention",
+         "delta_rule", "moe", "lm_head", "loss"],
+        ["updater", "router_bias"],
+        ("embed", "param_cast", "gqa_attention", "linear_attention", "moe",
+         "lm_head", "loss", "updater", "router_bias")),
     "DecoderModel-diffusion": (
         lambda: DecoderModel(DecoderConfig.tiny_diffusion(
             compute_dtype="bfloat16"), seed=1), _decoder_step,

@@ -221,6 +221,59 @@ def test_index_kernels_compile_for_v5e(one_chip):
         assert compiled.as_text().count("tpu_custom_call") == 1
 
 
+def test_delta_rule_kernels_compile_for_v5e(one_chip):
+    """Solar Open 2's recurrence across chunks at one 4,096-token sequence:
+    8 heads of 128 a grid step, 64 chunks of 64 in sequence, the state
+    [128, 128] float32 in VMEM; forward (outputs, every chunk's entering
+    state, the last) and the reverse walk (six gradients and the first
+    state's), one Mosaic call each."""
+    from deeplearning4j_tpu.ops.pallas import delta_rule as kernels
+    BH, T, C, d, f32 = 8, 4096, 64, 128, jnp.float32
+    rows, gc = ((BH, T, d), f32), ((BH, T // C, 1, d), f32)
+    aqk, state = ((BH, T, C), f32), ((BH, d, d), f32)
+    fwd = _compile(kernels.across_chunks, one_chip, rows, rows, rows, rows,
+                   gc, aqk, state)
+    assert [o.shape for o in fwd.out_info] == [
+        (BH, T, d), (BH, T // C, d, d), (BH, d, d)]
+    bwd = _compile(kernels.across_chunks_bwd, one_chip, rows, rows, rows,
+                   rows, rows, gc, aqk, ((BH, T // C, d, d), f32), state)
+    assert [o.shape for o in bwd.out_info] == [
+        (BH, T, d), (BH, T, d), (BH, T, d), (BH, T, d), gc[0], aqk[0],
+        (BH, d, d)]
+    for compiled in (fwd, bwd):
+        assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+def test_kimi_delta_attention_layer_compiles_for_v5e(one_chip,
+                                                     forced_kernels):
+    """Solar Open 2's KDA layer, `[1, 4096, 4096]` in, 8 held heads of 128,
+    bf16 products over a float32 stream, forward and gradient: one
+    recurrence kernel forward, one forward and one reverse in the gradient,
+    and the chunks' insides in XLA a head at a time."""
+    from benchmark.models import solar_open2
+    from benchmark import harness
+    c = solar_open2.decoder_config(harness.load_config(
+        harness.load_manifest(), "solar_open2_250b"))
+    model = object.__new__(DecoderModel)
+    model.config = c
+    n, d, H, dt = 8, 128, c.hidden, jnp.bfloat16
+    lp = {"norm1": ((H,), dt), "Wqkv": ((H, 3 * n * d), dt),
+          "conv_qkv": ((4, 3 * n * d), dt), "Wf_a": ((H, d), dt),
+          "Wf_b": ((d, n * d), dt), "A_log": ((n,), jnp.float32),
+          "dt_bias": ((n * d,), jnp.float32), "Wbeta": ((H, n), dt),
+          "Wg_a": ((H, d), dt), "Wg_b": ((d, n * d), dt),
+          "o_norm": ((d,), dt), "Wo": ((n * d, H), dt)}
+    x = ((1, 4096, H), jnp.float32)
+    layer = lambda x, lp: model._linear_attention(x, lp)[0]  # noqa: E731
+    fwd = _compile(layer, one_chip, x, lp)
+    grad = _compile(jax.grad(lambda x, lp, ct: jnp.sum(layer(x, lp) * ct),
+                             (0, 1)), one_chip, x, lp, x)
+    assert fwd.as_text().count("tpu_custom_call") == 1
+    assert grad.as_text().count("tpu_custom_call") == 2
+    # the chunks' insides stay a head's worth: no [8, 64, 4, 16, 16, 128]
+    assert "[8,64,4,16,16,128]" not in grad.as_text()
+
+
 # a `transpose` or `copy` whose result is a whole q, k, v or kernel output of
 # kanana's cell, in either order of tokens and heads
 _HEADS_COPY = re.compile(

@@ -99,5 +99,22 @@ def test_documented_series_exist():
     assert not stale, f"docs rows reference unknown families: {sorted(set(stale))}"
 
 
+def test_every_decoder_counter_is_documented():
+    """Each counter a `DecoderModel` train step keeps in `state_`, of every
+    kind of layer, and each of its layers' device scopes, has its name in
+    the docs' tables."""
+    from deeplearning4j_tpu.zoo import DecoderConfig, DecoderModel
+    with open(DOCS) as f:
+        doc = f.read()
+    names = set()
+    for preset in ("tiny", "tiny_hybrid", "tiny_diffusion", "tiny_sparse",
+                   "tiny_linear"):
+        names |= set(DecoderModel(getattr(DecoderConfig, preset)()).state_)
+    names |= {"linear_attention", "delta_rule", "gqa_attention",
+              "sparse_index", "index_loss", "mla_attention"}
+    missing = sorted(n for n in names if f"`{n}`" not in doc)
+    assert not missing, missing
+
+
 if __name__ == "__main__":
     pytest.main([__file__, "-v"])
