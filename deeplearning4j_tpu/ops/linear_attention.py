@@ -32,14 +32,18 @@ exp(G_r - G_i)`, both factors <= 1, and is a product over dk; a pair inside
 one sub-block takes the decay summed over the tokens between them, a channel
 ([sub, sub, dk] a sub-block).  Every decay is a sum over the tokens it
 spans, accumulated from its short end, never a difference of two long sums:
-a gradient that cancels to a small one keeps its precision.  All chunks'
-insides are computed at once, a head at a time (batched `jax.numpy`,
-float32, full precision, computed again for the backward pass); what crosses
-chunks — `V_new`, `O`, `S'` — is
-`ops/pallas/delta_rule.py`'s recurrence over the grid (head, chunk), a
-Mosaic kernel where the tier takes one, with a `custom_vjp` whose backward
-is the reverse recurrence over chunks (a kernel of the same shape).  The
-inside's gradients are autodiff's.
+a gradient that cancels to a small one keeps its precision.
+`_within_chunks` is that, every chunk of every head at once in batched
+`jax.numpy` (float32, full precision): the definition.  Where the tier takes
+the kernels, `ops/pallas/delta_rule.py`'s `within_chunks` computes the same
+one chunk of a block of heads a grid step in VMEM, and `within_chunks_bwd`
+its VJP from the same inputs; either pair sits behind a `custom_vjp` whose
+only residuals are the inputs (the reference pair's backward is autodiff's
+VJP of `_within_chunks`, the forward computed again).  What crosses
+chunks — `V_new`, `O`, `S'` — is `ops/pallas/delta_rule.py`'s recurrence
+over the grid (head, chunk), a Mosaic kernel where the tier takes one, with
+a `custom_vjp` whose backward is the reverse recurrence over chunks (a
+kernel of the same shape).
 
 Names: the caller puts the whole under `jax.named_scope("delta_rule")`
 (`zoo/decoder.py`); nothing is saved for the backward pass by name.
@@ -159,19 +163,50 @@ def _within_chunks(q, k, v, g, beta, scale: float):
 
 
 # ---------------------------------------------------------------------------
-# across the chunks: the tier's kernels, with the reverse recurrence as VJP
+# the tier's kernels: the insides and the recurrence across chunks, each
+# with its VJP
 # ---------------------------------------------------------------------------
 
-def _tier(w):
-    """`(forward, backward)` of `ops/pallas/delta_rule.py` as the tier
-    resolves them: the Mosaic kernels or their definitions."""
+def _within_chunks_bwd(q, k, v, g, beta, dw, du, dqg, dkd, dgc, daqk,
+                       scale: float):
+    """`(dq, dk, dv, dg, dbeta)`: autodiff's VJP of `_within_chunks`, the
+    forward computed again."""
+    _, vjp = jax.vjp(functools.partial(_within_chunks, scale=scale),
+                     q, k, v, g, beta)
+    return vjp((dw, du, dqg, dkd, dgc, daqk))
+
+
+def _tier(x):
+    """`((inside, inside_bwd), (across, across_bwd))` as the tier resolves
+    them by one answer: `ops/pallas/delta_rule.py`'s four Mosaic kernels,
+    or `_within_chunks` with its VJP and the recurrence's definitions."""
     from deeplearning4j_tpu.ops import pallas as tier
     mod = tier.delta_rule
-    if tier.dispatch.resolve("delta_rule", w) != "pallas":
-        return mod.across_chunks_reference, mod.across_chunks_bwd_reference
+    if tier.dispatch.resolve("delta_rule", x) != "pallas":
+        return ((_within_chunks, _within_chunks_bwd),
+                (mod.across_chunks_reference, mod.across_chunks_bwd_reference))
     interpret = tier.dispatch.interpret_mode()
-    return (functools.partial(mod.across_chunks, interpret=interpret),
-            functools.partial(mod.across_chunks_bwd, interpret=interpret))
+
+    def kernel(fn):
+        return functools.partial(fn, interpret=interpret)
+    return ((kernel(mod.within_chunks), kernel(mod.within_chunks_bwd)),
+            (kernel(mod.across_chunks), kernel(mod.across_chunks_bwd)))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _within(kernels, scale, q, k, v, g, beta):
+    return kernels[0](q, k, v, g, beta, scale)
+
+
+def _within_fwd(kernels, scale, q, k, v, g, beta):
+    return kernels[0](q, k, v, g, beta, scale), (q, k, v, g, beta)
+
+
+def _within_bwd(kernels, scale, res, cts):
+    return kernels[1](*res, *cts, scale)
+
+
+_within.defvjp(_within_fwd, _within_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -214,18 +249,16 @@ def chunk_delta_rule(q, k, v, g, beta, initial_state=None, scale=None,
                         + [(0, 0)] * (a.ndim - 3))
         return a.reshape(BH, N, chunk, *a.shape[3:])
 
-    # a head at a time, computed again for the backward pass: the pairs
-    # inside the sub-blocks are [N, nb, sub, sub, dk] a head (33.5 MB at
-    # 4,096 tokens and dk 128), eight times that all heads at once
-    within = jax.checkpoint(functools.partial(_within_chunks, scale=scale))
-    w, u, qg, kd, gc, aqk = jax.lax.map(
-        lambda xs: within(*xs),
-        (split(q), split(k), split(v), split(g), split(beta)))
+    # the insides' only residuals are their inputs: the backward computes
+    # them again, in VMEM where the tier takes the kernels
+    inside, across = _tier(q)
+    w, u, qg, kd, gc, aqk = _within(
+        inside, scale, split(q), split(k), split(v), split(g), split(beta))
     flat = lambda a: a.reshape(BH, N * chunk, a.shape[-1])  # noqa: E731
     w, u, qg, kd, aqk = map(flat, (w, u, qg, kd, aqk))
     s0 = (jnp.zeros((BH, dv, dk), f32) if initial_state is None else
           jnp.swapaxes(initial_state.astype(f32), -1, -2).reshape(BH, dv, dk))
-    o, last = _across(_tier(w), w, u, qg, kd, gc.reshape(BH, N, 1, dk), aqk,
+    o, last = _across(across, w, u, qg, kd, gc.reshape(BH, N, 1, dk), aqk,
                       s0)
     return (o.reshape(B, H, N * chunk, dv)[:, :, :T],
             jnp.swapaxes(last.reshape(B, H, dv, dk), -1, -2))

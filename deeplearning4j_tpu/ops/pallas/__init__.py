@@ -75,8 +75,8 @@ dispatch.register(
     reference_fn=sparse_index.index_scores_reference,
     supports=sparse_index.index_supports,
 )
-# the recurrence across chunks of `ops/linear_attention.py`, forward and
-# backward by one answer
+# the delta rule of `ops/linear_attention.py`: the chunks' insides and the
+# recurrence across them, forward and backward, by one answer
 dispatch.register(
     "delta_rule",
     pallas_fn=delta_rule.across_chunks,

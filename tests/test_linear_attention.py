@@ -3,7 +3,8 @@ float32: the chunkwise delta rule against its token-by-token recurrence,
 forward and gradient, in both tiers (the recurrence across chunks as its
 `jax.numpy` definition and as the Mosaic kernels in interpret mode), where
 the tail is no whole chunk, the decay is strong, beta is near 2 and the
-state starts nonzero; the kernels against their definitions; the layer kind
+state starts nonzero; the kernels (across the chunks and inside them)
+against their definitions; the layer kind
 through `fit`, its counter, `save` and `load`; gated NoPE attention; a share
 of the heads, and the shares that cannot be held."""
 import io
@@ -144,12 +145,57 @@ def test_the_kernels_against_their_definitions():
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
 
 
+def _by_chunks(a, chunk):
+    """[B, H, T, ...] -> [B H, N, C, ...] as `chunk_delta_rule` splits it
+    (the tail padded with zeros)."""
+    B, H, T = a.shape[:3]
+    C = min(chunk, -(-T // 8) * 8)
+    N = -(-T // C)
+    a = jnp.pad(a, [(0, 0), (0, 0), (0, N * C - T)]
+                + [(0, 0)] * (a.ndim - 3))
+    return a.reshape(B * H, N, C, *a.shape[3:])
+
+
+def _within(a, b):
+    """Largest difference within `LIMIT` of the reference's largest
+    magnitude (a decay strong enough leaves `gc` all zeros)."""
+    return float(jnp.max(jnp.abs(a - b))) <= LIMIT * float(
+        jnp.max(jnp.abs(b)))
+
+
+@pytest.mark.parametrize("chunk", [32, 64])
+@pytest.mark.parametrize("case", list(CASES))
+def test_the_inside_kernels_against_their_definition(case, chunk):
+    """The chunks' insides as Mosaic kernels in interpret mode: the forward
+    (w, u, qg, kd, gc, aqk) against `_within_chunks`, the backward (dq, dk,
+    dv, dg, dbeta) against autodiff's VJP of it."""
+    q, k, v, g, beta, _ = _inputs(5, **CASES[case])
+    ops = [_by_chunks(a, chunk) for a in (q, k, v, g, beta)]
+    scale = q.shape[-1] ** -0.5
+    want = la._within_chunks(*ops, scale)
+    got = kernels.within_chunks(*ops, scale, interpret=True)
+    for name, a, b in zip("w u qg kd gc aqk".split(), got, want):
+        assert a.shape == b.shape and _within(a, b), name
+    r = np.random.default_rng(6)
+    cts = tuple(jnp.asarray(r.normal(size=a.shape), jnp.float32)
+                for a in want)
+    _, vjp = jax.vjp(lambda *a: la._within_chunks(*a, scale), *ops)
+    got = kernels.within_chunks_bwd(*ops, *cts, scale, interpret=True)
+    for name, a, b in zip("q k v g beta".split(), got, vjp(cts)):
+        assert a.shape == b.shape and _within(a, b), name
+
+
 def test_the_tier_takes_the_kernels_where_it_says():
     w = jnp.zeros((8, 128, 128), jnp.float32)
     tier.dispatch.set_dispatch_mode("reference")
-    assert la._tier(w)[0] is kernels.across_chunks_reference
+    inside, across = la._tier(w)
+    assert across[0] is kernels.across_chunks_reference
+    assert inside == (la._within_chunks, la._within_chunks_bwd)
     tier.dispatch.set_dispatch_mode("pallas")
-    assert la._tier(w)[0].func is kernels.across_chunks
+    inside, across = la._tier(w)
+    assert across[0].func is kernels.across_chunks
+    assert [f.func for f in inside] == [kernels.within_chunks,
+                                       kernels.within_chunks_bwd]
 
 
 # ---------------------------------------------------------------------------

@@ -8,6 +8,7 @@ A compile that passes is not a chip run and gives no time.
 The topology is described inside a fixture and only in this file: the
 process that describes it loads libtpu and keeps it (see the
 `on-chip-measurement` guide, section 2)."""
+import functools
 import re
 
 import jax
@@ -244,12 +245,34 @@ def test_delta_rule_kernels_compile_for_v5e(one_chip):
         assert compiled.as_text().count("tpu_custom_call") == 1
 
 
+def test_delta_rule_inside_kernels_compile_for_v5e(one_chip):
+    """Solar Open 2's chunks' insides at one 4,096-token sequence: 8 heads
+    of 128 a grid step, 64 chunks of 64 (sub-blocks of 16), forward (w, u,
+    qg, kd, gc, aqk) and backward (dq, dk, dv, dg, dbeta), one Mosaic call
+    each."""
+    from deeplearning4j_tpu.ops.pallas import delta_rule as kernels
+    BH, N, C, d, f32 = 8, 64, 64, 128, jnp.float32
+    rows, beta = ((BH, N, C, d), f32), ((BH, N, C), f32)
+    fwd = _compile(functools.partial(kernels.within_chunks, scale=d ** -0.5),
+                   one_chip, rows, rows, rows, rows, beta)
+    assert [o.shape for o in fwd.out_info] == [
+        rows[0]] * 4 + [(BH, N, d), (BH, N, C, C)]
+    bwd = _compile(
+        functools.partial(kernels.within_chunks_bwd, scale=d ** -0.5),
+        one_chip, rows, rows, rows, rows, beta, rows, rows, rows, rows,
+        ((BH, N, d), f32), ((BH, N, C, C), f32))
+    assert [o.shape for o in bwd.out_info] == [rows[0]] * 4 + [beta[0]]
+    for compiled in (fwd, bwd):
+        assert compiled.as_text().count("tpu_custom_call") == 1
+
+
 def test_kimi_delta_attention_layer_compiles_for_v5e(one_chip,
                                                      forced_kernels):
     """Solar Open 2's KDA layer, `[1, 4096, 4096]` in, 8 held heads of 128,
-    bf16 products over a float32 stream, forward and gradient: one
-    recurrence kernel forward, one forward and one reverse in the gradient,
-    and the chunks' insides in XLA a head at a time."""
+    bf16 products over a float32 stream, forward and gradient: the chunks'
+    insides and the recurrence a kernel each forward, and in the gradient
+    those two and their two backward kernels; no triangular solve and no
+    sub-block decay tile ([.., 16, 16, 128]) outside the kernels."""
     from benchmark.models import solar_open2
     from benchmark import harness
     c = solar_open2.decoder_config(harness.load_config(
@@ -268,10 +291,11 @@ def test_kimi_delta_attention_layer_compiles_for_v5e(one_chip,
     fwd = _compile(layer, one_chip, x, lp)
     grad = _compile(jax.grad(lambda x, lp, ct: jnp.sum(layer(x, lp) * ct),
                              (0, 1)), one_chip, x, lp, x)
-    assert fwd.as_text().count("tpu_custom_call") == 1
-    assert grad.as_text().count("tpu_custom_call") == 2
-    # the chunks' insides stay a head's worth: no [8, 64, 4, 16, 16, 128]
-    assert "[8,64,4,16,16,128]" not in grad.as_text()
+    assert fwd.as_text().count("tpu_custom_call") == 2
+    text = grad.as_text()
+    assert text.count("tpu_custom_call") == 4
+    assert "triangular-solve" not in text
+    assert re.search(r"16,16,128\]", text) is None
 
 
 # a `transpose` or `copy` whose result is a whole q, k, v or kernel output of
