@@ -51,9 +51,14 @@ Everything dense in (query, key) is computed `_CHUNK` queries at a time: a
 of `ops/pallas/sparse_index.py` (a TPU, or the tier forced to `pallas`): the
 index heads are summed in the tile, so are the main heads' probabilities,
 the scores' three gradients come from one recomputation of a tile's dots,
-and the selection's words are turned in the tile.  Elsewhere the same four
-functions are plain `jax.numpy` (`pack_by_key` alone too, where a sequence's
-words come in no whole tiles: `pack_by_key_supports`).
+and the selection's words are turned in the tile.  The loss's kernels
+unpack the chunk's words in the tile too: the scores' pass keeps each
+row's logsumexp over its selected pairs, and the probabilities' last head
+step makes the KL term and the scores' cotangent, so only two [chunk, S]
+float32 arrays, the scores and their cotangent, go from one kernel to the
+next.  Elsewhere the same five functions are plain `jax.numpy`
+(`pack_by_key` alone too, where a sequence's words come in no whole tiles:
+`pack_by_key_supports`).
 """
 from __future__ import annotations
 
@@ -70,7 +75,8 @@ from deeplearning4j_tpu.ops.attention_kernels import (Selection, _pack_bits,
 SELECTION = "sparse_selection"
 INDEX_GRADS = "index_loss_grads"
 
-_CHUNK = 1024       # queries a pass: six dense [chunk, S] float32 arrays
+_CHUNK = 1024       # queries a pass: the selection's scores and bits, the
+                    # loss's scores and cotangent, [chunk, S] each
 
 
 def _chunk(T: int) -> int:
@@ -89,10 +95,12 @@ def _chunk(T: int) -> int:
 # ---------------------------------------------------------------------------
 
 def _dense(q_idx, k_idx, chunk: int):
-    """`(index_scores, index_scores_bwd, head_summed_probs, pack_by_key)` of
-    `ops/pallas/sparse_index.py` as the tier resolves them for `chunk` of
-    these queries at a time (the last for the sequence, and only where its
-    words come in whole tiles): the kernels, or their plain definitions."""
+    """`(index_scores, pack_by_key, index_scores_lse, kl_and_cotangent,
+    index_scores_bwd)` of `ops/pallas/sparse_index.py` — the selection's
+    two, then the loss's three — as the tier resolves them for `chunk` of
+    these queries at a time (`pack_by_key` for the sequence, and only where
+    its words come in whole tiles): the kernels, or their plain
+    definitions."""
     from deeplearning4j_tpu.ops import pallas as tier
     mod = tier.sparse_index
     B, n, T, d = q_idx.shape
@@ -100,14 +108,16 @@ def _dense(q_idx, k_idx, chunk: int):
             "sparse_index", jax.ShapeDtypeStruct((B, n, chunk, d),
                                                  q_idx.dtype),
             k_idx) != "pallas":
-        return (mod.index_scores_reference, mod.index_scores_bwd_reference,
-                mod.head_summed_probs_reference, mod.pack_by_key_reference)
-    kernels = [mod.index_scores, mod.index_scores_bwd, mod.head_summed_probs,
-               mod.pack_by_key]
+        return (mod.index_scores_reference, mod.pack_by_key_reference,
+                mod.index_scores_lse_reference,
+                mod.kl_and_cotangent_reference,
+                mod.index_scores_bwd_reference)
+    kernels = [mod.index_scores, mod.pack_by_key, mod.index_scores_lse,
+               mod.kl_and_cotangent, mod.index_scores_bwd]
     kernels = [functools.partial(f, interpret=tier.dispatch.interpret_mode())
                for f in kernels]
     if not mod.pack_by_key_supports(T, k_idx.shape[1]):
-        kernels[3] = mod.pack_by_key_reference
+        kernels[1] = mod.pack_by_key_reference
     return tuple(kernels)
 
 
@@ -233,7 +243,7 @@ def sparse_index(q_idx, k_idx, w, topk: int):
     `SELECTION`."""
     T = q_idx.shape[2]
     C = _chunk(T)
-    scores, _, _, pack_by_key = _dense(q_idx, k_idx, C)
+    scores, pack_by_key, *_ = _dense(q_idx, k_idx, C)
 
     def one(xs):
         q_c, w_c, q_offset = xs
@@ -258,35 +268,28 @@ def sparse_index(q_idx, k_idx, w, topk: int):
 
 def _loss_and_grads(q_idx, k_idx, w, by_query, q, k, lse, scale):
     """`(loss, (d q_idx, d k_idx, d w))` of `index_loss`, a chunk of
-    queries at a time."""
+    queries at a time: the scores with each row's logsumexp over its
+    selected pairs, the KL a row with the scores' cotangent, the three
+    gradients."""
     B, _, T, _ = q_idx.shape
-    H = q.shape[1]
     C = _chunk(T)
-    index_scores, index_scores_bwd, head_summed_probs, _ = _dense(
+    _, _, scores_lse, kl_and_cotangent, index_scores_bwd = _dense(
         q_idx, k_idx, C)
 
     def one(dk_sum, xs):
-        q_c, w_c, words, qm_c, lse_c, q_offset = xs
-        keep = _unpack_bits(words, 1)                       # [B, C, S]
-        scores = index_scores(q_c, k_idx, w_c, q_offset)
-        lse_idx = jax.nn.logsumexp(jnp.where(keep, scores, -jnp.inf),
-                                   axis=-1, keepdims=True)
-        log_p = jnp.where(keep, scores - lse_idx, 0.0)
-        pbar = jnp.where(
-            keep, head_summed_probs(qm_c, k, lse_c, scale, q_offset) / H,
-            0.0)
-        kl = jnp.sum(jnp.where(
-            pbar > 0, pbar * (jnp.log(jnp.where(pbar > 0, pbar, 1.0))
-                              - log_p), 0.0))
-        d_scores = (jnp.where(keep, jnp.exp(log_p), 0.0) - pbar) / (B * T)
+        q_c, w_c, qm_c, lse_c, q_offset = xs
+        words = jax.lax.dynamic_slice_in_dim(by_query, q_offset // 32,
+                                             C // 32, 1)
+        scores, lse_idx = scores_lse(q_c, k_idx, w_c, words, q_offset)
+        d_scores, kl = kl_and_cotangent(qm_c, k, lse_c, scale, scores, words,
+                                        lse_idx, B * T, q_offset)
         dq_c, dk_c, dw_c = index_scores_bwd(d_scores, q_c, k_idx, w_c,
                                             q_offset)
-        return dk_sum + dk_c.astype(jnp.float32), (kl, dq_c, dw_c)
+        return dk_sum + dk_c.astype(jnp.float32), (jnp.sum(kl), dq_c, dw_c)
 
     dk, (kl, dq, dw) = jax.lax.scan(
         one, jnp.zeros(k_idx.shape, jnp.float32),
-        (_chunks(q_idx, 2, C), _chunks(w, 1, C),
-         _chunks(by_query, 1, C // 32), _chunks(q, 2, C),
+        (_chunks(q_idx, 2, C), _chunks(w, 1, C), _chunks(q, 2, C),
          _chunks(lse, 2, C), jnp.arange(0, T, C, dtype=jnp.int32)))
     return jnp.sum(kl) / (B * T), (
         _unchunk(dq, 2), dk.astype(k_idx.dtype), _unchunk(dw, 1))

@@ -192,33 +192,40 @@ def test_selection_kernels_compile_for_v5e(one_chip):
 
 
 def test_index_kernels_compile_for_v5e(one_chip):
-    """The indexer's four kernels on a chunk of 1,024 queries over 16,384
+    """The indexer's five kernels on a chunk of 1,024 queries over 16,384
     keys: 16 index heads of 64 over one key head summed in a 512 x 1024
-    tile, their gradients, the 32 main heads' probabilities summed over the
-    grid's innermost axis, the chunk's first position a scalar operand;
-    and, once a sequence, the selection's words of 32 queries turned into
-    words of 32 keys, [1, 512, 16384] both."""
+    tile, alone and with each row's logsumexp over the chunk's words of the
+    selection, their gradients, the 32 main heads' probabilities summed
+    over the grid's innermost axis into the loss's KL and cotangent, the
+    chunk's first position a scalar operand; and, once a sequence, the
+    selection's words of 32 queries turned into words of 32 keys, [1, 512,
+    16384] both."""
     from deeplearning4j_tpu.ops.pallas import sparse_index as kernels
     B, n, C, d, S, dt = 1, 16, 1024, 64, 16384, jnp.bfloat16
     q_idx, k_idx, w = ((B, n, C, d), dt), ((B, S, d), dt), ((B, C, n),
                                                             jnp.float32)
     offset, dense = ((), jnp.int32), ((B, C, S), jnp.float32)
+    words, rows = ((B, C // 32, S), jnp.int32), ((B, C), jnp.float32)
     scores = _compile(kernels.index_scores, one_chip, q_idx, k_idx, w, offset)
     assert scores.out_info.shape == dense[0]
+    lse = _compile(kernels.index_scores_lse, one_chip, q_idx, k_idx, w,
+                   words, offset)
+    assert [o.shape for o in lse.out_info] == [dense[0], rows[0]]
     bwd = _compile(kernels.index_scores_bwd, one_chip, dense, q_idx, k_idx, w,
                    offset)
     assert [(o.shape, o.dtype) for o in bwd.out_info] == [
         (q_idx[0], dt), (k_idx[0], jnp.float32), (w[0], jnp.float32)]
     probs = _compile(
-        lambda q, k, lse, off: kernels.head_summed_probs(
-            q, k, lse, 128 ** -0.5, off),
+        lambda q, k, lse, *rest: kernels.kl_and_cotangent(
+            q, k, lse, 128 ** -0.5, *rest[:3], S, rest[3]),
         one_chip, ((B, 32, C, 128), dt), ((B, 4, S, 128), dt),
-        ((B, 32, C), jnp.float32), offset)
+        ((B, 32, C), jnp.float32), dense, words, rows, offset)
+    assert [o.shape for o in probs.out_info] == [dense[0], rows[0]]
     pack = _compile(kernels.pack_by_key, one_chip,
                     ((B, S // 32, S), jnp.int32))
     assert (pack.out_info.shape, pack.out_info.dtype) == ((B, S // 32, S),
                                                           jnp.int32)
-    for compiled in (scores, bwd, probs, pack):
+    for compiled in (scores, lse, bwd, probs, pack):
         assert compiled.as_text().count("tpu_custom_call") == 1
 
 
@@ -392,3 +399,39 @@ def test_the_top_ks_bits_stay_in_vmem(one_chip, forced_kernels):
             rf"^{re.escape(branch)} .*?\n(?:.*\n)*?.*= \((u32\[{B},{C},{T}\]"
             r"\S*), .* parameter\(0\)", text, re.M)
         assert "S(1)" in layout, layout
+
+
+def test_the_indexers_loss_leaves_no_dense_array_to_xla(one_chip,
+                                                        forced_kernels):
+    """Keye's `index_loss` and its gradients on one 16,384-token sequence,
+    16 chunks of 1,024 queries under `lax.scan`: three Mosaic calls a
+    chunk — the scores with each row's logsumexp over the selection, the
+    main heads' probabilities ending in the KL and the scores' cotangent,
+    the cotangent's three gradients — and nothing of XLA's reads or writes
+    a [1, 1024, 16384] array (float32, int32 or boolean; the words
+    unpacked are [1, 32, 32, 16384]): the two dense arrays pass from one
+    kernel to the next, and XLA only takes them out of the calls' tuples."""
+    from deeplearning4j_tpu.ops import sparse_index
+    B, n, T, d, H, Hk, D, C = 1, 16, 16384, 64, 32, 4, 128, 1024
+    dt = jnp.bfloat16
+    text = _compile(
+        jax.value_and_grad(lambda q_idx, k_idx, w, *rest: (
+            sparse_index.index_loss(q_idx, k_idx, w, *rest, D ** -0.5)),
+            (0, 1, 2)),
+        one_chip, ((B, n, T, d), dt), ((B, T, d), dt),
+        ((B, T, n), jnp.float32), ((B, T // 32, T), jnp.int32),
+        ((B, H, T, D), dt), ((B, Hk, T, D), dt),
+        ((B, H, T), jnp.float32)).as_text()
+    assert text.count("tpu_custom_call") == 3
+    # each claims at most 32 MB of VMEM: in the train step a larger claim
+    # made XLA move the selection's words out of VMEM around every chunk
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert all(int(size) <= 32 << 20 for line in calls for size in
+               re.findall(r'"scoped_memory_configs":\[\{[^]]*"size":"(\d+)"',
+                          line))
+    dense = re.compile(rf"\b(?:f32|s32|u32|pred)\[{B},(?:{C}|32,32),{T}\]")
+    touched = [line for line in text.splitlines() if dense.search(line)]
+    assert touched
+    for line in touched:
+        assert ("tpu_custom_call" in line
+                or " get-tuple-element(%closed_call" in line), line

@@ -294,17 +294,96 @@ def test_index_score_kernels_against_their_definitions(offset, small_tiles):
 
 @pytest.mark.parametrize("offset", [0, 32])
 def test_head_summed_probabilities_kernel(offset, small_tiles):
+    """The heads' probabilities as `kl_and_cotangent` sums them in the
+    tile, read back from its cotangent: with every causal pair selected
+    and every score 0, `p` is one over a row's keys and `pbar = p - rows *
+    d_scores`; the tile above the diagonal is skipped and left zero."""
     q, k = _normal(44, 2, 4, 32, 8), _normal(45, 2, 2, 64, 8)
     lse = _normal(46, 2, 4, 32) + 3.0
-    got = kernels.head_summed_probs(q, k, lse, 8 ** -0.5, jnp.int32(offset),
-                                    interpret=True)
     under = _causal(offset, 32, 64)
+    keys = jnp.sum(under, -1, dtype=jnp.float32)
+    d, _ = kernels.kl_and_cotangent(
+        q, k, lse, 8 ** -0.5, jnp.zeros((2, 32, 64), jnp.float32),
+        ak._pack_bits(jnp.stack([under] * 2), 1),
+        jnp.broadcast_to(jnp.log(keys), (2, 32)), 64, jnp.int32(offset),
+        interpret=True)
+    got = 4 * (jnp.exp(-jnp.log(keys))[:, None] - 64 * d)
     want = kernels.head_summed_probs_reference(q, k, lse, 8 ** -0.5)
     np.testing.assert_allclose(jnp.where(under, got, 0.0),
                                jnp.where(under, want, 0.0), rtol=1e-5,
                                atol=1e-6)
     if offset == 0:
-        assert not np.asarray(got)[:, :16, 32:].any()
+        assert not np.asarray(d)[:, :16, 32:].any()
+
+
+def _chunk_selection(scores, offset):
+    """A chunk's keep-mask [B, 32, 64] and its words: the top 16 a query,
+    so at offset 0 the first 15 rows keep fewer; at offset 32 the first
+    eight rows drop keys 0..31, so that a live key block holds none of
+    their keys, and keep their diagonal."""
+    keep = np.array(sparse_index.select_keys(scores, jnp.int32(offset), 16))
+    if offset:
+        keep[:, :8, :32] = False
+        keep[:, np.arange(8), offset + np.arange(8)] = True
+    return jnp.asarray(keep), ak._pack_bits(jnp.asarray(keep), 1)
+
+
+@pytest.mark.parametrize("offset", [0, 32])
+def test_the_scores_logsumexp_over_the_selection(offset, small_tiles):
+    """`index_scores_lse` in 32 x 32 tiles (a query block of whole words):
+    each row's logsumexp over its selected scores, accumulated across the
+    key blocks, against `logsumexp(where(keep, scores, -inf))`; the scores
+    under the diagonal as `index_scores` gives them."""
+    q, k, w = _normal(70, 2, 3, 32, 8), _normal(71, 2, 64, 8), _normal(
+        72, 2, 32, 3)
+    want = kernels.index_scores_reference(q, k, w)
+    keep, words = _chunk_selection(want, offset)
+    assert np.asarray(keep).sum(-1).min() < 16
+    if offset:                  # rows whose first key block holds no pick
+        assert not np.asarray(keep)[:, :8, :32].any()
+    scores, lse = kernels.index_scores_lse(q, k, w, words, jnp.int32(offset),
+                                           interpret=True)
+    assert lse.shape == (2, 32)
+    np.testing.assert_allclose(
+        lse, jax.nn.logsumexp(jnp.where(keep, want, -jnp.inf), -1),
+        rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(
+        lse, kernels.index_scores_lse_reference(q, k, w, words)[1],
+        rtol=1e-6, atol=1e-6)
+    under = _causal(offset, 32, 64)
+    np.testing.assert_allclose(jnp.where(under, scores, 0.0),
+                               jnp.where(under, want, 0.0), atol=1e-5)
+
+
+@pytest.mark.parametrize("offset", [0, 32])
+def test_the_loss_cotangent_kernel(offset, small_tiles):
+    """`kl_and_cotangent` from `index_scores_lse`'s results against the
+    definition (the indexer loss's arithmetic on a chunk): the scores'
+    cotangent and each row's KL; the cotangent exactly zero off the
+    selection and in the tile above the diagonal."""
+    q_idx, k_idx, w = _normal(73, 2, 3, 32, 8), _normal(74, 2, 64, 8), \
+        _normal(75, 2, 32, 3)
+    q, k = _normal(76, 2, 4, 32, 8), _normal(77, 2, 2, 64, 8)
+    lse = _normal(78, 2, 4, 32) + 3.0
+    keep, words = _chunk_selection(
+        kernels.index_scores_reference(q_idx, k_idx, w), offset)
+    scores, lse_idx = kernels.index_scores_lse(
+        q_idx, k_idx, w, words, jnp.int32(offset), interpret=True)
+    got, kl = kernels.kl_and_cotangent(q, k, lse, 8 ** -0.5, scores, words,
+                                       lse_idx, 2 * 64, jnp.int32(offset),
+                                       interpret=True)
+    ref_scores, ref_lse = kernels.index_scores_lse_reference(q_idx, k_idx, w,
+                                                             words)
+    want, want_kl = kernels.kl_and_cotangent_reference(
+        q, k, lse, 8 ** -0.5, ref_scores, words, ref_lse, 2 * 64)
+    assert got.shape == (2, 32, 64) and kl.shape == (2, 32)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-9)
+    np.testing.assert_allclose(kl, want_kl, rtol=1e-5, atol=1e-7)
+    assert float(jnp.abs(want).max()) > 1e-4 and float(want_kl.min()) != 0
+    got = np.asarray(got)
+    assert not got[~np.asarray(keep)].any()
+    if offset == 0:             # the tile above the diagonal
+        assert not got[:, :, 32:].any()
 
 
 @pytest.mark.parametrize("T,tile", [(128, (32, 64)), (128, (64, 32)),
@@ -327,8 +406,10 @@ def test_pack_by_key_kernel_turns_the_bits(T, tile, monkeypatch):
 
 
 def test_the_tier_answers_for_the_words_apart():
-    """The tier's one answer is for the three kernels that work a chunk of
-    scores; `pack_by_key` also needs blocks whose 32nds are whole sublane
+    """The tier's one answer is for the four kernels that work a chunk of
+    scores (the loss's two that read the chunk's words take query blocks
+    of whole sublane tiles of words or the whole chunk, so they always
+    can); `pack_by_key` also needs blocks whose 32nds are whole sublane
     tiles (or the whole side), the packed words' rows, and where a sequence
     has none it alone falls back to its definition."""
     spec = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
@@ -341,14 +422,18 @@ def test_the_tier_answers_for_the_words_apart():
     tier.dispatch.set_dispatch_mode("pallas")
     taken = sparse_index._dense(spec(1, 3, 2048, 8), spec(1, 2048, 8), 1024)
     assert [f.func for f in taken] == [
-        kernels.index_scores, kernels.index_scores_bwd,
-        kernels.head_summed_probs, kernels.pack_by_key]
-    # 1,152 = 9 x 128 tokens: the three take chunks of 128 queries over
-    # blocks of 128 keys, the words have 4 rows a block
+        kernels.index_scores, kernels.pack_by_key, kernels.index_scores_lse,
+        kernels.kl_and_cotangent, kernels.index_scores_bwd]
+    assert kernels._word_blocks(1024, 16384) == (512, 1024)
+    # 1,152 = 9 x 128 tokens: the four take chunks of 128 queries over
+    # blocks of 128 keys (the words' 4 rows the chunk's whole side), the
+    # sequence's words have 4 rows a block
+    assert kernels._word_blocks(128, 1152) == (128, 128)
     taken = sparse_index._dense(spec(1, 3, 1152, 8), spec(1, 1152, 8), 128)
     assert [getattr(f, "func", f) for f in taken] == [
-        kernels.index_scores, kernels.index_scores_bwd,
-        kernels.head_summed_probs, kernels.pack_by_key_reference]
+        kernels.index_scores, kernels.pack_by_key_reference,
+        kernels.index_scores_lse, kernels.kl_and_cotangent,
+        kernels.index_scores_bwd]
 
 
 def _tied_indexer(seed, T, n, d, alike: int):
