@@ -171,6 +171,17 @@ def validate_case(case: OpTestCase) -> None:
         _check_grad(fn, case, tensor_idx)
 
 
+def _compiled_first(f, *args):
+    """`f(*args)` as one compiled program, or eagerly where an op's
+    Python needs concrete values: one compile, not one a primitive."""
+    import jax
+
+    try:
+        return jax.jit(f)(*args)
+    except Exception:                   # not traceable -> run it eagerly
+        return f(*args)
+
+
 def _check_grad(fn, case: OpTestCase, tensor_idx) -> None:
     import jax
 
@@ -237,7 +248,8 @@ def _check_grad_x64(fn, case: OpTestCase, tensor_idx) -> None:
             vals[i] = x
         return loss_at(vals)
 
-    analytic_all = jax.grad(loss_args, argnums=tuple(range(len(case.grad))))(
+    analytic_all = _compiled_first(
+        jax.grad(loss_args, argnums=tuple(range(len(case.grad)))),
         *[jnp.asarray(f64_args[i]) for i in case.grad])
 
     sample = 0 if os.environ.get("OPVAL_FULL") else case.grad_sample
@@ -268,7 +280,8 @@ def _check_grad_x64(fn, case: OpTestCase, tensor_idx) -> None:
             xs = np.tile(flat, (2 * n, 1))
             xs[np.arange(n), coords] += eps
             xs[np.arange(n, 2 * n), coords] -= eps
-            vals = np.asarray(jax.vmap(loss_wrt)(
+            vals = np.asarray(_compiled_first(
+                jax.vmap(loss_wrt),
                 jnp.asarray(xs.reshape((2 * n,) + x0.shape))))
         except Exception:
             vals = None                 # not vmappable -> scalar fallback

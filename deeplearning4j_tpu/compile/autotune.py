@@ -5,7 +5,8 @@ framework already exposes but makes users hand-tune: `fused_steps` (scan
 block size), device prefetch depth, ZeRO-1 optimizer sharding on/off,
 buffer donation, and the serving bucket ladder.  The autotuner measures
 real steps/sec per candidate through a caller-supplied measure function
-(bench.py provides one), searches with a coarse grid over the
+(`examples/autotune_and_serve.py` supplies one), searches with a coarse
+grid over the
 highest-impact dimensions followed by greedy per-dimension refinement,
 and persists the winner as a JSON artifact next to the executable store
 — `load_schedule()` re-applies it at build time in any later process, so
@@ -128,7 +129,7 @@ class ScheduleAutotuner:
     """Grid + greedy-refinement search over `Schedule` space.
 
     `measure(schedule) -> steps/sec` (higher is better) is the only
-    contract; bench.py's `measure_training` builds one from a model
+    contract; `examples/autotune_and_serve.py` builds one from a model
     factory, tests rig one analytically.  Measurements are memoized per
     config, every evaluation lands in `history`, and the returned
     schedule carries its winning steps/sec + search metadata."""

@@ -85,7 +85,7 @@ class PersistentExecutableCache:
         from deeplearning4j_tpu.monitor.instrument import aot_instruments
         self._instr = aot_instruments()
         # per-instance tallies (registry counters are process-global; tests
-        # and bench read these to assert on ONE cache's behaviour)
+        # read these to assert on ONE cache's behaviour)
         self.stats: Dict[str, int] = {
             "disk_hits": 0, "disk_misses": 0, "compiles": 0, "stores": 0,
             "errors": 0, "bytes_read": 0, "bytes_written": 0}

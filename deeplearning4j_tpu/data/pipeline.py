@@ -2,8 +2,8 @@
 
 The compiled train step (`jax.jit` + donation + `lax.scan`) leaves three
 host-side stalls in the steady-state loop, and this module removes all
-three (PERF_ANALYSIS r5: once the step is compiled, the remaining wins are
-overlapping data movement with compute and eliminating host round-trips):
+three (once the step is compiled, the remaining wins are overlapping data
+movement with compute and eliminating host round-trips):
 
 1. **Device prefetch** — :class:`DevicePrefetchIterator` double/triple-
    buffers batches onto device with `jax.device_put` *ahead* of compute
@@ -21,9 +21,8 @@ overlapping data movement with compute and eliminating host round-trips):
    *on device* (`jnp.stack` over pre-staged per-batch arrays) instead of
    the old per-block host `np.stack` copy.
 
-Everything here is backend-agnostic: on CPU the same code path runs (and
-is what `bench.py --pipeline` measures); on TPU `device_put` overlaps the
-H2D DMA with the previous step's compute.
+Everything here is backend-agnostic: on CPU the same code path runs; on
+TPU `device_put` overlaps the H2D DMA with the previous step's compute.
 """
 from __future__ import annotations
 

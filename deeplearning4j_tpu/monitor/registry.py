@@ -8,8 +8,7 @@ serving-side SLO hub); this module is the one place a process answers
 1. **Near-zero cost when idle.**  Recording is one module-flag load, one
    lock acquire and one int/float op.  `set_enabled(False)` turns every
    record call into the flag load alone, so instrumented hot paths cost
-   nothing measurable when telemetry is off (`bench.py --obs` pins the
-   enabled-path overhead under 2% too).
+   no more than that check when telemetry is off.
 2. **Thread-safe.**  Training, the prefetch producer, the serving batcher
    worker and the UI server all record concurrently; every metric guards
    its state with its own lock (no global lock on the record path).
@@ -43,7 +42,7 @@ _ENABLED = True
 def set_enabled(on: bool) -> None:
     """Process-wide telemetry switch.  Off: every Counter.inc / Gauge.set /
     Histogram.observe returns after a single flag check (spans also skip
-    their TraceAnnotation).  The A/B lever for `bench.py --obs`."""
+    their TraceAnnotation)."""
     global _ENABLED
     _ENABLED = bool(on)
 

@@ -1,7 +1,7 @@
 """Pallas conv-backward-filter (wgrad) prototype.
 
-VERDICT r3 #3: ResNet-50's conv backward is 45% of step time at ~40% MXU
-(bench_artifacts/PERF_ANALYSIS.md); the prescribed experiment is a Pallas
+ResNet-50's conv backward is over half of its train step (`PERF_LEDGER.jsonl`,
+`conv_backward_ms_per_step`); the prescribed experiment is a Pallas
 wgrad (or dgrad) kernel for the 3x3 stride-1 SAME shapes, A/B'd against
 XLA's lowering ON CHIP — a measured win adopts it, a measured loss gets a
 committed negative-result table (measured on v5e, 2026-07-31: wgrad

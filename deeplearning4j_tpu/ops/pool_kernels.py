@@ -1,8 +1,8 @@
 """Taps-based max-pool backward — the select-and-scatter replacement.
 
 XLA lowers max-pool's gradient to `select-and-scatter`, a serial
-window-walk that costs 0.88 ms/step in the ResNet-50 profile
-(bench_artifacts/PERF_ANALYSIS.md r5) — the same per-window scan shape
+window-walk, one of the ten longest device ops of ResNet-50's step
+(`PERF_LEDGER.jsonl`: `resnet50_train_b256`) — the same per-window scan shape
 the reference delegates to cuDNN's `PoolingBackward`
 (`deeplearning4j-cuda/.../CudnnSubsamplingHelper.java` role).
 

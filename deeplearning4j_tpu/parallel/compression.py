@@ -136,9 +136,9 @@ def allreduce_compressed(exchange: CompressedGradientExchange,
 
 def allreduce_dense(transport, grads):
     """Sum a gradient pytree across ranks shipping FULL-PRECISION f32
-    leaves — the uncompressed baseline the `bench.py --comms` A/B measures
-    the threshold path against.  Same star all-gather, no codec, no
-    residuals; bytes on wire scale with the dense parameter count."""
+    leaves — the uncompressed baseline the threshold path is compared
+    against.  Same star all-gather, no codec, no residuals; bytes on wire
+    scale with the dense parameter count."""
     from deeplearning4j_tpu.parallel.transport import (pack_dense,
                                                        unpack_dense)
     leaves, treedef = jax.tree_util.tree_flatten(grads)

@@ -74,9 +74,9 @@ class HierarchicalGradientSharing:
     multihost launchers already export (resolved at `resolve()` time, not
     import time), so a worker script just passes the config through.
     `compressed=False` selects the dense f32 wire path — same topology,
-    no codec — which is the bench's A/B baseline.  `combine="mean"`
-    divides the cross-host sum by `world`, matching the global-mean
-    gradient a single SPMD mesh over all devices would produce;
+    no codec — the baseline the compressed path is compared against.
+    `combine="mean"` divides the cross-host sum by `world`, matching the
+    global-mean gradient a single SPMD mesh over all devices would produce;
     `combine="sum"` keeps the reference accumulator's raw-sum semantics.
     """
 
@@ -328,7 +328,8 @@ class HierarchicalAllReduce:
                                         cause=cause)
 
     def stats(self) -> dict:
-        """Last-exchange numbers (what BENCH_comms.json aggregates)."""
+        """Last-exchange wire bytes and compression ratio, the running byte
+        totals, and the gang's generation when elastic."""
         mesh = self._mesh
         out = {
             "rank": self.rank,

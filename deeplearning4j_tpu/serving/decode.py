@@ -271,7 +271,7 @@ class PagedKVCache:
 
 
 # ---------------------------------------------------------------------------
-# A minimal decode model (tests / bench / examples)
+# A minimal decode model (tests / examples)
 # ---------------------------------------------------------------------------
 
 
@@ -398,7 +398,7 @@ class DecodeEngine:
     for prefill, batch-row pow2 buckets for decode, a fixed pool shape
     for KV), so after `warmup()` a shape-skewed flood triggers zero fresh
     XLA compiles — verified via the jit caches themselves
-    (`fresh_compiles()`), gated by `bench.py --decode`."""
+    (`fresh_compiles()`)."""
 
     _ids = itertools.count()
 
@@ -477,7 +477,7 @@ class DecodeEngine:
 
     def fresh_compiles(self) -> int:
         """Traced-program count across the engine's jit caches — the
-        ground truth the zero-recompile bench gate reads (shape-key
+        ground truth a zero-recompile check reads (shape-key
         accounting can lie; the jit cache cannot)."""
         total = 0
         for f in (self._prefill_jit, self._qkv_jit, self._attn_jit):

@@ -35,7 +35,7 @@ readmit(slice)``):
 
 * :class:`LocalElasticGang` — in-process reference implementation over a
   model + :class:`~deeplearning4j_tpu.train.resilience.CheckpointManager`
-  (what the bench and the example drive); shrink/readmit exercise the
+  (what the tests and the example drive); shrink/readmit exercise the
   real blocking-save + pinned-restore path, so the bitwise gate is
   load-bearing, not assumed.
 * :class:`GangControlClient` — file-protocol client for a REAL elastic
@@ -153,7 +153,7 @@ class LocalElasticGang:
     BLOCKING checkpoint first, then drops the slice and restores the
     model pinned to that coordinated step — the same save-then-rewind
     ordering the real gang's coordinator performs, through the real
-    :class:`CheckpointManager`, so a bench comparing post-handoff
+    :class:`CheckpointManager`, so a test comparing post-handoff
     training against an uninterrupted run is checking actual restore
     bitwise-ness, not a stub.  `readmit` is the epoch-boundary grow:
     blocking save, add the slice at a bumped generation, restore from

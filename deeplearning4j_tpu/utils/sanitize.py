@@ -12,7 +12,7 @@ about *which* model/leaf was hit or why.  These helpers give the named,
 early error the reference's workspace validation gave.
 
 Used by transfer learning and early stopping (the two donation-aliasing
-bug sites fixed in round 2, ADVICE.md r1) and available as a public guard.
+bug sites fixed in round 2) and available as a public guard.
 """
 from __future__ import annotations
 
@@ -68,7 +68,7 @@ def assert_disjoint(tree_a: Any, tree_b: Any,
     Donation makes silent sharing fatal: when one network's step donates a
     buffer the other network still references, the second network dies on
     its next use.  Transfer learning / model-saver code paths must deep-copy
-    (the ADVICE.md round-1 bug class); this guard catches regressions.
+    (the round-1 bug class); this guard catches regressions.
     """
     ids_a = _buffer_ids(tree_a)
     shared = [(pa, ids_a[ptr]) for ptr, pa in _buffer_ids(tree_b).items()

@@ -7,7 +7,7 @@ model pays the same multi-second `jit` stall before its first step.  The
 1. `PersistentExecutableCache` — serialized compiled executables on disk,
    keyed by (jax/backend version, topology, model program, arg shapes).
    A restarted process deserializes instead of recompiling: same math,
-   ~10x faster to first step (`bench.py --aot`).
+   no compile before the first step.
 2. `ScheduleAutotuner` — measures steps/sec over a small config space
    (fused_steps, prefetch depth, donation, ZeRO-1) and persists the
    winning `Schedule`; later runs `load_schedule()` and start tuned.

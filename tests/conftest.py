@@ -3,7 +3,7 @@
 Mirrors the reference's "multi-node without a cluster" strategy (SURVEY.md
 §4: Aeron-on-loopback / Spark local[*]) — sharding/collective tests execute
 on `xla_force_host_platform_device_count=8` CPU devices; real-TPU paths are
-exercised by bench.py / the driver.
+exercised by `chip_smoke.py` and `benchmark/run.py`.
 """
 import os
 
@@ -12,8 +12,12 @@ import os
 os.environ["JAX_PLATFORMS"] = "cpu"
 xla_flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in xla_flags:
-    os.environ["XLA_FLAGS"] = (
-        xla_flags + " --xla_force_host_platform_device_count=8").strip()
+    xla_flags += " --xla_force_host_platform_device_count=8"
+# The tests' programs are tiny, so compiling them costs more than running
+# them: LLVM's optimisation passes are skipped (IEEE semantics are kept).
+if "xla_backend_optimization_level" not in xla_flags:
+    xla_flags += " --xla_backend_optimization_level=0"
+os.environ["XLA_FLAGS"] = xla_flags.strip()
 os.environ.setdefault("JAX_ENABLE_X64", "1")  # gradient checks need f64
 
 import jax  # noqa: E402

@@ -1,5 +1,5 @@
 """Hierarchical gradient-sharing TRAINING worker (spawned by test_comms
-and `bench.py --comms` via LocalLauncher — NOT a pytest file).
+via LocalLauncher — NOT a pytest file).
 
 Each rank builds the SAME small MLP, enables hierarchical gradient
 sharing (config resolved from the launcher's `DL4J_TPU_*` env), and

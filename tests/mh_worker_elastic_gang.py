@@ -1,6 +1,5 @@
-"""Elastic-gang training worker (spawned by test_elastic and
-`bench.py --elastic` via ElasticLocalRunner.run_elastic — NOT a pytest
-file).
+"""Elastic-gang training worker (spawned by test_elastic via
+ElasticLocalRunner.run_elastic — NOT a pytest file).
 
 Each process trains the SAME seeded MLN under `ElasticTrainer` with
 `HierarchicalGradientSharing(elastic=True)` (heartbeat / deadline / join

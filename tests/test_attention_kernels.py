@@ -59,8 +59,8 @@ def test_blockwise_gradients_match_reference():
         return jnp.sum(blockwise_attention(q_, k_, v_, None, True, None,
                                            32) ** 2)
 
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    gb = jax.grad(loss_blk, argnums=(0, 1, 2))(q, k, v)
+    gr = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
+    gb = jax.jit(jax.grad(loss_blk, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(gr, gb):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-4)
@@ -137,10 +137,10 @@ def test_ring_attention_differentiable():
             out_specs=P(None, None, "seq", None))
         return jnp.sum(f(q_, k_, v_) ** 2)
 
-    ref_grads = jax.grad(
+    ref_grads = jax.jit(jax.grad(
         lambda q_, k_, v_: jnp.sum(mha_reference(q_, k_, v_) ** 2),
-        argnums=(0, 1, 2))(q, k, v)
-    grads = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+        argnums=(0, 1, 2)))(q, k, v)
+    grads = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(grads, ref_grads):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-4)
@@ -163,7 +163,7 @@ def test_flash_bwd_kernel_interpret_matches_reference():
         def loss(q_, k_, v_):
             return jnp.sum(mha_reference(q_, k_, v_, causal=causal) * g)
 
-        rdq, rdk, rdv = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+        rdq, rdk, rdv = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
         for a, b in ((dq, rdq), (dk, rdk), (dv, rdv)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=2e-4, atol=2e-4)
@@ -203,7 +203,7 @@ def test_flash_bwd_kernel_interpret_masked_matches_reference():
     def loss(q_, k_, v_):
         return jnp.sum(mha_reference(q_, k_, v_, mask=mask) * g)
 
-    rdq, rdk, rdv = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    rdq, rdk, rdv = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
     for a, b in ((dq, rdq), (dk, rdk), (dv, rdv)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-4, atol=2e-4)
@@ -364,9 +364,9 @@ def _bwd_case(T, S, D, Dv, masked, seed=11):
 
 
 def _reference_grads(q, k, v, g, mask, causal):
-    return jax.grad(lambda q, k, v: jnp.sum(
+    return jax.jit(jax.grad(lambda q, k, v: jnp.sum(
         mha_reference(q, k, v, mask=mask, causal=causal) * g),
-        (0, 1, 2))(q, k, v)
+        (0, 1, 2)))(q, k, v)
 
 
 def _kernel_grads(q, k, v, g, mask, causal, bq, bk):
@@ -450,9 +450,10 @@ def test_gradient_through_the_padded_ragged_tail(causal, masked):
     from deeplearning4j_tpu.ops import pallas as tier
     q, k, v, g, mask = _bwd_case(200, 200, 192, 128, masked)
     tile = tier.TileConfig(block_q=64, block_kv=128)
-    got = jax.grad(lambda q, k, v: jnp.sum(tier.attention.flash_attention(
-        q, k, v, mask=mask, causal=causal, tile=tile, interpret=True) * g),
-        (0, 1, 2))(q, k, v)
+    got = jax.jit(jax.grad(lambda q, k, v: jnp.sum(
+        tier.attention.flash_attention(q, k, v, mask=mask, causal=causal,
+                                       tile=tile, interpret=True) * g),
+        (0, 1, 2)))(q, k, v)
     for a, b in zip(got, _reference_grads(q, k, v, g, mask, causal)):
         assert a.shape == b.shape
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -651,8 +652,8 @@ def test_kernels_on_the_schedule_match_reference(case, blocks, monkeypatch):
     np.testing.assert_allclose(
         np.asarray(lse).reshape(1, H, T),
         np.asarray(_lse_under(keep, q, k, 32 ** -0.5)), rtol=2e-5, atol=2e-5)
-    want = jax.grad(lambda q, k, v: jnp.sum(ref(q, k, v) * g),
-                    (0, 1, 2))(q, k, v)
+    want = jax.jit(jax.grad(lambda q, k, v: jnp.sum(ref(q, k, v) * g),
+                            (0, 1, 2)))(q, k, v)
     for a, b in zip(got, want):
         assert a.shape == b.shape and a.dtype == b.dtype
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -693,8 +694,8 @@ def test_pallas_layer_norm_gradients_match():
     def loss_r(x_, g_, b_):
         return jnp.mean((layer_norm_reference(x_, g_, b_) - t) ** 2)
 
-    gk = jax.grad(loss_k, argnums=(0, 1, 2))(x, g, b)
-    gr = jax.grad(loss_r, argnums=(0, 1, 2))(x, g, b)
+    gk = jax.jit(jax.grad(loss_k, argnums=(0, 1, 2)))(x, g, b)
+    gr = jax.jit(jax.grad(loss_r, argnums=(0, 1, 2)))(x, g, b)
     for a, bb in zip(gk, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(bb),
                                    rtol=1e-4, atol=1e-5)
@@ -777,11 +778,11 @@ def test_ring_attention_masked_differentiable():
             out_specs=P(None, None, "seq", None))
         return jnp.sum(f(q_, k_, v_, mask) ** 2)
 
-    ref_grads = jax.grad(
+    ref_grads = jax.jit(jax.grad(
         lambda q_, k_, v_: jnp.sum(mha_reference(q_, k_, v_,
                                                  mask=mask) ** 2),
-        argnums=(0, 1, 2))(q, k, v)
-    grads = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+        argnums=(0, 1, 2)))(q, k, v)
+    grads = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(grads, ref_grads):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-4)
@@ -831,9 +832,9 @@ def test_ring_attention_flash_inner_matches_full():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 
-    g = jax.grad(lambda q_: jnp.sum(f(q_, k, v) ** 2))(q)
-    g_ref = jax.grad(
-        lambda q_: jnp.sum(mha_reference(q_, k, v) ** 2))(q)
+    g = jax.jit(jax.grad(lambda q_: jnp.sum(f(q_, k, v) ** 2)))(q)
+    g_ref = jax.jit(jax.grad(
+        lambda q_: jnp.sum(mha_reference(q_, k, v) ** 2)))(q)
     np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref),
                                rtol=5e-4, atol=5e-5)
 
@@ -860,8 +861,8 @@ def test_ring_attention_flash_causal_matches_full():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 
-    g = jax.grad(lambda v_: jnp.sum(f(q, k, v_) ** 2))(v)
-    g_ref = jax.grad(
-        lambda v_: jnp.sum(mha_reference(q, k, v_, causal=True) ** 2))(v)
+    g = jax.jit(jax.grad(lambda v_: jnp.sum(f(q, k, v_) ** 2)))(v)
+    g_ref = jax.jit(jax.grad(
+        lambda v_: jnp.sum(mha_reference(q, k, v_, causal=True) ** 2)))(v)
     np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref),
                                rtol=5e-4, atol=5e-5)

@@ -1,5 +1,5 @@
-"""`chip_smoke.py` and `bench.py` off the chip: they say which platform they
-found and exit non-zero — no CPU branch, no result line.  (What they do on
+"""`chip_smoke.py` off the chip: it says which platform it found and
+exits non-zero — no CPU branch, no result line.  (What it does on
 the chip is the driver's chip check; see README "Running".)"""
 import os
 import subprocess
@@ -10,7 +10,7 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-@pytest.mark.parametrize("script", ["chip_smoke.py", "bench.py"])
+@pytest.mark.parametrize("script", ["chip_smoke.py"])
 def test_needs_the_chip_and_names_what_it_found(script):
     p = subprocess.run([sys.executable, os.path.join(REPO, script)],
                        env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd=REPO,

@@ -199,7 +199,7 @@ class TestPagedAttentionKernel:
         np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
 
     def test_int8_parity_within_one_percent_of_f32(self):
-        # the bench gate's parity criterion, pinned as a unit test
+        # int8 KV attention within 1% of the f32 pages
         q, k8, v8, bt, sl, ks, vs, kf, vf = _random_paged(dtype="int8",
                                                           seed=5)
         f32 = np.asarray(pa.paged_attention_reference(q, kf, vf, bt, sl))

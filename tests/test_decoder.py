@@ -925,8 +925,8 @@ def test_latent_attention_gradients_are_what_they_were(wrt):
     ct = jax.random.normal(jax.random.PRNGKey(12), x.shape)
 
     def grads(layer):
-        return jax.grad(lambda x, lp: jnp.sum(layer(x, lp) * ct),
-                        argnums=(0, 1))(x, lp)
+        return jax.jit(jax.grad(lambda x, lp: jnp.sum(layer(x, lp) * ct),
+                                argnums=(0, 1)))(x, lp)
 
     (gx, glp), (gx0, glp0) = grads(m._attention), grads(
         lambda x, lp: _attention_before(m, x, lp))

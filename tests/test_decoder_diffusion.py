@@ -437,8 +437,9 @@ def _shares_of_a_sparse_attention_layer():
                          "indexer_head_dim": c.index_head_dim,
                          "indexer_num_kv_heads": 1, "topk": c.index_topk},
            "num_experts_per_tok": c.top_k, "first_expert_held": 0}
-    want, _, want_kl = family.reference_block(cfg, x, lp)
-    alike, counted = whole._sparse_attention(x, lp)
+    want, _, want_kl = jax.jit(
+        lambda x, lp: family.reference_block(cfg, x, lp))(x, lp)
+    alike, counted = jax.jit(whole._sparse_attention)(x, lp)
     np.testing.assert_allclose(counted["index_kl"], want_kl, rtol=1e-5)
     assert float(counted["selected_keys"]) == 2 * 904   # sum_n min(n, 16)
     u = rms_norm(alike, lp["norm2"], c.eps).reshape(-1, c.hidden)

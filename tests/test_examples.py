@@ -28,7 +28,10 @@ def test_every_example_is_covered():
      else s for s in SCRIPTS])
 def test_example_runs(script):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("XLA_FLAGS", None)     # examples choose their own mesh size
+    # examples choose their own mesh size; the compile flags stay
+    env["XLA_FLAGS"] = " ".join(
+        f for f in env.get("XLA_FLAGS", "").split()
+        if "xla_force_host_platform_device_count" not in f)
     proc = subprocess.run(
         [sys.executable, os.path.join(EXAMPLES_DIR, script)],
         capture_output=True, text=True, timeout=300, env=env,

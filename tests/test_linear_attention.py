@@ -70,8 +70,8 @@ def test_chunks_against_the_token_recurrence(mode, case, start):
     q, k, v, g, beta, s0 = _inputs(0, **CASES[case])
     s0 = None if start == "zero" else s0
     chunked = lambda *a: la.chunk_delta_rule(*a, chunk=32)  # noqa: E731
-    o, s = chunked(q, k, v, g, beta, s0)
-    o_ref, s_ref = la.delta_rule_recurrent(q, k, v, g, beta, s0)
+    o, s = jax.jit(chunked)(q, k, v, g, beta, s0)
+    o_ref, s_ref = jax.jit(la.delta_rule_recurrent)(q, k, v, g, beta, s0)
     assert _rel(o, o_ref) < LIMIT and _rel(s, s_ref) < LIMIT
 
     def loss(fn):
@@ -82,8 +82,8 @@ def test_chunks_against_the_token_recurrence(mode, case, start):
 
     args = (q, k, v, g, beta) + (() if s0 is None else (s0,))
     which = tuple(range(len(args)))
-    got = jax.grad(loss(chunked), which)(*args)
-    want = jax.grad(loss(la.delta_rule_recurrent), which)(*args)
+    got = jax.jit(jax.grad(loss(chunked), which))(*args)
+    want = jax.jit(jax.grad(loss(la.delta_rule_recurrent), which))(*args)
     for name, a, b in zip("q k v g beta s0".split(), got, want):
         assert _rel(a, b) < LIMIT, name
 
@@ -93,20 +93,21 @@ def test_a_strong_decay_forgets_and_never_overflows():
     output is the token's own write, and nothing in the chunk form is inf
     or nan (no exp(-cumsum g) is formed)."""
     q, k, v, g, beta, _ = _inputs(1, A=16.0, dt_shift=6.0)
-    o, s = la.chunk_delta_rule(q, k, v, g, beta, chunk=32)
+    o, s = jax.jit(lambda *a: la.chunk_delta_rule(*a, chunk=32))(
+        q, k, v, g, beta)
     assert bool(jnp.all(jnp.isfinite(o))) and bool(jnp.all(jnp.isfinite(s)))
     own = (beta[..., None] * jnp.einsum("bhtk,bhtk->bht", q, k)[..., None]
            * v) * q.shape[-1] ** -0.5
     np.testing.assert_allclose(o, own, atol=1e-6)
-    grads = jax.grad(lambda g: jnp.sum(la.chunk_delta_rule(
-        q, k, v, g, beta, chunk=32)[0]))(g)
+    grads = jax.jit(jax.grad(lambda g: jnp.sum(la.chunk_delta_rule(
+        q, k, v, g, beta, chunk=32)[0])))(g)
     assert bool(jnp.all(jnp.isfinite(grads)))
 
 
 def test_chunk_length_changes_no_number():
     q, k, v, g, beta, s0 = _inputs(2, T=130, dk=16, dv=16)
-    outs = [la.chunk_delta_rule(q, k, v, g, beta, s0, chunk=c)
-            for c in (16, 64, 128)]
+    outs = [jax.jit(lambda *a, c=c: la.chunk_delta_rule(*a, chunk=c))(
+        q, k, v, g, beta, s0) for c in (16, 64, 128)]
     for o, s in outs[1:]:
         assert _rel(o, outs[0][0]) < LIMIT and _rel(s, outs[0][1]) < LIMIT
 
@@ -172,16 +173,19 @@ def test_the_inside_kernels_against_their_definition(case, chunk):
     q, k, v, g, beta, _ = _inputs(5, **CASES[case])
     ops = [_by_chunks(a, chunk) for a in (q, k, v, g, beta)]
     scale = q.shape[-1] ** -0.5
-    want = la._within_chunks(*ops, scale)
-    got = kernels.within_chunks(*ops, scale, interpret=True)
+    want = jax.jit(lambda *a: la._within_chunks(*a, scale))(*ops)
+    got = jax.jit(lambda *a: kernels.within_chunks(
+        *a, scale, interpret=True))(*ops)
     for name, a, b in zip("w u qg kd gc aqk".split(), got, want):
         assert a.shape == b.shape and _within(a, b), name
     r = np.random.default_rng(6)
     cts = tuple(jnp.asarray(r.normal(size=a.shape), jnp.float32)
                 for a in want)
-    _, vjp = jax.vjp(lambda *a: la._within_chunks(*a, scale), *ops)
-    got = kernels.within_chunks_bwd(*ops, *cts, scale, interpret=True)
-    for name, a, b in zip("q k v g beta".split(), got, vjp(cts)):
+    vjp = jax.jit(lambda ops, cts: jax.vjp(
+        lambda *a: la._within_chunks(*a, scale), *ops)[1](cts))
+    got = jax.jit(lambda *a: kernels.within_chunks_bwd(
+        *a, scale, interpret=True))(*ops, *cts)
+    for name, a, b in zip("q k v g beta".split(), got, vjp(ops, cts)):
         assert a.shape == b.shape and _within(a, b), name
 
 

@@ -700,24 +700,85 @@ def test_keras_conv2d_transpose_exact(tmp_path):
                                rtol=1e-4, atol=1e-5)
 
 
-def test_tf_import_full_depth_bert():
-    """Full-DEPTH import conformance (VERDICT r4 #3/weak#5): the exact
-    12-layer BERT-shaped GraphDef that bench.py times is value-asserted
-    against TF here, then fine-tuned — the deepest import path in the
-    repo is numerically checked, not just perf-timed.  Width is trimmed
-    (H=128, vocab=2000) to stay CPU-affordable; depth and op diet are the
-    bench's (reference: TFGraphTestAllSameDiff full-model conformance)."""
-    import sys
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    from bench import build_tf_bert_frozen
+def _build_tf_bert_frozen(batch, t, layers, hidden, heads, vocab):
+    """A BERT-shaped encoder of `layers` blocks from raw TF ops (embedding
+    gather, per-head attention, erf-GELU MLP, post-LN), frozen.  Returns
+    (graph_def, frozen_concrete_fn, name of the encoder's output node)."""
+    from tensorflow.python.framework.convert_to_constants import (
+        convert_variables_to_constants_v2)
 
+    rs = np.random.RandomState(0)
+    H, NH, L, T, B = hidden, heads, layers, t, batch
+    p = {"tok_emb": tf.constant(rs.randn(vocab, H).astype(np.float32)
+                                * 0.02),
+         "pos_emb": tf.constant(rs.randn(T, H).astype(np.float32) * 0.02)}
+    for l in range(L):
+        for w in ["wq", "wk", "wv", "wo"]:
+            p[f"{l}.{w}"] = tf.constant(
+                rs.randn(H, H).astype(np.float32) * 0.02)
+        p[f"{l}.w1"] = tf.constant(rs.randn(H, 4 * H).astype(np.float32)
+                                   * 0.02)
+        p[f"{l}.w2"] = tf.constant(rs.randn(4 * H, H).astype(np.float32)
+                                   * 0.02)
+        p[f"{l}.g1"] = tf.constant(np.ones(H, np.float32))
+        p[f"{l}.b1"] = tf.constant(np.zeros(H, np.float32))
+        p[f"{l}.g2"] = tf.constant(np.ones(H, np.float32))
+        p[f"{l}.b2"] = tf.constant(np.zeros(H, np.float32))
+
+    def ln(x, g, b):
+        mean = tf.reduce_mean(x, axis=-1, keepdims=True)
+        var = tf.reduce_mean(tf.math.squared_difference(x, mean), axis=-1,
+                             keepdims=True)
+        return (x - mean) * tf.math.rsqrt(var + 1e-6) * g + b
+
+    def gelu(x):
+        return 0.5 * x * (1.0 + tf.math.erf(
+            x / np.sqrt(2.0).astype(np.float32)))
+
+    def f(ids):
+        x = tf.gather(p["tok_emb"], ids, axis=0) + p["pos_emb"]
+        for l in range(L):
+            def heads_of(w):
+                y = tf.matmul(tf.reshape(x, [B * T, H]), w)
+                return tf.transpose(tf.reshape(y, [B, T, NH, H // NH]),
+                                    [0, 2, 1, 3])
+            q, k, v = (heads_of(p[f"{l}.wq"]), heads_of(p[f"{l}.wk"]),
+                       heads_of(p[f"{l}.wv"]))
+            s = tf.matmul(q, k, adjoint_b=True) / np.float32(
+                np.sqrt(H // NH))
+            ctx = tf.matmul(tf.nn.softmax(s, axis=-1), v)
+            ctx = tf.reshape(tf.transpose(ctx, [0, 2, 1, 3]), [B, T, H])
+            a = tf.matmul(tf.reshape(ctx, [B * T, H]), p[f"{l}.wo"])
+            x = ln(x + tf.reshape(a, [B, T, H]), p[f"{l}.g1"],
+                   p[f"{l}.b1"])
+            h = gelu(tf.matmul(tf.reshape(x, [B * T, H]), p[f"{l}.w1"]))
+            h = tf.matmul(h, p[f"{l}.w2"])
+            x = ln(x + tf.reshape(h, [B, T, H]), p[f"{l}.g2"],
+                   p[f"{l}.b2"])
+        return x
+
+    frozen = convert_variables_to_constants_v2(
+        tf.function(f).get_concrete_function(
+            tf.TensorSpec((B, T), tf.int32)))
+    gd = frozen.graph.as_graph_def()
+    # the frozen fn's structured output tensor names the true graph output
+    enc = frozen.outputs[0].name.split(":")[0]
+    return gd, frozen, enc
+
+
+def test_tf_import_full_depth_bert():
+    """Full-DEPTH import conformance (VERDICT r4 #3/weak#5): a 12-layer
+    BERT-shaped GraphDef is value-asserted against TF here, then
+    fine-tuned — the deepest import path in the repo is numerically
+    checked.  Width is trimmed (H=128, vocab=2000) to stay CPU-affordable;
+    depth and op diet are BERT-base's (reference: TFGraphTestAllSameDiff
+    full-model conformance)."""
     from deeplearning4j_tpu.autodiff import TrainingConfig
     from deeplearning4j_tpu.train.updaters import Adam as SDAdam
 
     B, T, L, H, NH, V = 2, 32, 12, 128, 4, 2000
-    gd, frozen, enc = build_tf_bert_frozen(batch=B, t=T, layers=L,
-                                           hidden=H, heads=NH, vocab=V)
+    gd, frozen, enc = _build_tf_bert_frozen(batch=B, t=T, layers=L,
+                                            hidden=H, heads=NH, vocab=V)
     n_layers = len([n for n in gd.node
                     if n.op == "Softmax"])
     assert n_layers == L, f"graph has {n_layers} attention softmaxes"

@@ -31,11 +31,12 @@ def test_pipeline_forward_matches_sequential():
     x = jnp.asarray(np.random.RandomState(1).randn(B, D)
                     .astype(np.float32))
     want = sequential_apply(_block, params, x)
-    got = pipeline_apply(_block, params, x, mesh)
+    got = jax.jit(lambda p, x: pipeline_apply(_block, p, x, mesh))(params, x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-6)
     # more microbatches than stages also works
-    got8 = pipeline_apply(_block, params, x, mesh, num_microbatches=8)
+    got8 = jax.jit(lambda p, x: pipeline_apply(
+        _block, p, x, mesh, num_microbatches=8))(params, x)
     np.testing.assert_allclose(np.asarray(got8), np.asarray(want),
                                rtol=1e-5, atol=1e-6)
 
@@ -59,8 +60,8 @@ def test_pipeline_gradients_match_sequential():
         out = sequential_apply(_block, p, x)
         return jnp.mean((out - y) ** 2)
 
-    g_pipe = jax.grad(loss_pipe)(params)
-    g_seq = jax.grad(loss_seq)(params)
+    g_pipe = jax.jit(jax.grad(loss_pipe))(params)
+    g_seq = jax.jit(jax.grad(loss_seq))(params)
     jax.tree_util.tree_map(
         lambda a, b: np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-6),

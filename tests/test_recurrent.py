@@ -134,6 +134,7 @@ def test_lstm_gradient_check():
         LSTM(n_out=5, activation="tanh"),
         RnnOutputLayer(n_out=2, loss="mcxent", activation="softmax"),
     ], InputType.recurrent(3, 4))
+    @jax.jit
     def score(params):
         return net._loss(params, net.state_, jnp.asarray(x, jnp.float64),
                          jnp.asarray(y, jnp.float64), None)[0]

@@ -82,7 +82,7 @@ def test_labels_mask_threaded_from_dataset():
     assert np.isfinite(net.score())
 
 
-# ---- round 2: ADVICE.md findings ----
+# ---- round 2: review findings ----
 
 def _tiny_net(seed=0):
     conf = (NeuralNetConfiguration.builder().seed(seed).updater(Adam(1e-2))
