@@ -3,7 +3,10 @@
 The layer of DeepSeek-V3 (arXiv:2412.19437, §2.1.2) and its descendants:
 a sigmoid router over all `E` routed experts with a selection bias that is
 no parameter, top-k, normalised and scaled weights, SwiGLU experts, and
-shared experts that see every token.
+shared experts that see every token.  Nemotron-H's experts, routed and
+shared, are not gated: `relu(x W_up)^2 W_down` (`relu2_mlp`), two grouped
+products a pass where SwiGLU's are three; a layer whose parameters have no
+`w_gate` (no `shared_gate`) has them.
 
     s = sigmoid(f32(x) W_g)                      [T, E]
     chosen = the k largest of s + b              (b picks, it does not weigh)
@@ -264,7 +267,7 @@ def _routed(x, w, w_gate, w_up, w_down, route, lo, rows: int, lowering):
     """The terms of the held pairs in the sorted rows `lo .. lo + rows`,
     summed into their tokens: the whole algorithm, on whichever rows.  `w`
     [T, k]: the pairs' weights in `x`'s dtype, zero where the expert is not
-    held.
+    held.  `w_gate` None: the experts are `relu2_mlp`'s.
 
     Under `jit`, so that a step traces and lowers it once for its forward
     rule, the blocks' recomputation and every expert block of its shapes
@@ -277,9 +280,13 @@ def _routed(x, w, w_gate, w_up, w_down, route, lo, rows: int, lowering):
         xs = _rows_of_tokens(x, ix, k)
     with jax.named_scope("experts"):
         sizes = ix["group_sizes"]
-        gate = _grouped(xs, w_gate, sizes)
-        up = _grouped(xs, w_up, sizes)
-        ys = _grouped(jax.nn.silu(gate) * up, w_down, sizes)
+        if w_gate is None:
+            hidden = jnp.square(jax.nn.relu(_grouped(xs, w_up, sizes)))
+        else:
+            gate = _grouped(xs, w_gate, sizes)
+            up = _grouped(xs, w_up, sizes)
+            hidden = jax.nn.silu(gate) * up
+        ys = _grouped(hidden, w_down, sizes)
     with jax.named_scope("combine"):
         # (the rows past the last pair name no pair: they read a zero)
         w_row = w.reshape(-1).at[ix["pair"]].get(
@@ -339,7 +346,8 @@ def _passes_bwd(rows, res, g):
                        *operands)[1](g)
 
     return (_in_passes(gradient, route, rows,
-                       tuple(jnp.zeros_like(a) for a in operands)),
+                       tuple(None if a is None else jnp.zeros_like(a)
+                             for a in operands)),
             _no_gradient(route))
 
 
@@ -351,13 +359,14 @@ def routed_experts(x, chosen, weights, w_gate, w_up, w_down,
     """The held experts' part of `sum_k w_k E_chosen_k(x)`: x [T, H],
     `chosen`/`weights` [T, k] from `router` over all experts, the held
     experts' matrices `w_gate`/`w_up` [held, H, I] and `w_down`
-    [held, I, H], which are experts `first_held .. first_held + held`.
+    [held, I, H], which are experts `first_held .. first_held + held`
+    (`w_gate` None: `relu2_mlp`'s experts).
     `rows` (static): the sorted-row buffers' length; a step that sends more
     pairs to the held experts takes further passes of as many rows.  Beside
     the result, whether this step did (int32, 0 or 1)."""
     t, k = chosen.shape
     n = t * k
-    held = w_gate.shape[0]
+    held = w_up.shape[0]
     rows = min(rows, n)
     with jax.named_scope("dispatch"):
         local = chosen - first_held
@@ -392,6 +401,12 @@ def swiglu(x, w_gate, w_up, w_down):
     return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
 
 
+def relu2_mlp(x, w_up, w_down):
+    """`relu(x W_up)^2 W_down`: the squared ReLU (Primer, arXiv:2109.08668)
+    of Nemotron-H's experts, not gated."""
+    return jnp.square(jax.nn.relu(x @ w_up)) @ w_down
+
+
 def expert_layer(x, p, bias, *, top_k: int, scale: float, first_held: int,
                  eps: float = 1e-20, score: str = "sigmoid"):
     """This chip's share of the layer for tokens `x` [T, H]; the step's
@@ -406,13 +421,14 @@ def expert_layer(x, p, bias, *, top_k: int, scale: float, first_held: int,
     [held, I, H]; `shared_gate`, `shared_up` [H, S] and `shared_down`
     [S, H], the shared experts side by side as one SwiGLU of their summed
     width — or none of the three, for a layer without shared experts.
+    Without `w_gate` and `shared_gate` the experts are `relu2_mlp`'s.
     `bias` [E] is the router's selection bias, `eps` the router's.
 
     The routed part's buffers are the layer's largest arrays and its
     arithmetic the smallest: a caller short of memory recomputes the layer
     in the backward pass (`jax.checkpoint`) before anything else."""
     n_experts = p["router"].shape[1]
-    rows = row_bound(x.shape[0] * top_k, p["w_gate"].shape[0], n_experts)
+    rows = row_bound(x.shape[0] * top_k, p["w_up"].shape[0], n_experts)
     with jax.named_scope("moe"):
         with jax.named_scope("router"):
             chosen, weights, *mean_prob = router(
@@ -420,12 +436,15 @@ def expert_layer(x, p, bias, *, top_k: int, scale: float, first_held: int,
             counts = expert_counts(chosen, n_experts)
             balance = [balance_loss(counts, m, x.shape[0])
                        for m in mean_prob]
-        routed, over = routed_experts(x, chosen, weights, p["w_gate"],
+        routed, over = routed_experts(x, chosen, weights, p.get("w_gate"),
                                       p["w_up"], p["w_down"], first_held,
                                       rows)
-        if "shared_gate" not in p:
+        if "shared_up" not in p:
             return (routed, counts, over, *balance)
         with jax.named_scope("shared"):
-            shared = swiglu(x, p["shared_gate"], p["shared_up"],
-                            p["shared_down"])
+            if "shared_gate" in p:
+                shared = swiglu(x, p["shared_gate"], p["shared_up"],
+                                p["shared_down"])
+            else:
+                shared = relu2_mlp(x, p["shared_up"], p["shared_down"])
         return (routed + shared, counts, over, *balance)

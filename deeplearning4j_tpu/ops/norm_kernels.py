@@ -41,6 +41,17 @@ def rms_norm(x, gain, eps: float = 1e-6):
     return (y * gain.astype(jnp.float32)).astype(x.dtype)
 
 
+def gated_rms_norm(y, z, gain, groups: int, eps: float = 1e-5):
+    """Mamba-2's gated RMSNorm (`norm_before_gate=False`): `h = y *
+    SiLU(z)`, then each of `groups` equal groups of the last axis divided
+    by its root mean square (`eps` added), times `gain` [channels].  For
+    `y`, `z` [..., channels]; float32 throughout, returned in float32."""
+    h = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    g = h.reshape(*h.shape[:-1], groups, h.shape[-1] // groups)
+    g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + eps)
+    return g.reshape(h.shape) * gain.astype(jnp.float32)
+
+
 # -- forward kernel ---------------------------------------------------------
 
 def _ln_fwd_kernel(x_ref, g_ref, b_ref, y_ref, mean_ref, rstd_ref, *, eps):

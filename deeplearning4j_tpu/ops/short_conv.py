@@ -9,7 +9,9 @@ depthwise causal convolution over a few positions, between two products.
     c_t = sum_j k_j * z_{t-(L-1)+j}       k [L, H]; z before position 0 is 0
     y = (C * c) W_out                     W_out [H, H]
 
-No activation and no bias anywhere in it.  The in-projection is ONE product
+No activation and no bias in it (`causal_depthwise_conv` takes an optional
+bias for the convolution of the Mamba-2 mixer, `zoo/decoder.py`, which has
+one).  The in-projection is ONE product
 of width 3H.  Between the two products the mix reads B, C and X once and
 writes one [T, H] array: L shifted multiply-adds a channel, which XLA fuses
 into one elementwise pass forward and one backward — it is bound by memory
@@ -26,13 +28,15 @@ import jax
 import jax.numpy as jnp
 
 
-def causal_depthwise_conv(z, kernel):
-    """`c[..., t, :] = sum_j kernel[j] * z[..., t - (L-1) + j, :]` for `z`
-    [..., T, C] and `kernel` [L, C]: every channel its own L taps, the last
+def causal_depthwise_conv(z, kernel, bias=None):
+    """`c[..., t, :] = sum_j kernel[j] * z[..., t - (L-1) + j, :] + bias` for
+    `z` [..., T, C], `kernel` [L, C] and `bias` [C] or None (no bias, the
+    gated short convolution's case): every channel its own L taps, the last
     tap on the position itself, positions before 0 zero."""
     taps, t = kernel.shape[0], z.shape[-2]
     padded = jnp.pad(z, [(0, 0)] * (z.ndim - 2) + [(taps - 1, 0), (0, 0)])
-    return sum(kernel[j] * padded[..., j:j + t, :] for j in range(taps))
+    c = sum(kernel[j] * padded[..., j:j + t, :] for j in range(taps))
+    return c if bias is None else c + bias
 
 
 def gated_short_conv(u, w_in, kernel, w_out):

@@ -5,8 +5,9 @@ Pre-norm residual blocks `h = x + Op_l(RMSNorm(x))`, `y = h + F_l(RMSNorm(h))`
 (RMSNorm in float32, `ops/norm_kernels.rms_norm`), no position embedding,
 next-token cross-entropy under a causal mask or (`objective`) the
 block-diffusion loss below.  `DecoderConfig.layer_types` names `Op_l` layer
-by layer; five published models are the presets the tests and the
-benchmark build:
+by layer, or `layer_pattern` makes each layer ONE of the two sub-blocks
+(below); six published models are the presets the tests and the benchmark
+build:
 
 - `deepseek_v3` configs (DeepSeek-V2/V3, arXiv:2405.04434, arXiv:2412.19437;
   kanana-2-30b-a3b): `latent_attention` in every layer (the default list) —
@@ -68,6 +69,26 @@ benchmark build:
   `attn_output_gate`); a sigmoid router beside one shared expert in every
   layer; an untied head.  Counted on the device: `delta_rule_updates`
   (`linear_stats()`).
+
+- `nemotron_h` configs (Nemotron-H, arXiv:2504.03624: the tower of
+  Nemotron-Labs-TwoTower-30B-A3B that its config describes):
+  `layer_pattern` is the `hybrid_override_pattern` of the layers held, one
+  character a layer and each layer one residual sub-block, `x + Op(RMSNorm
+  x)`: `M` a `mamba2` mixer, `*` a `full_attention` mixer (here with no
+  rotary, no norm of queries and keys, no gate), `E` an expert layer alone
+  (`EXPERTS`).  The Mamba-2 mixer (arXiv:2405.21060): `[z | xBC | dt] = h
+  W_in` as three products; `xBC = SiLU(conv(xBC) + b)` (causal, depthwise,
+  `conv_kernel` taps, a bias); `x | B | C` as `ssm_heads` heads of
+  `ssm_head_dim` and `ssm_groups` groups of `ssm_state` (a head reads group
+  `head // (heads / groups)`); `dt = softplus(dt + dt_bias)`, `A =
+  -exp(A_log)` a head; the state-space dual `ops/state_space.ssd` in chunks
+  of `ssm_chunk` with a `D` skip; `RMSNorm_grouped(y * SiLU(z))` over
+  `ssm_groups` groups (`ops/norm_kernels.gated_rms_norm`); `W_out`.  Its
+  experts, routed and shared, are squared-ReLU MLPs, not gated
+  (`expert_act="relu2"`, `ops/moe.relu2_mlp`), the shared one
+  `shared_intermediate` wide; the sigmoid router with a selection bias; an
+  untied head.  The router bias and the routing counters have one row an
+  `E` layer.
 
 Between a product and a kernel (latent attention; measured in PERF.md,
 PR 36).  The kernels read [B, heads, T, d] with `d` in the lanes; a product
@@ -140,8 +161,10 @@ has no such result and always
 is.
 
 Named scopes mark each part's device ops, forward and backward:
-`mla_attention`, `gqa_attention`, `sparse_index` (the indexer's projections,
-scores, top-k and the packed selection) and `index_loss` beside it,
+`mamba_mixer` and beneath it `ssd_scan` (the chunks and the state across
+them), `mla_attention`, `gqa_attention`, `sparse_index` (the indexer's
+projections, scores, top-k and the packed selection) and `index_loss`
+beside it,
 `linear_attention` and beneath it `delta_rule` (the chunks' insides and the
 recurrence across them),
 `short_conv` (beneath it `in_proj`, `mix`,
@@ -151,11 +174,14 @@ and `diffusion_loss` (the weighted cross-entropy and the auxiliary term).
 
 Not here yet: prefill/decode through a cache (growing pages for the
 attention layers beside a fixed `conv_kernel - 1` positions of state for the
-convolutions and a [dk, dv] state a head for the delta rule, and a cache of
+convolutions, a [dk, dv] state a head for the delta rule and an [N, P] state
+a head for the SSD, and a cache of
 the indexer's keys with the selection inside the paged kernel), absorbed latent attention, the experts' exchange over
 several chips, the decode loop that denoises a block over several passes, a
 vision tower and with it position streams that differ, the indexer's dense
-warm-up stage (the main model frozen).
+warm-up stage (the main model frozen), packed documents whose edges reset the
+SSD's state, a second tower conditioned on this one (Nemotron-Labs-TwoTower's
+denoiser).
 """
 from __future__ import annotations
 
@@ -176,18 +202,25 @@ from deeplearning4j_tpu.ops.linear_attention import (chunk_delta_rule,
                                                      l2_normalize)
 from deeplearning4j_tpu.ops.moe import (expert_layer, row_bound, swiglu,
                                         update_router_bias)
-from deeplearning4j_tpu.ops.norm_kernels import layer_norm_reference, rms_norm
+from deeplearning4j_tpu.ops.norm_kernels import (gated_rms_norm,
+                                                 layer_norm_reference,
+                                                 rms_norm)
 from deeplearning4j_tpu.ops.rotary import (rotary_half_split, rotary_pairs,
                                            rotary_sections)
 from deeplearning4j_tpu.ops.short_conv import (causal_depthwise_conv,
                                                gated_short_conv)
 from deeplearning4j_tpu.ops.sparse_index import (INDEX_GRADS, SELECTION,
                                                  index_loss, sparse_index)
+from deeplearning4j_tpu.ops.state_space import ssd
 from deeplearning4j_tpu.train.updaters import AdamW, IUpdater
 
 
 LAYER_KINDS = ("latent_attention", "full_attention", "conv",
-               "sparse_attention", "linear_attention")
+               "sparse_attention", "linear_attention", "mamba2")
+# `layer_pattern`: a layer of experts alone
+EXPERTS = "moe"
+# `layer_pattern`'s characters (Nemotron-H's `hybrid_override_pattern`)
+PATTERN = {"M": "mamba2", "*": "full_attention", "E": EXPERTS}
 
 
 @dataclasses.dataclass
@@ -246,6 +279,18 @@ class DecoderConfig:
     # first + held of `n_heads` (None: all), with their key-value heads
     first_head: int = 0
     n_heads_held: Optional[int] = None
+    # one residual sub-block a layer, by `PATTERN`: "MEM*E" (None: every
+    # layer a mixer and an FFN, `layer_types`)
+    layer_pattern: Optional[str] = None
+    ssm_heads: int = 64                # `mamba2`: heads of
+    ssm_head_dim: int = 64             # this many channels,
+    ssm_groups: int = 8                # groups of B and C
+    ssm_state: int = 128               # of this width,
+    ssm_chunk: int = 128               # tokens a chunk of the SSD,
+    out_proj_init_std: Optional[float] = None   # and W_out's (None: init_std)
+    expert_act: str = "swiglu"         # or "relu2": routed and shared
+    shared_intermediate: Optional[int] = None   # a shared expert's width
+                                                # (None: expert_intermediate)
 
     @property
     def held(self) -> int:
@@ -260,10 +305,23 @@ class DecoderConfig:
 
     @property
     def kinds(self) -> Tuple[str, ...]:
-        """The token mixer of each of the `n_layers` layers."""
+        """The token mixer of each of the `n_layers` layers; under
+        `layer_pattern` the one sub-block of each, `EXPERTS` for experts."""
+        if self.layer_pattern is not None:
+            return tuple(PATTERN.get(ch, ch) for ch in self.layer_pattern)
         if self.layer_types is None:
             return ("latent_attention",) * self.n_layers
         return tuple(self.layer_types)
+
+    def routes(self, kind: str) -> bool:
+        """Whether a layer of `kind` has experts (a row of the router
+        bias): every layer after the dense ones, under `layer_pattern` the
+        `EXPERTS` layers alone."""
+        return self.layer_pattern is None or kind == EXPERTS
+
+    @property
+    def n_expert_layers(self) -> int:
+        return sum(map(self.routes, self.kinds[self.n_dense_layers:]))
 
     def layout(self):
         """`(dense kind, period, periods, rest)`: the expert layers' kinds
@@ -341,6 +399,23 @@ class DecoderConfig:
         return DecoderConfig(**d)
 
     @staticmethod
+    def tiny_mamba(**kw) -> "DecoderConfig":
+        """Test-sized Nemotron-H: the pattern `MEM*E`, one sub-block a
+        layer; Mamba-2's 4 heads of 8 over 2 groups of B and C of 16 in
+        chunks of 8, conv 4; 4 query heads over 2 key-value heads of 8 with
+        no rotary, no norm of queries and keys; 8 squared-ReLU experts top-2
+        beside one shared expert of 32, scale 2.5; untied head."""
+        d = dict(vocab_size=96, hidden=32, n_layers=5, n_dense_layers=0,
+                 layer_pattern="MEM*E", n_heads=4, n_kv_heads=2, head_dim=8,
+                 ssm_heads=4, ssm_head_dim=8, ssm_groups=2, ssm_state=16,
+                 ssm_chunk=8, conv_kernel=4, expert_intermediate=16,
+                 shared_intermediate=32, expert_act="relu2", n_experts=8,
+                 n_shared_experts=1, top_k=2, routed_scale=2.5, eps=1e-5,
+                 rope=False, qk_norm=False)
+        d.update(kw)
+        return DecoderConfig(**d)
+
+    @staticmethod
     def tiny_hybrid(**kw) -> "DecoderConfig":
         """Test-sized LFM2-MoE: a dense `conv` layer, then one period
         `full_attention, conv, conv, conv` of expert layers; 4 query heads
@@ -379,10 +454,24 @@ class DecoderModel:
                 f"experts {c.first_expert}..{c.first_expert + c.held} are "
                 f"not among the router's {c.n_experts}")
         kinds = c.kinds
-        if len(kinds) != c.n_layers or set(kinds) - set(LAYER_KINDS):
+        if c.layer_pattern is not None and (
+                c.layer_types is not None or c.n_dense_layers
+                or set(c.layer_pattern) - set(PATTERN)
+                or EXPERTS not in kinds):
+            raise ValueError(
+                f"layer_pattern {c.layer_pattern!r}: one of {sorted(PATTERN)}"
+                f" a layer, at least one E, no layer_types and no dense "
+                f"layers")
+        allowed = set(LAYER_KINDS) | ({EXPERTS} if c.layer_pattern else set())
+        if len(kinds) != c.n_layers or set(kinds) - allowed:
             raise ValueError(
                 f"layer_types names {len(kinds)} layers {sorted(set(kinds))}"
                 f"; need {c.n_layers} of {LAYER_KINDS}")
+        if c.expert_act not in ("swiglu", "relu2"):
+            raise ValueError(f"expert_act {c.expert_act!r}")
+        if "mamba2" in kinds and c.ssm_heads % c.ssm_groups:
+            raise ValueError(f"{c.ssm_heads} SSM heads are no multiple of "
+                             f"{c.ssm_groups} groups")
         if not 0 <= c.n_dense_layers < c.n_layers:
             raise ValueError("need at least one expert layer")
         if c.n_dense_layers and len(set(kinds[:c.n_dense_layers])) != 1:
@@ -396,7 +485,8 @@ class DecoderModel:
         nh, nkv = c.heads_held
         if (c.first_head, nh) != (0, c.n_heads):
             group = c.n_heads // c.n_kv_heads
-            if {"latent_attention", "sparse_attention"} & set(kinds) \
+            if {"latent_attention", "sparse_attention", "mamba2"} \
+                    & set(kinds) \
                     or not 0 <= c.first_head <= c.n_heads - nh \
                     or c.first_head % group or nh % group or nh < 1:
                 raise ValueError(
@@ -422,7 +512,7 @@ class DecoderModel:
                 "counts its loss with the expert layers': it goes with the "
                 "next_token objective, after the dense layers")
         if self._diffusion:
-            if {"conv", "linear_attention"} & set(kinds):
+            if {"conv", "linear_attention", "mamba2"} & set(kinds):
                 raise ValueError(
                     "a convolution or a recurrence would run across the "
                     "noisy and the clean copy of a sequence: block diffusion "
@@ -438,7 +528,7 @@ class DecoderModel:
         # one program each, not one a leaf: a cold start compiles two
         self.params_ = jax.jit(self._init)(jax.random.PRNGKey(seed))
         self.opt_state_ = jax.jit(self.updater.init_state)(self.params_)
-        n_moe = c.n_layers - c.n_dense_layers
+        n_moe = c.n_expert_layers
         # what the step carries beside the parameters: the routers'
         # selection bias (a buffer, no gradient) and, per expert layer since
         # the model was built, how many tokens chose each expert and in how
@@ -542,25 +632,60 @@ class DecoderModel:
                     "Wg_a": nrm(L, H, hd), "Wg_b": nrm(L, hd, W),
                     "o_norm": jnp.ones((L, hd)),
                     "Wo": nrm(L, W, H)}
+            if kind == "mamba2":
+                # Mamba-2 (Nemotron-H's init): [z | xBC | dt] side by side;
+                # the convolution's taps and bias with the variance of
+                # PyTorch's Conv1d default at a fan-in of `taps`; A = 1 ..
+                # heads; dt_bias the inverse softplus of dt = logU(1e-3,
+                # 1e-1) floored at 1e-4; D = 1
+                n, taps = c.ssm_heads, c.conv_kernel
+                I = n * c.ssm_head_dim
+                xbc = I + 2 * c.ssm_groups * c.ssm_state
+                lo, hi = np.log(1e-3), np.log(1e-1)
+                dt = jnp.maximum(jnp.exp(jax.random.uniform(
+                    next(keys), (L, n), jnp.float32, lo, hi)), 1e-4)
+                return {
+                    **norms,
+                    "in_proj": nrm(L, H, I + xbc + n),
+                    "conv_xbc": nrm(L, taps, xbc, std=(3 * taps) ** -0.5),
+                    "conv_bias": nrm(L, xbc, std=(3 * taps) ** -0.5),
+                    "A_log": jnp.broadcast_to(
+                        jnp.log(jnp.arange(1, n + 1, dtype=jnp.float32)),
+                        (L, n)),
+                    "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+                    "D": jnp.ones((L, n)),
+                    "ssm_norm": jnp.ones((L, I)),
+                    "out_proj": nrm(L, I, H, std=c.out_proj_init_std
+                                    or c.init_std)}
             return {**norms, "conv_in": nrm(L, H, 3 * H),
                     "conv_kernel": nrm(L, c.conv_kernel, H),
                     "conv_out": nrm(L, H, H)}
 
-        I, Ie, S = (c.intermediate, c.expert_intermediate,
-                    c.n_shared_experts * c.expert_intermediate)
+        I, Ie = c.intermediate, c.expert_intermediate
+        S = c.n_shared_experts * (c.shared_intermediate or Ie)
 
         def dense(kind, L):
             return {**operator(kind, L), "mlp_gate": nrm(L, H, I),
                     "mlp_up": nrm(L, H, I), "mlp_down": nrm(L, I, H)}
 
         def experts(kind, L):
-            p = {**operator(kind, L), "router": nrm(L, H, c.n_experts),
-                 "w_gate": nrm(L, c.held, H, Ie),
-                 "w_up": nrm(L, c.held, H, Ie),
-                 "w_down": nrm(L, c.held, Ie, H)}
+            gated = c.expert_act == "swiglu"
+            if c.layer_pattern is None:
+                p = {**operator(kind, L), "router": nrm(L, H, c.n_experts)}
+            elif kind != EXPERTS:       # a mixer alone
+                p = operator(kind, L)
+                del p["norm2"]
+                return p
+            else:
+                p = {"norm1": jnp.ones((L, H)),
+                     "router": nrm(L, H, c.n_experts)}
+            if gated:
+                p["w_gate"] = nrm(L, c.held, H, Ie)
+            p.update(w_up=nrm(L, c.held, H, Ie), w_down=nrm(L, c.held, Ie, H))
             if S:
-                p.update(shared_gate=nrm(L, H, S), shared_up=nrm(L, H, S),
-                         shared_down=nrm(L, S, H))
+                if gated:
+                    p["shared_gate"] = nrm(L, H, S)
+                p.update(shared_up=nrm(L, H, S), shared_down=nrm(L, S, H))
             return p
 
         dense_kind, period, n, rest = c.layout()
@@ -738,6 +863,35 @@ class DecoderModel:
             return x + y.astype(x.dtype), {
                 "delta_rule_updates": jnp.sum(beta > 0, dtype=f32)}
 
+    def _mamba2(self, x, lp, L=None):
+        """`x + Mamba2(RMSNorm(x))` for `x` [B, T, H] (module docstring):
+        the three products, the biased causal convolution and SiLU, `dt`
+        and `A`, the SSD (`ops/state_space.ssd`, whose ops carry the scope
+        `ssd_scan`), the gated norm over groups and `W_out`.  Convolution,
+        `dt`, decays, state and norm in float32."""
+        c = self.config
+        B, T, _ = x.shape
+        n, P, G, N = c.ssm_heads, c.ssm_head_dim, c.ssm_groups, c.ssm_state
+        I, f32 = n * P, jnp.float32
+        with jax.named_scope("mamba_mixer"):
+            dt = lp["out_proj"].dtype
+            h = rms_norm(x, lp["norm1"], c.eps).astype(dt)
+            w = lp["in_proj"]
+            # three products, not one sliced: no pass over a [T, 10304]
+            z = h @ w[:, :I]
+            xbc = jax.nn.silu(causal_depthwise_conv(
+                (h @ w[:, I:-n]).astype(f32), lp["conv_xbc"].astype(f32),
+                lp["conv_bias"].astype(f32)))
+            step = jax.nn.softplus((h @ w[:, -n:]).astype(f32)
+                                   + lp["dt_bias"])
+            xs, Bs, Cs = jnp.split(xbc.astype(dt), [I, I + G * N], axis=-1)
+            y = ssd(xs.reshape(B, T, n, P), step, -jnp.exp(lp["A_log"]),
+                    Bs.reshape(B, T, G, N), Cs.reshape(B, T, G, N), lp["D"],
+                    c.ssm_chunk)
+            y = gated_rms_norm(y.reshape(B, T, I), z, lp["ssm_norm"], G,
+                               c.eps)
+            return x + (y.astype(dt) @ lp["out_proj"]).astype(x.dtype)
+
     def _index(self, h, lp, pos):
         """The indexer's queries [B, n, T, d], its one key head [B, T, d]
         (LayerNorm, then rotary; queries and key half-split rotary on all
@@ -807,6 +961,7 @@ class DecoderModel:
              "full_attention": self._gqa_attention,
              "sparse_attention": self._sparse_attention,
              "linear_attention": self._linear_attention,
+             "mamba2": self._mamba2,
              "conv": self._short_conv}[kind], L=L)
 
     def _no_counts(self) -> Dict[str, Any]:
@@ -829,8 +984,9 @@ class DecoderModel:
         than its row bound (`ops/moe.routed_experts`), under the softmax
         router `balance_loss` [L_moe], and in a model with `sparse_attention`
         layers `selected_keys`, `index_kl` and `tie_split_chunks` [L_moe]
-        (zero for a layer of another kind).  `L`: the clean sequence's
-        length where `ids` hold more than it (`_rows`)."""
+        (zero for a layer of another kind); L_moe counts the expert layers,
+        under `layer_pattern` the `E` layers alone.  `L`: the clean
+        sequence's length where `ids` hold more than it (`_rows`)."""
         c = self.config
         dt = jnp.dtype(c.compute_dtype)
 
@@ -844,10 +1000,10 @@ class DecoderModel:
                            lp["mlp_gate"], lp["mlp_up"], lp["mlp_down"])
                 return x + y.astype(x.dtype)
 
-        def moe_ffn(x, lp, bias):
+        def moe_ffn(x, lp, bias, norm="norm2"):
             B, T, H = x.shape
             y, counts, over, *balance = expert_layer(
-                rms_norm(x, lp["norm2"], c.eps).astype(dt).reshape(B * T, H),
+                rms_norm(x, lp[norm], c.eps).astype(dt).reshape(B * T, H),
                 lp, bias, top_k=c.top_k, scale=c.routed_scale,
                 first_held=c.first_expert, eps=c.router_eps,
                 score=c.router_score)
@@ -870,10 +1026,14 @@ class DecoderModel:
         def expert_block(kind, under_scan):
             def moe_block(x, layer):
                 lp, bias = layer
-                # the router and the decay's parameters stay float32
+                # the router's and the recurrences' parameters stay float32
                 lp = {**cast(lp), **{name: lp[name] for name in
-                                     ("router", "A_log", "dt_bias")
+                                     ("router", "A_log", "dt_bias", "D")
                                      if name in lp}}
+                if c.layer_pattern is not None:     # one sub-block
+                    if kind == EXPERTS:
+                        return moe_ffn(x, lp, bias, "norm1")
+                    return self._operator(kind, L)(x, lp), {}
                 x = self._operator(kind, L)(x, lp)
                 counted = {}
                 if kind in ("sparse_attention", "linear_attention"):
@@ -890,7 +1050,13 @@ class DecoderModel:
         for i in range(c.n_dense_layers):
             x = dense_block(x, cast(jax.tree_util.tree_map(
                 lambda a: a[i], params["dense"])))
-        scanned = {kind: expert_block(kind, True) for kind in period}
+        # a scan of ONE trip is no loop to XLA: it inlines the body, and
+        # without `prevent_cse` merges the backward's recomputation with the
+        # forward, so that every activation of the period stays alive (a
+        # `layer_pattern` step compiled to 16.9 GB for a v5e, 12.6 with it).
+        # The interleaved configs keep the step they were measured with
+        scanned = {kind: expert_block(
+            kind, n > 1 or c.layer_pattern is None) for kind in period}
         if len(period) == 1:                # all alike: no remainder either
             return jax.lax.scan(scanned[period[0]], x,
                                 (params["moe"], router_bias))
@@ -898,26 +1064,33 @@ class DecoderModel:
         def over_layers(join, seen):
             return jax.tree_util.tree_map(lambda *a: join(a), *seen)
 
+        # the router bias and the counters: a row an expert layer
         def whole_period(x, layers):
             lps, bias = layers
             seen = []
             for j, kind in enumerate(period):
-                x, s_j = scanned[kind](x, (lps[j], bias[j]))
-                seen.append(s_j)
+                x, s_j = scanned[kind](
+                    x, (lps[j], bias[len(seen)] if c.routes(kind) else None))
+                if c.routes(kind):
+                    seen.append(s_j)
             return x, over_layers(jnp.stack, seen)
 
-        in_periods = n * len(period)
+        in_periods = n * sum(map(c.routes, period))
         x, seen = jax.lax.scan(
             whole_period, x,
             (params["moe"],
-             router_bias[:in_periods].reshape(n, len(period), -1)))
+             router_bias[:in_periods].reshape(n, -1, c.n_experts)))
         seen = [jax.tree_util.tree_map(
             lambda a: a.reshape(in_periods, *a.shape[2:]), seen)]
         unrolled = {kind: expert_block(kind, False) for kind in rest}
+        row = in_periods
         for j, kind in enumerate(rest):
             x, s_j = unrolled[kind](
-                x, (params["rest"][j], router_bias[in_periods + j]))
-            seen.append(jax.tree_util.tree_map(lambda a: a[None], s_j))
+                x, (params["rest"][j],
+                    router_bias[row] if c.routes(kind) else None))
+            if c.routes(kind):
+                seen.append(jax.tree_util.tree_map(lambda a: a[None], s_j))
+                row += 1
         return x, over_layers(jnp.concatenate, seen)
 
     def _logits(self, params, hidden):

@@ -77,6 +77,26 @@ def test_gated_short_conv_against_loops():
         [1.0, 12.0, 123.0, 234.0])
 
 
+def test_the_convolutions_bias_is_optional_and_adds_nothing_when_absent():
+    """Without a bias the function is the taps' sum as it was written before
+    the bias existed, bit for bit (float32 and bfloat16); with one, that sum
+    plus the bias a channel."""
+    r = np.random.default_rng(3)
+    for dt in (jnp.float32, jnp.bfloat16):
+        z = jnp.asarray(r.normal(size=(2, 9, 5)), dt)
+        k = jnp.asarray(r.normal(size=(4, 5)), dt)
+        padded = jnp.pad(z, [(0, 0), (3, 0), (0, 0)])
+        before = sum(k[j] * padded[:, j:j + 9] for j in range(4))
+        got = causal_depthwise_conv(z, k)
+        assert got.dtype == before.dtype
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(before, np.float32))
+        b = jnp.asarray(r.normal(size=5), dt)
+        np.testing.assert_array_equal(
+            np.asarray(causal_depthwise_conv(z, k, b), np.float32),
+            np.asarray(before + b, np.float32))
+
+
 def test_gated_short_conv_gradients_against_hand_written_ones():
     """dL/dk, dL/dB, dL/dC, dL/dX of the mix by hand (shifted multiply-adds
     again), the two products' by the chain rule, against autodiff."""

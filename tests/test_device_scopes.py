@@ -119,6 +119,14 @@ FRONT_ENDS = {
         ["updater", "router_bias"],
         ("embed", "param_cast", "gqa_attention", "linear_attention", "moe",
          "lm_head", "loss", "updater", "router_bias")),
+    "DecoderModel-mamba": (
+        lambda: DecoderModel(DecoderConfig.tiny_mamba(
+            compute_dtype="bfloat16"), seed=1), _decoder_step,
+        ["embed", "param_cast", "gqa_attention", "mamba_mixer", "ssd_scan",
+         "moe", "lm_head", "loss"],
+        ["updater", "router_bias"],
+        ("embed", "param_cast", "gqa_attention", "mamba_mixer", "moe",
+         "lm_head", "loss", "updater", "router_bias")),
     "DecoderModel-diffusion": (
         lambda: DecoderModel(DecoderConfig.tiny_diffusion(
             compute_dtype="bfloat16"), seed=1), _decoder_step,

@@ -108,10 +108,11 @@ def test_every_decoder_counter_is_documented():
         doc = f.read()
     names = set()
     for preset in ("tiny", "tiny_hybrid", "tiny_diffusion", "tiny_sparse",
-                   "tiny_linear"):
+                   "tiny_linear", "tiny_mamba"):
         names |= set(DecoderModel(getattr(DecoderConfig, preset)()).state_)
     names |= {"linear_attention", "delta_rule", "gqa_attention",
-              "sparse_index", "index_loss", "mla_attention"}
+              "sparse_index", "index_loss", "mla_attention", "mamba_mixer",
+              "ssd_scan"}
     missing = sorted(n for n in names if f"`{n}`" not in doc)
     assert not missing, missing
 
